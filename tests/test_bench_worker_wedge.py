@@ -1,10 +1,9 @@
 """The bench device-worker wedge watchdog (ISSUE 8 satellite).
 
-BENCH_r05's failure shape: the worker heartbeated ``init_wait`` for the
-full 900s init budget while the parent built CPU fixtures, then died as
-``worker_killed`` / ``init_budget_exhausted`` with no cause and
-``device_cache_built s:0.0``.  The fix moves wedge detection onto a
-monitor thread that runs from spawn and kills the worker with a NAMED
+The failure shape it answers: a worker that heartbeats ``init_wait``
+while the parent builds CPU fixtures and never comes up, ending as
+``worker_killed`` / ``init_budget_exhausted`` with no cause.  Wedge
+detection sits on a monitor thread that runs from spawn and kills the worker with a NAMED
 cause at BENCH_INIT_STALL seconds — these tests drive the monitor's
 verdict logic directly on a harness-free DeviceWorker instance (no real
 subprocess, no jax backend)."""
@@ -96,9 +95,9 @@ def test_ready_worker_never_wedges():
 
 
 def test_wait_ready_returns_timeout_on_wedge_without_burning_budget():
-    """wait_ready surfaces the monitor's verdict immediately — the 900s
-    init budget is NOT burned, and the monitor-kill eof is not mistaken
-    for a respawnable worker death."""
+    """wait_ready surfaces the monitor's verdict immediately — its budget
+    is NOT waited out, and the monitor-kill eof is not mistaken for a
+    worker that died by itself."""
     w = _bare_worker(stall_s=1.0)
     w._wedged = "backend_init_stall"
     t0 = time.monotonic()
@@ -154,3 +153,23 @@ def test_wait_ready_backstop_wedges_on_stale_init_wait():
     assert w.wait_ready(900.0) == "timeout"
     assert w._wedged == "backend_init_stall"
     assert w.proc.killed.is_set()
+
+
+def test_worker_runs_where_the_caller_says(monkeypatch):
+    """The worker inherits the caller's platform; only the explicit rehearsal
+    (BENCH_FORCE_CPU=1) pins it to the CPU.  There is no demotion path: the
+    parent ends the run when the worker is not where it should be."""
+    spawned = []
+
+    class _Popen(_FakeProc):
+        def __init__(self, argv, env=None, **kw):
+            super().__init__()
+            self.stdout = iter(())
+            spawned.append(env)
+
+    monkeypatch.setattr(bench.subprocess, "Popen", _Popen)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    bench.DeviceWorker([])
+    bench.DeviceWorker([], force_cpu=True)
+    assert [env["JAX_PLATFORMS"] for env in spawned] == ["tpu", "cpu"]
+    assert not hasattr(bench, "LocalDevice")

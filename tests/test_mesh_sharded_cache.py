@@ -170,6 +170,68 @@ def test_unary_warm_request_rides_mesh(endpoints):
     assert r.data == cpu.handle_request(_req(1, _sum_dag(60))).data
 
 
+@pytest.mark.parametrize("exc,surfaces", [(TypeError, True), (ValueError, False)])
+def test_mesh_rung_program_error_is_not_absorbed(endpoints, monkeypatch, exc, surfaces):
+    """A type/shape error while tracing the sharded program is a bug every
+    call repeats: it reaches the caller (RungProgramError) instead of being
+    counted as a device fault while the next rung answers — the jax 0.9.0
+    scan-carry break hid behind that for a release.  ValueError stays the
+    documented decline onto the single-device warm path."""
+    from tikv_tpu.copr import jax_eval
+    from tikv_tpu.copr.endpoint import RungProgramError
+
+    sharded, _single, cpu = endpoints
+    sharded.handle_request(_req(2, _sum_dag(70)))  # warm the image
+
+    def boom(*_a, **_kw):
+        raise exc("scan body function carry input and carry output must "
+                  "have equal types")
+
+    monkeypatch.setattr(jax_eval, "launch_xregion_sharded", boom)
+    fallbacks = sharded.device_fallbacks
+    if surfaces:
+        with pytest.raises(RungProgramError, match="mesh rung"):
+            sharded.handle_request(_req(2, _sum_dag(70)))
+    else:
+        r = sharded.handle_request(_req(2, _sum_dag(70)))
+        assert r.from_device
+        assert r.data == cpu.handle_request(_req(2, _sum_dag(70))).data
+    assert sharded.device_fallbacks == fallbacks
+    assert sharded.breaker.state_of("mesh") == "closed"
+
+
+def test_min_max_merge_over_64_bit_lanes_without_pmin_pmax():
+    """The TPU lowers only SUM all-reduces over 64-bit lanes, so min/max
+    partials merge by gather + local fold + psum: exact on int64 extremes
+    and on float signed zeros and infinities."""
+    from jax.sharding import PartitionSpec as P
+
+    from tikv_tpu.parallel.mesh import _collective, _smap
+
+    mesh = make_mesh(groups=1)
+    n = mesh.shape["regions"]
+    info = np.iinfo(np.int64)
+    ints = np.arange(n * 3, dtype=np.int64).reshape(n, 3) - 7
+    ints[0, 0], ints[1, 1] = info.min, info.max
+    flts = np.full((n, 3), 5.5)
+    flts[:, 0] = -0.0
+    flts[2, 1], flts[3, 2] = -np.inf, np.inf
+
+    def merged(kind, x):
+        f = _smap(mesh, (P("regions"),), P())(
+            lambda a: _collective(kind, a[0], "regions"))
+        return np.asarray(jax.jit(f)(x))
+
+    assert np.array_equal(merged("min", ints), ints.min(axis=0))
+    assert np.array_equal(merged("max", ints), ints.max(axis=0))
+    lo, hi = merged("min", flts), merged("max", flts)
+    assert np.array_equal(lo, flts.min(axis=0)) and np.signbit(lo[0])
+    assert np.array_equal(hi, flts.max(axis=0)) and np.signbit(hi[0])
+    text = jax.jit(_smap(mesh, (P("regions"),), P())(
+        lambda a: _collective("min", a[0], "regions"))).lower(ints).as_text()
+    assert "all_gather" in text
+
+
 def test_huge_region_block_spread():
     """A single region bigger than the per-device budget block-spreads over
     the mesh; the sharded program merges per-device partials with the

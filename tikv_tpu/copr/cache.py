@@ -9,7 +9,7 @@ holds decoded column blocks keyed by (region/range, data-version ts):
 
 * any query shape over the cached range skips scan+decode (CPU and TPU both)
 * the device path additionally pins each block's arrays in HBM on first use,
-  so steady-state queries are pure on-device compute — no PCIe/tunnel traffic
+  so steady-state queries are pure on-device compute — no host→device traffic
 
 Invalidation follows the reference's rule: the key includes the region's data
 version (apply index / max commit ts), so any write produces a new key.

@@ -42,6 +42,12 @@ _WIRE_STAGE_BUCKETS = (1e-5, 5e-5, 1e-4, 5e-4, 1e-3, 5e-3, 0.01, 0.05, 0.1,
                       0.5, 1, 5)
 
 
+class RungProgramError(RuntimeError):
+    """A serving rung raised a type/shape error while tracing its program: a
+    bug that every call repeats, so it reaches the caller instead of being
+    absorbed as a device fault by the next rung down."""
+
+
 @dataclass
 class CoprRequest:
     """coppb.Request equivalent."""
@@ -488,9 +494,9 @@ class Endpoint:
             except Exception as exc:
                 from .integrity import IntegrityMismatch
 
-                if isinstance(exc, IntegrityMismatch):
-                    raise  # TIKV_TPU_INTEGRITY_FATAL: surface, never mask
-                # device/runtime failure (compiler, tunnel, OOM): the CPU
+                if isinstance(exc, (IntegrityMismatch, RungProgramError)):
+                    raise  # fatal integrity / program bug: surface, never mask
+                # device/runtime failure (compiler, runtime, OOM): the CPU
                 # pipeline is the correctness oracle and always available —
                 # re-run there off the same immutable snapshot rather than
                 # surfacing an accelerator error to the client
@@ -1170,6 +1176,11 @@ class Endpoint:
             self.breaker.release_probe("mesh")
             count_path_fallback("mesh", "ineligible")
             return None
+        except TypeError as exc:
+            # a type/shape error at trace time is a bug in the program, not
+            # a device fault: every call would throw it, so no fallback
+            self.breaker.release_probe("mesh")
+            raise RungProgramError(f"mesh rung: {exc}") from exc
         except Exception as exc:  # noqa: BLE001 — single-device path serves
             self.breaker.record_failure("mesh")
             self.device_fallbacks += 1

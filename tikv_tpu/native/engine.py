@@ -16,6 +16,7 @@ import subprocess
 import threading
 from typing import Iterator
 
+from . import ensure_built
 from ..storage.engine import ALL_CFS, Cursor, KvEngine, Snapshot, WriteBatch
 from ..util.io_limiter import IoType
 
@@ -58,33 +59,13 @@ _lib_err: str | None = None
 _build_mu = threading.Lock()
 
 
-def _so_stale(so: str, *srcs: str) -> bool:
-    """True when the shared object predates ANY of its sources (the .cc
-    plus shared headers) — the one place the dependency list lives."""
-    if not os.path.exists(so):
-        return True
-    newest = max(
-        (os.path.getmtime(p) for p in srcs if os.path.exists(p)), default=0
-    )
-    return os.path.getmtime(so) < newest
-
-
-def _build() -> None:
-    subprocess.run(
-        ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", _SO, _SRC],
-        check=True,
-        capture_output=True,
-    )
-
-
 def _load():
     global _lib, _lib_err
     with _build_mu:
         if _lib is not None or _lib_err is not None:
             return _lib
         try:
-            if _so_stale(_SO, _SRC, os.path.join(_HERE, "crypt.h")):
-                _build()
+            ensure_built(_SO, _SRC, os.path.join(_HERE, "crypt.h"))
             lib = ctypes.CDLL(_SO)
         except (OSError, subprocess.CalledProcessError) as e:
             _lib_err = str(e)

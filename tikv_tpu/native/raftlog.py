@@ -16,6 +16,8 @@ import struct
 import subprocess
 import threading
 
+from . import ensure_built
+
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "raftlog.cc")
 _SO = os.path.join(_HERE, "libtikv_raftlog.so")
@@ -28,33 +30,13 @@ _U32 = struct.Struct("<I")
 _FRAME = struct.Struct("<QI")  # idx u64 | len u32
 
 
-def _so_stale(so: str, *srcs: str) -> bool:
-    """True when the shared object predates ANY of its sources (the .cc
-    plus shared headers) — the one place the dependency list lives."""
-    if not os.path.exists(so):
-        return True
-    newest = max(
-        (os.path.getmtime(p) for p in srcs if os.path.exists(p)), default=0
-    )
-    return os.path.getmtime(so) < newest
-
-
-def _build() -> None:
-    subprocess.run(
-        ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", _SO, _SRC],
-        check=True,
-        capture_output=True,
-    )
-
-
 def _load():
     global _lib, _lib_err
     with _build_mu:
         if _lib is not None or _lib_err is not None:
             return _lib
         try:
-            if _so_stale(_SO, _SRC, os.path.join(_HERE, "crypt.h")):
-                _build()
+            ensure_built(_SO, _SRC, os.path.join(_HERE, "crypt.h"))
             lib = ctypes.CDLL(_SO)
         except (OSError, subprocess.CalledProcessError) as e:
             _lib_err = str(e)

@@ -39,28 +39,17 @@ from ..copr.jax_eval import (
     XRegionPending,
     _build_cols,
     _fused_step,
+    _pack_region_leaves,
     _seg_extreme,
     _seg_sum,
     _topn_key_operands,
 )
 from ..copr.rpn import eval_rpn
 
-# shard_map moved to the jax top level (with ``check_vma``) after 0.4.x; on
-# 0.4.x it lives in jax.experimental with the replication check spelled
-# ``check_rep``.  One shim so every sharded program here compiles on both.
-if hasattr(jax, "shard_map"):
-    _SHARD_MAP, _SM_CHECK_KW = jax.shard_map, "check_vma"
-else:  # pragma: no cover - exercised on 0.4.x images
-    from jax.experimental.shard_map import shard_map as _SHARD_MAP
-
-    _SM_CHECK_KW = "check_rep"
-
-
 def _smap(mesh: Mesh, in_specs, out_specs, check: bool = True):
-    """Version-portable ``shard_map`` decorator."""
-    kw = {} if check else {_SM_CHECK_KW: False}
-    return partial(_SHARD_MAP, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, **kw)
+    """``jax.shard_map`` as a decorator over this module's mesh programs."""
+    return partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+                   out_specs=out_specs, check_vma=check)
 
 
 _KEY_SENTINEL = jnp.int64(2**62)  # empty group-dictionary slot (sorts last)
@@ -128,21 +117,30 @@ def _shard_active_cols(device_cols, nullable, sel_rpns, col_data, col_nulls, val
 def _collective(kind: str, x, axis: str):
     if kind == "sum":
         return jax.lax.psum(x, axis)
-    if kind == "min":
-        return jax.lax.pmin(x, axis)
-    if kind == "max":
-        return jax.lax.pmax(x, axis)
-    # bitwise monoids: no dedicated collective exists, so gather the shard
-    # partials and fold them with the XLA and/or/xor reduction.  The fold's
-    # result is identical on every member but shard_map cannot infer that
-    # statically, so a final psum (member 0 contributes, others add zero)
-    # re-establishes provable replication.
+    # min/max and the bitwise monoids: gather the shard partials and fold
+    # them locally.  The bitwise ones have no collective at all, and over
+    # 64-bit lanes (int64 and float64 alike) the TPU lowers SUM all-reduces
+    # only: its compiler refuses pmin/pmax ("Supported lowering only of Sum
+    # all reduce").  The fold's result is identical on every member but
+    # shard_map cannot infer that statically, so a final psum (member 0
+    # contributes, the others add zero) re-establishes provable replication.
     from ..copr.jax_eval import _BIT_FN, _BIT_IDENT
 
     g = jax.lax.all_gather(x, axis)
-    folded = jax.lax.reduce(g, jnp.int64(_BIT_IDENT[kind]), _BIT_FN[kind], (0,))
-    mine = jnp.where(jax.lax.axis_index(axis) == 0, folded, jnp.zeros_like(folded))
-    return jax.lax.psum(mine, axis)
+    if kind == "min":
+        folded = jnp.min(g, axis=0)
+    elif kind == "max":
+        folded = jnp.max(g, axis=0)
+    else:
+        folded = jax.lax.reduce(g, jnp.int64(_BIT_IDENT[kind]), _BIT_FN[kind], (0,))
+    first = jax.lax.axis_index(axis) == 0
+    out = jax.lax.psum(jnp.where(first, folded, jnp.zeros_like(folded)), axis)
+    if jnp.issubdtype(folded.dtype, jnp.floating):
+        # a sum cannot carry -0.0 (x + 0.0 is +0.0); its sign rides a flag
+        neg_zero = first & (folded == 0) & jnp.signbit(folded)
+        out = jnp.where(jax.lax.psum(neg_zero.astype(jnp.int32), axis) > 0,
+                        jnp.full_like(out, -0.0), out)
+    return out
 
 
 def _combine(kind: str, a, b):
@@ -805,6 +803,82 @@ def device_slab_load(caches, mesh) -> dict[int, int]:
     return load
 
 
+def _xshard_program(ev: JaxDagEvaluator, flat: Mesh, R: int, capacity: int,
+                    ship: tuple, nullable: tuple, group_cols, enc):
+    """The jitted ``shard_map`` program of :func:`launch_xregion_sharded`
+    over the 1-D ``regions`` mesh ``flat``: arguments are the per-ship-column
+    slab stacks and null masks, slab metadata (region slot, n_valid, row
+    offset), all sharded over ``regions``, then the replicated per-region
+    dictionary radices and frame-of-reference rows.  Apart from the mesh it
+    depends on shapes only, so it also compiles for a mesh of described
+    devices (tests/test_tpu_compile.py)."""
+    device_aggs = ev.device_aggs
+    sel_rpns = ev.sel_rpns
+    track_first = bool(ev.group_rpns)
+    n_rows = ev.block_rows
+    cap_total = R * capacity
+    in_specs = (
+        tuple(P("regions") for _ in ship),
+        tuple(P("regions") for _ in nullable),
+        P("regions"), P("regions"), P("regions"), P(), P(),
+    )
+
+    @_smap(flat, in_specs, (P(), P()))
+    def xfn(col_data, col_nulls, slab_region, n_valids, offsets, dl_arr,
+            ref_arr):
+        state = (
+            jnp.full(cap_total, _NO_ROW, dtype=jnp.int64),
+            tuple(da.init_carry(cap_total) for da in device_aggs),
+        )
+
+        def body(st, xs):
+            cd, cn, r, nv, off = xs
+            # per-slab in-kernel decode: the slab's region row of the
+            # frame-of-reference matrix widens its bitpacked lanes
+            cols = _build_cols(ship, nullable, cd, cn, n_rows, enc,
+                               None if enc is None else ref_arr[r])
+            local = jnp.zeros(n_rows, dtype=jnp.int64)
+            for k, gi in enumerate(group_cols):
+                codes, gnulls = cols[gi]
+                dlen = dl_arr[r, k]
+                local = local * (dlen + 1) + jnp.where(gnulls, dlen, codes)
+            # region-slot-segmented gids: slab r's rows land in the
+            # [r*capacity, (r+1)*capacity) segment window, so ONE fused
+            # step accumulates every region's state side by side
+            gids = r.astype(jnp.int64) * capacity + local
+            return _fused_step(
+                sel_rpns, device_aggs, cap_total, n_rows, cols, nv, gids,
+                off, st, track_first=track_first,
+            ), None
+
+        # the body's output varies over "regions" (it folds this device's
+        # slabs); the scan wants the initial carry typed the same way
+        state = jax.tree.map(
+            lambda l: jax.lax.pcast(l, ("regions",), to="varying"), state)
+        state, _ = jax.lax.scan(
+            body, state, (col_data, col_nulls, slab_region, n_valids, offsets)
+        )
+        first, carries = state
+        # cross-device merge: a region's slabs may live on one device
+        # (others contribute identity) or spread across several (a
+        # block-sharded huge region) — the leaf-wise collective rules
+        # cover both
+        first = _collective("min", first, "regions")
+        merged = tuple(
+            tuple(
+                _collective(kind, leaf, "regions")
+                for kind, leaf in zip(_MERGE[da.op], c)
+            )
+            for da, c in zip(device_aggs, carries)
+        )
+        leaves = [first] + jax.tree.leaves(merged)
+        return _pack_region_leaves(leaves, R, capacity)  # (R, L*, cap)
+
+    # lint: allow(jit-nocache) -- compiled once per batch geometry: the one
+    # caller, launch_xregion_sharded, keeps the result in ev._agg_fn_cache
+    return _obs.timed_jit(jax.jit(xfn), "mesh.xshard", "mesh", ev.obs_sig)
+
+
 def launch_xregion_sharded(ev: JaxDagEvaluator, caches, mesh: Mesh) -> XRegionPending:
     """ONE aggregation plan over R cached region images as ONE ``shard_map``
     program over EVERY device of ``mesh`` — the sharded twin of
@@ -961,67 +1035,8 @@ def launch_xregion_sharded(ev: JaxDagEvaluator, caches, mesh: Mesh) -> XRegionPe
            ship, nullable, len(group_cols), enc)
     fn = ev._agg_fn_cache.get(key)
     if fn is None:
-        device_aggs = ev.device_aggs
-        sel_rpns = ev.sel_rpns
-        track_first = bool(ev.group_rpns)
-        cap_total = R * capacity
-        in_specs = (
-            tuple(P("regions") for _ in ship),
-            tuple(P("regions") for _ in nullable),
-            P("regions"), P("regions"), P("regions"), P(), P(),
-        )
-
-        @_smap(flat, in_specs, (P(), P()))
-        def xfn(col_data, col_nulls, slab_region, n_valids, offsets, dl_arr,
-                ref_arr):
-            state = (
-                jnp.full(cap_total, _NO_ROW, dtype=jnp.int64),
-                tuple(da.init_carry(cap_total) for da in device_aggs),
-            )
-
-            def body(st, xs):
-                cd, cn, r, nv, off = xs
-                # per-slab in-kernel decode: the slab's region row of the
-                # frame-of-reference matrix widens its bitpacked lanes
-                cols = _build_cols(ship, nullable, cd, cn, n_rows, enc,
-                                   None if enc is None else ref_arr[r])
-                local = jnp.zeros(n_rows, dtype=jnp.int64)
-                for k, gi in enumerate(group_cols):
-                    codes, gnulls = cols[gi]
-                    dlen = dl_arr[r, k]
-                    local = local * (dlen + 1) + jnp.where(gnulls, dlen, codes)
-                # region-slot-segmented gids: slab r's rows land in the
-                # [r*capacity, (r+1)*capacity) segment window, so ONE fused
-                # step accumulates every region's state side by side
-                gids = r.astype(jnp.int64) * capacity + local
-                return _fused_step(
-                    sel_rpns, device_aggs, cap_total, n_rows, cols, nv, gids,
-                    off, st, track_first=track_first,
-                ), None
-
-            state, _ = jax.lax.scan(
-                body, state, (col_data, col_nulls, slab_region, n_valids, offsets)
-            )
-            first, carries = state
-            # cross-device merge: a region's slabs may live on one device
-            # (others contribute identity) or spread across several (a
-            # block-sharded huge region) — the leaf-wise collective rules
-            # cover both
-            first = _collective("min", first, "regions")
-            merged = tuple(
-                tuple(
-                    _collective(kind, leaf, "regions")
-                    for kind, leaf in zip(_MERGE[da.op], c)
-                )
-                for da, c in zip(device_aggs, carries)
-            )
-            from ..copr.jax_eval import _pack_region_leaves
-
-            leaves = [first] + jax.tree.leaves(merged)
-            return _pack_region_leaves(leaves, R, capacity)  # (R, L*, cap)
-
-        fn = _obs.timed_jit(jax.jit(xfn), "mesh.xshard", "mesh", ev.obs_sig)
-        ev._agg_fn_cache[key] = fn
+        fn = ev._agg_fn_cache[key] = _xshard_program(
+            ev, flat, R, capacity, ship, nullable, group_cols, enc)
         xkeys = [k for k in ev._agg_fn_cache if isinstance(k, tuple)
                  and k and k[0] == "xshard"]
         while len(xkeys) > 16:

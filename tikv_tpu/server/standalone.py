@@ -31,24 +31,31 @@ from .server import Server
 from .service import KvService
 
 
-def _default_mesh():
+def init_device_backend() -> list:
+    """Bring the JAX backend up and say what it found.  A store asked to
+    serve on the device does not start without one: whatever backend
+    initialisation raises reaches the caller.  ``JAX_PLATFORMS=cpu`` in the
+    caller's environment is the explicit way to run on the CPU."""
+    import jax
+
+    devices = jax.devices()
+    d = devices[0]
+    print(f"[standalone] device backend: platform={d.platform} "
+          f"kind={d.device_kind} count={len(devices)}", file=sys.stderr,
+          flush=True)
+    return devices
+
+
+def _default_mesh(devices: list):
     """A (regions × groups) mesh over every visible device when more than one
-    is present — the serving-path scale-out of BASELINE config #5.  Single
-    device (or an unreachable backend) serves single-device; the Endpoint's
-    CPU oracle remains the fallback either way."""
-    try:
-        import jax
-
-        from ..parallel.mesh import make_mesh
-
-        n = jax.device_count()
-        if n <= 1:
-            return None
-        return make_mesh(groups=2 if n % 2 == 0 else 1)
-    except Exception as exc:  # backend init failure must not block serving
-        print(f"[standalone] device mesh unavailable, serving single-device: "
-              f"{exc!r}", file=sys.stderr)
+    is present — the serving-path scale-out of BASELINE config #5.  A single
+    device serves single-device."""
+    n = len(devices)
+    if n <= 1:
         return None
+    from ..parallel.mesh import make_mesh
+
+    return make_mesh(devices, groups=2 if n % 2 == 0 else 1)
 
 
 def open_engine(path: str | None, keys_mgr=None):
@@ -105,6 +112,8 @@ class StoreServer:
         overload_max_priority: str = "high",
         cost_router: bool = True,
     ):
+        # first, before anything is opened: no backend, no store
+        self.devices = init_device_backend() if enable_device else []
         self.pd = pd
         self.security = security
         self._peer_clients: dict[int, object] = {}
@@ -170,7 +179,7 @@ class StoreServer:
         # to one proposal per command
         self.storage = Storage(engine=self.raftkv,
                                group_commit_max=16 if group_commit else 1)
-        mesh = _default_mesh() if enable_device else None
+        mesh = _default_mesh(self.devices) if enable_device else None
         # cost-based path routing (docs/cost_router.md): --no-cost-router
         # forces the kill switch regardless of TIKV_TPU_COST_ROUTER
         from ..copr.costmodel import CostRouter, GeometryTuner
@@ -669,6 +678,10 @@ def main(argv=None) -> int:
     if not security.enabled:
         security = None
 
+    if args.enable_device:
+        from ..util.compile_cache import place_compile_cache
+
+        place_compile_cache()
     host, port = args.pd.rsplit(":", 1)
     pd = RemotePd(host, int(port), security=security)
     srv = StoreServer(
