@@ -853,3 +853,126 @@ def test_topn_merges_a_block_in_chunks_byte_identically(k, order):
     assert rows < jax_eval.DEFAULT_BLOCK_ROWS and (k + rows) & (k + rows - 1) == 0
     want = BatchExecutorsRunner(dag, FixtureScanSource(kvs)).handle_request().encode()
     assert JaxDagEvaluator(dag).run(FixtureScanSource(kvs)).encode() == want
+
+
+# ---------------------------------------------------------------------------
+# programs are named after their timed_jit site (docs/tracing.md): the XLA
+# module's name is what the device profiler's trace tells programs apart by
+# ---------------------------------------------------------------------------
+
+
+def _timed_jit_sites() -> dict:
+    """Every ``timed_jit(...)`` site string in the program's source, by file."""
+    import ast
+    import os
+
+    import tikv_tpu
+
+    root = os.path.dirname(tikv_tpu.__file__)
+    found: dict = {}
+    for rel in ("copr/jax_eval.py", "copr/jax_zone.py", "copr/jax_join.py",
+                "parallel/mesh.py"):
+        tree = ast.parse(open(os.path.join(root, rel)).read())
+        for call_ in ast.walk(tree):
+            if not (isinstance(call_, ast.Call)
+                    and getattr(call_.func, "attr", "") == "timed_jit"):
+                continue
+            site = call_.args[1]
+            if isinstance(site, ast.Constant):
+                found.setdefault(rel, set()).add(site.value)
+            else:  # f"jax_join.{path}": the literal prefix
+                found.setdefault(rel, set()).add(site.values[0].value)
+    return found
+
+
+@pytest.fixture
+def dispatched(monkeypatch):
+    """{site: (jitted fn, argument shapes)} of every program dispatched
+    through ``timed_jit``'s wrapper while the fixture is live."""
+    import jax
+
+    from tikv_tpu.copr import observatory
+
+    seen: dict = {}
+    real = observatory._TimedJit.__call__
+
+    def spy(self, *args):
+        seen.setdefault(self.site, (self.fn, jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype)
+            if hasattr(a, "dtype") else a, args)))
+        return real(self, *args)
+
+    monkeypatch.setattr(observatory._TimedJit, "__call__", spy)
+    return seen
+
+
+def _run_jax_eval_family():
+    cols, kvs, _ = numeric_table_kvs(600)
+    run_both([TableScan(TABLE_ID, cols),
+              Selection([call("lt", col(1), const_int(500))]),
+              Aggregation([], [AggDescriptor("sum", col(2))])], kvs)
+    run_both([TableScan(TABLE_ID, cols),
+              Selection([call("lt", col(1), const_int(500))])], kvs)
+
+
+def _run_jax_zone_family():
+    import test_jax_zone as tz
+    from tikv_tpu.copr import jax_zone
+
+    old, jax_zone.TILE_ROWS = jax_zone.TILE_ROWS, 64
+    try:
+        cols, _kvs, cache = tz.mixed_table_kvs(3000)
+        dag = DagRequest(executors=[
+            TableScan(TABLE_ID, cols),
+            Selection([call("lt", col(1), const_int(4000))]),
+            Aggregation([col(3)], [AggDescriptor("sum", col(4))])])
+        ev = JaxDagEvaluator(dag, block_rows=1024)
+        ev.run(None, cache=cache)
+        assert ev._zone_evaluator().served == 1
+    finally:
+        jax_zone.TILE_ROWS = old
+
+
+def _run_jax_join_family():
+    from tikv_tpu.copr import jax_join
+
+    keys = np.arange(16, dtype=np.int64)
+    jax_join._kernel("rank")(keys, keys[:8].copy())
+
+
+def _run_mesh_family():
+    from tikv_tpu.parallel.mesh import ShardedDagEvaluator, make_mesh
+
+    cols, _kvs, (a, b, c) = numeric_table_kvs(1024)
+    dag = DagRequest(executors=[
+        TableScan(TABLE_ID, cols),
+        Selection([call("lt", col(1), const_int(500))]),
+        Aggregation([], [AggDescriptor("count", None)])])
+    mesh = make_mesh(groups=2)
+    ev = ShardedDagEvaluator(dag, mesh, 1024 // mesh.shape["regions"],
+                             capacity=16)
+    nulls = np.zeros(1024, dtype=bool)
+    ev.run_arrays({1: (a.astype(np.int64), nulls), 2: (b.astype(np.int64), nulls),
+                   3: (c.astype(np.int64), nulls)}, 1024,
+                  np.zeros(1024, dtype=np.int32))
+
+
+@pytest.mark.parametrize("family,run,expect", [
+    ("copr/jax_eval.py", _run_jax_eval_family, "jax_eval.agg_step"),
+    ("copr/jax_zone.py", _run_jax_zone_family, "jax_zone.full"),
+    ("copr/jax_join.py", _run_jax_join_family, "jax_join.rank"),
+    ("parallel/mesh.py", _run_mesh_family, "mesh.agg_step"),
+])
+def test_program_module_is_named_after_its_site(family, run, expect, dispatched):
+    """One program of each family, lowered at the shapes it ran with: the
+    XLA module is ``jit_<site, its dot an underscore>``, never ``jit_fn``."""
+    sites = _timed_jit_sites()
+    assert sites[family], f"no timed_jit site found in {family}"
+    run()
+    assert expect in dispatched, sorted(dispatched)
+    prefixes = tuple(sites[family])
+    for site, (fn, specs) in dispatched.items():
+        assert site.startswith(prefixes), (site, prefixes)
+        first = fn.lower(*specs).as_text().splitlines()[0]
+        assert first.startswith(f"module @jit_{site.replace('.', '_')} "), \
+            (site, first)

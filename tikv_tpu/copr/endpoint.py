@@ -275,17 +275,15 @@ class Endpoint:
         wire-stage histogram (stage=copr_encode) so response assembly stays
         attributable next to decode/route/execute/encode
         (docs/wire_path.md)."""
-        import time as _time
-
         from ..util.metrics import REGISTRY
 
-        t0 = _time.perf_counter()
-        parts = resp.encode_parts()
+        with trace.timed_stage("copr.encode") as st:
+            parts = resp.encode_parts()
         REGISTRY.histogram(
             "tikv_wire_stage_seconds",
             "Wire-path time per served frame, by stage",
             buckets=_WIRE_STAGE_BUCKETS,
-        ).observe(_time.perf_counter() - t0, stage="copr_encode")
+        ).observe(st.seconds, stage="copr_encode")
         return parts, resp.encode_type
 
     def handle_request(self, req: CoprRequest) -> CoprResponse:
@@ -361,7 +359,7 @@ class Endpoint:
         # sleep here inflates measured serve latency — what the
         # observatory floor gate's regression test injects
         fail_point("coprocessor_serve")
-        with trace.span("copr.snapshot"):
+        with trace.stage("copr.snapshot"):
             snap = self.engine.snapshot(stale_read_ctx(req))
         tracker.on_snapshot_finished()
         # follower stale serving (docs/stale_reads.md): the snapshot itself
@@ -783,26 +781,27 @@ class Endpoint:
             # kill switch: skip even the dag_sig walk — a disabled
             # observatory must cost the hot path nothing
             return
-        sig = getattr(ev, "obs_sig", "") if ev is not None else ""
-        desc = getattr(ev, "obs_desc", "") if ev is not None else ""
-        if not sig:
-            try:
-                sig, desc = _obs.dag_sig(req.dag)
-            except Exception:  # noqa: BLE001 — profiling must not fail serving
-                return
-        tracker.metrics.serve_path = path
-        tracker.metrics.plan_sig = sig
-        m = tracker.metrics
-        # zone-map pruning effectiveness rides the profile (docs/zone_maps.md)
-        prune = getattr(resp, "_obs_prune", None) or (0, 0)
-        # device-join magnitudes ride it too (docs/device_join.md)
-        jn = getattr(resp, "_obs_join", None) or (0, 0, 0)
-        _obs.OBSERVATORY.record_serve(
-            sig, path, m.total_s, rows=rows, encoding=encoding,
-            queue_wait_s=m.schedule_wait_s, trace_id=tracker.trace_id,
-            desc=desc, blocks_examined=prune[0], blocks_pruned=prune[1],
-            join_build_rows=jn[0], join_probe_rows=jn[1],
-            join_out_rows=jn[2])
+        with trace.stage("copr.obs"):
+            sig = getattr(ev, "obs_sig", "") if ev is not None else ""
+            desc = getattr(ev, "obs_desc", "") if ev is not None else ""
+            if not sig:
+                try:
+                    sig, desc = _obs.dag_sig(req.dag)
+                except Exception:  # noqa: BLE001 — profiling must not fail serving
+                    return
+            tracker.metrics.serve_path = path
+            tracker.metrics.plan_sig = sig
+            m = tracker.metrics
+            # zone-map pruning effectiveness rides the profile (docs/zone_maps.md)
+            prune = getattr(resp, "_obs_prune", None) or (0, 0)
+            # device-join magnitudes ride it too (docs/device_join.md)
+            jn = getattr(resp, "_obs_join", None) or (0, 0, 0)
+            _obs.OBSERVATORY.record_serve(
+                sig, path, m.total_s, rows=rows, encoding=encoding,
+                queue_wait_s=m.schedule_wait_s, trace_id=tracker.trace_id,
+                desc=desc, blocks_examined=prune[0], blocks_pruned=prune[1],
+                join_build_rows=jn[0], join_probe_rows=jn[1],
+                join_out_rows=jn[2])
 
     def _cpu_bytes(self, req: CoprRequest, snap) -> bytes:
         """The CPU-oracle answer to ``req`` off ``snap`` — the byte-identity
@@ -819,6 +818,11 @@ class Endpoint:
         on mismatch the backing image is quarantined, the mismatch counts
         under stage=shadow_read, and the CPU bytes return for the caller to
         serve — zero wrong bytes reach the sampled client."""
+        with trace.stage("copr.shadow", path=path):
+            return self._shadow_compare(req, snap, device_data, path)
+
+    def _shadow_compare(self, req: CoprRequest, snap, device_data: bytes,
+                        path: str) -> bytes | None:
         from .integrity import IntegrityMismatch, count_mismatch, integrity_fatal
 
         # the device answer is an exposure: it is held across the oracle
@@ -1059,17 +1063,19 @@ class Endpoint:
         from ..server import wire
         from .dag_wire import dag_to_wire
 
-        key = wire.dumps(dag_to_wire(dag))
-        ev = self._evaluators.get(key)
-        if ev is None:
-            if self.block_rows is not None:
-                ev = jax_eval.JaxDagEvaluator(dag, block_rows=self.block_rows,
-                                              breaker=self.breaker)
-            else:
-                ev = jax_eval.JaxDagEvaluator(dag, breaker=self.breaker)
-            self._evaluators[key] = ev
-            while len(self._evaluators) > 64:
-                self._evaluators.pop(next(iter(self._evaluators)))
+        with trace.stage("copr.evaluator") as st:
+            key = wire.dumps(dag_to_wire(dag))
+            ev = self._evaluators.get(key)
+            if ev is None:
+                st.tag(built=True)
+                if self.block_rows is not None:
+                    ev = jax_eval.JaxDagEvaluator(
+                        dag, block_rows=self.block_rows, breaker=self.breaker)
+                else:
+                    ev = jax_eval.JaxDagEvaluator(dag, breaker=self.breaker)
+                self._evaluators[key] = ev
+                while len(self._evaluators) > 64:
+                    self._evaluators.pop(next(iter(self._evaluators)))
         return ev
 
     def device_enabled(self) -> bool:
@@ -1106,14 +1112,17 @@ class Endpoint:
         from . import encoding as _encoding
         from . import observatory as _obs
 
-        try:
-            sig, desc = _obs.dag_sig(req.dag)
-        except Exception:  # noqa: BLE001 — routing must not fail serving
-            return None
-        cands = _encoding.candidate_paths(
-            req.dag, device_ok=True,
-            mesh_ok=self._mesh_would_serve(req.dag))
-        return router.route(sig, cands, desc=desc)
+        with trace.stage("copr.route") as st:
+            try:
+                sig, desc = _obs.dag_sig(req.dag)
+            except Exception:  # noqa: BLE001 — routing must not fail serving
+                return None
+            cands = _encoding.candidate_paths(
+                req.dag, device_ok=True,
+                mesh_ok=self._mesh_would_serve(req.dag))
+            route = router.route(sig, cands, desc=desc)
+            st.tag(path=route.path)
+        return route
 
     def _note_route_delta(self, delta_ms: float, best_ms: float | None) -> None:
         if self.overload is not None:

@@ -63,6 +63,12 @@ from .groupby import GroupDict
 from .rpn import ColumnRef, RpnExpression, compile_expr, eval_rpn
 from .table import RowBatchDecoder, decode_record_handles
 
+# this module owns the device, so it is the one that mirrors the program's
+# stages into the device profiler's trace (docs/tracing.md): util/trace.py
+# stays importable without jax.  With no profiler session an annotation is
+# one atomic load.
+trace.set_mirror(jax.profiler.TraceAnnotation)
+
 DEFAULT_BLOCK_ROWS = 1 << 16
 _GROUP_CAPACITY_START = 1024
 _NO_ROW = 1 << 62  # first-active-row sentinel: "no row of this group survived"
@@ -1082,7 +1088,8 @@ class JaxDagEvaluator:
                 nv_dev = _masked_nv(blocks, keep)
             scan_fn = self._build_scan_fn_coded(dict_lens, capacity, n_blocks, group_cols, enc)
             packed = scan_fn(col_data, col_nulls, nv_dev, off_dev, refs)
-            state_np = _unpack_state(packed, self._host_state_template())
+            with trace.stage("device.pull"):
+                state_np = _unpack_state(packed, self._host_state_template())
 
             def key_of(slot: int) -> tuple:
                 parts = []
@@ -1119,7 +1126,8 @@ class JaxDagEvaluator:
             nv_dev = _masked_nv(blocks, keep)
         scan_fn = self._build_scan_fn(capacity, n_blocks, enc)
         packed = scan_fn(col_data, col_nulls, nv_dev, all_gids, off_dev, refs)
-        state_np = _unpack_state(packed, self._host_state_template())
+        with trace.stage("device.pull"):
+            state_np = _unpack_state(packed, self._host_state_template())
         resp = self._finalize_agg(state_np, n_slots, lambda r: groups.rows[r])
         resp._obs_encoding = "encoded" if enc else "plain"
         if prune_stats[0]:
@@ -1242,11 +1250,6 @@ class JaxDagEvaluator:
 
     def run(self, source: ScanSource, cache: "ColumnBlockCache | None" = None) -> SelectResponse:
         self._cache = cache
-        # first run of an evaluator traces+compiles its XLA programs; later
-        # runs reuse the jit caches — the tag separates compile cost from
-        # steady-state execute+pull in the trace timeline (docs/tracing.md)
-        first = not getattr(self, "_trace_ran", False)
-        self._trace_ran = True
         if self.plan.agg is not None:
             path = "agg_cached" if (cache is not None and cache.filled
                                     and cache.blocks) else "agg"
@@ -1255,7 +1258,9 @@ class JaxDagEvaluator:
         else:
             path = "scan"
         try:
-            with trace.span("device.run", path=path, first_call=first):
+            # a container: its stages are device.prepare/launch/pull/finalize
+            # (a compile shows as device.launch's tag ``compiled``)
+            with trace.span("device.run", path=path):
                 if self.plan.agg is not None:
                     if cache is not None and cache.filled and cache.blocks:
                         return self._run_aggregated_cached(cache)
@@ -1474,10 +1479,16 @@ class JaxDagEvaluator:
             pack_fn = _obs.timed_jit(jax.jit(_pack_state), "jax_eval.pack",
                                      "unary", self.obs_sig)
             self._agg_fn_cache[pack_key] = pack_fn
-        state_np = _unpack_state(pack_fn(state), state)
+        packed = pack_fn(state)
+        with trace.stage("device.pull"):
+            state_np = _unpack_state(packed, state)
         return self._finalize_agg(state_np, n_slots, lambda r: groups.rows[r])
 
     def _finalize_agg(self, state, n_slots: int, key_of) -> SelectResponse:
+        with trace.stage("device.finalize"):
+            return self._finalize_agg_pulled(state, n_slots, key_of)
+
+    def _finalize_agg_pulled(self, state, n_slots: int, key_of) -> SelectResponse:
         first_row, carries = state
         first_np = np.asarray(first_row)
         alive = np.flatnonzero(first_np[:n_slots] != _NO_ROW) if self.group_rpns else np.array([0])
@@ -1881,8 +1892,9 @@ def run_batch_cached(evaluators: list["JaxDagEvaluator"], cache) -> list[SelectR
         # the compiled program and its pins stay byte-for-byte identical
         nv_dev = _masked_nv(blocks, keep)
     int_m, flt_m = fn(col_data, col_nulls, nv_dev, off_dev, refs)
-    int_np = np.asarray(int_m)
-    flt_np = np.asarray(flt_m) if flt_m.shape[0] else None
+    with trace.stage("device.pull"):
+        int_np = np.asarray(int_m)
+        flt_np = np.asarray(flt_m) if flt_m.shape[0] else None
     out = []
     ii = fi = 0
     for ev, _gc, dicts, dict_lens, cap, n_slots in specs:
@@ -1946,9 +1958,19 @@ class XRegionPending:
         byte-identical to per-request serving."""
         ev = self._ev
         int_m, flt_m = self._packed
-        with trace.span("device.pull", regions=len(self._specs)):
+        with trace.stage("device.pull", regions=len(self._specs)):
             int_np = np.asarray(int_m)
             flt_np = np.asarray(flt_m) if flt_m.shape[1] else None
+        with trace.stage("device.release"):
+            # the packed states' device buffers are dropped here and not
+            # when the batch is forgotten: freeing them waits on the runtime
+            self._packed = None
+            del int_m, flt_m
+        with trace.stage("device.finalize", regions=len(self._specs)):
+            return self._finalize_pulled(int_np, flt_np)
+
+    def _finalize_pulled(self, int_np, flt_np) -> list[SelectResponse]:
+        ev = self._ev
         template = ev._host_state_template()
         out = []
         for r, (dicts, dict_lens, n_slots) in enumerate(self._specs):
@@ -1965,7 +1987,7 @@ class XRegionPending:
                     parts.append(None if c == dl else bytes(d[c]))
                 return tuple(reversed(parts))
 
-            resp = ev._finalize_agg(state_np, n_slots, key_of)
+            resp = ev._finalize_agg_pulled(state_np, n_slots, key_of)
             if self._prunes is not None and self._prunes[r][0]:
                 resp._obs_prune = self._prunes[r]
             out.append(resp)
@@ -2046,6 +2068,14 @@ def launch_xregion_cached(ev: "JaxDagEvaluator", caches) -> XRegionPending:
     batchable (non-aggregation plan, unstable group dictionaries, empty
     cache); the scheduler sheds those to the per-request path.
     """
+    with trace.stage("device.prepare", path="xregion"):
+        return _launch_xregion_cached(ev, caches)
+
+
+def _launch_xregion_cached(ev: "JaxDagEvaluator", caches) -> XRegionPending:
+    # everything here but the dispatch itself (a stage of its own, which
+    # suspends this one) is host work: eligibility, geometry, the regions'
+    # pinned inputs, pruning
     from . import encoding as _encoding
 
     specs, group_cols, capacity = xregion_specs(ev, caches)
@@ -2157,11 +2187,13 @@ def launch_xregion_cached(ev: "JaxDagEvaluator", caches) -> XRegionPending:
         while len(xkeys) > 16:
             ev._agg_fn_cache.pop(xkeys.pop(0))
 
-    # the async dispatch itself; the encoded-path decision batch_plan made
-    # (and counted) rides the trace as a tag (docs/tracing.md)
-    with trace.span("device.launch", kind="xregion", regions=len(caches),
-                    encoding="encoded" if plans else "decoded"):
-        packed = fn(tuple(region_inputs), dl_arr, refs_arr)
+    # the async dispatch itself (stage device.launch, in timed_jit's
+    # wrapper); the encoded-path decision batch_plan made (and counted)
+    # rides the dispatch span as a tag (docs/tracing.md)
+    cur = trace.current()
+    if cur is not None:
+        cur.tag(encoding="encoded" if plans else "decoded")
+    packed = fn(tuple(region_inputs), dl_arr, refs_arr)
     pending = XRegionPending(ev, specs, capacity, packed, order, prunes)
     # observatory encoding label for the riders' profiles
     pending.obs_encoding = "encoded" if plans else "plain"

@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from ..analysis.sanitizer import note_blocking
+from ..util import trace
 from . import observatory as _obs
 from .datatypes import EvalType
 from .rpn import RpnExpression, eval_rpn
@@ -694,63 +695,72 @@ class ZoneEvaluator:
     def _try_run_inner(self, cache):
         from .tracker import count_path_fallback
 
-        ev = self.ev
-        blocks = cache.blocks
-        if cache in self._declined:
-            return None
-        el = self.eligible(blocks)
-        if el is None:
-            return None
-        group_cols, dicts = el
-        if self.ev.sel_rpns and all(
-            _recognize_conjunct(r) is None for r in self.ev.sel_rpns
-        ):
-            # no conjunct classifiable → 100% partial tiles: don't pay for a
-            # layout the fallback check would immediately discard
-            self._declined.add(cache)
-            count_path_fallback("zone", "unclassifiable_selection")
-            return None
-        needed = self._referenced_cols()
-        sort_col = None
-        for rpn in ev.sel_rpns:
-            rec = _recognize_conjunct(rpn)
-            if rec is not None and rec[0] not in group_cols and ev.schema[rec[0]][0] != EvalType.REAL:
-                sort_col = rec[0]
-                break
-        layout = build_layout(cache, group_cols, dicts, sort_col, needed, ev.schema)
-        full, partial_idx = self._classify_tiles(layout)
-        if layout.n_tiles and len(partial_idx) / layout.n_tiles > PARTIAL_FALLBACK:
-            self._declined.add(cache)
-            count_path_fallback("zone", "partial_fraction")
-            return None
-        n_slots = layout.n_slots
-        capacity = 1
-        while capacity < n_slots + 1:  # +1: scratch slot for partial padding
-            capacity *= 2
+        # host work before the dispatches: eligibility, the layout (built
+        # once an image), tile classification, the masks' transfer; each
+        # dispatch is a stage of its own and suspends this one
+        with trace.stage("device.prepare", path="zone"):
+            ev = self.ev
+            blocks = cache.blocks
+            if cache in self._declined:
+                return None
+            el = self.eligible(blocks)
+            if el is None:
+                return None
+            group_cols, dicts = el
+            if self.ev.sel_rpns and all(
+                _recognize_conjunct(r) is None for r in self.ev.sel_rpns
+            ):
+                # no conjunct classifiable → 100% partial tiles: don't pay for a
+                # layout the fallback check would immediately discard
+                self._declined.add(cache)
+                count_path_fallback("zone", "unclassifiable_selection")
+                return None
+            needed = self._referenced_cols()
+            sort_col = None
+            for rpn in ev.sel_rpns:
+                rec = _recognize_conjunct(rpn)
+                if rec is not None and rec[0] not in group_cols and ev.schema[rec[0]][0] != EvalType.REAL:
+                    sort_col = rec[0]
+                    break
+            layout = build_layout(cache, group_cols, dicts, sort_col, needed, ev.schema)
+            full, partial_idx = self._classify_tiles(layout)
+            if layout.n_tiles and len(partial_idx) / layout.n_tiles > PARTIAL_FALLBACK:
+                self._declined.add(cache)
+                count_path_fallback("zone", "partial_fraction")
+                return None
+            n_slots = layout.n_slots
+            capacity = 1
+            while capacity < n_slots + 1:  # +1: scratch slot for partial padding
+                capacity *= 2
 
-        have_full = bool(full.any())
-        have_partial = len(partial_idx) > 0
-        states = []
-        if have_full:
-            fn = self._full_fn(layout, capacity)
-            states.append(fn(layout.dev, jnp.asarray(full)))
-        if have_partial:
-            pcap = 64
-            while pcap < len(partial_idx):
-                pcap *= 2
-            pidx = np.zeros(pcap, dtype=np.int32)
-            pidx[: len(partial_idx)] = partial_idx
-            pw = np.zeros(pcap, dtype=bool)
-            pw[: len(partial_idx)] = True
-            fn = self._partial_fn(layout, capacity, pcap)
-            states.append(fn(layout.dev, jnp.asarray(pidx), jnp.asarray(pw)))
-        if not states:
-            # every tile proved empty: zero contributions
-            states.append(
-                self._full_fn(layout, capacity)(layout.dev, jnp.zeros(layout.n_tiles, dtype=bool))
-            )
-        merged = states[0] if len(states) == 1 else _merge_states(ev.device_aggs, states[0], states[1])
-        state_np = jax.tree.map(np.asarray, merged)
+            have_full = bool(full.any())
+            have_partial = len(partial_idx) > 0
+            states = []
+            if have_full:
+                fn = self._full_fn(layout, capacity)
+                states.append(fn(layout.dev, jnp.asarray(full)))
+            if have_partial:
+                pcap = 64
+                while pcap < len(partial_idx):
+                    pcap *= 2
+                pidx = np.zeros(pcap, dtype=np.int32)
+                pidx[: len(partial_idx)] = partial_idx
+                pw = np.zeros(pcap, dtype=bool)
+                pw[: len(partial_idx)] = True
+                fn = self._partial_fn(layout, capacity, pcap)
+                states.append(fn(layout.dev, jnp.asarray(pidx), jnp.asarray(pw)))
+            if not states:
+                # every tile proved empty: zero contributions
+                states.append(
+                    self._full_fn(layout, capacity)(layout.dev, jnp.zeros(layout.n_tiles, dtype=bool))
+                )
+            merged = states[0] if len(states) == 1 else _merge_states(ev.device_aggs, states[0], states[1])
+        with trace.stage("device.pull"):
+            state_np = jax.tree.map(np.asarray, merged)
+        with trace.stage("device.release"):
+            # the states' device buffers are dropped here and not at the
+            # function's return: freeing them waits on the runtime
+            del states, merged
 
         dict_lens = layout.dict_lens
         dicts_l = layout.dicts

@@ -46,6 +46,7 @@ import os
 import time
 
 from ..analysis.sanitizer import make_lock
+from ..util import trace
 from ..util.metrics import REGISTRY, percentile_from_buckets
 
 __all__ = [
@@ -746,9 +747,12 @@ def floor_diff(floor: dict, current: dict, ratio: float = 2.0,
 
 
 class _TimedJit:
-    """Wraps an ALREADY-jitted callable: steady-state calls pay one C-level
-    ``_cache_size()`` probe and an int compare; a call that grew the
-    executable cache records a compile event (wall = that call's whole
+    """Wraps an ALREADY-jitted callable, the one funnel of every jitted
+    dispatch: the call is the stage ``device.launch`` (docs/tracing.md: host
+    time inside the jitted call — argument handling, dispatch, and compile
+    or cache fetch when there is one).  Steady-state calls also pay one
+    C-level ``_cache_size()`` probe and an int compare; a call that grew
+    the executable cache records a compile event (wall = that call's whole
     duration).  XLA cost/memory analysis is attempted only under
     ``TIKV_TPU_OBS_XLA_ANALYSIS=1`` (it costs a second lowering, and
     donated buffers can make it impossible after the fact — failures are
@@ -770,39 +774,60 @@ class _TimedJit:
             return None
 
     def __call__(self, *args):
-        t0 = time.perf_counter()
-        out = self.fn(*args)
-        # the post-call probe is the only reliable compile detector: a new
-        # argument SHAPE compiles even when the cache was already warm, so
-        # a pre-call fast path would miss every recompile after the first
-        after = self._cache_size()
-        if after is not None and after != self._seen:
-            wall = time.perf_counter() - t0
-            flops = nbytes = None
-            if OBSERVATORY.xla_analysis:
-                try:
-                    compiled = self.fn.lower(*args).compile()
-                    ca = compiled.cost_analysis()
-                    if isinstance(ca, (list, tuple)):
-                        ca = ca[0] if ca else {}
-                    flops = float(ca.get("flops", 0.0)) or None
-                    nbytes = float(ca.get("bytes accessed", 0.0)) or None
-                except Exception:  # noqa: BLE001 — analysis is best-effort
-                    pass
-            OBSERVATORY.record_compile(
-                self.site, self.path, wall, sig=self.sig,
-                cache_size=after, flops=flops, bytes_accessed=nbytes)
+        with trace.timed_stage("device.launch", site=self.site) as st:
+            out = self.fn(*args)
+            # the post-call probe is the only reliable compile detector: a
+            # new argument SHAPE compiles even when the cache was already
+            # warm, so a pre-call fast path would miss every recompile
+            # after the first
+            after = self._cache_size()
+            grew = after is not None and after != self._seen
+            if grew:
+                st.tag(compiled=True)
+        if grew:
             self._seen = after
+            _DEVICE_COMPILES.inc(site=self.site)
+            if OBSERVATORY.enabled:
+                self._record_compile(args, st.seconds, after)
         return out
+
+    def _record_compile(self, args, wall: float, cache_size: int) -> None:
+        flops = nbytes = None
+        if OBSERVATORY.xla_analysis:
+            try:
+                ca = self.fn.lower(*args).compile().cost_analysis()
+                if isinstance(ca, (list, tuple)):
+                    ca = ca[0] if ca else {}
+                flops = float(ca.get("flops", 0.0)) or None
+                nbytes = float(ca.get("bytes accessed", 0.0)) or None
+            except Exception:  # noqa: BLE001 — analysis is best-effort
+                pass
+        OBSERVATORY.record_compile(
+            self.site, self.path, wall, sig=self.sig,
+            cache_size=cache_size, flops=flops, bytes_accessed=nbytes)
+
+
+_DEVICE_COMPILES = REGISTRY.counter(
+    "tikv_device_compile_total",
+    "Jitted calls that grew their executable cache (a compile or a fetch "
+    "from the persistent cache), by timed_jit site")
 
 
 def timed_jit(fn, site: str, path: str, sig: str = ""):
-    """Hook a jitted callable into the device-cost ledger.  Call sites keep
-    their literal ``jax.jit(...)`` (the static-analysis jit rules still see
-    it) and wrap the result: ``timed_jit(jax.jit(f), "jax_eval.scan",
-    "unary", sig=self.obs_sig)``."""
-    if not OBSERVATORY.enabled:
-        return fn
+    """The funnel of every jitted dispatch.  Call sites keep their literal
+    ``jax.jit(...)`` (the static-analysis jit rules still see it) and wrap
+    the result: ``timed_jit(jax.jit(f), "jax_eval.scan", "unary",
+    sig=self.obs_sig)``.  The program takes its site's name, the dot as an
+    underscore (``jit_jax_eval_scan``): that is the XLA module's name in the
+    device profiler's trace, and JAX reads it when it first traces ``f``.
+    The wrapper is the stage ``device.launch`` and feeds the device-cost
+    ledger."""
+    inner = getattr(fn, "__wrapped__", None)
+    if inner is not None:
+        try:
+            inner.__name__ = inner.__qualname__ = site.replace(".", "_")
+        except (AttributeError, TypeError):  # no writable name: XLA's default
+            pass
     return _TimedJit(fn, site, path, sig)
 
 

@@ -78,6 +78,7 @@ from ..storage.engine import CF_DEFAULT, CF_LOCK, CF_WRITE
 from ..storage.mvcc import Statistics
 from ..storage.mvcc.reader import _check_lock
 from ..storage.txn_types import Key, Write, WriteType, append_ts, split_ts
+from ..util import trace
 from . import encoding as _encoding
 from . import integrity as _integrity
 from .cache import ColumnBlockCache
@@ -817,6 +818,15 @@ class RegionColumnCache:
 
         Returns ``(block_cache | None, outcome, delta_rows)``; a None block
         cache means "serve through the normal path" (outcome says why)."""
+        # the stage is the whole call LESS the lock check and any build or
+        # delta repair: those are stages of their own, and suspend this one
+        with trace.stage("cache.lookup") as st:
+            out = self._serve(snap, context, columns_info, ranges, start_ts,
+                              statistics)
+            st.tag(outcome=out[1])
+        return out
+
+    def _serve(self, snap, context, columns_info, ranges, start_ts, statistics):
         region_id = (context or {}).get("region_id")
         epoch = _epoch_of((context or {}).get("region_epoch"))
         apply_index = (context or {}).get("apply_index")
@@ -913,11 +923,13 @@ class RegionColumnCache:
                     "deleted_handles": np.array(sorted(pend["deleted"]), dtype=np.int64),
                     "max_commit_ts": max(img.max_commit_ts, pend["max_ct"]),
                 }
-                n = img.apply_delta(delta, apply_index, start_ts)
-                img.wt_pending = None
-                if self.devices:
-                    self._unplace(img)
-                    self._place(img)
+                with trace.stage("cache.fill", kind="wt_delta") as st:
+                    n = img.apply_delta(delta, apply_index, start_ts)
+                    img.wt_pending = None
+                    if self.devices:
+                        self._unplace(img)
+                        self._place(img)
+                    st.tag(rows=n)
                 self.stats.wt_deltas += 1
                 self.stats.wt_rows += n
                 self._count("wt_delta")
@@ -925,11 +937,12 @@ class RegionColumnCache:
                 self._enforce_budget(keep=key)
                 self._gauge_bytes(full=False)
                 return img.block_cache, "wt_delta", n
-            # lint: allow(lock-blocking-call) -- the fold-in must be atomic
-            # with the image version bump (docs: Concurrency); the scan is
-            # bounded by the delta size, and cold BUILDS run outside the lock
-            delta = scan_delta(snap, start_ts, ranges, img.handles,
-                               img.row_commit_ts, statistics=stats)
+            with trace.stage("cache.fill", kind="scan_delta"):
+                # lint: allow(lock-blocking-call) -- the fold-in must be atomic
+                # with the image version bump (docs: Concurrency); the scan is
+                # bounded by the delta size, and cold BUILDS run outside the lock
+                delta = scan_delta(snap, start_ts, ranges, img.handles,
+                                   img.row_commit_ts, statistics=stats)
             if delta is None:
                 self.stats.uncacheable += 1
                 self._count("uncacheable")
@@ -941,7 +954,9 @@ class RegionColumnCache:
                 return self._build(key, epoch, snap, columns_info, ranges,
                                    start_ts, apply_index, stats,
                                    tenant=tenant)
-            n = img.apply_delta(delta, apply_index, start_ts)
+            with trace.stage("cache.fill", kind="delta") as st:
+                n = img.apply_delta(delta, apply_index, start_ts)
+                st.tag(rows=n)
             if apply_index >= img.locks_dirty_at:
                 # scan_delta lock-checked the ranges on a snapshot that
                 # contains the dirtying batch
@@ -955,8 +970,9 @@ class RegionColumnCache:
             if self.devices:
                 # a structural repack can change the block count and bytes:
                 # refresh the placement so owner_devices stays block-aligned
-                self._unplace(img)
-                self._place(img)
+                with trace.stage("cache.fill", kind="place"):
+                    self._unplace(img)
+                    self._place(img)
             self.stats.deltas += 1
             self.stats.delta_rows += n
             self._count("delta")
@@ -1445,42 +1461,44 @@ class RegionColumnCache:
         reentrant); a racing build of the same key keeps whichever image
         reflects the newer apply index — this request serves its own blocks
         either way."""
-        src = MvccBatchScanSource(snap, start_ts, ranges, statistics=stats,
-                                  record_versions=True)
-        keys, values = src._resolve_all()
-        if not src.versions_exact:
-            self.stats.uncacheable += 1
-            self._count("uncacheable")
-            return None, "uncacheable", 0
-        handles = decode_record_handles(keys)
-        if len(handles) > 1 and not (handles[1:] > handles[:-1]).all():
-            self.stats.uncacheable += 1
-            self._count("uncacheable")
-            return None, "uncacheable", 0
-        img = RegionImage(key, epoch, list(columns_info), self.block_rows)
-        img.tenant = tenant
-        img.fill(handles, values, src.row_commit_ts, src.max_commit_ts,
-                 apply_index, start_ts, raw_keys=keys,
-                 encode=self.encode_columns)
-        if img.nbytes > self.byte_budget:
-            self.stats.uncacheable += 1
-            self._count("too_big")
-            # serve this request from the just-built blocks, but don't keep
-            # them resident — the budget is the OOM guard
-            return img.block_cache, "too_big", 0
-        with self._mu:
-            existing = self._images.get(key)
-            if (existing is None or existing.epoch != epoch
-                    or existing.apply_index <= apply_index):
-                if existing is not None:
-                    self._unplace(existing)
-                self._images[key] = img
-                self._place(img)
-                self._enforce_budget(keep=key)
-            self.stats.misses += 1
-            self._count("miss")
-            self._gauge_bytes()
-        return img.block_cache, "miss", 0
+        with trace.stage("cache.fill", kind="build") as st:
+            src = MvccBatchScanSource(snap, start_ts, ranges, statistics=stats,
+                                      record_versions=True)
+            keys, values = src._resolve_all()
+            if not src.versions_exact:
+                self.stats.uncacheable += 1
+                self._count("uncacheable")
+                return None, "uncacheable", 0
+            handles = decode_record_handles(keys)
+            st.tag(rows=len(handles))
+            if len(handles) > 1 and not (handles[1:] > handles[:-1]).all():
+                self.stats.uncacheable += 1
+                self._count("uncacheable")
+                return None, "uncacheable", 0
+            img = RegionImage(key, epoch, list(columns_info), self.block_rows)
+            img.tenant = tenant
+            img.fill(handles, values, src.row_commit_ts, src.max_commit_ts,
+                     apply_index, start_ts, raw_keys=keys,
+                     encode=self.encode_columns)
+            if img.nbytes > self.byte_budget:
+                self.stats.uncacheable += 1
+                self._count("too_big")
+                # serve this request from the just-built blocks, but don't
+                # keep them resident — the budget is the OOM guard
+                return img.block_cache, "too_big", 0
+            with self._mu:
+                existing = self._images.get(key)
+                if (existing is None or existing.epoch != epoch
+                        or existing.apply_index <= apply_index):
+                    if existing is not None:
+                        self._unplace(existing)
+                    self._images[key] = img
+                    self._place(img)
+                    self._enforce_budget(keep=key)
+                self.stats.misses += 1
+                self._count("miss")
+                self._gauge_bytes()
+            return img.block_cache, "miss", 0
 
     def _hit_fresh_locked(self, img, apply_index, start_ts, snap, ranges,
                           stats) -> bool:
@@ -1512,13 +1530,16 @@ class RegionColumnCache:
         """Raise on a blocking lock; return how many locks the ranges hold
         (0 lets callers clear a dirty-lock flag)."""
         seen = 0
-        for start, end in ranges:
-            enc_start = Key.from_raw(start).encoded
-            enc_end = Key.from_raw(end).encoded
-            for k, v in snap.scan_cf(CF_LOCK, enc_start, enc_end):
-                stats.lock.next += 1
-                seen += 1
-                _check_lock(v, Key.from_encoded(k).to_raw(), ts, frozenset())
+        walked0 = stats.lock.next
+        with trace.stage("cache.lock_check") as st:
+            for start, end in ranges:
+                enc_start = Key.from_raw(start).encoded
+                enc_end = Key.from_raw(end).encoded
+                for k, v in snap.scan_cf(CF_LOCK, enc_start, enc_end):
+                    stats.lock.next += 1
+                    seen += 1
+                    _check_lock(v, Key.from_encoded(k).to_raw(), ts, frozenset())
+            st.tag(locks_seen=seen, keys_walked=stats.lock.next - walked0)
         return seen
 
     def _drop(self, key, reason: str) -> None:

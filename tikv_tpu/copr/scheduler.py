@@ -231,13 +231,20 @@ class _Ticket:
     to the caller's thread (shed / ineligible work must not serialize the
     whole dispatcher behind one slow per-request execution)."""
 
-    __slots__ = ("done", "resp", "error", "direct")
+    __slots__ = ("done", "resp", "error", "direct", "handed_t")
 
     def __init__(self):
         self.done = threading.Event()
         self.resp: CoprResponse | None = None
         self.error: BaseException | None = None
         self.direct = False
+        self.handed_t = 0.0
+
+    def hand_back(self) -> None:
+        """Wake the caller; the instant is kept so that the caller can say
+        how long its thread took to come back (stage ``sched.handoff``)."""
+        self.handed_t = time.perf_counter()
+        self.done.set()
 
 
 @dataclass
@@ -314,7 +321,8 @@ class CoprReadScheduler:
                   deadline=deadline_from_context(r.context), trace_ctx=tctx)
             for j, (_i, r) in enumerate(live)
         ]
-        sub_results, sub_errors = self._serve(items)
+        with trace.shared([tctx]):
+            sub_results, sub_errors = self._serve(items)
         for (i, _r), res, err in zip(live, sub_results, sub_errors):
             results[i] = res
             errors[i] = err
@@ -457,6 +465,10 @@ class CoprReadScheduler:
             if not item.ticket.done.is_set():
                 sp.tag(outcome="timeout")
                 raise TimeoutError("scheduler did not serve the request in time")
+            # the hand-off between the scheduler's thread and this one: from
+            # the ticket's release to this thread running again
+            sp.record("sched.handoff", item.ticket.handed_t,
+                      time.perf_counter(), stage=True)
             if item.ticket.direct:
                 # the dispatcher shed this request back: serve it on OUR thread
                 # so one slow per-request path cannot stall every lane — unless
@@ -533,23 +545,27 @@ class CoprReadScheduler:
         fail_point("sched_dispatch")
         for i, it in enumerate(batch):
             it.index = i
-        try:
-            results, errors = self._serve(batch)
-        except BaseException as exc:  # noqa: BLE001 — scheduler bug: fail all
-            for it in batch:
-                it.ticket.error = exc
-                it.ticket.done.set()
-            return
-        # per-ticket delivery: one request's lock conflict or decode error
-        # must not poison the riders that coalesced into the same batch
-        for it in batch:
-            if it.ticket.done.is_set():
-                continue  # already handed back to its caller (direct)
-            if errors[it.index] is not None:
-                it.ticket.error = errors[it.index]
-            else:
-                it.ticket.resp = results[it.index]
-            it.ticket.done.set()
+        # every rider waits for all of the batch's stages, whichever trace
+        # they land in (docs/tracing.md, "Attributed time")
+        with trace.shared([it.trace_ctx for it in batch]):
+            try:
+                results, errors = self._serve(batch)
+            except BaseException as exc:  # noqa: BLE001 — scheduler bug: fail all
+                for it in batch:
+                    it.ticket.error = exc
+                    it.ticket.hand_back()
+                return
+            # per-ticket delivery: one request's lock conflict or decode error
+            # must not poison the riders that coalesced into the same batch
+            with trace.attach(batch[0].trace_ctx), trace.stage("sched.respond"):
+                for it in batch:
+                    if it.ticket.done.is_set():
+                        continue  # already handed back to its caller (direct)
+                    if errors[it.index] is not None:
+                        it.ticket.error = errors[it.index]
+                    else:
+                        it.ticket.resp = results[it.index]
+                    it.ticket.hand_back()
 
     def _check_stale_ready(self, req: CoprRequest, count: bool = True) -> None:
         """Raise DataNotReady for a stale read this replica cannot admit —
@@ -606,6 +622,33 @@ class CoprReadScheduler:
                 not_ready.append(it)
         if not_ready:
             items = [it for it in items if errors[it.index] is None]
+        with trace.attach(items[0].trace_ctx if items else None), \
+                trace.stage("sched.group", items=len(items)):
+            exec_groups, rest = self._group(items)
+
+        # double-buffered pipeline: resolve (host fill/delta) group i while
+        # group i-1 executes on device; pull i-1 only after i is launched
+        pending = None
+        for kind, meta, group in exec_groups:
+            if kind == "xregion":
+                launched = self._launch_xregion(meta, group, results, errors)
+            else:
+                launched = self._run_fused(meta, group, results, errors)
+            if pending is not None:
+                pending(results, errors)
+            pending = launched
+        if pending is not None:
+            pending(results, errors)
+
+        for it in rest:
+            self._per_request(it, results, errors, kind="direct")
+        return results, errors
+
+    def _group(self, items: list[_Item]):
+        """Form the batch's execution groups: ``(exec_groups, rest)`` with
+        ``exec_groups`` a list of ("xregion", sig, [slots]) and ("fused",
+        key, [items]) in launch order, ``rest`` the items that serve per
+        request."""
         # group by plan signature, then by distinct region view within a sig
         by_sig: dict[tuple, dict[tuple, _Slot]] = {}
         rest = []
@@ -652,24 +695,7 @@ class CoprReadScheduler:
             lane_rank[it.lane]
             for it in (sum((s.items for s in g[2]), []) if g[0] == "xregion" else g[2])
         ))
-
-        # double-buffered pipeline: resolve (host fill/delta) group i while
-        # group i-1 executes on device; pull i-1 only after i is launched
-        pending = None
-        for kind, meta, group in exec_groups:
-            if kind == "xregion":
-                launched = self._launch_xregion(meta, group, results, errors)
-            else:
-                launched = self._run_fused(meta, group, results, errors)
-            if pending is not None:
-                pending(results, errors)
-            pending = launched
-        if pending is not None:
-            pending(results, errors)
-
-        for it in rest:
-            self._per_request(it, results, errors, kind="direct")
-        return results, errors
+        return exec_groups, rest
 
     def _route_batch(self, sig: tuple) -> bool:
         """Cost-route one sig's micro-batch (docs/cost_router.md):
@@ -682,15 +708,17 @@ class CoprReadScheduler:
             return True  # killed router must cost the dispatch loop nothing
         from . import observatory as _obs
 
-        sid = _obs.sig_id(sig)
-        costs = router.obs.path_costs(sid)
-        table = {}
-        if "xregion" in costs:
-            table["xregion"] = costs["xregion"]
-        direct = [c for p, c in costs.items() if p != "xregion"]
-        if direct:
-            table["direct"] = min(direct, key=lambda c: c["cost_ms"])
-        d = router.route(sid, ["xregion", "direct"], costs=table)
+        with trace.stage("copr.route") as st:
+            sid = _obs.sig_id(sig)
+            costs = router.obs.path_costs(sid)
+            table = {}
+            if "xregion" in costs:
+                table["xregion"] = costs["xregion"]
+            direct = [c for p, c in costs.items() if p != "xregion"]
+            if direct:
+                table["direct"] = min(direct, key=lambda c: c["cost_ms"])
+            d = router.route(sid, ["xregion", "direct"], costs=table)
+            st.tag(path=d.path)
         return d.path != "direct"
 
     # -- eligibility & keying ----------------------------------------------
@@ -726,13 +754,14 @@ class CoprReadScheduler:
         return sig if ok else None
 
     def _evaluator_for(self, sig: tuple, dag: DagRequest):
-        ev = self._evs.get(sig)
-        if ev is None:
-            ev = self.ep._evaluator_for(dag)
-            with self._memo_mu:
-                self._evs[sig] = ev
-                while len(self._evs) > 64:
-                    self._evs.pop(next(iter(self._evs)))
+        with trace.stage("copr.evaluator"):
+            ev = self._evs.get(sig)
+            if ev is None:
+                ev = self.ep._evaluator_for(dag)
+                with self._memo_mu:
+                    self._evs[sig] = ev
+                    while len(self._evs) > 64:
+                        self._evs.pop(next(iter(self._evs)))
         return ev
 
     def _region_key(self, req: CoprRequest) -> tuple:
@@ -752,6 +781,11 @@ class CoprReadScheduler:
         """Resolve a slot's region view to a FILLED block cache, running the
         region cache's build/delta pass if needed.  Returns False when the
         slot must shed to the per-request path."""
+        # the slot's stages land in its first rider's trace
+        with trace.attach(slot.items[0].trace_ctx):
+            return self._resolve_slot_attached(slot)
+
+    def _resolve_slot_attached(self, slot: _Slot) -> bool:
         from .tracker import Tracker
 
         req = slot.items[0].req
@@ -764,7 +798,8 @@ class CoprReadScheduler:
                 self.ep.cm.read_range_check(
                     Key.from_raw(start), Key.from_raw(end), req.start_ts
                 )
-        snap = self.ep.engine.snapshot(stale_read_ctx(req))
+        with trace.stage("copr.snapshot"):
+            snap = self.ep.engine.snapshot(stale_read_ctx(req))
         tracker = Tracker()
         cache, outcome = self.ep._region_cache_for(req, snap, tracker)
         if cache is None:
@@ -781,8 +816,7 @@ class CoprReadScheduler:
             # normal per-request path (and keeps its own answer); the rest
             # of the slot then serves from the filled blocks
             filler = slot.items[0]
-            with trace.attach(filler.trace_ctx):
-                resp = self.ep.handle_request(filler.req)
+            resp = self.ep.handle_request(filler.req)
             self._stamp(resp, filler, kind="fill", occupancy=1)
             filler._filled_resp = resp  # type: ignore[attr-defined]
             if not cache.filled or not cache.blocks:
@@ -850,47 +884,51 @@ class CoprReadScheduler:
         live = [s for s in live if by_image.get(id(s.cache)) is s]
         if not live:
             return None
-        ev = self._evaluator_for(sig, live[0].items[0].req.dag)
-        mesh = self._sharded_mesh(ev)
-        breaker = self.ep.breaker
-        if mesh is not None and not breaker.allow("mesh"):
-            # mesh path tripped: degrade to the single-device cross-region
-            # program instead of losing batching entirely
-            from .tracker import count_path_fallback
+        # admission to the batch is still forming it: evaluator, mesh and
+        # breaker verdicts, the padding shed (zone pruning included)
+        with trace.attach(live[0].items[0].trace_ctx), \
+                trace.stage("sched.group", slots=len(live)):
+            ev = self._evaluator_for(sig, live[0].items[0].req.dag)
+            mesh = self._sharded_mesh(ev)
+            breaker = self.ep.breaker
+            if mesh is not None and not breaker.allow("mesh"):
+                # mesh path tripped: degrade to the single-device cross-region
+                # program instead of losing batching entirely
+                from .tracker import count_path_fallback
 
-            count_path_fallback("mesh", "breaker_open")
-            mesh = None
-        if mesh is None and not breaker.allow("xregion"):
-            from .tracker import count_path_fallback
+                count_path_fallback("mesh", "breaker_open")
+                mesh = None
+            if mesh is None and not breaker.allow("xregion"):
+                from .tracker import count_path_fallback
 
-            count_path_fallback("xregion", "breaker_open")
-            for slot in live:
-                self._shed(slot, "breaker_open", results, errors)
-            return None
-        path = "mesh" if mesh is not None else "xregion"
-        if mesh is not None:
-            live, device_load, sh_waste = self._shed_for_padding_sharded(
-                live, mesh, results, errors)
-        else:
-            live = self._shed_for_padding(live, results, errors)
-            device_load, sh_waste = None, 0.0
-        if len(live) < 2:
-            breaker.release_probe(path)  # nothing launched on this path
-            for slot in live:
-                self._shed(slot, "underfull", results, errors, path=path)
-            return None
-        # cold-fills were answered (and counted) by their own handle_request
-        # — the program serves the rest; occupancy counts the whole fan-in.
-        # Counted over the FINAL live set: a filled slot shed above (alias /
-        # padding) must not deflate this batch's request count.
-        n_batch = sum(len(s.items) for s in live)
-        n_filled = sum(
-            1 for s in live for it in s.items
-            if getattr(it, "_filled_resp", None) is not None
-        )
-        n_reqs = max(n_batch - n_filled, 1)
-        kind = "xregion" if mesh is None else "xregion_sharded"
-        waste = self._padding_waste(live, ev=ev) if mesh is None else sh_waste
+                count_path_fallback("xregion", "breaker_open")
+                for slot in live:
+                    self._shed(slot, "breaker_open", results, errors)
+                return None
+            path = "mesh" if mesh is not None else "xregion"
+            if mesh is not None:
+                live, device_load, sh_waste = self._shed_for_padding_sharded(
+                    live, mesh, results, errors)
+            else:
+                live = self._shed_for_padding(live, results, errors)
+                device_load, sh_waste = None, 0.0
+            if len(live) < 2:
+                breaker.release_probe(path)  # nothing launched on this path
+                for slot in live:
+                    self._shed(slot, "underfull", results, errors, path=path)
+                return None
+            # cold-fills were answered (and counted) by their own handle_request
+            # — the program serves the rest; occupancy counts the whole fan-in.
+            # Counted over the FINAL live set: a filled slot shed above (alias /
+            # padding) must not deflate this batch's request count.
+            n_batch = sum(len(s.items) for s in live)
+            n_filled = sum(
+                1 for s in live for it in s.items
+                if getattr(it, "_filled_resp", None) is not None
+            )
+            n_reqs = max(n_batch - n_filled, 1)
+            kind = "xregion" if mesh is None else "xregion_sharded"
+            waste = self._padding_waste(live, ev=ev) if mesh is None else sh_waste
         # fan-in linkage (docs/tracing.md): ONE device-dispatch span — its
         # own one-span trace naming every participating parent trace — and
         # each rider links back to it.  A shared dispatch can't be a child
@@ -955,11 +993,11 @@ class CoprReadScheduler:
             # overlap, not this batch's cost; attributing it here would
             # inflate the device-path percentiles with unrelated host work.
             dt = (t_launched - t0) + pull_dt
-            self._batch_metrics(kind, n_reqs, dt, waste, n_batch=n_batch)
+            with trace.attach(riders[0].trace_ctx), \
+                    trace.stage("copr.obs", batch=kind):
+                self._batch_metrics(kind, n_reqs, dt, waste, n_batch=n_batch)
             if bsp:
-                bsp.tag(outcome="ok", launch_ms=round((t_launched - t0) * 1e3, 3),
-                        pull_ms=round(pull_dt * 1e3, 3))
-                bsp.finish()
+                bsp.tag(outcome="ok").finish()
                 # each rider's trace gets a span for the shared dispatch it
                 # rode, linked to the dispatch span's own trace
                 for it in riders:
@@ -989,18 +1027,20 @@ class CoprReadScheduler:
                 # per-region chunk payloads: every rider of this slot shares
                 # the SAME unjoined column-slab parts, so one multi-response
                 # frame gather-writes each region's slabs once
-                parts, enc_tp = self.ep._encode_response(resp)
                 data = None
                 from_device = True
-                if slot.shadow_snap is not None:
-                    # sampled slot: CPU-oracle byte compare; a mismatch
-                    # quarantines the image and this slot serves the oracle
-                    fixed = self.ep.shadow_compare(
-                        slot.items[0].req, slot.shadow_snap,
-                        b"".join(bytes(p) for p in parts), "batch")
-                    if fixed is not None:
-                        data, parts = fixed, None
-                        from_device = False
+                # the slot's stages land in its first rider's trace
+                with trace.attach(slot.items[0].trace_ctx):
+                    parts, enc_tp = self.ep._encode_response(resp)
+                    if slot.shadow_snap is not None:
+                        # sampled slot: CPU-oracle byte compare; a mismatch
+                        # quarantines the image and this slot serves the oracle
+                        fixed = self.ep.shadow_compare(
+                            slot.items[0].req, slot.shadow_snap,
+                            b"".join(bytes(p) for p in parts), "batch")
+                        if fixed is not None:
+                            data, parts = fixed, None
+                            from_device = False
                 from_cache = from_device and slot.outcome not in ("", "miss", "too_big")
                 for it in slot.items:
                     if results[it.index] is not None:
@@ -1057,7 +1097,8 @@ class CoprReadScheduler:
         try:
             evs = [self._evaluator_for(sig, group[0].req.dag)
                    for sig, group in uniq.items()]
-            resps = jax_eval.run_batch_cached(evs, cache)
+            with bsp.active():
+                resps = jax_eval.run_batch_cached(evs, cache)
         except ValueError:
             # a documented decline (non-stable group dictionaries, empty
             # cache) — per-request path, no device-failure attribution
@@ -1100,8 +1141,10 @@ class CoprReadScheduler:
 
         if slot.shadow_snap is not None:
             groups = list(uniq.values())
-            fixed = self.ep.shadow_compare(groups[0][0].req, slot.shadow_snap,
-                                           resps[0].encode(), "batch")
+            with trace.attach(groups[0][0].trace_ctx):
+                fixed = self.ep.shadow_compare(
+                    groups[0][0].req, slot.shadow_snap, resps[0].encode(),
+                    "batch")
             if fixed is not None:
                 # the SHARED image is corrupt (and quarantined): the probe's
                 # signature group serves the oracle bytes already in hand;
@@ -1122,7 +1165,8 @@ class CoprReadScheduler:
             _rec_fused(group, g_ev, g_resp)
         from_cache = slot.outcome not in ("", "miss", "too_big")
         for group, resp in zip(uniq.values(), resps):
-            parts, enc_tp = self.ep._encode_response(resp)
+            with trace.attach(group[0].trace_ctx):
+                parts, enc_tp = self.ep._encode_response(resp)
             for it in group:
                 r = CoprResponse(None, from_device=True, from_cache=from_cache,
                                  data_parts=parts, encode_type=enc_tp)
@@ -1238,7 +1282,7 @@ class CoprReadScheduler:
             return
         if it.ticket is not None and not it.ticket.done.is_set():
             it.ticket.direct = True
-            it.ticket.done.set()
+            it.ticket.hand_back()
             return
         try:
             # explicit pool-boundary handoff: the dispatcher serves this on
@@ -1260,18 +1304,19 @@ class CoprReadScheduler:
         profile exemplar (docs/observatory.md)."""
         if not _obs.OBSERVATORY.enabled:
             return
-        sig = getattr(ev, "obs_sig", "")
-        if not sig and it.sig is not None:
-            sig = _obs.sig_id(it.sig)
-        qwait = (max(dispatch_t - it.enqueue_t, 0.0)
-                 if dispatch_t is not None and it.enqueue_t else 0.0)
-        prune = getattr(resp, "_obs_prune", None) or (0, 0)
-        _obs.OBSERVATORY.record_serve(
-            sig, path, latency_s, rows=rows, encoding=encoding,
-            occupancy=occupancy, queue_wait_s=qwait, padding_waste=waste,
-            trace_id=(it.trace_ctx or {}).get("trace_id"),
-            desc=getattr(ev, "obs_desc", ""),
-            blocks_examined=prune[0], blocks_pruned=prune[1])
+        with trace.attach(it.trace_ctx), trace.stage("copr.obs"):
+            sig = getattr(ev, "obs_sig", "")
+            if not sig and it.sig is not None:
+                sig = _obs.sig_id(it.sig)
+            qwait = (max(dispatch_t - it.enqueue_t, 0.0)
+                     if dispatch_t is not None and it.enqueue_t else 0.0)
+            prune = getattr(resp, "_obs_prune", None) or (0, 0)
+            _obs.OBSERVATORY.record_serve(
+                sig, path, latency_s, rows=rows, encoding=encoding,
+                occupancy=occupancy, queue_wait_s=qwait, padding_waste=waste,
+                trace_id=(it.trace_ctx or {}).get("trace_id"),
+                desc=getattr(ev, "obs_desc", ""),
+                blocks_examined=prune[0], blocks_pruned=prune[1])
 
     def _shed(self, slot: _Slot, reason: str, results, errors,
               path: str = "xregion") -> None:
@@ -1390,11 +1435,16 @@ class CoprReadScheduler:
     def _observe_wait(self, it: _Item) -> None:
         from ..util.metrics import REGISTRY
 
-        wait = time.perf_counter() - it.enqueue_t
+        now = time.perf_counter()
+        wait = now - it.enqueue_t
         REGISTRY.histogram(
             "tikv_coprocessor_sched_lane_wait_seconds",
             "Queue wait before dispatch, by priority lane",
         ).observe(wait, lane=it.lane)
+        # the same wait as a stage of the rider's trace: enqueue, on the
+        # connection's thread, to pick-up, on this one
+        trace.remote_span(it.trace_ctx, "sched.wait", start=it.enqueue_t,
+                          end=now, stage=True, lane=it.lane)
         ov = getattr(self.ep, "overload", None)
         if ov is not None:
             # adaptive-controller evidence: sampled lane waits say whether

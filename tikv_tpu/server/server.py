@@ -189,7 +189,7 @@ class Server:
             store=getattr(getattr(self.service, "read_plane", None),
                           "store_id", None) or "")
         if root:
-            root.record("wire.decode", t_dec, t_dec_end)
+            root.record("wire.decode", t_dec, t_dec_end, stage=True)
         return root
 
     @property
@@ -296,7 +296,7 @@ class Server:
                         # handler start is ALL routing overhead (trace/
                         # closure bookkeeping + pool queue wait), so the
                         # stage spans account for the whole request
-                        root.record("wire.route", t_dec_end, t0)
+                        root.record("wire.route", t_dec_end, t0, stage=True)
                     try:
                         with root.active(), trace.span("wire.execute"):
                             if method.startswith("pb/"):
@@ -366,21 +366,22 @@ class Server:
                     # data) as passthrough buffers and the frame writer
                     # gather-writes them — no re-encoding copy of the data
                     t_enc = time.perf_counter()
-                    parts = wire.dumps_parts([req_id, resp])
-                    with send_mu:
-                        try:
-                            # lint: allow(lock-blocking-call) -- per-socket
-                            # frame serialization (same as the stream path)
-                            write_frame_parts(conn, parts)
-                        except OSError:
-                            pass
+                    # response assembly, then the frame write with its wait
+                    # for the socket's turn: two stages that tile the root
+                    # from execute-end on (see wire.route)
+                    with root.active():
+                        with trace.stage("wire.encode"):
+                            parts = wire.dumps_parts([req_id, resp])
+                        with trace.stage("wire.send"), send_mu:
+                            try:
+                                # lint: allow(lock-blocking-call) -- per-socket
+                                # frame serialization (same as the stream path)
+                                write_frame_parts(conn, parts)
+                            except OSError:
+                                pass
                     t_enc_end = time.perf_counter()
                     WIRE_STAGE.observe(t_enc_end - t_enc, stage="encode")
-                    if root:
-                        # execute-end to send-done: response assembly +
-                        # frame write (tiles the root, see wire.route)
-                        root.record("wire.encode", t_done, t_enc_end)
-                        root.finish(end=t_enc_end)
+                    root.finish(end=t_enc_end)
 
                 if method.removeprefix("pb/") in _READ_METHODS:
                     ctx, group = {}, id(conn)

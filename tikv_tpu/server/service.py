@@ -21,6 +21,7 @@ from ..storage.mvcc.txn import AlreadyExistsError, TxnError
 from ..storage.storage import Storage
 from ..storage.txn import commands as cmds
 from ..storage.txn_types import Key, Mutation, MutationType
+from ..util import trace
 
 
 def _mutation_from_wire(m: dict) -> Mutation:
@@ -1090,8 +1091,6 @@ class KvService:
         """Recent + slow traces from the process tracer (docs/tracing.md):
         the ``ctl.py trace`` surface.  ``trace_id`` narrows to one trace;
         ``limit`` bounds the rings returned."""
-        from ..util import trace
-
         tid = req.get("trace_id")
         if tid:
             t = trace.TRACER.get(tid)
@@ -1344,7 +1343,8 @@ class KvService:
     def _coprocessor_local(self, req: dict) -> dict:
         assert self.copr is not None, "coprocessor endpoint not wired"
         try:
-            creq = self._parse_copr_request(req)
+            with trace.stage("copr.parse"):
+                creq = self._parse_copr_request(req)
             sched = getattr(self.copr, "scheduler", None)
             if sched is not None and sched.running:
                 r = sched.execute(creq)

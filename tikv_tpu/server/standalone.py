@@ -531,6 +531,11 @@ class StoreServer:
             return None
 
     def start(self) -> None:
+        from ..util import trace
+
+        # one interpreter serves every thread of the store: its collector's
+        # pauses are timed from here on (docs/tracing.md, stage host.gc)
+        trace.install_gc_hook()
         self.server.start()
         self.status_server.start()
         self._ttl_thread.start()

@@ -8,6 +8,7 @@ and histograms with labels, rendered in the Prometheus text format.
 from __future__ import annotations
 
 import threading
+from bisect import bisect_left
 
 _DEFAULT_BUCKETS = (0.0005, 0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1, 5, 10)
 
@@ -20,15 +21,38 @@ class _Metric:
         self._mu = threading.Lock()
 
 
+class _Bound:
+    """One label set of a counter or histogram with its key made once
+    (``metric.labels(...)``): for sites that move the same series on every
+    call."""
+
+    __slots__ = ("_add", "_key")
+
+    def __init__(self, add, key: tuple):
+        self._add = add
+        self._key = key
+
+    def inc(self, value: float = 1) -> None:
+        self._add(self._key, value)
+
+    def observe(self, value: float) -> None:
+        self._add(self._key, value)
+
+
 class Counter(_Metric):
     def __init__(self, name, help_=""):
         super().__init__(name, help_, "counter")
         self._values: dict[tuple, float] = {}
 
     def inc(self, value: float = 1, **labels) -> None:
-        key = tuple(sorted(labels.items()))
+        self._add(tuple(sorted(labels.items())), value)
+
+    def _add(self, key: tuple, value: float) -> None:
         with self._mu:
             self._values[key] = self._values.get(key, 0) + value
+
+    def labels(self, **labels) -> _Bound:
+        return _Bound(self._add, tuple(sorted(labels.items())))
 
     def get(self, **labels) -> float:
         return self._values.get(tuple(sorted(labels.items())), 0)
@@ -64,17 +88,22 @@ class Histogram(_Metric):
         self._n: dict[tuple, int] = {}
 
     def observe(self, value: float, **labels) -> None:
-        key = tuple(sorted(labels.items()))
+        self._add(tuple(sorted(labels.items())), value)
+
+    def _add(self, key: tuple, value: float) -> None:
+        # the first bucket whose bound is at or above the value; past the
+        # last finite bound it is the +Inf bucket
+        i = bisect_left(self.buckets, value)
         with self._mu:
-            counts = self._counts.setdefault(key, [0] * (len(self.buckets) + 1))
-            for i, b in enumerate(self.buckets):
-                if value <= b:
-                    counts[i] += 1
-                    break
-            else:
-                counts[-1] += 1
+            counts = self._counts.get(key)
+            if counts is None:
+                counts = self._counts[key] = [0] * (len(self.buckets) + 1)
+            counts[i] += 1
             self._sum[key] = self._sum.get(key, 0) + value
             self._n[key] = self._n.get(key, 0) + 1
+
+    def labels(self, **labels) -> _Bound:
+        return _Bound(self._add, tuple(sorted(labels.items())))
 
     def count(self, **labels) -> int:
         """Observation count for a label set (the _count series)."""
@@ -157,6 +186,13 @@ class Registry:
     def __init__(self):
         self._mu = threading.Lock()
         self._metrics: dict[str, _Metric] = {}
+        self._render_hooks: list = []
+
+    def on_render(self, hook) -> None:
+        """``hook()`` runs before every render: for values only a reader
+        needs, computed when one reads instead of on every change."""
+        with self._mu:
+            self._render_hooks.append(hook)
 
     def counter(self, name: str, help_: str = "") -> Counter:
         return self._get_or_create(name, lambda: Counter(name, help_))
@@ -176,6 +212,10 @@ class Registry:
             return m
 
     def render(self) -> str:
+        with self._mu:
+            hooks = list(self._render_hooks)
+        for hook in hooks:
+            hook()
         with self._mu:
             return "\n".join(m.render() for m in self._metrics.values()) + "\n"
 

@@ -38,6 +38,20 @@ said drop ("the slow request you could not predict").  ``sample_rate == 0``
 turns the plane off: every entry point is ONE branch returning the no-op
 span, no allocation beyond the call itself.
 
+Stages
+------
+A **stage** (``stage(name)``) is a leaf span with three faces: a child span
+of the thread's current span (flagged ``stage``), a wall and a thread-CPU
+total per stage name in the registry, and, while a mirror is installed
+(``copr/jax_eval.py`` installs ``jax.profiler.TraceAnnotation``), an
+annotation of the same name in the device profiler's trace, so that the
+device trace names its idle gaps after the program's own stages.  Stages
+never nest: one entered inside another suspends the outer one, which
+resumes as a new segment when the inner one ends.  When a root ``rpc.*``
+span commits, the stage time in its tree plus the stage time a shared
+batch spent on its behalf (``shared``) is its **attributed** time; the rest
+is what the instrumentation cannot see.
+
 The tracer's lock is a LEAF by construction — span operations touch only
 tracer state, never another subsystem's lock — so spans are safe to open or
 finish while holding scheduler/cache/raft locks (the sanitizer's order graph
@@ -46,6 +60,7 @@ can never find a cycle through it).
 
 from __future__ import annotations
 
+import gc
 import os
 import random
 import threading
@@ -53,12 +68,15 @@ import time
 from collections import deque
 
 from ..analysis.sanitizer import make_lock
+from .metrics import REGISTRY
 
 __all__ = [
     "Span", "attach", "begin", "current", "current_context",
-    "current_trace_id", "enabled", "fanin_span", "inject", "record",
-    "remote_span", "sample_rate", "set_sample_rate", "set_slow_threshold",
-    "slow_threshold", "snapshot", "span", "start_trace", "timeline", "TRACER",
+    "current_trace_id", "enabled", "fanin_span", "inject",
+    "install_gc_hook", "record", "remote_span", "sample_rate", "set_mirror",
+    "set_sample_rate", "set_slow_threshold", "shared", "slow_threshold",
+    "snapshot", "span", "stage", "start_trace", "timed_stage", "timeline",
+    "TRACER",
 ]
 
 #: per-trace span cap: one runaway loop must not balloon the live table
@@ -71,13 +89,43 @@ RING = 64
 _CTX_KEYS = ("trace_id", "span_id", "sampled")
 
 
-def _count(outcome: str) -> None:
-    from .metrics import REGISTRY
+_TRACE_TOTAL = REGISTRY.counter(
+    "tikv_trace_total",
+    "Trace head/tail sampling decisions at trace completion, by outcome")
+_RING_TRACES = REGISTRY.gauge(
+    "tikv_trace_ring_traces",
+    "Traces held per tracer ring (live = still open)")
+_STAGE_SECONDS = REGISTRY.histogram(
+    "tikv_trace_stage_seconds",
+    "Wall time inside served-path stages (docs/tracing.md), by stage")
+_STAGE_CPU = REGISTRY.counter(
+    "tikv_trace_stage_cpu_seconds_total",
+    "The thread's own CPU time inside served-path stages, by stage")
+_REQUEST_SECONDS = REGISTRY.counter(
+    "tikv_trace_request_seconds_total",
+    "Root rpc span time of finished traces, by method")
+_REQUEST_ATTRIBUTED = REGISTRY.counter(
+    "tikv_trace_request_attributed_seconds_total",
+    "Stage time attributed to finished rpc traces, by method")
+_GC_PAUSE = REGISTRY.counter(
+    "tikv_process_gc_pause_seconds_total",
+    "Time inside the interpreter's cyclic collector, by generation")
 
-    REGISTRY.counter(
-        "tikv_trace_total",
-        "Trace head/tail sampling decisions at trace completion, by outcome",
-    ).inc(outcome=outcome)
+
+_STAGE_SERIES: dict[str, tuple] = {}
+
+
+def _stage_series(name: str) -> tuple:
+    """(wall histogram, CPU counter) of one stage, its label key made once."""
+    series = _STAGE_SERIES.get(name)
+    if series is None:
+        series = _STAGE_SERIES[name] = (_STAGE_SECONDS.labels(stage=name),
+                                        _STAGE_CPU.labels(stage=name))
+    return series
+
+
+def _count(outcome: str) -> None:
+    _TRACE_TOTAL.inc(outcome=outcome)
 
 
 class _Noop:
@@ -141,12 +189,137 @@ class _Active:
         return False
 
 
+class _Stage:
+    """One stage of a served request (module docstring, "Stages").  The
+    clock is read once on the way in and once on the way out; the span, the
+    totals and the mirror all take those two readings."""
+
+    __slots__ = ("_tracer", "name", "tags", "seconds", "_sp", "_ann", "_t0",
+                 "_c0", "_outer")
+
+    def __init__(self, tracer: "Tracer", name: str, tags: dict):
+        self._tracer = tracer
+        self.name = name
+        self.tags = tags
+        self.seconds = 0.0  # wall time so far (timed_stage's sites read it)
+        self._sp = None
+        self._ann = None
+        self._outer = None
+
+    def __bool__(self):
+        return True
+
+    def tag(self, **kv) -> "_Stage":
+        self.tags.update(kv)
+        return self
+
+    def __enter__(self) -> "_Stage":
+        st = self._tracer._state
+        outer = getattr(st, "stage", None)
+        if outer is not None:
+            outer._close()  # suspended: stages never nest
+        self._outer = outer
+        st.stage = self
+        self._open()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if exc is not None and "error" not in self.tags:
+            self.tags["error"] = repr(exc)
+        self._close()
+        outer = self._outer
+        self._tracer._state.stage = outer
+        if outer is not None:
+            outer._open()  # resumes as a new segment
+        return False
+
+    def _open(self) -> None:
+        tracer = self._tracer
+        mirror = tracer._mirror
+        if mirror is not None:
+            self._ann = mirror(self.name)
+            self._ann.__enter__()
+        self._c0 = time.thread_time()
+        self._t0 = t0 = time.perf_counter()
+        cur = getattr(tracer._state, "cur", None)
+        if cur is not None:
+            self._sp = tracer._child(cur.rec, cur.span_id, self.name, None,
+                                     start=t0, stage=True)
+
+    def _close(self) -> None:
+        t1 = time.perf_counter()
+        cpu = time.thread_time() - self._c0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        dt = t1 - self._t0
+        self.seconds += dt
+        wall_total, cpu_total = _stage_series(self.name)
+        wall_total.observe(dt)
+        cpu_total.inc(cpu)
+        sp, self._sp = self._sp, None
+        shared = getattr(self._tracer._state, "shared", None)
+        if shared:
+            own = sp.rec if sp is not None else None
+            for rec in shared:
+                if rec is not own:
+                    rec.shared_s += dt
+        if sp is not None:
+            sp.tags.update(self.tags)
+            sp.finish(end=t1)
+
+
+class _Clock:
+    """What ``timed_stage`` gives while the plane is off: the two clock
+    reads its site needs for a histogram of its own, and nothing else.
+    Falsy and tag-deaf like the no-op span."""
+
+    __slots__ = ("seconds", "_t0")
+
+    def __bool__(self):
+        return False
+
+    def tag(self, **kv) -> "_Clock":
+        return self
+
+    def __enter__(self) -> "_Clock":
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.seconds = time.perf_counter() - self._t0
+        return False
+
+
+class _Shared:
+    """Tracer.shared(): while active on this thread, every stage that closes
+    here also counts as attributed time of each listed trace, but for the
+    trace the stage's own span belongs to."""
+
+    __slots__ = ("_tracer", "_recs", "_prev")
+
+    def __init__(self, tracer: "Tracer", recs: list):
+        self._tracer = tracer
+        self._recs = recs
+        self._prev = None
+
+    def __enter__(self):
+        st = self._tracer._state
+        self._prev = getattr(st, "shared", None)
+        st.shared = self._recs
+        return self
+
+    def __exit__(self, *exc):
+        self._tracer._state.shared = self._prev
+        return False
+
+
 class _Rec:
     """One live trace: its spans plus the open-span refcount that decides
     when the trace is complete and the sampling verdict applies."""
 
     __slots__ = ("trace_id", "sampled", "spans", "open", "had_root",
-                 "root_dur", "truncated", "t0")
+                 "root", "root_dur", "truncated", "t0", "stage_s", "shared_s")
 
     def __init__(self, trace_id: str, sampled: bool):
         self.trace_id = trace_id
@@ -154,18 +327,24 @@ class _Rec:
         self.spans: list[Span] = []
         self.open = 0
         self.had_root = False
+        self.root: Span | None = None
         self.root_dur: float | None = None
         self.truncated = 0
         self.t0 = time.time()
+        # attributed time: stage spans of this tree, and stage time a shared
+        # batch spent while this request waited for it (Tracer.shared)
+        self.stage_s = 0.0
+        self.shared_s = 0.0
 
 
 class Span:
     __slots__ = ("rec", "name", "span_id", "parent_id", "wall", "t0",
-                 "dur", "tags", "root", "_tracer", "_prev", "_pushed")
+                 "dur", "tags", "root", "stage", "_tracer", "_prev", "_pushed")
 
     def __init__(self, tracer: "Tracer", rec: _Rec, name: str,
                  parent_id: str | None, root: bool,
-                 start: float | None = None, tags: dict | None = None):
+                 start: float | None = None, tags: dict | None = None,
+                 stage: bool = False):
         self.rec = rec
         self.name = name
         self.span_id = tracer._new_id()
@@ -175,6 +354,7 @@ class Span:
         self.dur: float | None = None
         self.tags = dict(tags) if tags else {}
         self.root = root
+        self.stage = stage
         self._tracer = tracer
         self._prev = None
         self._pushed = False
@@ -201,11 +381,18 @@ class Span:
         return self._tracer._child(self.rec, self.span_id, name, tags,
                                    start=start)
 
-    def record(self, name: str, start: float, end: float, **tags) -> "Span":
+    def record(self, name: str, start: float, end: float,
+               stage: bool = False, **tags) -> "Span":
         """A finished child with explicit perf_counter bounds (stages
-        measured before/after the span tree could be current)."""
-        sp = self.child(name, start=start, **tags)
+        measured before/after the span tree could be current).  With
+        ``stage`` it is a recorded stage: it counts as attributed time and
+        moves the stage's wall total, with no CPU total and no mirror (its
+        bounds lie on two threads or before the tree existed)."""
+        sp = self._tracer._child(self.rec, self.span_id, name, tags,
+                                 start=start, stage=stage)
         sp.finish(end=end)
+        if stage:
+            _stage_series(name)[0].observe(end - start)
         return sp
 
     def active(self) -> "_Active":
@@ -242,7 +429,7 @@ class Span:
             self, time.perf_counter() if end is None else end)
 
     def to_dict(self) -> dict:
-        return {
+        d = {
             "span_id": self.span_id,
             "parent_id": self.parent_id,
             "name": self.name,
@@ -250,6 +437,9 @@ class Span:
             "duration_ms": round((self.dur or 0.0) * 1000, 3),
             "tags": {k: _plain(v) for k, v in self.tags.items()},
         }
+        if self.stage:
+            d["stage"] = True
+        return d
 
 
 def _plain(v):
@@ -282,6 +472,16 @@ class Tracer:
         self._slow: deque[dict] = deque(maxlen=RING)
         self._rng = random.Random()
         self._idgen = random.Random()
+        # stage mirror: a factory of context managers (jax.profiler.
+        # TraceAnnotation, installed by copr/jax_eval.py: this module stays
+        # importable without jax)
+        self._mirror = None
+        # collector pauses, noted by the gc callback and moved into the
+        # registry later: the callback runs wherever an allocation tripped
+        # the collector, also inside this module's and the registry's locked
+        # regions, so it takes no lock
+        self._gc_pauses: deque = deque()
+        self._gc_open = None
 
     # -- knobs (online-config controller + ctl.py trace set-sample-rate) ----
 
@@ -361,9 +561,7 @@ class Tracer:
         if rec is None:
             _count("dropped")
             return NOOP
-        sp = Span(self, rec, name, parent, root, start=start, tags=tags)
-        self._gauge()
-        return sp
+        return Span(self, rec, name, parent, root, start=start, tags=tags)
 
     def span(self, name: str, **tags):
         """Child of the current span; NOOP when no trace is active here."""
@@ -393,10 +591,86 @@ class Tracer:
         return sp
 
     def _child(self, rec: _Rec, parent_id: str | None, name: str,
-               tags: dict, start: float | None = None) -> Span:
+               tags: dict | None, start: float | None = None,
+               stage: bool = False) -> Span:
         with self._mu:
             rec.open += 1
-        return Span(self, rec, name, parent_id, False, start=start, tags=tags)
+        return Span(self, rec, name, parent_id, False, start=start, tags=tags,
+                    stage=stage)
+
+    # -- stages --------------------------------------------------------------
+
+    def stage(self, name: str, **tags):
+        """A leaf span of the current span that also moves the stage's wall
+        and CPU totals and is mirrored into the device profiler's trace
+        (module docstring, "Stages").  With no current span it does the
+        latter two; with the plane off it is the shared NOOP."""
+        if self._rate <= 0.0 and getattr(self._state, "cur", None) is None:
+            return NOOP
+        if self._gc_pauses:
+            self._drain_gc()
+        return _Stage(self, name, tags)
+
+    def timed_stage(self, name: str, **tags):
+        """A stage whose site also reports its wall time elsewhere (a
+        histogram that predates the stage): ``.seconds`` holds it after the
+        block whether the plane is on or off, from one pair of clock reads."""
+        return self.stage(name, **tags) or _Clock()
+
+    def set_mirror(self, factory) -> None:
+        """``factory(name)`` gives the context manager a stage enters around
+        its block; None takes the mirror away."""
+        self._mirror = factory
+
+    def shared(self, ctxs: list):
+        """For a block on this thread, stages count as attributed time of
+        every live trace among ``ctxs`` too: a rider of a shared batch
+        waits for all of the batch's stages, whichever trace they land in."""
+        if self._rate <= 0.0 and not any(ctxs):
+            return NOOP
+        recs: list = []
+        with self._mu:
+            for ctx in ctxs:
+                rec = self._live.get(ctx["trace_id"]) if ctx else None
+                if rec is not None and rec not in recs:
+                    recs.append(rec)
+        return _Shared(self, recs)
+
+    # -- collector pauses ----------------------------------------------------
+
+    def install_gc_hook(self) -> None:
+        """Time every pause of the interpreter's cyclic collector as the
+        stage ``host.gc`` (no span: a pause belongs to no request) and into
+        ``tikv_process_gc_pause_seconds_total``.  Installed once a process,
+        by the store at start."""
+        if self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            if self._rate <= 0.0:
+                return
+            ann = None
+            if self._mirror is not None:
+                ann = self._mirror("host.gc")
+                ann.__enter__()
+            self._gc_open = (time.perf_counter(), ann)
+        elif self._gc_open is not None:
+            t0, ann = self._gc_open
+            self._gc_open = None
+            dt = time.perf_counter() - t0
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            self._gc_pauses.append((info.get("generation"), dt))
+
+    def _drain_gc(self) -> None:
+        while True:
+            try:
+                generation, dt = self._gc_pauses.popleft()
+            except IndexError:
+                return
+            _GC_PAUSE.inc(dt, generation=str(generation))
+            _stage_series("host.gc")[0].observe(dt)
 
     # -- explicit handoff ----------------------------------------------------
 
@@ -429,12 +703,13 @@ class Tracer:
 
     def remote_span(self, ctx: dict | None, name: str,
                     start: float | None = None, end: float | None = None,
-                    **tags):
+                    stage: bool = False, **tags):
         """Record a span directly into the trace named by ``ctx`` without
         touching this thread's current stack — how a dispatcher thread
         stamps per-rider spans for work it served on their behalf.  Applies
         to unsampled live records too: tail promotion exists to keep
-        exactly these phases when the request turns out slow."""
+        exactly these phases when the request turns out slow.  ``stage``
+        (with both bounds) makes it a recorded stage, as ``Span.record``."""
         if not ctx or not ctx.get("trace_id"):
             return NOOP
         with self._mu:
@@ -443,9 +718,11 @@ class Tracer:
                 return NOOP  # trace already finished (or cross-process)
             rec.open += 1
         sp = Span(self, rec, name, ctx.get("span_id"), False,
-                  start=start, tags=tags)
+                  start=start, tags=tags, stage=stage)
         if end is not None or start is not None:
             sp.finish(end=end)
+        if stage and sp.dur is not None:
+            _stage_series(name)[0].observe(sp.dur)
         return sp
 
     def fanin_span(self, name: str, parents: list[dict | None], **tags):
@@ -488,7 +765,10 @@ class Tracer:
             else:
                 rec.truncated += 1
             rec.open -= 1
+            if sp.stage:
+                rec.stage_s += sp.dur
             if sp.root:
+                rec.root = sp
                 rec.root_dur = sp.dur
             if rec.open <= 0 and self._live.get(rec.trace_id) is rec:
                 del self._live[rec.trace_id]
@@ -497,6 +777,12 @@ class Tracer:
             self._commit(finished)
 
     def _commit(self, rec: _Rec) -> None:
+        root = rec.root
+        if root is not None and root.name.startswith("rpc."):
+            # what the stages account for, and what they cannot see
+            method = root.tags.get("method") or root.name[4:]
+            _REQUEST_SECONDS.inc(root.dur, method=method)
+            _REQUEST_ATTRIBUTED.inc(rec.stage_s + rec.shared_s, method=method)
         dur = rec.root_dur
         if dur is None and rec.spans:
             # rootless (joined-only, cross-process): the local fragment's
@@ -505,7 +791,6 @@ class Tracer:
         slow = dur is not None and dur >= self._slow_s
         if not rec.sampled and not slow:
             _count("dropped")
-            self._gauge()
             return
         d = self._trace_dict(rec, dur, slow)
         with self._mu:
@@ -514,7 +799,6 @@ class Tracer:
             if slow:
                 self._slow.append(d)
         _count("sampled" if rec.sampled else "promoted")
-        self._gauge()
 
     def _trace_dict(self, rec: _Rec, dur, slow: bool) -> dict:
         return {
@@ -524,21 +808,20 @@ class Tracer:
             "slow": slow,
             "start": round(rec.t0, 6),
             "duration_ms": round((dur or 0.0) * 1000, 3),
+            "attributed_ms": round((rec.stage_s + rec.shared_s) * 1000, 3),
+            "shared_ms": round(rec.shared_s * 1000, 3),
             "truncated": rec.truncated,
             "spans": [s.to_dict() for s in
                       sorted(rec.spans, key=lambda s: s.wall)],
         }
 
-    def _gauge(self) -> None:
-        from .metrics import REGISTRY
-
-        g = REGISTRY.gauge(
-            "tikv_trace_ring_traces",
-            "Traces held per tracer ring (live = still open)",
-        )
-        g.set(len(self._live), ring="live")
-        g.set(len(self._recent), ring="recent")
-        g.set(len(self._slow), ring="slow")
+    def publish(self) -> None:
+        """What only a reader of the registry needs, computed when it
+        renders: the rings' sizes and the collector pauses noted so far."""
+        _RING_TRACES.set(len(self._live), ring="live")
+        _RING_TRACES.set(len(self._recent), ring="recent")
+        _RING_TRACES.set(len(self._slow), ring="slow")
+        self._drain_gc()
 
     # -- export (debug_traces RPC, /debug/traces, ctl.py trace) --------------
 
@@ -647,6 +930,7 @@ class _Attach:
 
 
 TRACER = Tracer()
+REGISTRY.on_render(TRACER.publish)
 
 # -- module-level facade (the call-site API) --------------------------------
 
@@ -680,6 +964,26 @@ def span(name: str, **tags):
     return TRACER.span(name, **tags)
 
 
+def stage(name: str, **tags):
+    return TRACER.stage(name, **tags)
+
+
+def timed_stage(name: str, **tags):
+    return TRACER.timed_stage(name, **tags)
+
+
+def set_mirror(factory) -> None:
+    TRACER.set_mirror(factory)
+
+
+def shared(ctxs: list):
+    return TRACER.shared(ctxs)
+
+
+def install_gc_hook() -> None:
+    TRACER.install_gc_hook()
+
+
 def begin(name: str, **tags):
     return TRACER.begin(name, **tags)
 
@@ -709,8 +1013,9 @@ def attach(ctx: dict | None):
 
 
 def remote_span(ctx: dict | None, name: str, start: float | None = None,
-                end: float | None = None, **tags):
-    return TRACER.remote_span(ctx, name, start=start, end=end, **tags)
+                end: float | None = None, stage: bool = False, **tags):
+    return TRACER.remote_span(ctx, name, start=start, end=end, stage=stage,
+                              **tags)
 
 
 def fanin_span(name: str, parents: list, **tags):
