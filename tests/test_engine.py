@@ -221,3 +221,51 @@ def test_native_delete_range_after_hinted_inserts():
     snap = ne.snapshot()
     got = [k for k, _ in snap.scan_cf("default", b"k", b"l")]
     assert got == [b"k%02d" % i for i in list(range(5)) + list(range(15, 20))]
+
+
+def _touch(engine, op, cf):
+    wb = WriteBatch()
+    if op == "put":
+        wb.put_cf(cf, b"k", b"v")
+    elif op == "delete":
+        wb.delete_cf(cf, b"k")
+    else:
+        wb.delete_range_cf(cf, b"a", b"z")
+    engine.write(wb)
+
+
+@pytest.mark.parametrize("op", ["put", "delete", "delete_range"])
+def test_cf_touched_seq_moves_with_its_cf_only(engine, op):
+    """The per-CF stamp (Snapshot.cf_touched_seq) is the sequence of the
+    newest batch that touched that CF: it moves on put, delete and
+    delete_range there, stands still under writes to other CFs, and never
+    passes the engine's sequence."""
+    engine.put_cf(CF_LOCK, b"k", b"v")
+    stamp = engine.cf_touched_seq(CF_LOCK)
+    assert stamp == engine.seq()
+    _touch(engine, op, CF_WRITE)
+    _touch(engine, op, CF_DEFAULT)
+    assert engine.cf_touched_seq(CF_LOCK) == stamp < engine.seq()
+    _touch(engine, op, CF_LOCK)
+    assert stamp < engine.cf_touched_seq(CF_LOCK) == engine.seq()
+    assert engine.cf_touched_seq(CF_WRITE) < engine.seq()
+
+
+def test_snapshot_sequence_and_late_stamp(engine):
+    """A snapshot reads at its own sequence; the stamp is read from the
+    engine when asked, so it can name a batch the snapshot does not hold
+    (the race the region cache's lock-free memo must survive)."""
+    engine.put_cf(CF_WRITE, b"k", b"v")
+    snap = engine.snapshot()
+    assert snap.sequence() == engine.seq()
+    assert snap.cf_touched_seq(CF_LOCK) <= snap.sequence()
+    engine.put_cf(CF_LOCK, b"k", b"v")
+    assert snap.sequence() < snap.cf_touched_seq(CF_LOCK) == engine.seq()
+    assert snap.get_cf(CF_LOCK, b"k") is None
+    assert engine.snapshot().sequence() == engine.seq()
+
+
+def test_bulk_load_moves_the_stamp(engine):
+    engine.bulk_load(CF_LOCK, [(b"a", b"1"), (b"b", b"2")])
+    assert 0 < engine.cf_touched_seq(CF_LOCK) == engine.seq()
+    assert engine.cf_touched_seq(CF_WRITE) == 0

@@ -10,7 +10,7 @@ import os
 import pytest
 
 from tikv_tpu.native.engine import NativeEngine, native_available
-from tikv_tpu.storage.engine import CF_DEFAULT, CF_WRITE, WriteBatch
+from tikv_tpu.storage.engine import CF_DEFAULT, CF_LOCK, CF_WRITE, WriteBatch
 
 pytestmark = pytest.mark.skipif(not native_available(), reason="no native engine")
 
@@ -554,3 +554,55 @@ def test_reads_do_not_serialize_behind_wal_sync(tmp_path):
     # while 60MB of synced batches went through; off-lock WAL IO gives it
     # hundreds.  10 is a conservative floor that still proves overlap.
     assert reads_during[0] >= 10, (reads_during[0], wt)
+
+
+def test_cf_touched_seq_survives_flush_and_merge(tmp_path):
+    """Flushes, run merges and compaction move versions between the memtable
+    and runs; no snapshot reads anything else for them, so the per-CF stamp
+    stands still through all three, and goes on counting after them."""
+    e = NativeEngine(path=str(tmp_path / "db"))
+    put(e, b"l1", b"x", cf=CF_LOCK)
+    delete(e, b"l1", cf=CF_LOCK)
+    stamp = e.cf_touched_seq(CF_LOCK)
+    assert stamp == e.seq()
+    for gen in range(3):
+        put(e, b"w%d" % gen, b"v", cf=CF_WRITE)
+        e.flush()
+        assert e.cf_touched_seq(CF_LOCK) == stamp < e.seq()
+    assert e.run_count("lock") == 1 and e.run_count("write") == 3
+    assert e.merge_runs("write") == 1
+    e.compact()
+    assert e.cf_touched_seq(CF_LOCK) == stamp
+    assert e.cf_touched_seq(CF_WRITE) == e.seq()
+    wb = WriteBatch()
+    wb.delete_range_cf(CF_LOCK, b"a", b"z")
+    e.write(wb)
+    assert e.cf_touched_seq(CF_LOCK) == e.seq() > stamp
+    e.close()
+
+
+def test_cf_touched_seq_after_reopen_and_ingest(tmp_path):
+    """A reopened engine cannot say which CF its runs' newest batch touched:
+    every stamp starts at the recovered sequence (too high costs a scan,
+    never skips one).  An ingested SST stamps the CFs it loads, no other."""
+    from tikv_tpu.native.engine import build_sst
+
+    e = NativeEngine(path=str(tmp_path / "db"))
+    put(e, b"l1", b"x", cf=CF_LOCK)
+    put(e, b"w1", b"v", cf=CF_WRITE)
+    e.flush()
+    put(e, b"w2", b"v", cf=CF_WRITE)  # replayed from the WAL on reopen
+    assert e.cf_touched_seq(CF_LOCK) < e.seq()
+    seq = e.seq()
+    e.close()
+    e = NativeEngine(path=str(tmp_path / "db"))
+    assert e.seq() == seq
+    assert e.cf_touched_seq(CF_LOCK) == e.cf_touched_seq(CF_WRITE) == seq
+    sst = str(tmp_path / "in.sst")
+    build_sst(sst, [("write", b"w3", b"v")])
+    e.ingest_sst(sst)
+    assert e.cf_touched_seq(CF_LOCK) == seq < e.cf_touched_seq(CF_WRITE) == e.seq()
+    build_sst(sst, [("lock", b"l2", b"x")])
+    e.ingest_sst(sst)
+    assert e.cf_touched_seq(CF_LOCK) == e.seq() > e.cf_touched_seq(CF_WRITE)
+    e.close()

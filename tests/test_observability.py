@@ -55,6 +55,7 @@ LAZY_SERIES = {
     "tikv_coprocessor_follower_read_total",
     "tikv_coprocessor_region_cache_total",
     "tikv_coprocessor_region_cache_wt_lost_total",
+    "tikv_coprocessor_region_cache_lock_check_total",
     "tikv_coprocessor_integrity_mismatch_total",
     "tikv_coprocessor_integrity_quarantine_total",
     "tikv_coprocessor_integrity_scrub_total",

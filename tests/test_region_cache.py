@@ -20,14 +20,25 @@ from tikv_tpu.copr.rpn import call, col, const_int
 from tikv_tpu.copr.rowv2 import encode_row_v2
 from tikv_tpu.copr.table import encode_row, record_key, record_range
 from tikv_tpu.storage.btree_engine import BTreeEngine
+from tikv_tpu.storage.engine import CF_LOCK, Snapshot
 from tikv_tpu.storage.kv import LocalEngine
+from tikv_tpu.storage.txn_types import Key
+from tikv_tpu.util import trace
+from tikv_tpu.util.metrics import REGISTRY
+
+try:
+    from tikv_tpu.native.engine import NativeEngine, native_available
+
+    _NATIVE = native_available()
+except ImportError:
+    _NATIVE = False
 
 NON_HANDLE = [c for c in PRODUCT_COLUMNS if not c.is_pk_handle]
 N_ROWS = 64
 
 
-def _engine(n=N_ROWS, v2=False, table_id=TABLE_ID):
-    eng = BTreeEngine()
+def _engine(n=N_ROWS, v2=False, table_id=TABLE_ID, mk=BTreeEngine):
+    eng = mk()
     enc = encode_row_v2 if v2 else encode_row
     for i in range(n):
         name = [b"apple", b"banana", b"cherry"][i % 3]
@@ -285,3 +296,326 @@ def test_delta_rollback_pick_resolves_older_version():
     # row 9 must still be present (update fingerprint, keep old value)
     r2 = warm.handle_request(_req(_sel_dag(), 300, 4))
     assert r2.data == cold.handle_request(_req(_sel_dag(), 300, 4)).data
+
+
+# ---------------------------------------------------------------------------
+# the lock-free memo (docs/region_column_cache.md "Locks"): a warm hit skips
+# the CF_LOCK scan only where its snapshot cannot differ, in CF_LOCK, from a
+# snapshot whose scan of the image's ranges met no lock.  What the scan would
+# have said is asked of the scan itself, never of a switch.
+# ---------------------------------------------------------------------------
+
+both_engines = pytest.mark.parametrize("mk_kv", [
+    BTreeEngine,
+    pytest.param(lambda: NativeEngine(), id="NativeEngine", marks=pytest.mark.skipif(
+        not _NATIVE, reason="no native engine")),
+])
+
+
+class _HeldEngine(LocalEngine):
+    """Hands out ``held`` (a snapshot frozen earlier) while it is set."""
+
+    held = None
+
+    def snapshot(self, ctx=None):
+        return self.held if self.held is not None else super().snapshot(ctx)
+
+
+def _checks() -> dict:
+    c = REGISTRY.counter("tikv_coprocessor_region_cache_lock_check_total", "")
+    return {how: c.get(how=how) for how in ("memo", "scan")}
+
+
+def _how(warm, ts, apply_index=3):
+    """Serve one warm hit at ``ts``; say how its lock check was answered, by
+    the stage's tag and by the counter, which must agree."""
+    before = _checks()
+    rate = trace.sample_rate()
+    trace.set_sample_rate(1.0)  # keep this trace whatever the store's rate
+    try:
+        with trace.start_trace("root") as root:
+            r = warm.handle_request(_req(_scan_dag(), ts, apply_index))
+    finally:
+        trace.set_sample_rate(rate)
+    assert r.metrics["region_cache"] == "hit"
+    after = _checks()
+    moved = {how: after[how] - before[how] for how in after}
+    stages = [s for s in trace.TRACER.get(root.rec.trace_id)["spans"]
+              if s["name"] == "cache.lock_check"]
+    assert len(stages) == 1 and sum(moved.values()) == 1
+    how = stages[0]["tags"]["how"]
+    assert moved[how] == 1
+    return how
+
+
+def _warm_with_memo(kv):
+    """An endpoint whose image of the table holds a lock-free memo: a miss,
+    then a hit at a higher ts that scans CF_LOCK and finds it empty."""
+    eng = _HeldEngine(kv)
+    warm = Endpoint(eng, enable_device=True)
+    assert warm.handle_request(
+        _req(_scan_dag(), 200, 3)).metrics["region_cache"] == "miss"
+    assert _how(warm, 300) == "scan"
+    (img,) = warm.region_cache._images.values()
+    assert img.lock_free_seq == kv.seq()
+    return eng, warm, img
+
+
+def _scan_would_say(warm, img, snap, ts) -> int:
+    """The oracle: the scan itself, on an image with no memo to consult."""
+    from tikv_tpu.copr.region_cache import RegionImage
+    from tikv_tpu.storage.mvcc.reader import Statistics
+
+    bare = RegionImage(img.key, img.epoch, img.schema, img.block_rows)
+    return warm.region_cache._check_locks(
+        bare, snap, list(img.key[1]), ts, Statistics())
+
+
+def test_memo_second_rising_hit_skips_the_scan():
+    """(a) nothing written between two hits at rising start_ts: the second
+    is answered by the memo, byte-identical to the cold endpoint."""
+    kv = _engine()
+    _eng, warm, img = _warm_with_memo(kv)
+    cold = Endpoint(LocalEngine(kv), enable_device=True, enable_region_cache=False)
+    assert _how(warm, 400) == "memo"
+    assert _how(warm, 500) == "memo"
+    assert img.snapshot_ts == 500  # still raised to the reader's on a hit
+    assert _scan_would_say(warm, img, kv.snapshot(), 500) == 0
+    r = warm.handle_request(_req(_scan_dag(), 600, 3))
+    assert r.data == cold.handle_request(_req(_scan_dag(), 600, 3)).data
+    # writes to other CFs do not move CF_LOCK's stamp
+    put_committed(kv, record_key(TABLE_ID + 1, 1), b"x", 610, 620)
+    assert _how(warm, 700) == "memo"
+
+
+@both_engines
+def test_memo_lock_written_after_it_is_met(mk_kv):
+    """(b) a lock written after the memo blocks the next reader exactly as
+    the scanners would have it."""
+    kv = _engine(mk=mk_kv)
+    _eng, warm, img = _warm_with_memo(kv)
+    assert _how(warm, 400) == "memo"
+    lock_key(kv, record_key(TABLE_ID, 4), record_key(TABLE_ID, 4), 450)
+    before = _checks()
+    with pytest.raises(Exception, match="locked"):
+        warm.handle_request(_req(_scan_dag(), 500, 3))
+    assert _checks()["scan"] == before["scan"] + 1
+    assert _checks()["memo"] == before["memo"]
+    with pytest.raises(Exception, match="locked"):
+        _scan_would_say(warm, img, kv.snapshot(), 500)
+
+
+@both_engines
+def test_memo_records_the_snapshot_not_a_late_stamp(mk_kv):
+    """(c) the race the witness exists for: a snapshot frozen BEFORE a lock
+    is written scans empty AFTER it; what it records is its own sequence,
+    so a reader on a new snapshot still scans, and meets the lock."""
+    kv = _engine(mk=mk_kv)
+    eng, warm, img = _warm_with_memo(kv)
+    frozen = kv.snapshot()
+    lock_key(kv, record_key(TABLE_ID, 4), record_key(TABLE_ID, 4), 350)
+    assert frozen.cf_touched_seq(CF_LOCK) == kv.seq() > frozen.sequence()
+    eng.held = frozen
+    assert _how(warm, 400) == "scan"  # the stamp moved; the frozen scan is empty
+    assert img.lock_free_seq == frozen.sequence() < kv.seq()
+    eng.held = None
+    with pytest.raises(Exception, match="locked"):
+        warm.handle_request(_req(_scan_dag(), 500, 3))
+    with pytest.raises(Exception, match="locked"):
+        _scan_would_say(warm, img, kv.snapshot(), 500)
+
+
+@both_engines
+def test_memo_reader_older_than_it_scans(mk_kv):
+    """(d) a lock written and removed between two lock-free scans: a reader
+    whose snapshot is OLDER than the memo's, and holds the lock, must not be
+    vouched for by the newer scan."""
+    kv = _engine(mk=mk_kv)
+    eng, warm, img = _warm_with_memo(kv)
+    lock_key(kv, record_key(TABLE_ID, 4), record_key(TABLE_ID, 4), 350)
+    holds_lock = kv.snapshot()
+    kv.delete_cf(CF_LOCK, Key.from_raw(record_key(TABLE_ID, 4)).encoded)
+    assert _how(warm, 400) == "scan"  # CF_LOCK moved: one scan, empty again
+    assert img.lock_free_seq == kv.seq() > holds_lock.sequence()
+    assert _how(warm, 450) == "memo"
+    eng.held = holds_lock
+    before = _checks()
+    with pytest.raises(Exception, match="locked"):
+        warm.handle_request(_req(_scan_dag(), 500, 3))
+    assert _checks() == {"memo": before["memo"], "scan": before["scan"] + 1}
+    eng.held = None
+    assert _how(warm, 600) == "memo"
+
+
+def test_memo_lock_outside_the_ranges_costs_one_scan():
+    """(e) the stamp is per CF and per store: a lock in another table moves
+    it, costs this image one scan, and the memo is set again."""
+    kv = _engine()
+    _eng, warm, img = _warm_with_memo(kv)
+    assert _how(warm, 400) == "memo"
+    lock_key(kv, record_key(TABLE_ID + 1, 9), record_key(TABLE_ID + 1, 9), 410)
+    assert _how(warm, 500) == "scan"
+    assert img.lock_free_seq == kv.seq()
+    assert _how(warm, 600) == "memo"
+
+
+@both_engines
+def test_memo_cf_lock_write_that_bypasses_raft_apply(mk_kv):
+    """(f) unsafe_destroy_range deletes a range of CF_LOCK straight on the
+    engine: no apply, no notify, no apply_index.  The stamp still moves."""
+    from tikv_tpu.server.gc_worker import GcWorker
+
+    kv = _engine(mk=mk_kv)
+    eng, warm, img = _warm_with_memo(kv)
+    assert _how(warm, 400) == "memo"
+    memo = img.lock_free_seq
+    GcWorker(eng).unsafe_destroy_range(
+        record_key(TABLE_ID + 1, 0), record_key(TABLE_ID + 1, 100))
+    assert kv.cf_touched_seq(CF_LOCK) == kv.seq() > memo
+    assert _how(warm, 500) == "scan"
+    assert img.lock_free_seq == kv.seq()
+    assert _how(warm, 600) == "memo"
+
+
+class _NoStampSnapshot(Snapshot):
+    """A snapshot type that keeps no sequence numbers (the trait's default)."""
+
+    def __init__(self, inner):
+        self._inner = inner
+
+    def get_cf(self, cf, key):
+        return self._inner.get_cf(cf, key)
+
+    def cursor_cf(self, cf, lower=None, upper=None):
+        return self._inner.cursor_cf(cf, lower, upper)
+
+
+def test_memo_snapshot_without_a_stamp_scans_every_time():
+    """(g) the behaviour follows what the snapshot can prove."""
+    kv = _engine()
+
+    class _NoStampEngine(LocalEngine):
+        def snapshot(self, ctx=None):
+            return _NoStampSnapshot(self.kv.snapshot())
+
+    warm = Endpoint(_NoStampEngine(kv), enable_device=True)
+    warm.handle_request(_req(_scan_dag(), 200, 3))
+    assert [_how(warm, ts) for ts in (300, 400, 500)] == ["scan"] * 3
+    (img,) = warm.region_cache._images.values()
+    assert img.lock_free_seq is None
+    # and a memo set through a snapshot that can say is no use to one that cannot
+    img.lock_free_seq = kv.seq()
+    assert _how(warm, 600) == "scan"
+
+
+def test_memo_not_recorded_over_a_non_blocking_lock():
+    """(h) a lock that does not block ts 300 may block ts 1100: a scan that
+    met any lock records nothing."""
+    kv = _engine()
+    eng = _HeldEngine(kv)
+    warm = Endpoint(eng, enable_device=True)
+    warm.handle_request(_req(_scan_dag(), 200, 3))
+    lock_key(kv, record_key(TABLE_ID, 4), record_key(TABLE_ID, 4), 1000)
+    assert _how(warm, 300) == "scan"
+    (img,) = warm.region_cache._images.values()
+    assert img.lock_free_seq is None
+    assert _how(warm, 400) == "scan"
+    with pytest.raises(Exception, match="locked"):
+        warm.handle_request(_req(_scan_dag(), 1100, 3))
+
+
+def test_memo_does_not_override_a_dirty_image():
+    """``locks_dirty`` (a write-through batch locked a key in range) still
+    forces a scan, whatever the stamps say."""
+    kv = _engine()
+    _eng, warm, img = _warm_with_memo(kv)
+    img.locks_dirty = True
+    img.locks_dirty_at = 3
+    assert _how(warm, 400) == "scan"
+    assert img.locks_dirty is False
+    assert _how(warm, 500) == "memo"
+
+
+def test_memo_serves_the_warm_checksum_path():
+    """checksum_serve shares _hit_fresh_locked, so the memo and a lock
+    written after it behave there as on a served hit."""
+    kv = _engine()
+    _eng, warm, img = _warm_with_memo(kv)
+    ctx = {"region_id": 7, "region_epoch": (1, 1), "apply_index": 3}
+    ranges = list(img.key[1])
+    before = _checks()
+    if warm.region_cache.checksum_serve(kv.snapshot(), ctx, ranges, 400) is not None:
+        assert _checks() == {"memo": before["memo"] + 1, "scan": before["scan"]}
+        lock_key(kv, record_key(TABLE_ID, 4), record_key(TABLE_ID, 4), 450)
+        with pytest.raises(Exception, match="locked"):
+            warm.region_cache.checksum_serve(kv.snapshot(), ctx, ranges, 500)
+    else:
+        pytest.skip("image has no fingerprint")
+
+
+@both_engines
+def test_memo_agrees_with_the_scan_under_concurrent_lock_writes(mk_kv):
+    """Readers on fresh snapshots race a writer that locks and unlocks a key
+    in range (straight on the engine: no notify, no apply_index).  Whatever
+    the interleaving, a reader is refused exactly when ITS snapshot holds
+    the lock: the memo never vouches for a snapshot it did not cover."""
+    import sys
+    import threading
+    import time
+
+    kv = _engine(mk=mk_kv)
+    cache = RegionColumnCache()
+    ctx = {"region_id": 7, "region_epoch": (1, 1), "apply_index": 3}
+    ranges = [record_range(TABLE_ID)]
+    locked_key = Key.from_raw(record_key(TABLE_ID, 4)).encoded
+    assert cache.serve(kv.snapshot(), ctx, PRODUCT_COLUMNS, ranges, 200)[1] == "miss"
+    stop = time.monotonic() + 0.5
+    ts_mu = threading.Lock()
+    next_ts = [300]
+    wrong: list = []
+    seen = {"hit": 0, "locked": 0}
+
+    def writer():
+        # the sleeps hand the interpreter to the readers with the lock held,
+        # and with it gone, whatever the scheduler does under load
+        try:
+            while time.monotonic() < stop:
+                lock_key(kv, record_key(TABLE_ID, 4), record_key(TABLE_ID, 4), 50)
+                time.sleep(0.001)
+                kv.delete_cf(CF_LOCK, locked_key)
+                time.sleep(0.001)
+        except Exception as e:  # noqa: BLE001 — a dead writer must fail the test
+            wrong.append(("writer", repr(e)))
+
+    def reader():
+        while time.monotonic() < stop:
+            with ts_mu:
+                next_ts[0] += 1
+                ts = next_ts[0]
+            snap = kv.snapshot()
+            holds = snap.get_cf(CF_LOCK, locked_key) is not None
+            try:
+                outcome = cache.serve(snap, ctx, PRODUCT_COLUMNS, ranges, ts)[1]
+            except Exception as e:  # noqa: BLE001 — KeyIsLocked, by its text
+                outcome = "locked" if "locked" in str(e).lower() else repr(e)
+            if outcome == "stale":
+                continue  # a slower reader's ts fell below the image's
+            if outcome != ("locked" if holds else "hit"):
+                wrong.append((ts, holds, outcome))
+            elif outcome in seen:
+                seen[outcome] += 1
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=writer)] + [
+            threading.Thread(target=reader) for _ in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    assert not wrong, wrong[:5]
+    assert seen["hit"] and seen["locked"], seen

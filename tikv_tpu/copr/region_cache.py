@@ -251,6 +251,11 @@ class RegionImage:
         # nothing about that batch's lock.
         self.locks_dirty = False
         self.locks_dirty_at = 0
+        # lock-free memo: the engine sequence of the newest snapshot whose
+        # CF_LOCK scan over this image's ranges met no lock at all, or None.
+        # A reader whose snapshot provably reads the same CF_LOCK skips the
+        # scan at any start_ts (_check_locks).
+        self.lock_free_seq: int | None = None
         # integrity fingerprint (docs/integrity.md): one crc64 per row over
         # the RAW (key, value) chain — byte-identical to the coprocessor
         # Checksum entry — plus a commit_ts-mixed variant, both folded
@@ -893,7 +898,7 @@ class RegionColumnCache:
                 # the one thing a buffered batch cannot prove absent, so a
                 # dirty lock state re-scans CF_LOCK (tiny) first.
                 if img.locks_dirty or start_ts > img.snapshot_ts:
-                    seen = self._check_locks(snap, ranges, start_ts, stats)
+                    seen = self._check_locks(img, snap, ranges, start_ts, stats)
                     if seen == 0 and apply_index >= img.locks_dirty_at:
                         img.locks_dirty = False
                 n_touch = len(pend["changed"]) + len(pend["deleted"])
@@ -1517,7 +1522,7 @@ class RegionColumnCache:
                 or img.max_commit_ts <= img.snapshot_ts)):
             return False
         if start_ts > img.snapshot_ts or img.locks_dirty:
-            seen = self._check_locks(snap, ranges, start_ts, stats)
+            seen = self._check_locks(img, snap, ranges, start_ts, stats)
             if seen == 0 and apply_index >= img.locks_dirty_at:
                 # this snapshot contains the dirtying batch and the range is
                 # lock-free — safe to stop re-scanning.  An OLDER snapshot
@@ -1526,19 +1531,51 @@ class RegionColumnCache:
             img.snapshot_ts = max(img.snapshot_ts, start_ts)
         return True
 
-    def _check_locks(self, snap, ranges, ts, stats) -> int:
+    def _check_locks(self, img, snap, ranges, ts, stats) -> int:
         """Raise on a blocking lock; return how many locks the ranges hold
-        (0 lets callers clear a dirty-lock flag)."""
+        (0 lets callers clear a dirty-lock flag).
+
+        The CF_LOCK scan is skipped (``how=memo``) only where ``snap`` cannot
+        differ, in CF_LOCK, from a snapshot whose scan of these ranges met no
+        lock: an empty range blocks no reader whatever its timestamp.  The
+        witness is the engine's own: ``snap`` reads at sequence S, the memo's
+        snapshot read at S0, and no batch has touched CF_LOCK after the older
+        of the two (``cf_touched_seq``, read after both were taken, so it can
+        only be too high).  A snapshot that cannot say, a dirty image, or
+        any CF_LOCK write anywhere in the store since, scans as before."""
+        from ..util.metrics import REGISTRY
+
         seen = 0
         walked0 = stats.lock.next
         with trace.stage("cache.lock_check") as st:
-            for start, end in ranges:
-                enc_start = Key.from_raw(start).encoded
-                enc_end = Key.from_raw(end).encoded
-                for k, v in snap.scan_cf(CF_LOCK, enc_start, enc_end):
-                    stats.lock.next += 1
-                    seen += 1
-                    _check_lock(v, Key.from_encoded(k).to_raw(), ts, frozenset())
+            seq = snap.sequence()
+            memo = img.lock_free_seq
+            how = "scan"
+            if seq is not None and memo is not None and not img.locks_dirty:
+                touched = snap.cf_touched_seq(CF_LOCK)
+                if touched is not None and touched <= min(seq, memo):
+                    how = "memo"
+            # tagged and counted before the scan, which may raise
+            st.tag(how=how)
+            REGISTRY.counter(
+                "tikv_coprocessor_region_cache_lock_check_total",
+                "Warm-hit lock checks, by how they were answered (memo: the "
+                "snapshot proved CF_LOCK unchanged since a lock-free scan)",
+            ).inc(how=how)
+            if how == "scan":
+                for start, end in ranges:
+                    enc_start = Key.from_raw(start).encoded
+                    enc_end = Key.from_raw(end).encoded
+                    for k, v in snap.scan_cf(CF_LOCK, enc_start, enc_end):
+                        stats.lock.next += 1
+                        seen += 1
+                        _check_lock(v, Key.from_encoded(k).to_raw(), ts, frozenset())
+                if seen == 0 and seq is not None:
+                    # the SNAPSHOT's sequence, never a stamp read after it: a
+                    # lock written since is not in what was scanned.  A lock
+                    # that did not block this ts may block the next: seen > 0
+                    # records nothing
+                    img.lock_free_seq = seq if memo is None else max(memo, seq)
             st.tag(locks_seen=seen, keys_walked=stats.lock.next - walked0)
         return seen
 

@@ -134,6 +134,14 @@ struct Perf {
 struct Engine {
   Table cfs[kNumCfs];
   uint64_t seq = 0;
+  // per CF, the seq of the newest batch that put, deleted or range-deleted
+  // in it: set where the batch is applied, so under the same unique mu that
+  // publishes seq, and never above it for a reader under mu.  "Has this CF
+  // changed between two snapshots" is then two integers, not a scan (the
+  // region column cache's lock check, docs/write_path.md).  Flushes, merges
+  // and compaction change where versions live, not what a snapshot reads,
+  // and leave it alone.
+  uint64_t cf_touched_seq[kNumCfs] = {};
   std::multiset<uint64_t> snapshots;
   mutable std::shared_mutex mu;
   // Writer serialization, SEPARATE from mu: the WAL append + fdatasync —
@@ -331,6 +339,7 @@ int apply_batch(Engine* e, const uint8_t* data, uint64_t len, uint64_t seq) {
     std::string val(reinterpret_cast<const char*>(p), vlen);
     p += vlen;
     Table& t = e->cfs[cf];
+    if (op != 4) e->cf_touched_seq[cf] = seq;  // an SST stamps the CFs it loads
     if (op == 1) {
       put_version(e, t, std::move(key), seq, false, std::move(val), min_snap);
     } else if (op == 2) {
@@ -371,6 +380,7 @@ int load_sst_from_buf(Engine* e, const uint8_t* data, uint64_t len, uint64_t seq
     p += klen;
     uint32_t vlen = read_u32(p);
     if (static_cast<uint64_t>(end - p) < vlen) return -1;
+    e->cf_touched_seq[cf] = seq;
     // sorted input streams through the emplace-hint fast path in put_version
     put_version(e, e->cfs[cf], std::move(key), seq, false,
                 std::string(reinterpret_cast<const char*>(p), vlen), min_snap);
@@ -1844,6 +1854,9 @@ void* eng_open_at(const char* path, int sync_mode) {
     delete e;
     return nullptr;
   }
+  // which CF the runs' newest batch touched is not recorded: every CF starts
+  // as touched at the recovered seq (too high costs a scan, never skips one)
+  for (int cf = 0; cf < kNumCfs; cf++) e->cf_touched_seq[cf] = e->seq;
   return e;
 }
 
@@ -2095,6 +2108,14 @@ uint64_t eng_seq(void* h) {
   Engine* e = static_cast<Engine*>(h);
   std::shared_lock lk(e->mu);
   return e->seq;
+}
+
+// seq of the newest batch that touched `cf` (Engine::cf_touched_seq)
+uint64_t eng_cf_touched_seq(void* h, int cf) {
+  Engine* e = static_cast<Engine*>(h);
+  if (cf < 0 || cf >= kNumCfs) return UINT64_MAX;
+  std::shared_lock lk(e->mu);
+  return e->cf_touched_seq[cf];
 }
 
 uint64_t eng_mem_bytes(void* h) {

@@ -122,6 +122,8 @@ def _load():
         for fn in (lib.eng_seq, lib.eng_mem_bytes, lib.eng_wal_bytes):
             fn.argtypes = [ctypes.c_void_p]
             fn.restype = ctypes.c_uint64
+        lib.eng_cf_touched_seq.argtypes = [ctypes.c_void_p, ctypes.c_int]
+        lib.eng_cf_touched_seq.restype = ctypes.c_uint64
         lib.eng_compact_step.argtypes = [
             ctypes.c_void_p, ctypes.c_int, ctypes.c_char_p, ctypes.c_uint64,
             ctypes.c_uint64, ctypes.POINTER(u8p), ctypes.POINTER(ctypes.c_uint64),
@@ -307,6 +309,12 @@ class NativeSnapshot(Snapshot):
         if not self._released and self._engine._handle is not None:
             self._lib.eng_release_snapshot(self._handle, self._seq)
             self._released = True
+
+    def sequence(self) -> int:
+        return self._seq
+
+    def cf_touched_seq(self, cf: str) -> int:
+        return self._engine.cf_touched_seq(cf)
 
     def get_cf(self, cf: str, key: bytes) -> bytes | None:
         out = ctypes.POINTER(ctypes.c_uint8)()
@@ -502,6 +510,11 @@ class NativeEngine(KvEngine):
 
     def seq(self) -> int:
         return self._lib.eng_seq(self._handle)
+
+    def cf_touched_seq(self, cf: str) -> int:
+        """Sequence number of the newest batch that put, deleted or
+        range-deleted in ``cf`` (``Snapshot.cf_touched_seq``)."""
+        return self._lib.eng_cf_touched_seq(self._handle, _CF_IDS[cf])
 
     def mem_bytes(self) -> int:
         """Approximate resident key+value bytes (tikv_alloc-style accounting)."""

@@ -86,6 +86,13 @@ class RegionSnapshot(Snapshot):
             # offered only over an engine that has it: callers probe for it
             self.scan_raw = self._scan_raw
 
+    def sequence(self) -> int | None:
+        return self._snap.sequence()
+
+    def cf_touched_seq(self, cf: str) -> int | None:
+        # per CF and per STORE: another region's write to the CF moves it too
+        return self._snap.cf_touched_seq(cf)
+
     def _clamp(self, start: bytes, end: bytes | None) -> tuple[bytes, bytes]:
         lo = max(keys.data_key(start), self._lower)
         hi = self._upper if end is None else min(keys.data_key(end), self._upper)

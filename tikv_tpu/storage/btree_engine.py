@@ -78,10 +78,18 @@ class _ListCursor(Cursor):
 
 
 class BTreeSnapshot(Snapshot):
-    __slots__ = ("_states",)
+    __slots__ = ("_states", "_seq", "_engine")
 
-    def __init__(self, states: dict[str, _CfState]):
+    def __init__(self, states: dict[str, _CfState], seq: int, engine: "BTreeEngine"):
         self._states = states
+        self._seq = seq
+        self._engine = engine
+
+    def sequence(self) -> int:
+        return self._seq
+
+    def cf_touched_seq(self, cf: str) -> int:
+        return self._engine.cf_touched_seq(cf)
 
     def get_cf(self, cf: str, key: bytes) -> bytes | None:
         return self._states[cf].vals.get(key)
@@ -94,16 +102,31 @@ class BTreeEngine(KvEngine):
     def __init__(self, cfs: tuple[str, ...] = ALL_CFS):
         self._lock = threading.RLock()
         self._cfs: dict[str, _CfState] = {cf: _CfState() for cf in cfs}
+        # one number per batch, and per CF the number of the newest batch
+        # that touched it (Snapshot.sequence / cf_touched_seq), both moved
+        # under _lock with the batch itself
+        self._seq = 0
+        self._cf_seq: dict[str, int] = {cf: 0 for cf in cfs}
 
     def _writable(self, cf: str) -> _CfState:
         state = self._cfs[cf]
         if state.frozen:
             state = state.clone()
             self._cfs[cf] = state
+        self._cf_seq[cf] = self._seq
         return state
+
+    def seq(self) -> int:
+        with self._lock:
+            return self._seq
+
+    def cf_touched_seq(self, cf: str) -> int:
+        with self._lock:
+            return self._cf_seq[cf]
 
     def write(self, batch: WriteBatch) -> None:
         with self._lock:
+            self._seq += 1
             for op, cf, key, val in batch.ops:
                 state = self._writable(cf)
                 if op == "put":
@@ -127,6 +150,7 @@ class BTreeEngine(KvEngine):
     def bulk_load(self, cf: str, items: list[tuple[bytes, bytes]]) -> None:
         """Merge a batch of (key, value) pairs in one sort — O((n+m) log(n+m))."""
         with self._lock:
+            self._seq += 1
             state = self._writable(cf)
             state.vals.update(items)
             state.keys = sorted(state.vals)
@@ -135,7 +159,7 @@ class BTreeEngine(KvEngine):
         with self._lock:
             for state in self._cfs.values():
                 state.frozen = True
-            return BTreeSnapshot(dict(self._cfs))
+            return BTreeSnapshot(dict(self._cfs), self._seq, self)
 
     def get_cf(self, cf: str, key: bytes) -> bytes | None:
         with self._lock:
@@ -146,7 +170,7 @@ class BTreeEngine(KvEngine):
         with self._lock:
             state = self._cfs[cf]
             state.frozen = True
-            return BTreeSnapshot({cf: state})
+            return BTreeSnapshot({cf: state}, self._seq, self)
 
     def scan_cf(
         self,

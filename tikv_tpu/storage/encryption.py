@@ -338,3 +338,9 @@ class EncryptedSnapshot(Snapshot):
 
     def cursor_cf(self, cf, lower=None, upper=None):
         return _DecCursor(self._snap.cursor_cf(cf, lower, upper), self._e)
+
+    def sequence(self):
+        return self._snap.sequence()
+
+    def cf_touched_seq(self, cf):
+        return self._snap.cf_touched_seq(cf)

@@ -77,6 +77,21 @@ class Snapshot(abc.ABC):
     def cursor(self, lower: bytes | None = None, upper: bytes | None = None) -> Cursor:
         return self.cursor_cf(CF_DEFAULT, lower, upper)
 
+    def sequence(self) -> int | None:
+        """The engine sequence number this snapshot reads at: it holds every
+        batch up to it and none after.  None where the engine keeps none."""
+        return None
+
+    def cf_touched_seq(self, cf: str) -> int | None:
+        """Sequence number of the newest batch that put, deleted or
+        range-deleted in ``cf``, read from the engine NOW, so after this
+        snapshot was taken: it may name a batch the snapshot does not hold,
+        never miss one it does.  Read after snapshots ``a`` and ``b`` of one
+        engine were taken, ``cf_touched_seq(cf) <= min(a.sequence(),
+        b.sequence())`` therefore proves that both read the same ``cf``
+        (docs/write_path.md).  None where the engine cannot say."""
+        return None
+
     def scan_cf(
         self,
         cf: str,
