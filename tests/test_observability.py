@@ -56,6 +56,7 @@ LAZY_SERIES = {
     "tikv_coprocessor_region_cache_total",
     "tikv_coprocessor_region_cache_wt_lost_total",
     "tikv_coprocessor_region_cache_lock_check_total",
+    "tikv_coprocessor_region_cache_below_snapshot_total",
     "tikv_coprocessor_integrity_mismatch_total",
     "tikv_coprocessor_integrity_quarantine_total",
     "tikv_coprocessor_integrity_scrub_total",
@@ -108,6 +109,8 @@ LAZY_SERIES = {
     "tikv_engine_memtable_bytes",
     "tikv_engine_run_count",
     "tikv_engine_perf_events",
+    "tikv_engine_closed_call_total",
+    "tikv_server_stop_abandoned_thread_total",
 }
 
 _METRIC_RE = re.compile(r"\btikv_[a-z0-9_]+")

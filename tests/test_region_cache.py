@@ -326,22 +326,28 @@ def _checks() -> dict:
     return {how: c.get(how=how) for how in ("memo", "scan")}
 
 
+def _traced(warm, req) -> tuple:
+    """Serve ``req`` under a kept trace, whatever the store's rate; the
+    response and the trace's spans."""
+    rate = trace.sample_rate()
+    trace.set_sample_rate(1.0)
+    try:
+        with trace.start_trace("root") as root:
+            r = warm.handle_request(req)
+    finally:
+        trace.set_sample_rate(rate)
+    return r, trace.TRACER.get(root.rec.trace_id)["spans"]
+
+
 def _how(warm, ts, apply_index=3):
     """Serve one warm hit at ``ts``; say how its lock check was answered, by
     the stage's tag and by the counter, which must agree."""
     before = _checks()
-    rate = trace.sample_rate()
-    trace.set_sample_rate(1.0)  # keep this trace whatever the store's rate
-    try:
-        with trace.start_trace("root") as root:
-            r = warm.handle_request(_req(_scan_dag(), ts, apply_index))
-    finally:
-        trace.set_sample_rate(rate)
+    r, spans = _traced(warm, _req(_scan_dag(), ts, apply_index))
     assert r.metrics["region_cache"] == "hit"
     after = _checks()
     moved = {how: after[how] - before[how] for how in after}
-    stages = [s for s in trace.TRACER.get(root.rec.trace_id)["spans"]
-              if s["name"] == "cache.lock_check"]
+    stages = [s for s in spans if s["name"] == "cache.lock_check"]
     assert len(stages) == 1 and sum(moved.values()) == 1
     how = stages[0]["tags"]["how"]
     assert moved[how] == 1
@@ -598,8 +604,9 @@ def test_memo_agrees_with_the_scan_under_concurrent_lock_writes(mk_kv):
                 outcome = cache.serve(snap, ctx, PRODUCT_COLUMNS, ranges, ts)[1]
             except Exception as e:  # noqa: BLE001 — KeyIsLocked, by its text
                 outcome = "locked" if "locked" in str(e).lower() else repr(e)
-            if outcome == "stale":
-                continue  # a slower reader's ts fell below the image's
+            # a slower reader's ts falls below the image's snapshot_ts; no
+            # data is committed here, so it is served all the same: "stale"
+            # is a failure like any other outcome
             if outcome != ("locked" if holds else "hit"):
                 wrong.append((ts, holds, outcome))
             elif outcome in seen:
@@ -619,3 +626,307 @@ def test_memo_agrees_with_the_scan_under_concurrent_lock_writes(mk_kv):
         sys.setswitchinterval(old)
     assert not wrong, wrong[:5]
     assert seen["hit"] and seen["locked"], seen
+
+
+# ---------------------------------------------------------------------------
+# readers below the snapshot (docs/region_column_cache.md "Readers below the
+# snapshot"): another session's hit raised snapshot_ts past this reader's
+# start_ts.  The image serves it exactly when it holds nothing the reader may
+# not see: same apply_index and start_ts >= max_commit_ts.  The plain
+# reference is the cache-off endpoint at the reader's own start_ts.
+# ---------------------------------------------------------------------------
+
+
+def _below_counts() -> dict:
+    c = REGISTRY.counter("tikv_coprocessor_region_cache_below_snapshot_total", "")
+    return {o: c.get(outcome=o) for o in ("served", "refused")}
+
+
+def _lookup_tags(warm, req) -> tuple:
+    """The response to ``req`` and the tags of its ``cache.lookup`` stage
+    (the last segment carries them all)."""
+    r, spans = _traced(warm, req)
+    stages = [s for s in spans if s["name"] == "cache.lookup"]
+    assert stages
+    return r, stages[-1]["tags"]
+
+
+def _overtaken(eng, mk_dag=_scan_dag, **kw):
+    """An image another session has read at 400: built at 200, hit at 400."""
+    warm, cold = _pair(eng, **kw)
+    assert warm.handle_request(
+        _req(mk_dag(), 200, 3)).metrics["region_cache"] == "miss"
+    assert warm.handle_request(
+        _req(mk_dag(), 400, 3)).metrics["region_cache"] == "hit"
+    (img,) = warm.region_cache._images.values()
+    assert (img.snapshot_ts, img.max_commit_ts) == (400, 100)
+    return warm, cold, img
+
+
+@pytest.mark.parametrize("mk_dag", [_scan_dag, _sel_dag, _agg_dag],
+                         ids=["scan", "selection", "aggregation"])
+def test_below_snapshot_reader_at_or_above_max_commit_ts_is_a_hit(mk_dag):
+    """(a) same apply_index, start_ts >= max_commit_ts: a hit, byte-identical
+    to the cold endpoint at the reader's ts; snapshot_ts and the memo stay
+    where they were; counted and tagged."""
+    eng = _engine()
+    warm, cold, img = _overtaken(eng, mk_dag)
+    memo = img.lock_free_seq
+    before = _below_counts()
+    hits = warm.region_cache.stats.hits
+    r, tags = _lookup_tags(warm, _req(mk_dag(), 300, 3))
+    assert r.metrics["region_cache"] == "hit"
+    assert tags["outcome"] == "hit" and tags["below_snapshot"] == 1
+    assert r.data == cold.handle_request(_req(mk_dag(), 300, 3)).data
+    assert img.snapshot_ts == 400 and img.max_commit_ts == 100
+    assert img.lock_free_seq == memo is not None and not img.locks_dirty
+    st = warm.region_cache.stats
+    assert (st.below_snapshot, st.stale, st.hits) == (1, 0, hits + 1)
+    assert _below_counts() == {"served": before["served"] + 1,
+                               "refused": before["refused"]}
+    # the bound itself: a reader AT max_commit_ts sees that commit
+    r = warm.handle_request(_req(mk_dag(), 100, 3))
+    assert r.metrics["region_cache"] == "hit"
+    assert r.data == cold.handle_request(_req(mk_dag(), 100, 3)).data
+    # a reader at or above the snapshot is no such reader
+    _r, tags = _lookup_tags(warm, _req(mk_dag(), 400, 3))
+    assert "below_snapshot" not in tags
+    assert st.below_snapshot == 2
+
+
+def _commit_between(eng, kind: str) -> None:
+    """One commit at 350 (start 340), between the readers at 300 and 400."""
+    if kind == "update":
+        put_committed(eng, record_key(TABLE_ID, 5),
+                      encode_row(NON_HANDLE, [b"durian", 999, 5]), 340, 350)
+    elif kind == "insert":
+        put_committed(eng, record_key(TABLE_ID, 500),
+                      encode_row(NON_HANDLE, [b"elderberry", 7, 1]), 340, 350)
+    else:
+        delete_committed(eng, record_key(TABLE_ID, 0), 340, 350)
+
+
+@pytest.mark.parametrize("kind", ["update", "insert", "delete"])
+def test_below_snapshot_reader_under_max_commit_ts_is_stale(kind):
+    """(b) a commit between the two timestamps is IN the image: the lower
+    reader is stale and byte-identical to the cold endpoint.  With the bound
+    loosened (the image made to forget that commit's timestamp, which is
+    what comparing against anything lower amounts to) the same reader is
+    served the image and its answer is wrong: the case guards the bound."""
+    eng = _engine()
+    warm, cold = _pair(eng)
+    warm.handle_request(_req(_scan_dag(), 200, 3))
+    _commit_between(eng, kind)
+    r = warm.handle_request(_req(_scan_dag(), 400, 4))
+    assert r.metrics["region_cache"] == "delta"
+    assert r.data == cold.handle_request(_req(_scan_dag(), 400, 4)).data
+    (img,) = warm.region_cache._images.values()
+    assert (img.snapshot_ts, img.max_commit_ts) == (400, 350)
+    before = _below_counts()
+    want = cold.handle_request(_req(_scan_dag(), 300, 4)).data
+    assert want != r.data
+    r, tags = _lookup_tags(warm, _req(_scan_dag(), 300, 4))
+    assert r.metrics["region_cache"] == "stale" and tags["below_snapshot"] == 1
+    assert r.data == want
+    assert warm.region_cache.stats.stale == 1
+    assert warm.region_cache.stats.below_snapshot == 0
+    assert _below_counts() == {"served": before["served"],
+                               "refused": before["refused"] + 1}
+    # a reader between the commit and the snapshot is served
+    r = warm.handle_request(_req(_scan_dag(), 360, 4))
+    assert r.metrics["region_cache"] == "hit"
+    assert r.data == cold.handle_request(_req(_scan_dag(), 360, 4)).data
+    # the bound loosened
+    img.max_commit_ts = 100
+    r = warm.handle_request(_req(_scan_dag(), 300, 4))
+    assert r.metrics["region_cache"] == "hit" and r.data != want
+
+
+def test_below_snapshot_reader_at_another_apply_index_is_stale():
+    """The image speaks for one engine state only: a reader below the
+    snapshot whose snapshot is at another apply_index is stale, whatever its
+    timestamp."""
+    eng = _engine()
+    warm, cold, img = _overtaken(eng)
+    for apply_index in (2, 4):
+        r = warm.handle_request(_req(_scan_dag(), 300, apply_index))
+        assert r.metrics["region_cache"] == "stale"
+        assert r.data == cold.handle_request(_req(_scan_dag(), 300, apply_index)).data
+    assert warm.region_cache.stats.below_snapshot == 0
+    assert (img.snapshot_ts, img.apply_index) == (400, 3)
+
+
+@both_engines
+def test_below_snapshot_reader_meets_a_lock_at_its_own_ts(mk_kv):
+    """(c) the lock check runs at the READER's start_ts: a lock that blocks
+    the lower reader raises for it as the oracle scan does, and one above
+    its timestamp does not block it."""
+    kv = _engine(mk=mk_kv)
+    warm, cold, img = _overtaken(kv)
+    lock_key(kv, record_key(TABLE_ID, 4), record_key(TABLE_ID, 4), 250)
+    before = warm.region_cache.stats.below_snapshot
+    for ep in (warm, cold):
+        with pytest.raises(Exception, match="locked"):
+            ep.handle_request(_req(_scan_dag(), 300, 3))
+    assert warm.region_cache.stats.below_snapshot == before  # not served
+    r = warm.handle_request(_req(_scan_dag(), 240, 3))
+    assert r.metrics["region_cache"] == "hit"
+    assert r.data == cold.handle_request(_req(_scan_dag(), 240, 3)).data
+    assert warm.region_cache.stats.below_snapshot == before + 1
+    assert img.snapshot_ts == 400 and img.lock_free_seq is not None
+
+
+def test_below_snapshot_warm_checksum_follows_the_rule():
+    """(d) the warm Checksum path shares the one definition."""
+    from tikv_tpu.copr.analyze import checksum_range
+    from tikv_tpu.storage.mvcc import ForwardScanner
+
+    def oracle(ts):
+        start, end = record_range(TABLE_ID)
+        r = checksum_range(list(ForwardScanner(
+            kv.snapshot(), ts, Key.from_raw(start), Key.from_raw(end))))
+        return r["checksum"], r["total_kvs"], r["total_bytes"]
+
+    kv = _engine()
+    warm, _cold, img = _overtaken(kv)
+    if not img.fp_valid:
+        pytest.skip("image has no fingerprint")
+    cache = warm.region_cache
+    ranges = list(img.key[1])
+    ctx = {"region_id": 7, "region_epoch": (1, 1), "apply_index": 3}
+    before = _below_counts()
+    assert cache.checksum_serve(kv.snapshot(), ctx, ranges, 300) == oracle(300)
+    assert cache.stats.below_snapshot == 1 and img.snapshot_ts == 400
+    # a commit between the two timestamps, folded into the image
+    _commit_between(kv, "update")
+    assert warm.handle_request(
+        _req(_scan_dag(), 500, 4)).metrics["region_cache"] == "delta"
+    ctx["apply_index"] = 4
+    assert cache.checksum_serve(kv.snapshot(), ctx, ranges, 300) is None
+    assert cache.checksum_serve(kv.snapshot(), ctx, ranges, 360) == oracle(360)
+    assert oracle(360) != oracle(300)
+    assert _below_counts() == {"served": before["served"] + 2,
+                               "refused": before["refused"] + 1}
+
+
+def test_below_snapshot_warm_checksum_counts_a_reader_once():
+    """One Checksum reader is held to every image of the region in turn: it
+    counts once, `served` if any image answered it, else `refused`."""
+    kv = _engine()
+    warm, _cold, img = _overtaken(kv)
+    narrow = DagRequest(executors=[TableScan(TABLE_ID, PRODUCT_COLUMNS[:2]),
+                                   Limit(1 << 20)])
+    warm.handle_request(_req(narrow, 400, 3))
+    _commit_between(kv, "update")
+    # the first image alone is repaired to apply_index 4 (and touched last,
+    # so the loop meets the narrow image, still at 3, first)
+    assert warm.handle_request(
+        _req(_scan_dag(), 500, 4)).metrics["region_cache"] == "delta"
+    cache = warm.region_cache
+    imgs = list(cache._images.values())
+    assert len(imgs) == 2 and imgs[-1] is img
+    if not all(i.fp_valid for i in imgs):
+        pytest.skip("an image has no fingerprint")
+    ctx = {"region_id": 7, "region_epoch": (1, 1), "apply_index": 4}
+    ranges = list(img.key[1])
+    before = _below_counts()
+    assert cache.checksum_serve(kv.snapshot(), ctx, ranges, 360) is not None
+    assert _below_counts() == {"served": before["served"] + 1,
+                               "refused": before["refused"]}
+    assert cache.checksum_serve(kv.snapshot(), ctx, ranges, 300) is None
+    assert _below_counts() == {"served": before["served"] + 1,
+                               "refused": before["refused"] + 1}
+
+
+def test_below_snapshot_reader_leaves_a_pending_chain_alone():
+    """A reader at the image's own apply_index, overtaken by a session AND by
+    a write-through batch it predates, is served the image as it stands; the
+    pending chain waits for a reader whose snapshot holds it."""
+    from tikv_tpu.copr.region_cache import notify_region_write
+    from tikv_tpu.storage.engine import CF_WRITE, WriteBatch
+    from tikv_tpu.storage.txn_types import Write, WriteType
+
+    kv = _engine()
+    eng = _HeldEngine(kv)
+    warm = Endpoint(eng, enable_device=True)
+    cold = Endpoint(eng, enable_device=True, enable_region_cache=False)
+    warm.handle_request(_req(_scan_dag(), 200, 3))
+    warm.handle_request(_req(_scan_dag(), 400, 3))
+    predates = kv.snapshot()
+    k = Key.from_raw(record_key(TABLE_ID, 5))
+    val = encode_row(NON_HANDLE, [b"durian", 999, 5])
+    ops = [("put", CF_WRITE, k.append_ts(460).encoded,
+            Write(WriteType.PUT, 450, short_value=val).to_bytes())]
+    wb = WriteBatch()
+    wb.put_cf(*ops[0][1:])
+    kv.write(wb)
+    notify_region_write(7, ops, 4)
+    (img,) = warm.region_cache._images.values()
+    assert img.wt_pending is not None
+    eng.held = predates
+    r = warm.handle_request(_req(_scan_dag(), 300, 3))
+    assert r.metrics["region_cache"] == "hit"
+    assert r.data == cold.handle_request(_req(_scan_dag(), 300, 3)).data
+    assert img.wt_pending is not None and img.apply_index == 3
+    eng.held = None
+    r = warm.handle_request(_req(_scan_dag(), 500, 4))
+    assert r.metrics["region_cache"] == "wt_delta"
+    assert r.data == cold.handle_request(_req(_scan_dag(), 500, 4)).data
+    # now the image is ahead of that reader's snapshot: stale, as before
+    eng.held = predates
+    r = warm.handle_request(_req(_scan_dag(), 470, 3))
+    assert r.metrics["region_cache"] == "stale"
+    assert r.data == cold.handle_request(_req(_scan_dag(), 470, 3)).data
+
+
+@pytest.mark.parametrize("seed", [11, 12, 13, 14])
+@pytest.mark.parametrize("mk_dag", [_scan_dag, _agg_dag],
+                         ids=["scan", "aggregation"])
+def test_below_snapshot_seeded_interleaving_equals_cold(seed, mk_dag):
+    """(e) sessions draw timestamps from one oracle, commits and deletes land
+    between some of them, and the tasks reach the region in another order:
+    every answer equals the cold endpoint's at its own start_ts."""
+    rng = np.random.default_rng(seed)
+    eng = _engine()
+    warm, cold = _pair(eng)
+    tso = [200]
+    apply_index = [3]
+
+    def ts():
+        tso[0] += int(rng.integers(1, 9))
+        return tso[0]
+
+    def write():
+        handle = int(rng.integers(0, N_ROWS + 8))
+        start = ts()
+        if rng.random() < 0.3:
+            delete_committed(eng, record_key(TABLE_ID, handle), start, ts())
+        else:
+            name = [b"apple", b"banana", b"fig"][int(rng.integers(3))]
+            put_committed(
+                eng, record_key(TABLE_ID, handle),
+                encode_row(NON_HANDLE, [name, int(rng.integers(50)), handle]),
+                start, ts())
+        apply_index[0] += 1
+
+    outcomes: dict = {}
+    warm.handle_request(_req(mk_dag(), ts(), apply_index[0]))
+    for _round in range(24):
+        # the round's sessions draw their timestamps, a writer commits
+        # between some of them; all of it is in the engine before the first
+        # of the round's tasks is served
+        readers = []
+        for _ in range(int(rng.integers(2, 6))):
+            if rng.random() < 0.15:
+                write()
+            readers.append(ts())
+        for i in rng.permutation(len(readers)):
+            req = _req(mk_dag(), readers[int(i)], apply_index[0])
+            r = warm.handle_request(req)
+            o = r.metrics["region_cache"]
+            outcomes[o] = outcomes.get(o, 0) + 1
+            assert r.data == cold.handle_request(req).data, (seed, readers, o)
+    st = warm.region_cache.stats
+    assert st.below_snapshot > 0 and st.stale > 0, (st.to_dict(), outcomes)
+    assert outcomes.get("stale", 0) == st.stale
+

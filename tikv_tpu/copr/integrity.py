@@ -48,6 +48,7 @@ from __future__ import annotations
 
 import hashlib
 import os
+import threading
 import time
 
 import numpy as np
@@ -439,6 +440,9 @@ class IntegrityScrubber:
         self.interval_s: float | None = None
         self._mu = make_lock("copr.integrity.scrub")
         self._worker = None
+        # set by stop(): a cadenced round in flight ends at its next image
+        # instead of reading a stopping store's engine to the round's end
+        self._halt = threading.Event()
         self._cursor = 0
         # TIKV_TPU_INTEGRITY_FATAL on the cadenced path: the Worker timer
         # swallows exceptions, so the fatal raise is recorded here instead
@@ -462,7 +466,9 @@ class IntegrityScrubber:
 
     # -- the scrub core ------------------------------------------------------
 
-    def scrub_once(self, limit: int | None = None) -> list[dict]:
+    def scrub_once(self, limit: int | None = None, halt=None) -> list[dict]:
+        """One round.  ``halt`` (an Event; the cadenced rounds pass theirs)
+        ends it at the next image once set."""
         cache = self.cache
         if cache is None:
             return []
@@ -478,6 +484,8 @@ class IntegrityScrubber:
         out = []
         fatal: IntegrityMismatch | None = None
         for key in picked:
+            if halt is not None and halt.is_set():
+                break
             try:
                 snap = self._snapshot_for(key)
             except Exception as exc:  # noqa: BLE001 — peer gone, engine closed
@@ -538,6 +546,7 @@ class IntegrityScrubber:
         from ..util.worker import Runnable, Worker
 
         self.interval_s = interval_s
+        self._halt.clear()
         scrubber = self
 
         class _ScrubRunnable(Runnable):
@@ -545,7 +554,7 @@ class IntegrityScrubber:
                 if scrubber.fatal_error is not None:
                     return  # fatal mode already fired: no further rounds
                 try:
-                    scrubber.scrub_once()
+                    scrubber.scrub_once(halt=scrubber._halt)
                 except IntegrityMismatch as exc:
                     # the Worker swallows exceptions, so the fatal raise
                     # would otherwise vanish: record + log it loudly and
@@ -568,10 +577,12 @@ class IntegrityScrubber:
         w.start(_ScrubRunnable())
         self._worker = w
 
-    def stop(self) -> None:
+    def stop(self) -> bool:
+        """False where a round in flight outlived the worker's join: it goes
+        on reading the engine until it ends or the engine is closed."""
         w, self._worker = self._worker, None
-        if w is not None:
-            w.stop()
+        self._halt.set()
+        return w.stop() if w is not None else True
 
     @property
     def running(self) -> bool:

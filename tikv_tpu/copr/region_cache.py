@@ -22,9 +22,13 @@ deltas move.
   those rows re-resolve and re-decode.  Pure in-place updates patch the
   pinned device arrays with ``.at[].set`` scatters; inserts/deletes repack
   the host blocks (still no KV decode) and drop the pins to rebuild lazily.
-* fallback: a read below the image's snapshot ts, a non-vectorizable range,
-  or an over-budget region serves through the existing per-request path —
-  the cache only ever degrades to current behavior.
+* below the snapshot: a reader another session overtook (its ``start_ts``
+  lies below the ``snapshot_ts`` a later reader raised) is a hit whenever the
+  image provably holds nothing it may not see — same ``apply_index`` and
+  ``start_ts >= max_commit_ts`` (``_hit_fresh_locked``).
+* fallback: any other read below the image's snapshot ts, a non-vectorizable
+  range, or an over-budget region serves through the existing per-request
+  path — the cache only ever degrades to current behavior.
 
 Follower stale serving (docs/stale_reads.md): images built off STALE-read
 snapshots need no special handling — a stale snapshot's ``apply_index`` is
@@ -700,7 +704,8 @@ class RegionImage:
 
 
 class RegionCacheStats:
-    __slots__ = ("hits", "misses", "deltas", "delta_rows", "stale", "uncacheable",
+    __slots__ = ("hits", "misses", "deltas", "delta_rows", "stale",
+                 "below_snapshot", "uncacheable",
                  "evictions", "invalidations", "bytes_pinned",
                  "wt_deltas", "wt_rows", "wt_lost")
 
@@ -710,6 +715,7 @@ class RegionCacheStats:
         self.deltas = 0      # scan_delta-path serves (CF_WRITE re-scans)
         self.delta_rows = 0
         self.stale = 0
+        self.below_snapshot = 0  # readers below snapshot_ts the image served
         self.uncacheable = 0
         self.evictions = 0
         self.invalidations = 0
@@ -826,17 +832,21 @@ class RegionColumnCache:
         # the stage is the whole call LESS the lock check and any build or
         # delta repair: those are stages of their own, and suspend this one
         with trace.stage("cache.lookup") as st:
-            out = self._serve(snap, context, columns_info, ranges, start_ts,
-                              statistics)
+            *out, below = self._serve(snap, context, columns_info, ranges,
+                                      start_ts, statistics)
             st.tag(outcome=out[1])
-        return out
+            if below:
+                st.tag(below_snapshot=1)
+        return tuple(out)
 
     def _serve(self, snap, context, columns_info, ranges, start_ts, statistics):
+        """``serve``'s answer, and last whether the reader came below the
+        image's ``snapshot_ts`` (another session overtook it)."""
         region_id = (context or {}).get("region_id")
         epoch = _epoch_of((context or {}).get("region_epoch"))
         apply_index = (context or {}).get("apply_index")
         if region_id is None or epoch is None or apply_index is None:
-            return None, "off", 0
+            return None, "off", 0, False
         tenant = str((context or {}).get("tenant") or "default")
         key = (region_id, tuple(ranges), schema_sig(columns_info))
         stats = statistics or Statistics()
@@ -861,23 +871,30 @@ class RegionColumnCache:
             # (full MVCC resolve + decode) must not stall hits on warm
             # regions.  A concurrent build of the same key wastes one build;
             # the insert below keeps whichever image is newest.
-            return self._build(key, epoch, snap, columns_info, ranges,
-                               start_ts, apply_index, stats, tenant=tenant)
+            return *self._build(key, epoch, snap, columns_info, ranges,
+                                start_ts, apply_index, stats,
+                                tenant=tenant), False
         with self._mu:
             if self._images.get(key) is not img or img.epoch != epoch:
                 # raced with an invalidation between lookup and here
                 self.stats.uncacheable += 1
                 self._count("uncacheable")
-                return None, "uncacheable", 0
-            if start_ts < img.snapshot_ts:
-                self.stats.stale += 1
-                self._count("stale")
-                return None, "stale", 0
-            if self._hit_fresh_locked(img, apply_index, start_ts, snap,
-                                      ranges, stats):
+                return None, "uncacheable", 0, False
+            below = start_ts < img.snapshot_ts
+            fresh = self._hit_fresh_locked(img, apply_index, start_ts, snap,
+                                           ranges, stats)
+            if below:
+                self._count_below_snapshot(served=fresh)
+            if fresh:
                 self.stats.hits += 1
                 self._count("hit")
-                return img.block_cache, "hit", 0
+                return img.block_cache, "hit", 0, below
+            if below:
+                # the image may hold rows this reader must not see: only a
+                # fresh scan can answer it
+                self.stats.stale += 1
+                self._count("stale")
+                return None, "stale", 0, True
             pend = img.wt_pending
             if (pend is not None
                     and img.apply_index > apply_index):
@@ -886,7 +903,7 @@ class RegionColumnCache:
                 # keep the pending for current readers, serve this one cold
                 self.stats.stale += 1
                 self._count("stale")
-                return None, "stale", 0
+                return None, "stale", 0, False
             if (pend is not None
                     and apply_index >= pend["apply_index"]
                     and img.apply_index >= pend["base"]
@@ -913,12 +930,12 @@ class RegionColumnCache:
                     img.wt_pending = None
                     self.stats.hits += 1
                     self._count("hit")
-                    return img.block_cache, "hit", 0
+                    return img.block_cache, "hit", 0, False
                 if img.n_rows and n_touch > _REBUILD_FRACTION * img.n_rows:
                     self._drop(key, reason="delta_too_big")
-                    return self._build(key, epoch, snap, columns_info, ranges,
-                                       start_ts, apply_index, stats,
-                                       tenant=tenant)
+                    return *self._build(key, epoch, snap, columns_info,
+                                        ranges, start_ts, apply_index, stats,
+                                        tenant=tenant), False
                 handles = np.array(sorted(pend["changed"]), dtype=np.int64)
                 delta = {
                     "changed_handles": handles,
@@ -941,7 +958,7 @@ class RegionColumnCache:
                 self._count_delta_rows(n)
                 self._enforce_budget(keep=key)
                 self._gauge_bytes(full=False)
-                return img.block_cache, "wt_delta", n
+                return img.block_cache, "wt_delta", n, False
             with trace.stage("cache.fill", kind="scan_delta"):
                 # lint: allow(lock-blocking-call) -- the fold-in must be atomic
                 # with the image version bump (docs: Concurrency); the scan is
@@ -952,13 +969,13 @@ class RegionColumnCache:
                 self.stats.uncacheable += 1
                 self._count("uncacheable")
                 self._drop(key, reason="unvectorizable")
-                return None, "uncacheable", 0
+                return None, "uncacheable", 0, False
             n_touch = len(delta["changed_handles"]) + len(delta["deleted_handles"])
             if img.n_rows and n_touch > _REBUILD_FRACTION * img.n_rows:
                 self._drop(key, reason="delta_too_big")
-                return self._build(key, epoch, snap, columns_info, ranges,
-                                   start_ts, apply_index, stats,
-                                   tenant=tenant)
+                return *self._build(key, epoch, snap, columns_info, ranges,
+                                    start_ts, apply_index, stats,
+                                    tenant=tenant), False
             with trace.stage("cache.fill", kind="delta") as st:
                 n = img.apply_delta(delta, apply_index, start_ts)
                 st.tag(rows=n)
@@ -984,7 +1001,7 @@ class RegionColumnCache:
             self._count_delta_rows(n)
             self._enforce_budget(keep=key)
             self._gauge_bytes(full=False)
-            return img.block_cache, "delta", n
+            return img.block_cache, "delta", n, False
 
     # -- integrity plane (docs/integrity.md) ---------------------------------
 
@@ -1068,19 +1085,27 @@ class RegionColumnCache:
             return None
         rkey = tuple(ranges)
         stats = Statistics()
+        below = False  # below the snapshot_ts of an image it was held to
         with self._mu:
             for key, img in self._images.items():
                 if key[0] != region_id or key[1] != rkey:
                     continue
                 if img.epoch != epoch or not img.fp_valid:
                     continue
+                img_below = start_ts < img.snapshot_ts
                 # the hit path's exact freshness + stale-guard + lock rules
                 # (ONE definition — _hit_fresh_locked — so the warm
                 # Checksum path can never drift from what a served hit
                 # would have answered)
                 if self._hit_fresh_locked(img, apply_index, start_ts, snap,
                                           ranges, stats):
+                    if img_below:
+                        self._count_below_snapshot(served=True)
                     return img.checksum_parts()
+                below = below or img_below
+            if below:
+                # one reader, once: no image of the region served it
+                self._count_below_snapshot(served=False)
         return None
 
     def invalidate_region(self, region_id: int, reason: str = "epoch") -> None:
@@ -1513,21 +1538,28 @@ class RegionColumnCache:
         on a blocking lock, exactly like the oracle scan would) and
         maintains ``locks_dirty`` / ``snapshot_ts`` like a served hit.
         Caller holds the manager lock."""
-        if start_ts < img.snapshot_ts:
-            # the image may contain rows committed above this reader's ts —
-            # only a fresh scan can answer below the image's snapshot
-            return False
+        below = start_ts < img.snapshot_ts
+        # below: a reader another session overtook.  snapshot_ts was raised
+        # past this start_ts, the rows were not: at apply_index the image
+        # holds exactly the rows of commits at or below max_commit_ts, so a
+        # reader of the same engine state at or above that timestamp sees
+        # those rows and no others (docs/region_column_cache.md, "Readers
+        # below the snapshot").  Any other reader below the snapshot may be
+        # shown rows committed above its timestamp.
         if not (apply_index == img.apply_index and (
-                start_ts == img.snapshot_ts
-                or img.max_commit_ts <= img.snapshot_ts)):
+                start_ts >= img.max_commit_ts if below
+                else (start_ts == img.snapshot_ts
+                      or img.max_commit_ts <= img.snapshot_ts))):
             return False
-        if start_ts > img.snapshot_ts or img.locks_dirty:
+        if start_ts != img.snapshot_ts or img.locks_dirty:
+            # at the READER's start_ts, below the snapshot as above it
             seen = self._check_locks(img, snap, ranges, start_ts, stats)
             if seen == 0 and apply_index >= img.locks_dirty_at:
                 # this snapshot contains the dirtying batch and the range is
                 # lock-free — safe to stop re-scanning.  An OLDER snapshot
                 # seeing no locks proves nothing.
                 img.locks_dirty = False
+            # never lowered: a reader below it leaves it where it was
             img.snapshot_ts = max(img.snapshot_ts, start_ts)
         return True
 
@@ -1640,6 +1672,19 @@ class RegionColumnCache:
             "tikv_coprocessor_region_cache_total",
             "Region column cache lookups, by outcome",
         ).inc(outcome=outcome)
+
+    def _count_below_snapshot(self, served: bool) -> None:
+        """One reader below an image's snapshot_ts, once it is known whether
+        an image served it or it goes on to ``stale``."""
+        from ..util.metrics import REGISTRY
+
+        if served:
+            self.stats.below_snapshot += 1
+        REGISTRY.counter(
+            "tikv_coprocessor_region_cache_below_snapshot_total",
+            "Readers below an image's snapshot_ts, by whether the image "
+            "served them (served) or they went on to stale (refused)",
+        ).inc(outcome="served" if served else "refused")
 
     def _count_wt_lost(self) -> None:
         from ..util.metrics import REGISTRY

@@ -355,15 +355,19 @@ class CoprReadScheduler:
             )
             self._thread.start()
 
-    def stop(self) -> None:
+    def stop(self) -> bool:
+        """False where the dispatch thread was given up on, still inside a
+        task it serves itself."""
         with self._mu:
             if not self._running:
-                return
+                return True
             self._running = False
             self._mu.notify_all()
-        if self._thread is not None:
-            self._thread.join(timeout=5.0)
-            self._thread = None
+        t, self._thread = self._thread, None
+        if t is not None:
+            t.join(timeout=5.0)
+            return not t.is_alive()
+        return True
 
     def execute(self, req: CoprRequest, timeout: float | None = None) -> CoprResponse:
         """Continuous-mode unary entry: enqueue into the request's priority

@@ -39,6 +39,7 @@
 #include <vector>
 
 #include "crypt.h"
+#include "guard.h"
 
 namespace {
 
@@ -1765,7 +1766,17 @@ extern "C" {
 
 static thread_local const enc::State* g_pending_enc = nullptr;
 
-void* eng_open() { return new Engine(); }
+// Every handle that crosses the C ABI is a guard::Handle<Engine> (guard.h):
+// never freed, so a call through a copy of it made after eng_close is turned
+// away with guard::kClosed instead of walking a freed engine, and a call in
+// flight when eng_close comes holds the engine until it returns.
+#define ENG_OR(closed) GUARD_OR(Engine, closed)
+
+static void* guarded(Engine* e) {
+  return e == nullptr ? nullptr : new guard::Handle<Engine>(e);
+}
+
+void* eng_open() { return guarded(new Engine()); }
 
 // Open (or create) a durable engine on a directory.  sync_mode: 1 = WAL
 // fdatasync on every commit (crash-durable), 0 = OS-buffered (fast, loses
@@ -1783,7 +1794,7 @@ static enc::State make_enc_state(uint32_t current_id, const uint32_t* ids,
   return st;
 }
 
-void* eng_open_at(const char* path, int sync_mode) {
+static Engine* open_at(const char* path, int sync_mode) {
   Engine* e = new Engine();
   e->dir = path;
   e->sync_mode = sync_mode;
@@ -1860,6 +1871,10 @@ void* eng_open_at(const char* path, int sync_mode) {
   return e;
 }
 
+void* eng_open_at(const char* path, int sync_mode) {
+  return guarded(open_at(path, sync_mode));
+}
+
 // Durable open with encryption at rest: (ids, keys32) is the data-key
 // registry from the Python DataKeyManager (manager/mod.rs:398 role); files
 // written from here on encrypt under `current_id`, existing files decrypt
@@ -1872,16 +1887,16 @@ void* eng_open_at_enc(const char* path, int sync_mode, uint32_t current_id,
   // real open through a thread-local (the open path stays ONE function)
   enc::State st = make_enc_state(current_id, ids, keys32, n);
   g_pending_enc = &st;
-  void* e = eng_open_at(path, sync_mode);
+  Engine* e = open_at(path, sync_mode);
   g_pending_enc = nullptr;
-  return e;
+  return guarded(e);
 }
 
 // Rotate the data-key registry on a RUNNING engine: new runs/WAL segments
 // use `current_id`; files already on disk keep their sidecar key.
 int eng_set_encryption(void* h, uint32_t current_id, const uint32_t* ids,
                        const uint8_t* keys32, int n) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(guard::kClosed);
   // write_mu keeps the live WAL segment's identity stable; enc_mu covers
   // concurrent readers of the registry (background compaction writers)
   std::lock_guard<std::mutex> wl(e->write_mu);
@@ -1890,14 +1905,17 @@ int eng_set_encryption(void* h, uint32_t current_id, const uint32_t* ids,
   return 0;
 }
 
+// Waits for the calls in flight, then frees the engine; the handle stays.
+// A second close finds nothing to free.
 void eng_close(void* h) {
-  Engine* e = static_cast<Engine*>(h);
+  Engine* e = guard::take<Engine>(h);
+  if (e == nullptr) return;
   if (e->wal_fd >= 0) close(e->wal_fd);
   delete e;
 }
 
 int eng_write(void* h, const uint8_t* data, uint64_t len) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(guard::kClosed);
   std::unique_lock wlk(e->write_mu);
   if (e->failed) return -5;
   // validate BEFORE logging: a malformed batch must never reach the WAL
@@ -1963,7 +1981,7 @@ int eng_build_sst(const char* path, const uint8_t* body, uint64_t len) {
 // WAL-log the op-4 reference, load.  For a pure in-memory engine the file
 // is loaded in place (no copy, no WAL).
 int eng_ingest_sst(void* h, const char* src_path) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(guard::kClosed);
   std::unique_lock wlk(e->write_mu);  // WAL writer: ahead of mu (lock order)
   std::unique_lock lk(e->mu);
   if (e->failed) return -5;
@@ -2033,7 +2051,7 @@ int eng_checkpoint(void* h) {
   // checkpoint == memtable flush: durable sorted runs + WAL truncation.
   // (The legacy O(DB) full-state spill is gone; ckpt_load remains for
   // reading directories written by it.)
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(guard::kClosed);
   std::unique_lock wlk(e->write_mu);  // flush rotates the WAL segment
   std::unique_lock lk(e->mu);
   if (e->dir.empty()) return -1;
@@ -2044,15 +2062,16 @@ int eng_checkpoint(void* h) {
 
 int eng_flush(void* h) { return eng_checkpoint(h); }
 
-void eng_set_mem_limit(void* h, uint64_t bytes) {
-  Engine* e = static_cast<Engine*>(h);
+int eng_set_mem_limit(void* h, uint64_t bytes) {
+  ENG_OR(guard::kClosed);
   std::unique_lock lk(e->mu);
   e->mem_limit = bytes;
+  return 0;
 }
 
 // number of on-disk sorted runs for one CF
 int eng_run_count(void* h, int cf) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(guard::kClosed);
   if (cf < 0 || cf >= kNumCfs) return -2;
   std::shared_lock lk(e->mu);
   return static_cast<int>(e->runs[cf].size());
@@ -2061,7 +2080,7 @@ int eng_run_count(void* h, int cf) {
 // merge all runs of one CF into a single run (background compaction step);
 // returns 1 when a merge happened, 0 when <2 runs, <0 on error
 int eng_merge_runs(void* h, int cf) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(guard::kClosed);
   if (cf < 0 || cf >= kNumCfs) return -2;
   return merge_runs_cf(e, cf);
 }
@@ -2069,8 +2088,8 @@ int eng_merge_runs(void* h, int cf) {
 // perf context (engine_rocks/src/perf_context.rs):
 // out[0]=gets out[1]=memtable_hits out[2]=run_probes out[3]=bloom_skips
 // out[4]=blocks_read out[5]=flushes out[6]=run_merges
-void eng_perf(void* h, uint64_t* out) {
-  Engine* e = static_cast<Engine*>(h);
+int eng_perf(void* h, uint64_t* out) {
+  ENG_OR(guard::kClosed);
   out[0] = e->perf.gets.load(std::memory_order_relaxed);
   out[1] = e->perf.memtable_hits.load(std::memory_order_relaxed);
   out[2] = e->perf.run_probes.load(std::memory_order_relaxed);
@@ -2078,12 +2097,14 @@ void eng_perf(void* h, uint64_t* out) {
   out[4] = e->perf.blocks_read.load(std::memory_order_relaxed);
   out[5] = e->perf.flushes.load(std::memory_order_relaxed);
   out[6] = e->perf.run_merges.load(std::memory_order_relaxed);
+  return 0;
 }
 
-void eng_set_wal_limit(void* h, uint64_t bytes) {
-  Engine* e = static_cast<Engine*>(h);
+int eng_set_wal_limit(void* h, uint64_t bytes) {
+  ENG_OR(guard::kClosed);
   std::unique_lock lk(e->mu);
   e->wal_limit = bytes;
+  return 0;
 }
 
 // import-mode tuning (sst_importer/src/import_mode.rs): bulk loads drop to
@@ -2092,7 +2113,7 @@ void eng_set_wal_limit(void* h, uint64_t bytes) {
 // the buffered tail is NOT durable and the engine stops acking writes rather
 // than promising per-commit durability it cannot deliver.
 int eng_set_sync(void* h, int sync_mode) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(guard::kClosed);
   std::unique_lock wlk(e->write_mu);  // WAL state lives under write_mu
   if (e->sync_mode == 0 && sync_mode == 1 && e->wal_fd >= 0) {
     if (fdatasync(e->wal_fd) != 0) {
@@ -2105,40 +2126,40 @@ int eng_set_sync(void* h, int sync_mode) {
 }
 
 uint64_t eng_seq(void* h) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(UINT64_MAX);
   std::shared_lock lk(e->mu);
   return e->seq;
 }
 
 // seq of the newest batch that touched `cf` (Engine::cf_touched_seq)
 uint64_t eng_cf_touched_seq(void* h, int cf) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(UINT64_MAX);
   if (cf < 0 || cf >= kNumCfs) return UINT64_MAX;
   std::shared_lock lk(e->mu);
   return e->cf_touched_seq[cf];
 }
 
 uint64_t eng_mem_bytes(void* h) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(UINT64_MAX);
   std::shared_lock lk(e->mu);
   return e->mem_bytes;
 }
 
 uint64_t eng_wal_bytes(void* h) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(UINT64_MAX);
   std::lock_guard<std::mutex> wlk(e->write_mu);  // wal state's guard
   return e->wal_bytes;
 }
 
 uint64_t eng_snapshot(void* h) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(UINT64_MAX);
   std::unique_lock lk(e->mu);
   e->snapshots.insert(e->seq);
   return e->seq;
 }
 
 void eng_release_snapshot(void* h, uint64_t seq) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR();
   std::unique_lock lk(e->mu);
   auto it = e->snapshots.find(seq);
   if (it != e->snapshots.end()) e->snapshots.erase(it);
@@ -2148,7 +2169,7 @@ void eng_release_snapshot(void* h, uint64_t seq) {
 // caller frees *out with eng_free.
 int eng_get(void* h, int cf, const uint8_t* key, uint64_t klen,
             uint64_t snap_seq, uint8_t** out, uint64_t* out_len) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(guard::kClosed);
   if (cf < 0 || cf >= kNumCfs) return -2;
   std::string k(reinterpret_cast<const char*>(key), klen);
   std::string mem_val;
@@ -2220,7 +2241,7 @@ long eng_scan(void* h, int cf, uint64_t snap_seq, const uint8_t* start,
               uint64_t start_len, const uint8_t* end_key, uint64_t end_len,
               int has_end, uint64_t limit, int reverse, uint8_t** out,
               uint64_t* out_len) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(guard::kClosed);
   if (cf < 0 || cf >= kNumCfs) return -2;
   std::string s(reinterpret_cast<const char*>(start), start_len);
   std::string en(reinterpret_cast<const char*>(end_key), end_len);
@@ -2259,7 +2280,7 @@ int eng_seek(void* h, int cf, uint64_t snap_seq, const uint8_t* target,
              const uint8_t* upper, uint64_t upper_len, int has_upper,
              int for_prev, uint8_t** kout, uint64_t* kout_len, uint8_t** vout,
              uint64_t* vout_len) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(guard::kClosed);
   if (cf < 0 || cf >= kNumCfs) return -2;
   std::string tg(reinterpret_cast<const char*>(target), target_len);
   std::string lo(reinterpret_cast<const char*>(lower), lower_len);
@@ -2295,7 +2316,7 @@ int eng_seek(void* h, int cf, uint64_t snap_seq, const uint8_t* target,
 void eng_free(uint8_t* p) { free(p); }
 
 uint64_t eng_stats_keys(void* h, int cf) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(UINT64_MAX);
   std::shared_lock lk(e->mu);
   return e->cfs[cf].size();
 }
@@ -2318,7 +2339,7 @@ uint64_t eng_stats_keys(void* h, int cf) {
 long eng_compact_step(void* h, int cf, const uint8_t* from, uint64_t from_len,
                       uint64_t max_keys, uint8_t** resume,
                       uint64_t* resume_len, int* done) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(guard::kClosed);
   if (cf < 0 || cf >= kNumCfs) return -2;
   std::unique_lock lk(e->mu);
   Table& t = e->cfs[cf];
@@ -2421,7 +2442,7 @@ long eng_compact_step(void* h, int cf, const uint8_t* from, uint64_t from_len,
 int eng_mvcc_props(void* h, int cf, const uint8_t* start, uint64_t start_len,
                    const uint8_t* end_key, uint64_t end_len, int has_end,
                    uint64_t snap_seq, uint64_t* out) {
-  Engine* e = static_cast<Engine*>(h);
+  ENG_OR(guard::kClosed);
   if (cf < 0 || cf >= kNumCfs) return -2;
   std::string s(reinterpret_cast<const char*>(start), start_len);
   std::string en(reinterpret_cast<const char*>(end_key), end_len);

@@ -16,7 +16,7 @@ import subprocess
 import threading
 from typing import Iterator
 
-from . import ensure_built
+from . import CLOSED, U64_CLOSED, ensure_built, refused
 from ..storage.engine import ALL_CFS, Cursor, KvEngine, Snapshot, WriteBatch
 from ..util.io_limiter import IoType
 
@@ -65,7 +65,8 @@ def _load():
         if _lib is not None or _lib_err is not None:
             return _lib
         try:
-            ensure_built(_SO, _SRC, os.path.join(_HERE, "crypt.h"))
+            ensure_built(_SO, _SRC, os.path.join(_HERE, "crypt.h"),
+                         os.path.join(_HERE, "guard.h"))
             lib = ctypes.CDLL(_SO)
         except (OSError, subprocess.CalledProcessError) as e:
             _lib_err = str(e)
@@ -117,6 +118,7 @@ def _load():
         lib.eng_checkpoint.argtypes = [ctypes.c_void_p]
         lib.eng_checkpoint.restype = ctypes.c_int
         lib.eng_set_wal_limit.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
+        lib.eng_set_wal_limit.restype = ctypes.c_int
         lib.eng_set_sync.argtypes = [ctypes.c_void_p, ctypes.c_int]
         lib.eng_set_sync.restype = ctypes.c_int
         for fn in (lib.eng_seq, lib.eng_mem_bytes, lib.eng_wal_bytes):
@@ -143,11 +145,13 @@ def _load():
         lib.eng_flush.argtypes = [ctypes.c_void_p]
         lib.eng_flush.restype = ctypes.c_int
         lib.eng_set_mem_limit.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
+        lib.eng_set_mem_limit.restype = ctypes.c_int
         lib.eng_run_count.argtypes = [ctypes.c_void_p, ctypes.c_int]
         lib.eng_run_count.restype = ctypes.c_int
         lib.eng_merge_runs.argtypes = [ctypes.c_void_p, ctypes.c_int]
         lib.eng_merge_runs.restype = ctypes.c_int
         lib.eng_perf.argtypes = [ctypes.c_void_p, ctypes.POINTER(ctypes.c_uint64)]
+        lib.eng_perf.restype = ctypes.c_int
         _lib = lib
         return _lib
 
@@ -193,6 +197,21 @@ def parse_frames(buf: bytes, n: int):
         yield k, v
 
 
+def _failed(what: str, r: int) -> RuntimeError:
+    """The exception for a native call's error code: ``EngineClosed`` where
+    the call came after ``close()`` (guard.h)."""
+    if r == CLOSED:
+        return refused("kv", what)
+    return RuntimeError(f"{what} failed: {r}")
+
+
+def _u64(what: str, r: int) -> int:
+    """An unsigned result, or ``EngineClosed``."""
+    if r == U64_CLOSED:
+        raise refused("kv", what)
+    return r
+
+
 def _take(lib, ptr, length) -> bytes:
     try:
         return ctypes.string_at(ptr, length)
@@ -233,6 +252,8 @@ class _NativeCursor(Cursor):
             self._value = _take(lib, vout, vlen.value)
             return True
         self._key = self._value = None
+        if r < 0:
+            raise _failed("eng_seek", r)
         return False
 
     def seek(self, key: bytes) -> bool:
@@ -292,11 +313,16 @@ class _NativeCursor(Cursor):
 
 
 class NativeSnapshot(Snapshot):
+    """Holds its engine, so the engine is not collected under it.  Its copy
+    of the handle stays good after ``close()``: the handle is never freed
+    (guard.h), and a read through it then raises ``EngineClosed``."""
+
     def __init__(self, engine: "NativeEngine"):
         self._lib = engine._lib
         self._handle = engine._handle
         self._engine = engine
-        self._seq = self._lib.eng_snapshot(self._handle)
+        self._released = True  # until there is a sequence to release
+        self._seq = _u64("eng_snapshot", self._lib.eng_snapshot(self._handle))
         self._released = False
 
     def __del__(self):
@@ -306,7 +332,8 @@ class NativeSnapshot(Snapshot):
             pass
 
     def release(self) -> None:
-        if not self._released and self._engine._handle is not None:
+        if not self._released:
+            # a closed engine has no snapshots left to release: a no-op there
             self._lib.eng_release_snapshot(self._handle, self._seq)
             self._released = True
 
@@ -327,6 +354,8 @@ class NativeSnapshot(Snapshot):
             val = _take(self._lib, out, out_len.value)
             self._engine._io(IoType.FOREGROUND_READ, len(val))
             return val
+        if r < 0:
+            raise _failed("eng_get", r)
         return None
 
     def cursor_cf(self, cf: str, lower: bytes | None = None, upper: bytes | None = None) -> Cursor:
@@ -344,7 +373,7 @@ class NativeSnapshot(Snapshot):
             ctypes.byref(out), ctypes.byref(out_len),
         )
         if n < 0:
-            raise RuntimeError(f"eng_scan failed: {n}")
+            raise _failed("eng_scan", n)
         buf = _take(self._lib, out, out_len.value)
         self._engine._io(IoType.FOREGROUND_READ, len(buf))
         return n, buf
@@ -420,8 +449,9 @@ class NativeEngine(KvEngine):
         if self._keys_mgr is None:
             raise RuntimeError("engine opened without encryption")
         ids, keys, current = _key_registry(self._keys_mgr)
-        if self._lib.eng_set_encryption(self._handle, current, ids, keys, len(ids)) != 0:
-            raise RuntimeError("eng_set_encryption failed")
+        r = self._lib.eng_set_encryption(self._handle, current, ids, keys, len(ids))
+        if r != 0:
+            raise _failed("eng_set_encryption", r)
 
     def rotate_data_key(self) -> int:
         """Mint a new data key and refresh the engine registry."""
@@ -450,18 +480,23 @@ class NativeEngine(KvEngine):
         nbytes = self.mem_bytes() if self.path is not None else 0
         r = self._lib.eng_checkpoint(self._handle)
         if r != 0:
-            raise RuntimeError(f"eng_checkpoint failed: {r}")
+            raise _failed("eng_checkpoint", r)
         self._io(IoType.FLUSH, nbytes)
 
     flush = checkpoint
 
     def set_mem_limit(self, limit: int) -> None:
         """Memtable flush threshold in bytes (0 = manual flush only)."""
-        self._lib.eng_set_mem_limit(self._handle, limit)
+        r = self._lib.eng_set_mem_limit(self._handle, limit)
+        if r != 0:
+            raise _failed("eng_set_mem_limit", r)
 
     def run_count(self, cf: str = "default") -> int:
         """On-disk sorted runs for one CF."""
-        return self._lib.eng_run_count(self._handle, _CF_IDS[cf])
+        r = self._lib.eng_run_count(self._handle, _CF_IDS[cf])
+        if r < 0:
+            raise _failed("eng_run_count", r)
+        return r
 
     def merge_runs(self, cf: str) -> int:
         """Merge every run of a CF into one (background compaction step);
@@ -484,7 +519,7 @@ class NativeEngine(KvEngine):
                         pass
         r = self._lib.eng_merge_runs(self._handle, _CF_IDS[cf])
         if r < 0:
-            raise RuntimeError(f"eng_merge_runs failed: {r}")
+            raise _failed("eng_merge_runs", r)
         if r:
             self._io(IoType.COMPACTION, nbytes)
         return r
@@ -494,7 +529,9 @@ class NativeEngine(KvEngine):
         import ctypes
 
         out = (ctypes.c_uint64 * 7)()
-        self._lib.eng_perf(self._handle, out)
+        r = self._lib.eng_perf(self._handle, out)
+        if r != 0:
+            raise _failed("eng_perf", r)
         names = ("gets", "memtable_hits", "run_probes", "bloom_skips",
                  "blocks_read", "flushes", "run_merges")
         return dict(zip(names, out))
@@ -506,22 +543,23 @@ class NativeEngine(KvEngine):
         if r != 0:
             # the flush closing the unsynced window failed: the buffered tail
             # is not durable and the engine has latched into refuse-writes
-            raise RuntimeError(f"eng_set_sync failed: {r}")
+            raise _failed("eng_set_sync", r)
 
     def seq(self) -> int:
-        return self._lib.eng_seq(self._handle)
+        return _u64("eng_seq", self._lib.eng_seq(self._handle))
 
     def cf_touched_seq(self, cf: str) -> int:
         """Sequence number of the newest batch that put, deleted or
         range-deleted in ``cf`` (``Snapshot.cf_touched_seq``)."""
-        return self._lib.eng_cf_touched_seq(self._handle, _CF_IDS[cf])
+        return _u64("eng_cf_touched_seq",
+                    self._lib.eng_cf_touched_seq(self._handle, _CF_IDS[cf]))
 
     def mem_bytes(self) -> int:
         """Approximate resident key+value bytes (tikv_alloc-style accounting)."""
-        return self._lib.eng_mem_bytes(self._handle)
+        return _u64("eng_mem_bytes", self._lib.eng_mem_bytes(self._handle))
 
     def wal_bytes(self) -> int:
-        return self._lib.eng_wal_bytes(self._handle)
+        return _u64("eng_wal_bytes", self._lib.eng_wal_bytes(self._handle))
 
     # -- compaction ---------------------------------------------------------
 
@@ -545,7 +583,7 @@ class NativeEngine(KvEngine):
                 ctypes.byref(resume), ctypes.byref(resume_len), ctypes.byref(done),
             )
             if r < 0:
-                raise RuntimeError(f"eng_compact_step failed: {r}")
+                raise _failed("eng_compact_step", r)
             total += r
             if done.value:
                 return total
@@ -595,7 +633,7 @@ class NativeEngine(KvEngine):
         Survives crash/reopen; folded into the next checkpoint."""
         r = self._lib.eng_ingest_sst(self._handle, os.fsencode(path))
         if r != 0:
-            raise RuntimeError(f"eng_ingest_sst failed: {r}")
+            raise _failed("eng_ingest_sst", r)
 
     # -- MVCC properties ----------------------------------------------------
 
@@ -613,7 +651,7 @@ class NativeEngine(KvEngine):
             self.seq(), out,
         )
         if r != 0:
-            raise RuntimeError(f"eng_mvcc_props failed: {r}")
+            raise _failed("eng_mvcc_props", r)
         return {
             "num_entries": out[0],
             "num_rows": out[1],
@@ -639,10 +677,12 @@ class NativeEngine(KvEngine):
         return p["num_entries"] >= p["num_rows"] * ratio_threshold
 
     def close(self) -> None:
+        """Free the native engine.  A call in flight on another thread
+        finishes normally first; every call after this one, through the
+        engine or through a snapshot or cursor taken before it, raises
+        ``EngineClosed``; a second close does nothing (guard.h)."""
         self.stop_auto_compaction()
-        if self._handle is not None:
-            self._lib.eng_close(self._handle)
-            self._handle = None
+        self._lib.eng_close(self._handle)
 
     def __del__(self):
         try:
@@ -654,7 +694,7 @@ class NativeEngine(KvEngine):
         self._io(IoType.FOREGROUND_WRITE, len(out))
         r = self._lib.eng_write(self._handle, out, len(out))
         if r != 0:
-            raise RuntimeError(f"eng_write failed: {r}")
+            raise _failed("eng_write", r)
 
     def write(self, batch: WriteBatch) -> None:
         self._write_buf(_serialize_ops(batch.ops))

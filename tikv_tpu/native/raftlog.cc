@@ -63,6 +63,7 @@
 #include <unistd.h>
 
 #include "crypt.h"
+#include "guard.h"
 
 namespace {
 
@@ -690,6 +691,12 @@ struct RaftLogEng {
 
 extern "C" {
 
+// Every handle that crosses the C ABI is a guard::Handle<RaftLogEng>
+// (guard.h): never freed, so a call after rl_close is turned away with
+// guard::kClosed, and a call in flight when rl_close comes holds the log
+// until it returns.
+#define RL_OR(closed) GUARD_OR(RaftLogEng, closed)
+
 static enc::State rl_make_enc(uint32_t current_id, const uint32_t* ids,
                               const uint8_t* keys32, int n) {
   enc::State st;
@@ -735,27 +742,29 @@ void* rl_open_enc(const char* dir, uint64_t seg_bytes, int sync_default,
     delete e;
     return nullptr;
   }
-  return e;
+  return new guard::Handle<RaftLogEng>(e);
 }
 
 // Data-key rotation on a running log: new segments use current_id.
 int rl_set_encryption(void* h, uint32_t current_id, const uint32_t* ids,
                       const uint8_t* keys32, int n) {
-  auto* e = static_cast<RaftLogEng*>(h);
+  RL_OR(guard::kClosed);
   std::lock_guard<std::mutex> wlk(e->wmu);
   std::unique_lock<std::shared_mutex> lk(e->mu);
   e->enc = rl_make_enc(current_id, ids, keys32, n);
   return 0;
 }
 
-void rl_close(void* h) { delete static_cast<RaftLogEng*>(h); }
+// Waits for the calls in flight, then frees the log; the handle stays.  A
+// second close finds nothing to free.
+void rl_close(void* h) { delete guard::take<RaftLogEng>(h); }
 
 // Append `count` entries (concatenated blobs + lens) starting at first_index,
 // optionally with a new hard-state blob, in ONE durable record batch.
 int rl_append(void* h, uint64_t region, uint64_t first_index, uint32_t count,
               const uint8_t* blobs, const uint32_t* lens, const uint8_t* state,
               uint32_t state_len, int sync) {
-  auto* e = static_cast<RaftLogEng*>(h);
+  RL_OR(guard::kClosed);
   uint64_t my_seq;
   {
     std::lock_guard<std::mutex> wlk(e->wmu);
@@ -804,7 +813,7 @@ int rl_put_state(void* h, uint64_t region, const uint8_t* blob, uint32_t len, in
 }
 
 int64_t rl_first_index(void* h, uint64_t region) {
-  auto* e = static_cast<RaftLogEng*>(h);
+  RL_OR(guard::kClosed);
   std::shared_lock<std::shared_mutex> lk(e->mu);
   auto it = e->regions.find(region);
   if (it == e->regions.end() || it->second.locs.empty()) return 0;
@@ -812,7 +821,7 @@ int64_t rl_first_index(void* h, uint64_t region) {
 }
 
 int64_t rl_last_index(void* h, uint64_t region) {
-  auto* e = static_cast<RaftLogEng*>(h);
+  RL_OR(guard::kClosed);
   std::shared_lock<std::shared_mutex> lk(e->mu);
   auto it = e->regions.find(region);
   if (it == e->regions.end() || it->second.locs.empty()) return 0;
@@ -821,7 +830,7 @@ int64_t rl_last_index(void* h, uint64_t region) {
 
 // Bytes needed by rl_fetch for [lo, hi) — framing is idx(u64) + len(u32) + blob.
 int64_t rl_fetch_size(void* h, uint64_t region, uint64_t lo, uint64_t hi) {
-  auto* e = static_cast<RaftLogEng*>(h);
+  RL_OR(guard::kClosed);
   std::shared_lock<std::shared_mutex> lk(e->mu);
   auto it = e->regions.find(region);
   if (it == e->regions.end() || it->second.locs.empty()) return 0;
@@ -836,7 +845,7 @@ int64_t rl_fetch_size(void* h, uint64_t region, uint64_t lo, uint64_t hi) {
 // Returns the number of entries written, or -1 if cap is too small.
 int64_t rl_fetch(void* h, uint64_t region, uint64_t lo, uint64_t hi, uint8_t* out,
                  uint64_t cap) {
-  auto* e = static_cast<RaftLogEng*>(h);
+  RL_OR(guard::kClosed);
   struct Piece {
     uint64_t idx;
     std::shared_ptr<Seg> seg;
@@ -879,7 +888,7 @@ int64_t rl_fetch(void* h, uint64_t region, uint64_t lo, uint64_t hi, uint8_t* ou
 
 // Latest hard-state blob; returns its length, -1 if cap too small, -2 if none.
 int rl_state(void* h, uint64_t region, uint8_t* out, uint32_t cap) {
-  auto* e = static_cast<RaftLogEng*>(h);
+  RL_OR(guard::kClosed);
   std::shared_lock<std::shared_mutex> lk(e->mu);
   auto it = e->regions.find(region);
   if (it == e->regions.end() || !it->second.has_state) return -2;
@@ -890,7 +899,7 @@ int rl_state(void* h, uint64_t region, uint8_t* out, uint32_t cap) {
 }
 
 int rl_purge(void* h, uint64_t region, uint64_t to) {
-  auto* e = static_cast<RaftLogEng*>(h);
+  RL_OR(guard::kClosed);
   {
     std::lock_guard<std::mutex> wlk(e->wmu);
     std::string payload;
@@ -907,7 +916,7 @@ int rl_purge(void* h, uint64_t region, uint64_t to) {
 }
 
 int rl_clean(void* h, uint64_t region) {
-  auto* e = static_cast<RaftLogEng*>(h);
+  RL_OR(guard::kClosed);
   {
     std::lock_guard<std::mutex> wlk(e->wmu);
     std::string payload;
@@ -925,7 +934,7 @@ int rl_clean(void* h, uint64_t region) {
 // All region ids with any indexed entries or state; returns count (caller
 // re-calls with a bigger buffer when count > cap).
 int64_t rl_regions(void* h, uint64_t* out, uint32_t cap) {
-  auto* e = static_cast<RaftLogEng*>(h);
+  RL_OR(guard::kClosed);
   std::shared_lock<std::shared_mutex> lk(e->mu);
   uint32_t n = 0;
   for (auto& [rid, ri] : e->regions) {
@@ -937,7 +946,7 @@ int64_t rl_regions(void* h, uint64_t* out, uint32_t cap) {
 }
 
 int rl_sync(void* h) {
-  auto* e = static_cast<RaftLogEng*>(h);
+  RL_OR(guard::kClosed);
   uint64_t seq;
   {
     std::lock_guard<std::mutex> slk(e->smu);
@@ -948,8 +957,8 @@ int rl_sync(void* h) {
 }
 
 // segments | active_size | live_total | rewrites | purged | append_seq
-void rl_stats(void* h, uint64_t* out6) {
-  auto* e = static_cast<RaftLogEng*>(h);
+int rl_stats(void* h, uint64_t* out6) {
+  RL_OR(guard::kClosed);
   {
     std::shared_lock<std::shared_mutex> lk(e->mu);
     uint64_t live_total = 0;
@@ -962,6 +971,7 @@ void rl_stats(void* h, uint64_t* out6) {
   }
   std::lock_guard<std::mutex> slk(e->smu);
   out6[5] = e->append_seq;
+  return 0;
 }
 
 }  // extern "C"

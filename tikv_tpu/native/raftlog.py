@@ -16,7 +16,7 @@ import struct
 import subprocess
 import threading
 
-from . import ensure_built
+from . import CLOSED, ensure_built, refused
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "raftlog.cc")
@@ -36,7 +36,8 @@ def _load():
         if _lib is not None or _lib_err is not None:
             return _lib
         try:
-            ensure_built(_SO, _SRC, os.path.join(_HERE, "crypt.h"))
+            ensure_built(_SO, _SRC, os.path.join(_HERE, "crypt.h"),
+                         os.path.join(_HERE, "guard.h"))
             lib = ctypes.CDLL(_SO)
         except (OSError, subprocess.CalledProcessError) as e:
             _lib_err = str(e)
@@ -81,12 +82,21 @@ def _load():
         lib.rl_sync.argtypes = [c.c_void_p]
         lib.rl_sync.restype = c.c_int
         lib.rl_stats.argtypes = [c.c_void_p, c.POINTER(c.c_uint64)]
+        lib.rl_stats.restype = c.c_int
         _lib = lib
         return lib
 
 
 def raftlog_available() -> bool:
     return _load() is not None
+
+
+def _live(what: str, r: int) -> int:
+    """A native call's result, or ``EngineClosed`` where the call came after
+    ``close()`` (guard.h)."""
+    if r == CLOSED:
+        raise refused("raftlog", what)
+    return r
 
 
 def _key_registry(keys_mgr):
@@ -102,7 +112,8 @@ class NativeRaftLog:
     """One store's raft log: entries + hard-state blobs keyed by region id.
 
     Thread-safe; the entry blob format is opaque to this layer (the store's
-    ``_encode_entry`` bytes go in and come back verbatim).
+    ``_encode_entry`` bytes go in and come back verbatim).  After ``close()``
+    every call raises ``EngineClosed``; one in flight finishes first.
     """
 
     def __init__(self, path: str, segment_bytes: int = 64 << 20,
@@ -126,14 +137,14 @@ class NativeRaftLog:
         if not self._h:
             raise RuntimeError(f"raftlog open failed: {err.value.decode()}")
         self.path = path
-        self._closed = False
 
     def refresh_encryption(self) -> None:
         """Re-read the key registry after an external rotate."""
         if self._keys_mgr is None:
             raise RuntimeError("raftlog opened without encryption")
         ids, keys, current = _key_registry(self._keys_mgr)
-        if self._lib.rl_set_encryption(self._h, current, ids, keys, len(ids)) != 0:
+        if _live("rl_set_encryption", self._lib.rl_set_encryption(
+                self._h, current, ids, keys, len(ids))) != 0:
             raise RuntimeError("rl_set_encryption failed")
 
     def rotate_data_key(self) -> int:
@@ -159,39 +170,40 @@ class NativeRaftLog:
         r = self._lib.rl_append(
             self._h, region_id, first_index, n, buf, lens, st, len(st), sync
         )
-        if r != 0:
+        if _live("rl_append", r) != 0:
             raise OSError("raftlog append failed")
 
     def put_state(self, region_id: int, state: bytes, sync: int = -1) -> None:
-        if self._lib.rl_put_state(self._h, region_id, state, len(state), sync) != 0:
+        if _live("rl_put_state", self._lib.rl_put_state(
+                self._h, region_id, state, len(state), sync)) != 0:
             raise OSError("raftlog put_state failed")
 
     def purge(self, region_id: int, to_index: int) -> None:
         """Logically drop entries <= to_index; dead segments are unlinked and
         nearly-dead ones rewritten (engine.rs purge_expired_files role)."""
-        if self._lib.rl_purge(self._h, region_id, to_index) != 0:
+        if _live("rl_purge", self._lib.rl_purge(self._h, region_id, to_index)) != 0:
             raise OSError("raftlog purge failed")
 
     def clean(self, region_id: int) -> None:
-        if self._lib.rl_clean(self._h, region_id) != 0:
+        if _live("rl_clean", self._lib.rl_clean(self._h, region_id)) != 0:
             raise OSError("raftlog clean failed")
 
     def sync(self) -> None:
-        self._lib.rl_sync(self._h)
+        _live("rl_sync", self._lib.rl_sync(self._h))
 
     # -- read path ----------------------------------------------------------
 
     def first_index(self, region_id: int) -> int:
-        return self._lib.rl_first_index(self._h, region_id)
+        return _live("rl_first_index", self._lib.rl_first_index(self._h, region_id))
 
     def last_index(self, region_id: int) -> int:
-        return self._lib.rl_last_index(self._h, region_id)
+        return _live("rl_last_index", self._lib.rl_last_index(self._h, region_id))
 
     def state(self, region_id: int) -> bytes | None:
         cap = 512
         while True:
             buf = ctypes.create_string_buffer(cap)
-            r = self._lib.rl_state(self._h, region_id, buf, cap)
+            r = _live("rl_state", self._lib.rl_state(self._h, region_id, buf, cap))
             if r == -2:
                 return None
             if r == -1:
@@ -201,14 +213,17 @@ class NativeRaftLog:
 
     def entries(self, region_id: int, lo: int = 0, hi: int = 1 << 62) -> list[tuple[int, bytes]]:
         """(index, blob) pairs for [lo, hi), ascending."""
-        need = self._lib.rl_fetch_size(self._h, region_id, lo, hi)
+        need = _live("rl_fetch_size",
+                     self._lib.rl_fetch_size(self._h, region_id, lo, hi))
         if need <= 0:
             return []
         while True:
             buf = ctypes.create_string_buffer(int(need))
-            n = self._lib.rl_fetch(self._h, region_id, lo, hi, buf, need)
+            n = _live("rl_fetch",
+                      self._lib.rl_fetch(self._h, region_id, lo, hi, buf, need))
             if n == -1:  # raced with an append that grew the range
-                need = self._lib.rl_fetch_size(self._h, region_id, lo, hi)
+                need = _live("rl_fetch_size",
+                             self._lib.rl_fetch_size(self._h, region_id, lo, hi))
                 continue
             if n == -2:
                 raise OSError("raftlog fetch IO error")
@@ -226,14 +241,14 @@ class NativeRaftLog:
         cap = 1024
         while True:
             arr = (ctypes.c_uint64 * cap)()
-            n = self._lib.rl_regions(self._h, arr, cap)
+            n = _live("rl_regions", self._lib.rl_regions(self._h, arr, cap))
             if n <= cap:
                 return [arr[i] for i in range(n)]
             cap = int(n) + 64
 
     def stats(self) -> dict:
         out = (ctypes.c_uint64 * 6)()
-        self._lib.rl_stats(self._h, out)
+        _live("rl_stats", self._lib.rl_stats(self._h, out))
         return {
             "segments": out[0],
             "active_size": out[1],
@@ -244,9 +259,8 @@ class NativeRaftLog:
         }
 
     def close(self) -> None:
-        if not self._closed:
-            self._closed = True
-            self._lib.rl_close(self._h)
+        """Free the native log: after the calls in flight, once (guard.h)."""
+        self._lib.rl_close(self._h)
 
     def __del__(self):  # pragma: no cover - interpreter shutdown ordering
         try:

@@ -190,7 +190,8 @@ class Node:
             t.start()
             self._threads.append(t)
 
-    def stop(self) -> None:
+    def stop(self) -> list:
+        """The loop threads given up on (still alive after their join)."""
         self._stop.set()
         bs = getattr(self, "_batch_system", None)
         if bs is not None:
@@ -198,6 +199,7 @@ class Node:
         for t in self._threads:
             t.join(timeout=2)
         self.store.stop_apply_pipeline()
+        return [t for t in self._threads if t.is_alive()]
 
     def pump(self) -> None:
         """Synchronous message pump for RaftKv when loops aren't running.

@@ -14,6 +14,7 @@ Run:  python -m tikv_tpu.server.standalone \
 from __future__ import annotations
 
 import argparse
+import faulthandler
 import os
 import sys
 import threading
@@ -536,6 +537,11 @@ class StoreServer:
         # one interpreter serves every thread of the store: its collector's
         # pauses are timed from here on (docs/tracing.md, stage host.gc)
         trace.install_gc_hook()
+        # the store runs native engines in this interpreter: a crash inside
+        # one leaves every thread's Python frames on stderr, whoever started
+        # the process (docs/tracing.md)
+        if not faulthandler.is_enabled():
+            faulthandler.enable()
         self.server.start()
         self.status_server.start()
         self._ttl_thread.start()
@@ -572,23 +578,40 @@ class StoreServer:
         raise TimeoutError("cluster never formed")
 
     def stop(self) -> None:
-        if self.copr.scrubber is not None:
-            self.copr.scrubber.stop()
-        self.copr.scheduler.stop()
+        """Stop the store's threads, then close its engines.  A thread that
+        outlives its join is given up on and counted by name
+        (``tikv_server_stop_abandoned_thread_total``); whatever it still
+        asks of a native engine after the close below is refused with
+        ``EngineClosed`` (native/guard.h), and what it had in flight at the
+        close finishes first."""
+        from ..util.metrics import REGISTRY
+
+        abandoned = REGISTRY.counter(
+            "tikv_server_stop_abandoned_thread_total",
+            "Threads StoreServer.stop() gave up joining, by thread name",
+        )
+        if self.copr.scrubber is not None and not self.copr.scrubber.stop():
+            abandoned.inc(thread="integrity-scrub")
+        if not self.copr.scheduler.stop():
+            abandoned.inc(thread="copr-sched")
         self._ttl_stop.set()
         self._rts_stop.set()
         self._tuner_stop.set()
         # the advance thread inserts into _peer_clients: join it BEFORE
         # closing/iterating the clients
-        if self._rts_thread.is_alive():
-            self._rts_thread.join(timeout=10.0)
+        for t in (self._rts_thread, self._ttl_thread, self._tuner_thread):
+            if t.is_alive():
+                t.join(timeout=10.0)
+                if t.is_alive():
+                    abandoned.inc(thread=t.name)
         for cl in list(self._peer_clients.values()):
             try:
                 cl.close()
             except OSError:
                 pass
         self.read_plane.close()
-        self.node.stop()
+        for t in self.node.stop():
+            abandoned.inc(thread=t.name)
         self.server.stop()
         self.status_server.stop()
         self.transport.close()

@@ -103,7 +103,9 @@ class Worker:
                 pass
             self.handled += 1
 
-    def stop(self, wait: bool = True) -> None:
+    def stop(self, wait: bool = True) -> bool:
+        """False where the thread was given up on: it is still inside a task
+        and goes on using whatever that task uses."""
         with self._cv:
             self._stopped = True
             self._cv.notify_all()
@@ -113,9 +115,10 @@ class Worker:
                 # A wedged task still owns run(); calling shutdown() now would
                 # race with it.  Leave the runnable alive and let the daemon
                 # thread die with the process.
-                return
+                return False
         if self._runnable is not None:
             self._runnable.shutdown()
+        return True
 
     def pending(self) -> int:
         with self._cv:
