@@ -6,7 +6,9 @@ Every batched response must be byte-identical to the per-request CPU
 pipeline — the scheduler only ever removes dispatches, never changes bytes.
 """
 
+import contextlib
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -23,6 +25,7 @@ from tikv_tpu.storage.btree_engine import BTreeEngine
 from tikv_tpu.storage.engine import CF_WRITE
 from tikv_tpu.storage.kv import LocalEngine
 from tikv_tpu.storage.txn_types import Key, Write, WriteType
+from tikv_tpu.util.inbound import InboundReads
 from tikv_tpu.util.metrics import REGISTRY
 
 from copr_fixtures import PRODUCT_COLUMNS, TABLE_ID as PRODUCT_TABLE, product_engine
@@ -590,3 +593,364 @@ def test_mesh_serves_warm_cache_no_bypass(engines):
     assert r2.from_device
     assert REGISTRY.counter("tikv_coprocessor_mesh_cache_hit_total", "").get() == b2
     assert r2.data == cpu.handle_request(req(first_dag)).data
+
+
+# ---------------------------------------------------------------------------
+# the linger ends when the store's inbound pipeline is empty (ISSUE 30)
+# ---------------------------------------------------------------------------
+#
+# max_wait_s is 0.5 s in all of these, so "did not wait" (well under 0.25 s)
+# and "waited" (0.5 s) are far apart; nothing is driven by a sleep shorter
+# than the linger: the tests wait for events and read the dispatcher's own
+# record of its passes.
+
+LINGER = 0.5
+
+
+class _WatchedInbound(InboundReads):
+    """The store's inbound count with one addition: an event set whenever the
+    DISPATCHER reads the count, which it only does with riders queued and
+    their linger still running."""
+
+    def __init__(self):
+        super().__init__()
+        self.looked = threading.Event()
+
+    def pending(self):
+        n = super().pending()
+        if threading.current_thread().name == "copr-sched":
+            self.looked.set()
+        return n
+
+
+def _passes():
+    """(passes, riders) by ``why`` from the dispatcher's own series."""
+    c = REGISTRY.counter("tikv_coprocessor_sched_dispatch_total")
+    h = REGISTRY.histogram("tikv_coprocessor_sched_dispatch_riders",
+                           buckets=(1, 2, 4, 8, 16, 32, 64))
+    return {why: (c.get(why=why), h.total(why=why))
+            for why in ("drained", "deadline", "full", "stop")}
+
+
+def _moved(before: dict) -> dict:
+    after = _passes()
+    return {why: (after[why][0] - before[why][0], after[why][1] - before[why][1])
+            for why in after
+            if after[why] != before[why]}
+
+
+@pytest.fixture()
+def lingering(engines):
+    """A scheduler of its own over the module's device endpoint, lingering
+    half a second on every lane unless a test says otherwise."""
+    from tikv_tpu.copr.scheduler import CoprReadScheduler
+
+    dev, cpu = engines
+    # images, and the programs of every shape the tests dispatch: a batch
+    # of all regions, of three, of two, and a lone task
+    for regions in (range(N_REGIONS), range(3), range(2)):
+        dev.handle_batch([_region_req(r, ROWS_PER, _sum_dag(33))
+                          for r in regions])
+    for r in range(N_REGIONS):
+        dev.handle_request(_region_req(r, ROWS_PER, _sum_dag(33)))
+    sched = CoprReadScheduler(dev, SchedulerConfig(
+        max_wait_s=LINGER, high_max_wait_s=LINGER, low_max_wait_s=LINGER))
+    yield sched, cpu
+    assert sched.stop()
+
+
+def _submit(sched, inbound, region, out, priority=None, dag=None,
+            arrived=False):
+    """What the server does for one frame: count it off the socket (unless
+    the test did, ahead of time: ``arrived``), then serve it on a thread that
+    owes the count."""
+    if inbound is not None and not arrived:
+        inbound.arrived()
+
+    def serve():
+        t0 = time.perf_counter()
+        with inbound.handling() if inbound is not None else contextlib.nullcontext():
+            resp = sched.execute(
+                _region_req(region, ROWS_PER, dag or _sum_dag(33),
+                            priority=priority),
+                timeout=30.0)
+        out[region] = (resp.data, time.perf_counter() - t0)
+
+    t = threading.Thread(target=serve)
+    t.start()
+    return t
+
+
+def _want(cpu, region):
+    return cpu.handle_request(_region_req(region, ROWS_PER, _sum_dag(33))).data
+
+
+def _join(threads):
+    for t in threads:
+        t.join(30.0)
+        assert not t.is_alive()
+
+
+def test_riders_of_one_plan_leave_at_once_when_nothing_is_inbound(lingering):
+    sched, cpu = lingering
+    inbound = _WatchedInbound()
+    sched.watch_inbound(inbound)
+    sched.start()
+    before, out = _passes(), {}
+    _join([_submit(sched, inbound, r, out) for r in range(2)])
+    for r in range(2):
+        data, took = out[r]
+        assert data == _want(cpu, r)
+        assert took < LINGER / 2, f"waited {took:.3f} s with nobody inbound"
+    assert _moved(before) == {"drained": (1, 2)}
+    assert inbound.pending() == 0 and inbound.low == 0
+
+
+@pytest.mark.parametrize("parked_first", [1, 2])
+def test_riders_wait_for_the_one_on_its_way(lingering, parked_first):
+    """One more frame is off the socket than riders are parked: the
+    dispatcher must see that and hold them (two of one plan could leave, but
+    for the count), and the last one's arrival (the count's fall to zero)
+    releases all in ONE pass, long before the linger ends."""
+    sched, cpu = lingering
+    inbound = _WatchedInbound()
+    sched.watch_inbound(inbound)
+    sched.start()
+    before, out = _passes(), {}
+    inbound.arrived()  # the last frame, read right behind the others
+    ts = [_submit(sched, inbound, r, out) for r in range(parked_first)]
+    t_end = time.monotonic() + 10.0
+    while sum(len(q) for q in sched._queues.values()) < parked_first:
+        assert time.monotonic() < t_end
+        time.sleep(0.001)
+    # the dispatcher has looked at a queue that holds the first riders and
+    # a count that holds the last: it must not have let them go
+    inbound.looked.clear()
+    assert inbound.looked.wait(10.0)
+    assert inbound.pending() == 1
+    assert _moved(before) == {} and not out
+    t0 = time.perf_counter()
+    ts.append(_submit(sched, inbound, parked_first, out, arrived=True))
+    _join(ts)
+    took = time.perf_counter() - t0
+    for r in range(parked_first + 1):
+        assert out[r][0] == _want(cpu, r)
+    assert _moved(before) == {"drained": (1, parked_first + 1)}, "not ONE pass"
+    assert took < LINGER / 2, f"released by the linger, not the arrival: {took:.3f} s"
+    assert inbound.pending() == 0 and inbound.low == 0
+
+
+@pytest.mark.parametrize("source", ["stuck_at_one", "none", "lone_rider"])
+def test_linger_runs_to_its_deadline_without_a_zero(lingering, source):
+    """A count that never falls to zero (a leak upward, or a frame that
+    really is on its way), a scheduler nobody gave a count, and a rider alone
+    of its plan with nobody inbound (its query's next task may not have been
+    SENT yet, which no store can see): the pass leaves at the oldest rider's
+    deadline, as it always did."""
+    sched, cpu = lingering
+    inbound = None
+    if source != "none":
+        inbound = _WatchedInbound()
+        sched.watch_inbound(inbound)
+        if source == "stuck_at_one":
+            inbound.arrived()  # never served
+    sched.start()
+    before, out = _passes(), {}
+    _join([_submit(sched, inbound, 0, out)])
+    data, took = out[0]
+    assert data == _want(cpu, 0)
+    assert took >= LINGER * 0.9, f"left after {took:.3f} s of a {LINGER} s linger"
+    assert _moved(before) == {"deadline": (1, 1)}
+    if inbound is not None:
+        assert inbound.pending() == (source == "stuck_at_one") and inbound.low == 0
+
+
+def test_a_rider_alone_of_its_plan_holds_the_pass_it_would_leave_in(lingering):
+    """Two riders of one plan and one of another, nobody inbound: leaving now
+    would serve the third per request, so the pass keeps the linger it always
+    had, and the third's partner, a while later, releases all four."""
+    sched, cpu = lingering
+    sched.ep.handle_batch([_region_req(r, ROWS_PER, _group_dag()) for r in (2, 3)])
+    inbound = _WatchedInbound()
+    sched.watch_inbound(inbound)
+    inbound.arrived()  # holds the pass until all three riders are parked
+    sched.start()
+    before, out = _passes(), {}
+    ts = [_submit(sched, inbound, r, out) for r in range(2)]
+    ts.append(_submit(sched, inbound, 2, out, dag=_group_dag()))
+    t_end = time.monotonic() + 10.0
+    while sum(len(q) for q in sched._queues.values()) < 3:
+        assert time.monotonic() < t_end
+        time.sleep(0.001)
+    inbound.left()  # nobody else is coming, and the dispatcher is told so
+    inbound.looked.clear()
+    assert inbound.looked.wait(10.0)  # looked at three riders and a zero
+    assert _moved(before) == {} and not out
+    t0 = time.perf_counter()
+    ts.append(_submit(sched, inbound, 3, out, dag=_group_dag()))
+    _join(ts)
+    assert time.perf_counter() - t0 < LINGER / 2
+    assert _moved(before) == {"drained": (1, 4)}
+    for r in range(4):
+        dag = _group_dag() if r >= 2 else _sum_dag(33)
+        assert out[r][0] == cpu.handle_request(_region_req(r, ROWS_PER, dag)).data
+    assert inbound.pending() == 0 and inbound.low == 0
+
+
+@pytest.mark.parametrize("lane,wait_s", [("high", 0.4), ("low", 0.7)])
+def test_lanes_obey_the_rule_with_their_own_deadlines(lingering, lane, wait_s):
+    """With a frame on its way two riders of one plan wait out THEIR lane's
+    linger; with none they leave at once, whatever the lane."""
+    sched, cpu = lingering
+    setattr(sched.cfg, f"{lane}_max_wait_s", wait_s)
+    inbound = _WatchedInbound()
+    sched.watch_inbound(inbound)
+    sched.start()
+    before, out = _passes(), {}
+    _join([_submit(sched, inbound, r, out, priority=lane) for r in range(2)])
+    assert max(took for _d, took in out.values()) < wait_s / 2
+    assert _moved(before) == {"drained": (1, 2)}
+    inbound.arrived()  # one more on its way, for good
+    before, out = _passes(), {}
+    _join([_submit(sched, inbound, r, out, priority=lane) for r in range(2)])
+    slowest = max(took for _d, took in out.values())
+    assert wait_s * 0.9 <= slowest < wait_s + 0.2, slowest
+    assert _moved(before) == {"deadline": (1, 2)}
+    assert out[1][0] == _want(cpu, 1)
+    assert inbound.pending() == 1 and inbound.low == 0
+
+
+def test_a_full_batch_and_a_stop_are_counted_as_such(lingering):
+    sched, _cpu = lingering
+    sched.cfg.max_batch = 2
+    inbound = _WatchedInbound()
+    sched.watch_inbound(inbound)
+    inbound.arrived()  # keeps the linger running: only `full` can release
+    sched.start()
+    before, out = _passes(), {}
+    ts = [_submit(sched, inbound, r, out) for r in range(2)]
+    for t in ts:
+        t.join(30.0)
+        assert not t.is_alive()
+    assert max(took for _d, took in out.values()) < LINGER / 2
+    assert _moved(before) == {"full": (1, 2)}
+    before = _passes()
+    inbound.looked.clear()
+    t = _submit(sched, inbound, 2, out)
+    assert inbound.looked.wait(10.0)  # parked, and held by the count
+    assert sched.stop()
+    t.join(30.0)
+    assert not t.is_alive()
+    assert _moved(before) == {"stop": (1, 1)}
+
+
+class _Refuses:
+    """An overload control that turns every request away at admission."""
+
+    def admit(self, ctx, where="", wait=True):
+        from tikv_tpu.util.retry import ServerBusyError
+
+        raise ServerBusyError("tenant over quota", retry_after_s=0.01)
+
+
+@pytest.fixture()
+def served(engines, monkeypatch):
+    """The module's device endpoint behind a real ``Server``, wired as a
+    store wires it: one inbound count shared by server and scheduler."""
+    from tikv_tpu.server.server import Client, Server
+    from tikv_tpu.server.service import KvService
+    from tikv_tpu.storage.storage import Storage
+
+    dev, _cpu = engines
+    sched = dev.scheduler
+    old_cfg = sched.cfg
+    sched.cfg = SchedulerConfig(max_wait_s=LINGER)
+    inbound = InboundReads()
+    sched.watch_inbound(inbound)
+    svc = KvService(Storage(engine=dev.engine), dev)
+    srv = Server(svc, inbound=inbound)
+    srv.start()
+    sched.start()
+    client = Client(*srv.addr)
+    try:
+        yield dev, svc, client, inbound
+    finally:
+        client.close()
+        sched.stop()
+        srv.stop()
+        sched.cfg = old_cfg
+        sched._inbound = None
+
+
+def _wire_req(region: int, dag: DagRequest, **ctx) -> dict:
+    from tikv_tpu.copr.dag_wire import dag_to_wire
+
+    req = _region_req(region, ROWS_PER, dag)
+    return {"dag": dag_to_wire(dag), "ranges": [list(r) for r in req.ranges],
+            "start_ts": req.start_ts, "context": {**req.context, **ctx}}
+
+
+@pytest.mark.parametrize("exit_", [
+    "batched", "bypass", "queue_full", "busy_reject", "dead_on_arrival",
+    "stale_not_ready", "overload", "parse_error", "handler_raises",
+    "kv_get", "kv_scan",
+])
+def test_every_exit_counts_the_request_down_once(served, monkeypatch, exit_):
+    """However a read leaves its handler, the inbound count is back at zero
+    when the answer is, and it never went below."""
+    dev, svc, client, inbound = served
+    sched = dev.scheduler
+    shed = REGISTRY.counter("tikv_coprocessor_sched_shed_total")
+    coalesce = REGISTRY.counter("tikv_wire_coalesce_total")
+    method, req = "coprocessor", _wire_req(0, _sum_dag(33))
+    moved = None  # (counter, labels) that must move by one
+    want_error = True
+    if exit_ == "batched":
+        # parks alone of its plan: leaves at its deadline, served direct
+        want_error, moved = False, (coalesce, {"outcome": "direct"})
+    elif exit_ == "bypass":
+        req = _wire_req(0, _scan_dag())
+        want_error, moved = False, (coalesce, {"outcome": "bypass"})
+    elif exit_ == "queue_full":
+        sched.cfg.max_queue = 0
+        want_error, moved = False, (coalesce, {"outcome": "queue_full"})
+    elif exit_ == "busy_reject":
+        sched.cfg.max_queue, sched.cfg.busy_reject = 0, True
+        moved = (shed, {"reason": "busy_reject"})
+    elif exit_ == "dead_on_arrival":
+        req = _wire_req(0, _sum_dag(33), timeout_ms=-1)
+        moved = (REGISTRY.counter("tikv_coprocessor_deadline_expired_total"),
+                 {"at": "admission"})
+    elif exit_ == "stale_not_ready":
+        class DataNotReadyError(Exception):
+            pass
+
+        def not_ready(ctx):
+            raise DataNotReadyError("read_ts above the watermark")
+
+        monkeypatch.setattr(dev.engine, "check_read_ready", not_ready,
+                            raising=False)
+        req = _wire_req(0, _sum_dag(33), stale_read=True)
+        moved = (shed, {"reason": "data_not_ready"})
+    elif exit_ == "overload":
+        monkeypatch.setattr(dev, "overload", _Refuses(), raising=False)
+        moved = (shed, {"reason": "tenant_quota"})
+    elif exit_ == "parse_error":
+        req = dict(req, dag={"executors": [{"no": "such executor"}]})
+    elif exit_ == "handler_raises":
+        def boom(method, request):
+            raise RuntimeError("handler fell over")
+
+        monkeypatch.setattr(svc, "dispatch", boom)
+    elif exit_ == "kv_get":
+        method, req, want_error = "kv_get", {"key": b"k", "version": 10}, False
+    elif exit_ == "kv_scan":
+        method, want_error = "kv_scan", False
+        req = {"start_key": b"a", "end_key": b"z", "limit": 4, "version": 10}
+    before = moved[0].get(**moved[1]) if moved else None
+    resp = client.call(method, req, timeout=30.0)
+    assert bool(resp.get("error")) == want_error, resp
+    if moved:
+        assert moved[0].get(**moved[1]) == before + 1, (exit_, resp)
+    assert inbound.pending() == 0, f"{exit_} left the count at {inbound.pending()}"
+    assert inbound.low == 0, f"{exit_} took the count to {inbound.low}"

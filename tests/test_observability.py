@@ -17,6 +17,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 import tikv_tpu.server.node  # noqa: F401,E402
 import tikv_tpu.server.server  # noqa: F401,E402
 import tikv_tpu.storage.txn.scheduler  # noqa: F401,E402
+import tikv_tpu.util.inbound  # noqa: F401,E402
 
 # series registered lazily at first use (counters created inside handlers)
 LAZY_SERIES = {
@@ -31,6 +32,8 @@ LAZY_SERIES = {
     "tikv_coprocessor_sched_batch_occupancy",
     "tikv_coprocessor_sched_padding_waste",
     "tikv_coprocessor_sched_lane_wait_seconds",
+    "tikv_coprocessor_sched_dispatch_total",
+    "tikv_coprocessor_sched_dispatch_riders",
     "tikv_coprocessor_sched_batches_total",
     "tikv_coprocessor_sched_shed_total",
     "tikv_coprocessor_sched_device_occupancy",

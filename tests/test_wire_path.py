@@ -606,3 +606,143 @@ def test_owner_forward_end_to_end_socket():
             rp.close()
     finally:
         srv_b.stop()
+
+
+# ---------------------------------------------------------------------------
+# the inbound count, from the socket to the scheduler's lanes (ISSUE 30)
+# ---------------------------------------------------------------------------
+
+_LINGER = 0.5
+
+
+def _dispatch_passes() -> tuple[float, float]:
+    """(passes, riders) of the read scheduler's dispatcher, every release."""
+    c = REGISTRY.counter("tikv_coprocessor_sched_dispatch_total")
+    h = REGISTRY.histogram("tikv_coprocessor_sched_dispatch_riders",
+                           buckets=(1, 2, 4, 8, 16, 32, 64))
+    whys = ("drained", "deadline", "full", "stop")
+    return (sum(c.get(why=w) for w in whys), sum(h.total(why=w) for w in whys))
+
+
+@pytest.fixture(scope="module")
+def lingering_store():
+    """A store's wiring at test size: a ``Server`` and a read scheduler that
+    lingers half a second, sharing one inbound count; two warm regions."""
+    from tikv_tpu.copr.scheduler import SchedulerConfig
+    from tikv_tpu.util.inbound import InboundReads
+
+    regions, rows_per = 2, 800
+    eng = _numeric_engine(regions, rows_per)
+    ep = Endpoint(LocalEngine(eng), enable_device=True, block_rows=1 << 10,
+                  sched_config=SchedulerConfig(max_wait_s=_LINGER))
+    inbound = InboundReads()
+    ep.scheduler.watch_inbound(inbound)
+    svc = KvService(Storage(engine=LocalEngine(eng)), ep)
+    srv = Server(svc, inbound=inbound)
+    srv.start()
+    ep.scheduler.start()
+    reqs = _wire_reqs(regions, rows_per, 1)[:regions]  # one plan, each region
+    # images and programs: each task alone, then both in one batch
+    c = Client(*srv.addr)
+    for r in reqs:
+        assert not c.call("coprocessor", r, timeout=120.0).get("error")
+    c.close()
+    ep.handle_batch([svc._parse_copr_request(r) for r in reqs])
+    try:
+        yield srv, inbound, reqs
+    finally:
+        ep.scheduler.stop()
+        srv.stop()
+
+
+def _frame(req_id: int, req: dict) -> bytes:
+    body = wire.dumps([req_id, "coprocessor", req])
+    return len(body).to_bytes(4, "big") + body
+
+
+def test_two_frames_back_to_back_ride_one_pass(lingering_store):
+    """A session's query: both regions' tasks written to the socket at once.
+    The second frame is counted before the first reaches the scheduler, so
+    the first waits for it, and its arrival (not the half-second linger)
+    releases ONE pass of two riders."""
+    import time
+
+    srv, inbound, reqs = lingering_store
+    before = _dispatch_passes()
+    sock = socket.create_connection(srv.addr)
+    try:
+        t0 = time.perf_counter()
+        sock.sendall(_frame(1, reqs[0]) + _frame(2, reqs[1]))
+        answers = dict(wire.loads(read_frame(sock)) for _ in range(2))
+        took = time.perf_counter() - t0
+    finally:
+        sock.close()
+    assert sorted(answers) == [1, 2]
+    assert not any(a.get("error") for a in answers.values()), answers
+    assert took < _LINGER / 2, f"waited out the linger: {took:.3f} s"
+    passes, riders = (a - b for a, b in zip(_dispatch_passes(), before))
+    assert (passes, riders) == (1, 2)
+    assert inbound.pending() == 0 and inbound.low == 0
+
+
+@pytest.mark.parametrize("gap_ms", [1, 2, 20])
+def test_two_frames_a_moment_apart_ride_one_pass(lingering_store, gap_ms):
+    """A client that writes a query's tasks one after the other: when the
+    first is parked nothing else is inbound and nothing the store observes
+    says that a second will be SENT.  A rider alone of its plan therefore
+    keeps its linger, and the second's arrival, not the count, releases ONE
+    pass of two: such a client pays what it always paid, never two lone
+    serves."""
+    import time
+
+    srv, inbound, reqs = lingering_store
+    before = _dispatch_passes()
+    sock = socket.create_connection(srv.addr)
+    sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+    try:
+        t0 = time.perf_counter()
+        sock.sendall(_frame(1, reqs[0]))
+        time.sleep(gap_ms / 1000)
+        sock.sendall(_frame(2, reqs[1]))
+        answers = dict(wire.loads(read_frame(sock)) for _ in range(2))
+        took = time.perf_counter() - t0
+    finally:
+        sock.close()
+    assert sorted(answers) == [1, 2]
+    assert not any(a.get("error") for a in answers.values()), answers
+    assert took < _LINGER / 2, f"waited out the linger: {took:.3f} s"
+    passes, riders = (a - b for a, b in zip(_dispatch_passes(), before))
+    assert (passes, riders) == (1, 2)
+    assert inbound.pending() == 0 and inbound.low == 0
+
+
+def test_sixteen_connections_still_coalesce(lingering_store):
+    """Under load somebody is nearly always on the way, so the linger does
+    what it is for: passes carry more than a lone session's two tasks."""
+    srv, inbound, reqs = lingering_store
+    before = _dispatch_passes()
+    datas = _serve_concurrent(srv.addr, reqs * 32, 16)
+    assert len(set(datas[0::2])) == 1 and len(set(datas[1::2])) == 1
+    passes, riders = (a - b for a, b in zip(_dispatch_passes(), before))
+    assert riders == 64
+    assert riders / passes > 2, f"{riders} riders in {passes} passes"
+    assert inbound.pending() == 0 and inbound.low == 0
+
+
+def test_connection_dropped_between_its_frames_owes_nothing(lingering_store):
+    """One whole frame and the head of a second, then the peer is gone: the
+    whole frame is served and counted down, the torn one never counted."""
+    srv, inbound, reqs = lingering_store
+    before = _dispatch_passes()
+    sock = socket.create_connection(srv.addr)
+    sock.sendall(_frame(1, reqs[0]) + _frame(2, reqs[1])[:9])
+    rid, answer = wire.loads(read_frame(sock))
+    sock.close()
+    assert rid == 1 and not answer.get("error")
+    # the server's thread for that connection ends on the torn frame
+    c = Client(*srv.addr)
+    assert not c.call("coprocessor", reqs[1], timeout=30.0).get("error")
+    c.close()
+    passes, riders = (a - b for a, b in zip(_dispatch_passes(), before))
+    assert (passes, riders) == (2, 2)
+    assert inbound.pending() == 0 and inbound.low == 0

@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import threading
 import time
+from collections import Counter
 from dataclasses import dataclass, field
 
 from ..analysis.sanitizer import make_condition, make_lock
@@ -94,7 +95,11 @@ class SchedulerConfig:
     max_batch: int = 64            # regions/queries fused into one program
     max_queue: int = 256           # pending cap before admission sheds
     padding_budget: float = 0.5    # max wasted fraction of padded block slots
-    max_wait_s: float = 0.004      # normal-lane linger before partial dispatch
+    # per-lane linger: the LONGEST a partial batch waits for more riders.
+    # Where the store counts its inbound reads (watch_inbound) the wait of
+    # riders that can share a program ends as soon as no read is on its way;
+    # without that count, and for a rider alone of its plan, it runs to the end
+    max_wait_s: float = 0.004
     high_max_wait_s: float = 0.001
     low_max_wait_s: float = 0.02
     # busy_reject=True turns queue-full admission into a ServerIsBusy-style
@@ -214,7 +219,7 @@ class _Item:
     lane: str = "normal"
     ticket: "_Ticket | None" = None
     enqueue_t: float = 0.0
-    sig: tuple | None = None  # plan signature, set once during grouping
+    sig: tuple | None = None  # plan signature: at admission, or grouping
     # absolute monotonic deadline (context "deadline"/"timeout_ms", see
     # util.retry.deadline_from_context); expired items shed BEFORE dispatch
     deadline: float | None = None
@@ -271,6 +276,9 @@ class CoprReadScheduler:
         self._queues: dict[str, list[_Item]] = {lane: [] for lane in LANES}
         self._running = False
         self._thread: threading.Thread | None = None
+        # the store's count of reads on their way here (util/inbound.py);
+        # None = nobody counts them, so a partial batch lingers to its end
+        self._inbound = None
         # per-signature memos: device eligibility (supports() re-analyzes the
         # whole plan) and the compiled evaluator (endpoint._evaluator_for
         # keys on serialized plan bytes — ~1ms of wire encoding per lookup
@@ -292,6 +300,21 @@ class CoprReadScheduler:
                 self.cfg.high_max_wait_s = float(value)
             elif key == "low_max_wait_s":
                 self.cfg.low_max_wait_s = float(value)
+
+    def watch_inbound(self, inbound) -> None:
+        """Called where the store wires its server to this scheduler
+        (``server/standalone.py``, ``server/cluster.py``): ``inbound`` counts
+        the reads that are off the socket and not yet in a lane, and tells
+        :meth:`_inbound_drained` when the last of them went elsewhere."""
+        self._inbound = inbound
+        inbound.on_drained = self._inbound_drained
+
+    def _inbound_drained(self) -> None:
+        # the unlocked look saves the lock on every read that is not ours;
+        # an enqueue it misses wakes the dispatcher itself
+        if any(self._queues.values()):
+            with self._mu:
+                self._mu.notify_all()
 
     # -- synchronous entry (endpoint.handle_batch / batch_coprocessor) -----
 
@@ -399,14 +422,16 @@ class CoprReadScheduler:
             except ServerBusyError:
                 self._count_shed("tenant_quota")
                 raise
-        if (not self._running or not self.ep._gate_ok("batch")
-                or not self._batchable(req)):
+        sig = None
+        if self._running and self.ep._gate_ok("batch"):
+            sig = self._batchable_sig(req)
+        if sig is None:
             # the BATCH_FUSION gate guards this path exactly like
             # handle_batch: a mixed-version cluster keeps fusion off
             self._count_coalesce("bypass")
             return self.ep.handle_request(req)
         item = _Item(req=req, index=0, lane=_clamped_lane(req, self.cfg, ov),
-                     ticket=_Ticket(),
+                     ticket=_Ticket(), sig=sig,
                      enqueue_t=time.perf_counter(), deadline=deadline)
         # queue-lane span (docs/tracing.md): covers enqueue→batch-completion
         # on the submitting thread; the dispatcher stamps dispatcher-side
@@ -445,6 +470,11 @@ class CoprReadScheduler:
                 else:
                     do_direct = False
                     self._queues[item.lane].append(item)
+                    if self._inbound is not None:
+                        # in a lane: no longer on its way (counted down here,
+                        # under the lock, so the dispatcher this wakes reads
+                        # the queue and the count as one state)
+                        self._inbound.parked()
                     self._gauge_depth()
                     self._mu.notify_all()
             if ov is not None:
@@ -512,10 +542,14 @@ class CoprReadScheduler:
                         self._queues[lane].clear()
                     self._gauge_depth()
                     stopping = True
+                    why = "stop"
                 else:
                     stopping = False
                 if not stopping:
-                    # linger until the oldest item's lane deadline or max_batch
+                    # linger for more riders: until max_batch are queued or
+                    # the oldest item's lane deadline, and no longer than any
+                    # can still come (no read inbound), unless a rider would
+                    # then be served alone: that one is still worth the wait
                     now = time.perf_counter()
                     deadline = min(
                         it.enqueue_t + cfg.wait_for(lane)
@@ -523,7 +557,15 @@ class CoprReadScheduler:
                         for it in self._queues[lane]
                     )
                     total = sum(len(q) for q in self._queues.values())
-                    if total < cfg.max_batch and now < deadline:
+                    if total >= cfg.max_batch:
+                        why = "full"
+                    elif now >= deadline:
+                        why = "deadline"
+                    elif (self._inbound is not None
+                          and self._inbound.pending() == 0
+                          and self._no_lone_rider()):
+                        why = "drained"
+                    else:
                         self._mu.wait(min(deadline - now, 0.05))
                         continue
                     batch = []
@@ -531,14 +573,21 @@ class CoprReadScheduler:
                         while self._queues[lane] and len(batch) < cfg.max_batch:
                             batch.append(self._queues[lane].pop(0))
                     self._gauge_depth()
-            if stopping:
-                if batch:
-                    self._serve_ticketed(batch)
-                return
             if batch:
-                for it in batch:
-                    self._observe_wait(it)
+                self._count_dispatch(why, len(batch))
+                if not stopping:
+                    for it in batch:
+                        self._observe_wait(it)
                 self._serve_ticketed(batch)
+            if stopping:
+                return
+
+    def _no_lone_rider(self) -> bool:
+        """Every queued rider has another of its plan beside it (under
+        ``_mu``), so a pass that leaves now serves nobody per request who a
+        longer linger might have batched."""
+        plans = Counter(it.sig for q in self._queues.values() for it in q)
+        return min(plans.values()) >= 2
 
     def _serve_ticketed(self, batch: list[_Item]) -> None:
         from ..util.failpoint import fail_point
@@ -1396,6 +1445,23 @@ class CoprReadScheduler:
             "Wasted fraction of padded block slots per cross-region batch",
             buckets=(0.01, 0.05, 0.1, 0.2, 0.3, 0.5, 0.75, 1.0),
         ).observe(waste, kind=kind)
+
+    def _count_dispatch(self, why: str, riders: int) -> None:
+        """One pass of the dispatch loop that took riders off the lanes,
+        whichever rung serves them afterwards, by what released it:
+        ``drained`` (nobody else on the way), ``deadline`` (the oldest
+        rider's linger ran out), ``full`` (max_batch queued), ``stop``."""
+        from ..util.metrics import REGISTRY
+
+        REGISTRY.counter(
+            "tikv_coprocessor_sched_dispatch_total",
+            "Dispatcher passes that took riders off the lanes, by release",
+        ).inc(why=why)
+        REGISTRY.histogram(
+            "tikv_coprocessor_sched_dispatch_riders",
+            "Riders per dispatcher pass, by release",
+            buckets=(1, 2, 4, 8, 16, 32, 64),
+        ).observe(riders, why=why)
 
     def _count_shed(self, reason: str) -> None:
         from ..util.metrics import REGISTRY

@@ -25,6 +25,7 @@ from ..raft.region import Peer as RegionPeer, Region, RegionEpoch
 from ..raft.store import StorePeer
 from ..storage.engine import CF_DEFAULT, WriteBatch
 from ..util import keys as keymod, retry
+from ..util.inbound import InboundReads
 from .node import Node
 from .raft_client import RemoteTransport
 from .server import Server
@@ -101,10 +102,15 @@ class StoreNode:
                 lock_manager=self.lock_manager, pd=cluster.pd,
                 resolved_ts=self.resolved_ts, read_plane=self.read_plane,
             )
+            # the standalone store's wiring: the server counts the reads on
+            # their way to the scheduler, which stops lingering at zero
+            inbound = InboundReads()
+            copr.scheduler.watch_inbound(inbound)
         else:
             self.lock_manager = None
             self.service = KvService(storage=None, raft_router=self.store)
-        self.server = Server(self.service, security=security)
+            inbound = None
+        self.server = Server(self.service, security=security, inbound=inbound)
         self.running = False
 
     def start(self) -> None:

@@ -9,6 +9,7 @@ commands don't block the socket reader.
 
 from __future__ import annotations
 
+import contextlib
 import inspect
 import os
 import queue
@@ -146,8 +147,10 @@ class Server:
         workers: int = 8,
         security=None,
         read_pool_workers: int | None = None,  # ReadPoolConfig.unified_max_threads
+        inbound=None,  # util.inbound.InboundReads shared with the read scheduler
     ):
         self.service = service
+        self.inbound = inbound
         self.security = security
         self._ssl_ctx = security.server_context() if security is not None else None
         self._sock = socket.create_server((host, port))
@@ -285,9 +288,14 @@ class Server:
                 # no allocation when tracing is off and no ctx carries a
                 # trace id.
                 root = self._trace_root(method, request, t_dec, t_dec_end)
+                is_read = method.removeprefix("pb/") in _READ_METHODS
+                # a read is on its way to the read scheduler from here until
+                # the scheduler parks it or its handler ends (util/inbound.py)
+                counted = is_read and self.inbound is not None
 
                 def run(req_id=req_id, method=method, request=request,
-                        t_submit=t_submit, root=root, t_dec_end=t_dec_end):
+                        t_submit=t_submit, root=root, t_dec_end=t_dec_end,
+                        counted=counted):
                     t0 = time.perf_counter()
                     # route = pool queue wait: submission to handler start
                     WIRE_STAGE.observe(t0 - t_submit, stage="route")
@@ -297,8 +305,12 @@ class Server:
                         # closure bookkeeping + pool queue wait), so the
                         # stage spans account for the whole request
                         root.record("wire.route", t_dec_end, t0, stage=True)
+                    # the handler pays the inbound count down as it returns
+                    # or raises, unless the scheduler took it at its queue
+                    owes = (self.inbound.handling() if counted
+                            else contextlib.nullcontext())
                     try:
-                        with root.active(), trace.span("wire.execute"):
+                        with owes, root.active(), trace.span("wire.execute"):
                             if method.startswith("pb/"):
                                 # kvproto mode: request/response are protobuf
                                 # bytes (pb_gateway), framing unchanged
@@ -383,7 +395,7 @@ class Server:
                     WIRE_STAGE.observe(t_enc_end - t_enc, stage="encode")
                     root.finish(end=t_enc_end)
 
-                if method.removeprefix("pb/") in _READ_METHODS:
+                if is_read:
                     ctx, group = {}, id(conn)
                     prio_hint = None
                     if isinstance(request, dict):
@@ -403,9 +415,13 @@ class Server:
                         if ctx.get("priority") == "high" or prio_hint == "high"
                         else TaskPriority.NORMAL
                     )
+                    if counted:
+                        self.inbound.arrived()
                     try:
                         self.read_pool.submit(run, group=group, priority=prio)
                     except RuntimeError:  # pool/server stopped mid-shutdown
+                        if counted:
+                            self.inbound.left()
                         root.finish()
                         return
                 else:

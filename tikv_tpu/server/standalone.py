@@ -25,6 +25,7 @@ from ..pd.service import RemotePd
 from ..raft.raftkv import RaftKv
 from ..raft.region import Peer as RegionPeer, Region, RegionEpoch
 from ..storage.storage import Storage
+from ..util.inbound import InboundReads
 from .debug import Debugger
 from .node import FIRST_REGION_ID, Node
 from .raft_client import RemoteTransport
@@ -237,6 +238,11 @@ class StoreServer:
             # requests across regions ride one vmapped device program and
             # identical requests share a slot (docs/wire_path.md)
             self.copr.scheduler.start()
+        # one count of the reads between the socket and the scheduler's
+        # lanes, kept by the server and read by the scheduler: a partial
+        # batch leaves as soon as nobody else can join it
+        self.inbound = InboundReads()
+        self.copr.scheduler.watch_inbound(self.inbound)
         self.gc_worker = GcWorker(self.raftkv)
         # wait-for edges route to the cluster detector leader (region 1's
         # leader store); cross-store lock cycles break by error, not timeout
@@ -449,7 +455,8 @@ class StoreServer:
             read_plane=self.read_plane,
             overload=self.overload,
         )
-        self.server = Server(self.service, host=host, port=port, security=security)
+        self.server = Server(self.service, host=host, port=port, security=security,
+                             inbound=self.inbound)
         self.recovered_peers = recovered
 
     def _advertise_device_placement(self) -> None:
