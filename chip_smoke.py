@@ -75,18 +75,18 @@ def plan_set() -> dict:
     Selection+Limit scan answered in TypeChunk encoding."""
     from dataclasses import replace
 
-    import bench
+    import lineitem_fixture as fx
     from tikv_tpu.copr.aggr import AggDescriptor
     from tikv_tpu.copr.dag import (
         ENC_TYPE_CHUNK, Aggregation, DagRequest, Selection, TableScan, TopN,
     )
     from tikv_tpu.copr.rpn import call, col, const_int
 
-    schema = bench._lineitem()
+    schema = fx._lineitem()
     le_ship = Selection([call("le", col(4), const_int(10500))])
     return {
-        "q6": bench.q6_dag(),
-        "q1": bench.q1_dag(),
+        "q6": fx.q6_dag(),
+        "q1": fx.q1_dag(),
         "g550": DagRequest(executors=[
             TableScan(TABLE_ID, schema), le_ship,
             Aggregation([col(1), col(3)],
@@ -101,13 +101,14 @@ def plan_set() -> dict:
                          AggDescriptor("min", col(4)),
                          AggDescriptor("max", col(1))]),
         ]),
-        # bench._topn_endpoint's plan over the whole row: the flag columns
-        # ride as dictionary codes, and the plans share one image per region
+        # lineitem_fixture._topn_endpoint's plan over the whole row: the flag
+        # columns ride as dictionary codes, and the plans share one image per
+        # region
         "topn": DagRequest(executors=[
             TableScan(TABLE_ID, schema), le_ship,
             TopN([(col(2), True), (col(1), False)], 100),
         ]),
-        "scan_chunk": replace(bench._filter_dag("selection", limit=4096),
+        "scan_chunk": replace(fx._filter_dag("selection", limit=4096),
                               encode_type=ENC_TYPE_CHUNK),
     }
 
@@ -296,12 +297,12 @@ class Smoke:
         raise RuntimeError(f"no region holds {raw_key!r}")
 
     def load(self, budget_s: float = LOAD_BUDGET_S) -> None:
-        import bench
+        import lineitem_fixture as fx
         from tikv_tpu.copr.table import record_key
 
         n, rpr = self.regions, self.rows_per_region
         t0 = time.perf_counter()
-        kvs = bench.build_kvs(n * rpr, seed=self.seed)
+        kvs = fx.build_kvs(n * rpr, seed=self.seed)
         gen_s = time.perf_counter() - t0
         for k in range(1, n):
             split = record_key(TABLE_ID, k * rpr)
@@ -431,8 +432,8 @@ class Smoke:
         """The CPU pipeline's bytes for region ``k``: the endpoint's own
         oracle steps (negotiate the encoding, BatchExecutorsRunner, encode)
         over the rows that were WRITTEN, decoded once per region as
-        ``bench.run_cpu`` does; none of the store's read path, none of the
-        device path."""
+        ``lineitem_fixture.run_cpu`` does; none of the store's read path, none
+        of the device path."""
         from tikv_tpu.copr.cache import ColumnBlockCache
         from tikv_tpu.copr.dag import BatchExecutorsRunner, negotiate_encode_type
         from tikv_tpu.copr.executors import CachedBlocksExecutor
@@ -529,7 +530,7 @@ class Smoke:
     def write_then_read(self) -> None:
         """A few hundred rows change in a warm region; Q6 at a newer
         timestamp must see them, through a delta and not a refill."""
-        import bench
+        import lineitem_fixture as fx
         from tikv_tpu.copr.dag_wire import dag_to_wire
 
         k = self.regions - 1
@@ -537,8 +538,8 @@ class Smoke:
         # new values, drawn from another seed, for keys spread over the region
         step = max(1, rpr // WRITE_ROWS)
         rows = [(self.rows[k][i * step][0], v) for i, (_key, v) in
-                enumerate(bench.build_kvs(min(WRITE_ROWS, rpr), self.seed + 1))]
-        dag = bench.q6_dag()
+                enumerate(fx.build_kvs(min(WRITE_ROWS, rpr), self.seed + 1))]
+        dag = fx.q6_dag()
         wire_dag = dag_to_wire(dag)
         before_bytes = self.reference(dag, k)
         self._put_rows(self.region_ids[k], rows)

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from tikv_tpu.copr import jax_eval
+from tikv_tpu.copr import observatory as obs
 from tikv_tpu.copr.aggr import AggDescriptor
 from tikv_tpu.copr.dag import Aggregation, DagRequest, Limit, Selection, TableScan
 from tikv_tpu.copr.datatypes import ColumnInfo, FieldType
@@ -148,6 +149,82 @@ def test_xregion_batch_byte_identical(engines):
     occ = [r.metrics.get("batch_occupancy") for r in got
            if r.metrics.get("sched_batch") == "xregion"]
     assert occ and all(o >= 2 for o in occ)
+
+
+def _seed_profiles(sid: str, xregion_s: float, zone_s: float) -> None:
+    for _ in range(8):
+        obs.OBSERVATORY.record_serve(sid, "xregion", xregion_s, rows=ROWS_PER)
+        obs.OBSERVATORY.record_serve(sid, "zone", zone_s, rows=ROWS_PER)
+
+
+def _xregion_carries_a_compile(sid: str) -> None:
+    """The state that flipped runs: ``xregion`` cheaper by p50, with a 20 s
+    entry in the compile ledger that ``zone`` has not."""
+    _seed_profiles(sid, 0.002, 0.009)
+    obs.OBSERVATORY.record_compile("jax_eval.xregion", "xregion", 20.0, sig=sid)
+
+
+def _xregion_aged_out(sid: str) -> None:
+    """The state a ``zone`` run stayed in: every retained window of the
+    ``xregion`` profile has rolled past (windowed count 0, lifetime count
+    kept) and only ``zone`` is warm."""
+    _seed_profiles(sid, 0.012, 0.003)
+    for (path, _enc), prof in obs.OBSERVATORY._sigs[sid].paths.items():
+        if path == "xregion":
+            prof.windows = [type(prof.windows[0])(time.monotonic())]
+
+
+@pytest.mark.parametrize("profiles", [
+    pytest.param(lambda sid: None, id="cold"),
+    pytest.param(lambda sid: _seed_profiles(sid, 0.012, 0.003),
+                 id="xregion_dearer_by_p50"),
+    pytest.param(_xregion_carries_a_compile, id="xregion_carries_a_compile"),
+    pytest.param(_xregion_aged_out, id="xregion_aged_out"),
+])
+def test_same_plan_riders_are_one_xregion_group_whatever_was_measured(
+        engines, monkeypatch, profiles):
+    """The rule of ``_group``: two riders of one plan over two region views
+    are ONE xregion group.  No state of the observatory's profiles takes
+    the pair apart, with a live router whose probes are held at 0 (the
+    benchmark's configurations): each of these states could, or did, send
+    the pair to per-request serving while the scheduler weighed the batch
+    against a synthetic ``direct`` path."""
+    from tikv_tpu.copr.costmodel import CostRouter, RouterConfig
+
+    dev, cpu = engines
+    router = CostRouter(enabled=True, config=RouterConfig(
+        seed=3, epsilon=0.0, cold_probe_rate=0.0))
+    ep = Endpoint(dev.engine, enable_device=True, block_rows=1024,
+                  cost_router=router)
+    sig = plan_signature(_sum_dag(61))
+    pair = lambda: [_region_req(r, ROWS_PER, _sum_dag(61)) for r in range(2)]
+    ep.handle_batch(pair())  # images and the program
+    groups = []
+    group = ep.scheduler._group
+
+    def spy(items):
+        groups.append(group(items))
+        return groups[-1]
+
+    monkeypatch.setattr(ep.scheduler, "_group", spy)
+    obs.OBSERVATORY.reset()
+    profiles(obs.sig_id(sig))
+    try:
+        got = ep.handle_batch(pair())
+    finally:
+        obs.OBSERVATORY.reset()
+    [(exec_groups, rest)] = groups
+    assert rest == []
+    [(kind, g_sig, slots)] = exec_groups
+    assert (kind, g_sig, len(slots)) == ("xregion", sig, 2)
+    for req, resp in zip(pair(), got):
+        assert resp.from_device
+        assert resp.metrics.get("sched_batch") == "xregion"
+        assert resp.data == cpu.handle_request(req).data
+    # the label that named the other side of the weighing is gone from the
+    # router's series, whichever test ran before this one in the process
+    routed = REGISTRY.counter("tikv_coprocessor_cost_route_total", "").render()
+    assert 'path="direct"' not in routed
 
 
 def test_xregion_dedupes_identical_requests(engines):

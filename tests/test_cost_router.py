@@ -157,11 +157,10 @@ def test_kill_switch_env(monkeypatch):
 def test_measured_picks_cheapest_and_reports_delta():
     r = CostRouter(config=RouterConfig(seed=5, epsilon=0.0,
                                        cold_probe_rate=0.0))
-    costs = {"zone": {"count": 10, "cost_ms": 8.0},
-             "unary": {"count": 10, "cost_ms": 2.0},
-             "cpu": {"count": 10, "cost_ms": 30.0}}
+    _seed_profiles("sigM", {"zone": 0.008, "unary": 0.002, "cpu": 0.030},
+                   n=10)
     for _ in range(20):
-        d = r.route("sigM", ["zone", "unary", "cpu"], costs=costs)
+        d = r.route("sigM", ["zone", "unary", "cpu"])
         assert (d.path, d.reason) == ("unary", "measured")
         assert d.delta_ms == 0.0
 
@@ -170,21 +169,18 @@ def test_explore_share_bounded_and_recovers_after_profile_improves():
     eps = 0.1
     r = CostRouter(config=RouterConfig(seed=7, epsilon=eps,
                                        cold_probe_rate=0.0))
-    slow = {"fast": {"count": 50, "cost_ms": 1.0},
-            "slow": {"count": 50, "cost_ms": 10.0}}
+    _seed_profiles("sigE", {"fast": 0.001, "slow": 0.010}, n=50)
     n = 4000
-    picks = [r.route("sigE", ["slow", "fast"], costs=slow).path
-             for _ in range(n)]
+    picks = [r.route("sigE", ["slow", "fast"]).path for _ in range(n)]
     share = picks.count("slow") / n
     # the worse path keeps a BOUNDED probe share: epsilon, not zero and
     # not runaway (3-sigma slack around the configured rate)
     assert 0.05 < share < 0.15
     # the profile improves (the slow path got faster than the incumbent):
     # measured routing must recover its share immediately
-    fast_now = {"fast": {"count": 50, "cost_ms": 1.0},
-                "slow": {"count": 50, "cost_ms": 0.2}}
-    picks = [r.route("sigE", ["slow", "fast"], costs=fast_now).path
-             for _ in range(1000)]
+    obs.OBSERVATORY.reset()
+    _seed_profiles("sigE", {"fast": 0.001, "slow": 0.0002}, n=50)
+    picks = [r.route("sigE", ["slow", "fast"]).path for _ in range(1000)]
     assert picks.count("slow") / 1000 > 0.85
 
 
@@ -192,9 +188,9 @@ def test_cold_paths_probed_at_budgeted_rate_round_robin():
     rate = 0.04
     r = CostRouter(config=RouterConfig(seed=13, epsilon=0.0,
                                        cold_probe_rate=rate))
-    costs = {"unary": {"count": 50, "cost_ms": 1.0}}
+    _seed_profiles("sigC", {"unary": 0.001}, n=50)
     n = 6000
-    picks = [r.route("sigC", ["zone", "unary", "cpu", "fused"], costs=costs)
+    picks = [r.route("sigC", ["zone", "unary", "cpu", "fused"])
              for _ in range(n)]
     cold = [d for d in picks if d.reason == "cold"]
     share = len(cold) / n
@@ -202,6 +198,12 @@ def test_cold_paths_probed_at_budgeted_rate_round_robin():
     # budget rotates across ALL cold candidates, not just the first
     probed = {d.path for d in cold}
     assert probed == {"zone", "cpu", "fused"}
+
+
+def test_route_prices_from_the_observatory_alone():
+    """No caller can hand ``route`` a price table of its own."""
+    with pytest.raises(TypeError):
+        CostRouter().route("s", ["unary", "cpu"], costs={})
 
 
 def test_route_requires_candidates():
@@ -619,22 +621,3 @@ def test_cost_router_snapshot_includes_tuner():
     ep.geometry_tuner = GeometryTuner(observatory=_FakeObs())
     snap = ep.cost_router_snapshot()
     assert snap["tuner"]["enabled"] is True
-
-
-# ---------------------------------------------------------------------------
-# scheduler batch routing: xregion vs direct through the same router
-# ---------------------------------------------------------------------------
-
-def test_batch_router_weighs_xregion_against_best_direct():
-    r = CostRouter(config=RouterConfig(seed=9, epsilon=0.0,
-                                       cold_probe_rate=0.0, min_count=3))
-    # xregion measured slower than the best direct path -> route direct
-    table = {"xregion": {"count": 10, "cost_ms": 12.0},
-             "direct": {"count": 10, "cost_ms": 3.0}}
-    d = r.route("sigB", ["xregion", "direct"], costs=table)
-    assert (d.path, d.reason) == ("direct", "measured")
-    # and the reverse keeps the batch grouping
-    table = {"xregion": {"count": 10, "cost_ms": 2.0},
-             "direct": {"count": 10, "cost_ms": 9.0}}
-    d = r.route("sigB", ["xregion", "direct"], costs=table)
-    assert (d.path, d.reason) == ("xregion", "measured")

@@ -1,6 +1,6 @@
 """Differential tests: JAX device path vs CPU oracle path.
 
-The BASELINE.json contract: for every eligible DAG, the device path's encoded
+The contract: for every eligible DAG, the device path's encoded
 SelectResponse must equal the CPU pipeline's bytes exactly (int/decimal
 pipelines; REAL aggregates are float-rounding-exempt).
 """
@@ -108,7 +108,7 @@ def test_selection_identical():
 
 
 def test_selection_three_predicates_identical():
-    # the BASELINE config-2 shape: lt/gt/eq conjunction
+    # lt/gt/eq conjunction
     conds = [
         call("lt", col(1), const_int(800)),
         call("gt", col(2), const_int(10)),
@@ -841,11 +841,11 @@ def test_topn_merges_a_block_in_chunks_byte_identically(k, order):
     (the TPU compiler's time for one 65,636-row sort is minutes): ties keep
     global stream order across chunks and blocks, and the last, overlapping
     chunk counts no row twice."""
-    import bench
+    import lineitem_fixture as fx
 
-    kvs = bench.build_kvs(jax_eval.DEFAULT_BLOCK_ROWS + 4500, seed=5)
+    kvs = fx.build_kvs(jax_eval.DEFAULT_BLOCK_ROWS + 4500, seed=5)
     dag = DagRequest(executors=[
-        TableScan(bench.TABLE_ID, bench._lineitem()),
+        TableScan(fx.TABLE_ID, fx._lineitem()),
         Selection([call("le", col(4), const_int(10500))]),
         TopN([(col(i), desc) for i, desc in order], k),
     ])

@@ -45,8 +45,9 @@ def mixed_table_kvs(n, seed=0, with_nulls=False):
     Returns (cols, kvs, cache): kvs feed the CPU oracle; the pre-filled
     ColumnBlockCache is the decoded image with dict-coded varchars sharing
     ONE dictionary object across blocks (the stable-dictionary contract the
-    zone path keys on — built directly, the same way bench.build_cache does,
-    because the row decoder only dictionary-encodes fixed-layout rows)."""
+    zone path keys on — built directly, the same way
+    lineitem_fixture.build_cache does, because the row decoder only
+    dictionary-encodes fixed-layout rows)."""
     rng = np.random.default_rng(seed)
     cols = [
         ColumnInfo(1, FieldType.int64(), is_pk_handle=True),

@@ -298,7 +298,7 @@ def test_too_many_group_keys_rejected_at_init():
         ShardedGroupedEvaluator(dag, make_mesh(groups=1), 64, capacity=8)
 
 
-# --- serving-path mesh integration (BASELINE config #5 shape) ---------------
+# --- serving-path mesh integration -----------------------------------------
 
 
 def _mvcc_engine(n=3000):

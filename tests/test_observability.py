@@ -84,7 +84,6 @@ LAZY_SERIES = {
     "tikv_observatory_pinned_hbm_watermark_bytes",
     "tikv_observatory_sigs",
     "tikv_observatory_evicted_sigs",
-    "tikv_observatory_backend_probe_total",
     "tikv_coprocessor_encoding_total",
     "tikv_coprocessor_encoding_demote_total",
     "tikv_coprocessor_encoded_path_total",

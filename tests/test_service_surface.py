@@ -306,8 +306,8 @@ def test_diagnostics_service(node_client, tmp_path):
 
 def test_standalone_builds_mesh_endpoint_on_multidevice(tmp_path):
     """Under the 8-virtual-device test mesh, the ASSEMBLED store serves the
-    coprocessor through a (regions × groups) mesh (BASELINE config #5: the
-    copr scale-out path is reachable from the real serving assembly)."""
+    coprocessor through a (regions × groups) mesh (the copr scale-out path
+    is reachable from the real serving assembly)."""
     import jax
 
     from tikv_tpu.pd.client import MockPd

@@ -33,8 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-import bench
 import chip_smoke
+import lineitem_fixture as fx
 from tikv_tpu.copr import encoding, jax_eval, observatory
 from tikv_tpu.copr.aggr import AggDescriptor
 from tikv_tpu.copr.dag import Aggregation, DagRequest, Selection, TableScan
@@ -86,7 +86,7 @@ def four_chips(topo):
 
 def _image(encoded: bool, shared_dicts: bool = True):
     """A filled block cache shaped like one region's warm image."""
-    cache = bench.build_cache(ROWS, BLOCK)
+    cache = fx.build_cache(ROWS, BLOCK)
     if not shared_dicts:
         # a dictionary object per block: the group keys are no longer
         # "stable", so the host assigns group ids (jax_eval.scan)
@@ -95,7 +95,7 @@ def _image(encoded: bool, shared_dicts: bool = True):
                 if c.dictionary is not None:
                     c.dictionary = c.dictionary.copy()
     if encoded:
-        encoding.encode_blocks(cache, bench._lineitem())
+        encoding.encode_blocks(cache, fx._lineitem())
     return cache
 
 
@@ -219,7 +219,7 @@ def test_cold_block_step_compiles(plan, one_chip, recorder):
     """``jax_eval.agg_step`` (and the packed pull): what a cold fill's own
     request runs per block; Q1's host-assigned group ids start in the
     1024-slot bucket, so this is the limb-matmul form."""
-    kvs = bench.build_kvs(BLOCK + 1000, seed=1)
+    kvs = fx.build_kvs(BLOCK + 1000, seed=1)
     _evaluator(plan).run(FixtureScanSource(kvs))
     compile_captured(recorder, one_chip, "jax_eval.agg_step")
 
@@ -251,7 +251,7 @@ def test_warm_scan_host_gids_compiles(plan, encoded, one_chip, recorder, images)
 def test_topn_step_compiles(source, one_chip, recorder, images):
     ev = _evaluator("topn")
     if source == "cold":
-        ev.run(FixtureScanSource(bench.build_kvs(BLOCK + 1000, seed=1)))
+        ev.run(FixtureScanSource(fx.build_kvs(BLOCK + 1000, seed=1)))
     else:
         ev.run(None, cache=images(source == "encoded"))
     compile_captured(recorder, one_chip, "jax_eval.topn")
@@ -290,10 +290,10 @@ def test_real_aggregate_compiles(one_chip, recorder):
     cols = [ColumnInfo(1, FieldType.int64(), is_pk_handle=True),
             ColumnInfo(2, FieldType.double()),
             ColumnInfo(3, FieldType.int64())]
-    kvs = [(record_key(bench.TABLE_ID, i),
+    kvs = [(record_key(fx.TABLE_ID, i),
             encode_row(cols[1:], [i * 0.25, i % 7])) for i in range(256)]
     dag = DagRequest(executors=[
-        TableScan(bench.TABLE_ID, cols),
+        TableScan(fx.TABLE_ID, cols),
         Selection([call("ge", col(2), const_int(1))]),
         Aggregation([col(2)], [AggDescriptor("sum", col(1)),
                                AggDescriptor("avg", col(1)),

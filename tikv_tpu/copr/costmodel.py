@@ -144,21 +144,18 @@ class CostRouter:
         self._reasons = dict.fromkeys(ROUTE_REASONS, 0)
         self._started = time.monotonic()
 
-    def route(self, sig: str, candidates: list[str], *, desc: str = "",
-              costs: dict[str, dict] | None = None) -> Decision:
+    def route(self, sig: str, candidates: list[str], *,
+              desc: str = "") -> Decision:
         """Route one request.  ``candidates`` MUST be in static-ladder
-        order (head = what today's rules would pick); ``costs`` overrides
-        the observatory's ``path_costs`` view — the scheduler passes a
-        synthetic table when weighing batch vs per-request execution."""
+        order (head = what today's rules would pick)."""
         if not candidates:
             raise ValueError("route() needs at least one candidate path")
         if not self.enabled:
             d = Decision(candidates[0], "kill_switch")
             self._note(sig, d, desc)
             return d
-        table = (costs if costs is not None
-                 else self.obs.path_costs(
-                     sig, amortize_floor=self.cfg.compile_amortize_floor))
+        table = self.obs.path_costs(
+            sig, amortize_floor=self.cfg.compile_amortize_floor)
         warm = {p: c for p, c in table.items()
                 if p in candidates and c.get("count", 0) >= self.cfg.min_count}
         cold = [p for p in candidates if p not in warm]

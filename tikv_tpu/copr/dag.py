@@ -12,7 +12,7 @@ Re-expression of tipb's ``DagRequest``/executor descriptors and the
   (``SelectResponse``-equivalent), chunked every 1024 rows
 
 Response bytes are produced by a deterministic encoder so the CPU oracle and
-the TPU path can be compared byte-for-byte (the BASELINE.json contract).
+the TPU path can be compared byte-for-byte.
 """
 
 from __future__ import annotations

@@ -4,7 +4,7 @@ Re-expression of ``src/coprocessor/endpoint.rs`` (:45 Endpoint, :144
 parse_request_and_check_memory_locks, :392/:459/:486 unary path): takes a
 coprocessor request (DAG over key ranges at a start_ts), obtains a snapshot
 from the engine, and runs the plan — on the **device path** when the DAG is
-eligible (the plugin-boundary gating from BASELINE.json), else the CPU batch
+eligible (gated at the plugin boundary), else the CPU batch
 pipeline.  A response cache keyed by (region, data version) serves repeated
 requests and backs the columnar block cache.
 """

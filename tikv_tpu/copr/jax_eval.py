@@ -1,9 +1,9 @@
 """JAX/TPU DAG evaluator — the coprocessor's device execution backend.
 
-This is the subsystem the whole build aims at (BASELINE.json north star): DAGs
-whose shape fits (TableScan → Selection? → Aggregation? → TopN?/Limit?) run as
-ONE jitted XLA program per fixed-size row block, with aggregation carry state
-living on device across blocks:
+This is the subsystem the whole build aims at: DAGs whose shape fits
+(TableScan → Selection? → Aggregation? → TopN?/Limit?) run as ONE jitted XLA
+program per fixed-size row block, with aggregation carry state living on
+device across blocks:
 
     host: MVCC scan → RowBatchDecoder → numpy columns → pad to block shape
     device (jit): RPN predicates → mask; RPN agg args; segment reductions

@@ -876,14 +876,4 @@ def format_sig(sig: str, entry: dict) -> str:
     return "\n".join(lines)
 
 
-def count_backend_probe(verdict: str) -> None:
-    """Bench backend-probe verdicts (ok / timeout / error): the counter
-    that makes an attested-accelerator bench run distinguishable from a
-    wedged probe (ROADMAP bench-attestation gap)."""
-    REGISTRY.counter(
-        "tikv_observatory_backend_probe_total",
-        "Bench backend-probe verdicts (docs/observatory.md)",
-    ).inc(verdict=verdict)
-
-
 OBSERVATORY = Observatory()

@@ -518,7 +518,7 @@ class CoprReadScheduler:
                 sp.tag(outcome="error")
                 raise item.ticket.error
             # served out of a dispatcher micro-batch: the wire-path coalescing
-            # outcome the cluster bench floors on (docs/wire_path.md)
+            # outcome (docs/wire_path.md)
             self._count_coalesce("batched")
             sp.tag(outcome="batched")
             if item.batch_ref is not None:
@@ -701,7 +701,9 @@ class CoprReadScheduler:
         """Form the batch's execution groups: ``(exec_groups, rest)`` with
         ``exec_groups`` a list of ("xregion", sig, [slots]) and ("fused",
         key, [items]) in launch order, ``rest`` the items that serve per
-        request."""
+        request.  Two or more riders of one plan signature over distinct
+        region views are an xregion group: that is the whole rule, and no
+        measurement overrides it (PERF.md section 6, PR 32)."""
         # group by plan signature, then by distinct region view within a sig
         by_sig: dict[tuple, dict[tuple, _Slot]] = {}
         rest = []
@@ -718,13 +720,6 @@ class CoprReadScheduler:
         leftovers: list[_Item] = []
         for sig, slots in by_sig.items():
             if len(slots) >= 2:
-                if not self._route_batch(sig):
-                    # cost-routed (docs/cost_router.md): the measured
-                    # per-request path beats the cross-region batch for
-                    # this plan shape — serve the slots directly
-                    for slot in slots.values():
-                        rest.extend(slot.items)
-                    continue
                 slot_list = list(slots.values())
                 for s in range(0, len(slot_list), self.cfg.max_batch):
                     exec_groups.append(("xregion", sig,
@@ -749,30 +744,6 @@ class CoprReadScheduler:
             for it in (sum((s.items for s in g[2]), []) if g[0] == "xregion" else g[2])
         ))
         return exec_groups, rest
-
-    def _route_batch(self, sig: tuple) -> bool:
-        """Cost-route one sig's micro-batch (docs/cost_router.md):
-        measured "xregion" against a synthetic "direct" = the best
-        per-request path this sig has profiles for.  True keeps the batch
-        (the static choice, and the kill-switch/cold answer); False sends
-        the slots to per-request serving."""
-        router = getattr(self.ep, "cost_router", None)
-        if router is None or not router.enabled:
-            return True  # killed router must cost the dispatch loop nothing
-        from . import observatory as _obs
-
-        with trace.stage("copr.route") as st:
-            sid = _obs.sig_id(sig)
-            costs = router.obs.path_costs(sid)
-            table = {}
-            if "xregion" in costs:
-                table["xregion"] = costs["xregion"]
-            direct = [c for p, c in costs.items() if p != "xregion"]
-            if direct:
-                table["direct"] = min(direct, key=lambda c: c["cost_ms"])
-            d = router.route(sid, ["xregion", "direct"], costs=table)
-            st.tag(path=d.path)
-        return d.path != "direct"
 
     # -- eligibility & keying ----------------------------------------------
 

@@ -38,7 +38,8 @@ GRPC_MSG_FAIL = REGISTRY.counter(
 # time goes — decode (frame bytes -> request value), route (read/handler
 # pool queue wait), execute (service dispatch), encode (response value ->
 # socket).  THE profiling surface for the decode->endpoint->encode gap;
-# summarized by bench_cluster.py and the debug_wire_stages RPC.
+# summarized by the debug_wire_stages RPC and read by the benchmark
+# (`wire_codec_ms_per_task`, PERF.md section 3).
 WIRE_STAGE = REGISTRY.histogram(
     "tikv_wire_stage_seconds",
     "Wire-path time per served frame, by stage",

@@ -1032,9 +1032,8 @@ class KvService:
 
     def debug_wire_stages(self, req: dict) -> dict:
         """Per-stage wire-path summary (tikv_wire_stage_seconds): count and
-        accumulated seconds for decode/route/execute/encode — the RPC the
-        cluster bench scrapes to report where the wire path spends its time
-        (docs/wire_path.md)."""
+        accumulated seconds for decode/route/execute/encode — where the wire
+        path spends its time (docs/wire_path.md)."""
         from .server import WIRE_STAGE
 
         stages = {}

@@ -50,7 +50,7 @@ def init_device_backend() -> list:
 
 def _default_mesh(devices: list):
     """A (regions × groups) mesh over every visible device when more than one
-    is present — the serving-path scale-out of BASELINE config #5.  A single
+    is present — the serving path scaled out over the host's chips.  A single
     device serves single-device."""
     n = len(devices)
     if n <= 1:

@@ -2,7 +2,7 @@
 
 The cache key includes the directory, so a directory that moves never hits.
 Every process that compiles for the device (the store, ``chip_smoke.py``, the
-bench worker) calls :func:`place_compile_cache` before its first compile and
+benchmark) calls :func:`place_compile_cache` before its first compile and
 gets the same answer: the directory ``JAX_COMPILATION_CACHE_DIR`` names when
 the caller's environment sets it (JAX reads that variable itself, so nothing
 is set in code), else ``<checkout>/.jax_cache``, where every compiled program
