@@ -67,3 +67,14 @@ def numeric_table_kvs(n, table_id=TABLE_ID, seed=0):
     for i in range(n):
         kvs.append((record_key(table_id, i), encode_row(non_handle, [int(a[i]), int(b[i]), int(c[i])])))
     return cols, kvs, (a, b, c)
+
+
+def rowv2_rows_decoded(fn):
+    """``fn()`` and the rows it moved
+    ``tikv_coprocessor_rowv2_decode_rows_total`` by, per path."""
+    from tikv_tpu.util.metrics import REGISTRY
+
+    c = REGISTRY.counter("tikv_coprocessor_rowv2_decode_rows_total")
+    before = {p: c.get(path=p) for p in ("uniform", "vector", "walk")}
+    out = fn()
+    return out, {p: c.get(path=p) - before[p] for p in before}

@@ -21,7 +21,7 @@ import random
 import numpy as np
 import pytest
 
-from copr_fixtures import PRODUCT_COLUMNS, TABLE_ID
+from copr_fixtures import PRODUCT_COLUMNS, TABLE_ID, rowv2_rows_decoded
 from fixtures import put_committed
 
 from tikv_tpu.copr import integrity
@@ -97,18 +97,20 @@ def test_crc64_batch_matches_scalar():
 
 
 def test_crc64_batch_bounded_on_skewed_lengths(monkeypatch):
-    """A jumbo blob among small rows must take the scalar path (never a
-    dense matrix padded to the blob's length), and the small-row matrix is
-    sliced — both paths stay bit-identical to the scalar crc64."""
+    """A jumbo blob among small rows must take the scalar path (never a step
+    a byte of the blob for the whole batch), and the small rows the batch's
+    — both paths stay bit-identical to the scalar crc64."""
+    scalar = []
+    monkeypatch.setattr("tikv_tpu.copr.analyze.crc64",
+                        lambda data, _crc64=crc64: scalar.append(len(data)) or _crc64(data))
     rng = random.Random(1)
     rows = [bytes(rng.randrange(256) for _ in range(rng.randrange(1, 40)))
             for _ in range(64)]
     rows[7] = bytes(rng.randrange(256)
                     for _ in range(integrity._JUMBO_ROW + 500))
     rows[40] = b""
-    # tiny slice budget: force multiple matrix chunks
-    monkeypatch.setattr(integrity, "_MATRIX_BYTES", 256)
     got = integrity.crc64_batch(rows)
+    assert scalar == [integrity._JUMBO_ROW + 500]
     want = np.array([crc64(r) for r in rows], dtype=np.uint64)
     assert (got == want).all()
 
@@ -425,6 +427,80 @@ def test_scrubber_deep_detects_block_corruption_without_traffic():
     r = warm.handle_request(_req(_scan_dag(), 200, 3))
     assert r.metrics["region_cache"] == "hit"
     assert r.data == cold.handle_request(_req(_scan_dag(), 200, 3)).data
+
+
+def _mixed_engine(n=96):
+    """v2 rows of several layouts: names of three lengths, NULL prices, ints
+    of one and two bytes — the array decoder's block, not the reshape's."""
+    from tikv_tpu.storage.btree_engine import BTreeEngine
+
+    eng = BTreeEngine()
+    for i in range(n):
+        name = [b"fig", b"banana", b"clementine"][i % 3]
+        price = None if i % 11 == 0 else 100 + i * 37
+        put_committed(eng, record_key(TABLE_ID, i),
+                      encode_row_v2(NON_HANDLE, [name, i * 7 % 23, price]), 90, 100)
+    return eng
+
+
+def test_mixed_layout_image_fingerprints_are_what_they_were():
+    """The image of mixed-layout rows is built by the array decoder, and its
+    fingerprints are the literal values the per-row build gave (computed on
+    the parent of the PR that brought the arrays, cd28a35)."""
+    eng = _mixed_engine()
+    warm, cold = _pair(eng)
+    _r, moved = rowv2_rows_decoded(lambda: warm.handle_request(_req(_scan_dag(), 200, 3)))
+    assert moved == {"uniform": 0, "vector": 96, "walk": 0}
+    _key, img = _the_image(warm)
+    assert img.fp_valid
+    assert (img.fp_value, img.fp_integrity) == (FP_VALUE_PARENT, FP_INTEGRITY_PARENT)
+    assert img.fp_value == checksum_range(
+        (record_key(TABLE_ID, i), v) for i, v in _mixed_values(eng))["checksum"]
+    r = warm.handle_request(_req(_scan_dag(), 200, 3))
+    assert r.metrics["region_cache"] == "hit"
+    assert r.data == cold.handle_request(_req(_scan_dag(), 200, 3)).data
+
+
+FP_VALUE_PARENT = 759004103826497781
+FP_INTEGRITY_PARENT = 363352670706918358
+
+
+def _mixed_values(eng):
+    snap = eng.snapshot()
+    for i in range(96):
+        k = Key.from_raw(record_key(TABLE_ID, i)).append_ts(100).encoded
+        yield i, Write.from_bytes(snap.get_cf(CF_WRITE, k)).short_value
+
+
+@pytest.mark.parametrize("seed", [2, 5, 9])
+def test_mixed_layout_block_flip_ends_in_its_column(seed):
+    eng = _mixed_engine()
+    warm, cold = _pair(eng)
+    warm.handle_request(_req(_scan_dag(), 200, 3))
+    info = chaos.corrupt_image(warm.region_cache, random.Random(seed), mode="block")
+    results = warm.scrubber.scrub_once()
+    assert [x["outcome"] for x in results] == ["mismatch"]
+    assert results[0]["failed"] == [f"column:{info['column']}"]
+    # repaired eagerly, through the same decoder
+    assert [x["outcome"] for x in warm.scrubber.scrub_once()] == ["ok"]
+    r = warm.handle_request(_req(_scan_dag(), 200, 3))
+    assert r.metrics["region_cache"] == "hit"
+    assert r.data == cold.handle_request(_req(_scan_dag(), 200, 3)).data
+
+
+def test_mixed_layout_engine_value_flip_ends_in_content():
+    """A bit flipped in a row's bytes in the engine, under an image that was
+    built before: the oracle's hashes no longer fold to the image's."""
+    eng = _mixed_engine()
+    warm, _cold = _pair(eng)
+    warm.handle_request(_req(_scan_dag(), 200, 3))
+    _i, v = list(_mixed_values(eng))[40]
+    ba = bytearray(v)
+    ba[-1] ^= 0x04  # the last cell's last byte: the row still decodes
+    put_committed(eng, record_key(TABLE_ID, 40), bytes(ba), 90, 100)
+    results = warm.scrubber.scrub_once()
+    assert [x["outcome"] for x in results] == ["mismatch"]
+    assert "content" in results[0]["failed"]
 
 
 def test_scrubber_worker_cadence_and_snapshot():

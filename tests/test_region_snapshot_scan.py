@@ -3,8 +3,8 @@
 The served path reads every region through a ``RegionSnapshot``.  Without a
 ``scan_cf`` of its own it inherited the cursor walk (one engine seek per row:
 over the native engine one FFI call and one merge of memtable and runs per
-row), and without ``scan_raw`` the vectorised MVCC resolver never took its
-zero-copy path on a raft-backed store; chip_smoke's cold fills of 300,000-row
+row), and without ``scan_spans`` (``scan_raw`` until PR 33) the vectorised
+MVCC resolver never took its zero-copy path on a raft-backed store; chip_smoke's cold fills of 300,000-row
 regions outlasted a 120 s client timeout that way (ISSUE 22)."""
 
 import pytest
@@ -54,22 +54,31 @@ def test_scan_cf_equals_cursor_walk(engine, uniform):
 
 
 @pytest.mark.parametrize("uniform", [True, False], ids=["one-size", "mixed-size"])
-def test_scan_raw_frames_equal_scan_cf(uniform):
-    snap = _region_snap(_fill(NativeEngine(), uniform))
+def test_scan_spans_equal_scan_cf(uniform):
+    from tikv_tpu.copr.byterows import ByteRows
+
+    eng = _fill(NativeEngine(), uniform)
+    snap = _region_snap(eng)
     for start, end in RANGES:
-        n, buf = snap.scan_raw(CF_WRITE, start, end)
+        buf, k_at, k_len, v_at, v_len = snap.scan_spans(CF_WRITE, start, end)
         want = list(Snapshot.scan_cf(snap, CF_WRITE, start, end))
-        assert n == len(want)
-        assert [(bytes(k), bytes(v)) for k, v in parse_frames(buf, n)] == want
+        assert len(k_at) == len(want)
+        assert list(zip(ByteRows(buf, k_at, k_len), ByteRows(buf, v_at, v_len))) == want
+    # the engine's own snapshot: the same frames, the z prefix still on
+    buf, k_at, k_len, v_at, v_len = eng.snapshot().scan_spans(CF_WRITE, b"z", b"{")
+    n, raw = eng.snapshot().scan_raw(CF_WRITE, b"z", b"{")
+    assert buf == raw and n == len(k_at) == 200
+    assert list(zip(ByteRows(buf, k_at, k_len), ByteRows(buf, v_at, v_len))) == [
+        (bytes(k), bytes(v)) for k, v in parse_frames(raw, n)]
 
 
-def test_scan_raw_only_over_an_engine_that_has_it():
-    assert not hasattr(_region_snap(_fill(BTreeEngine(), True)), "scan_raw")
+def test_scan_spans_only_over_an_engine_that_has_it():
+    assert not hasattr(_region_snap(_fill(BTreeEngine(), True)), "scan_spans")
 
 
 def test_batch_resolver_takes_its_matrix_path_over_a_region_snapshot():
     """The vectorised MVCC resolver over a RegionSnapshot gives what the
-    per-key scanner gives, through ``scan_raw``."""
+    per-key scanner gives, through ``scan_spans``."""
     from tikv_tpu.copr.executors import MvccScanSource
     from tikv_tpu.copr.mvcc_batch import MvccBatchScanSource
     from tikv_tpu.copr.table import record_key
@@ -87,8 +96,8 @@ def test_batch_resolver_takes_its_matrix_path_over_a_region_snapshot():
     snap = RegionSnapshot(eng.snapshot(), Region(
         9, Key.from_raw(lo).encoded, Key.from_raw(hi).encoded, RegionEpoch(), []))
     calls = []
-    real = snap.scan_raw
-    snap.scan_raw = lambda *a: calls.append(a) or real(*a)
+    real = snap.scan_spans
+    snap.scan_spans = lambda *a: calls.append(a) or real(*a)
     for ts in (20, 100):
         rng = [(record_key(5, 0), record_key(5, 300))]
         got_k, got_v = MvccBatchScanSource(snap, ts, rng)._resolve_all()

@@ -170,7 +170,8 @@ def test_encode_bin_clamps_when_widening_overflows():
 
 
 # ---------------------------------------------------------------------------
-# Grouped mixed-layout batch decode
+# Mixed-layout batch decode (the array decoder since PR 33: more of it in
+# test_rowv2_vector_decode.py)
 # ---------------------------------------------------------------------------
 
 
@@ -191,9 +192,14 @@ def _col_values(cols, schema):
     return out
 
 
-def test_grouped_decode_mixed_layouts_matches_per_row():
+@pytest.mark.parametrize("times,path", [(1, "walk"), (8, "vector")])
+def test_grouped_decode_mixed_layouts_matches_per_row(times, path):
     """Rows with different layouts (NULL patterns, value widths, varchar
-    lengths) must decode identically to the per-row walk, in row order."""
+    lengths) must decode identically to the per-row walk, in row order: a
+    handful of layouts, as a block of a few rows (which walks) and as one
+    large enough for the arrays."""
+    from tikv_tpu.copr.rowv2 import decode_block
+
     schema = _schema()
     rows = [
         [7, 1.5, b"xy", 1234, 2],
@@ -202,9 +208,10 @@ def test_grouped_decode_mixed_layouts_matches_per_row():
         [7, 1.5, b"xy", 1234, 2],                   # same layout as row 0
         [3, None, None, None, 1],                   # mostly NULL
         [1 << 40, 2.5, b"longer-string", 5678, 1],  # same layout as row 1
-    ]
+    ] * times
     encoded = [encode_row_v2(schema[1:], r) for r in rows]
-    cols = decode_rows_v2(schema, encoded)
+    cols, took = decode_block(schema, encoded)
+    assert took == path
     per_row = [decode_rows_v2(schema, [e]) for e in encoded]
     for r, cols1 in enumerate(per_row):
         got = _col_values(cols, schema)[r]
@@ -213,8 +220,9 @@ def test_grouped_decode_mixed_layouts_matches_per_row():
 
 
 def test_grouped_decode_layout_explosion_falls_back():
-    """One distinct layout per row (> _MAX_LAYOUT_GROUPS) must still decode
-    correctly through the slow path."""
+    """One distinct layout per row must still decode correctly: the rows
+    share one header shape (two columns, none NULL), so the arrays take all
+    forty whatever their offsets, with no limit on the layouts."""
     schema = [
         ColumnInfo(1, FieldType.int64(), is_pk_handle=True),
         ColumnInfo(2, FieldType.varchar()),
@@ -222,8 +230,14 @@ def test_grouped_decode_layout_explosion_falls_back():
     ]
     rows = [[b"x" * (i + 1), i] for i in range(40)]
     encoded = [encode_row_v2(schema[1:], r) for r in rows]
+    assert len({(len(e), e[:12]) for e in encoded}) == 40
     cols = decode_rows_v2(schema, encoded)
     vals = _col_values(cols, schema)
     for i in range(40):
         assert vals[i][1] == b"x" * (i + 1)
         assert vals[i][2] == i
+    from tikv_tpu.copr import rowv2
+
+    assert rowv2.decode_block(schema, encoded)[1] == "vector"
+    walked = _col_values(rowv2._slow_decode(schema, encoded, 40), schema)
+    assert [v[1:] for v in vals] == [v[1:] for v in walked]

@@ -54,8 +54,9 @@ import time
 import numpy as np
 
 from ..analysis.sanitizer import make_lock
-from ..util import codec
+from ..util import codec, trace
 from .analyze import _crc64_table
+from .byterows import ByteRows
 
 _CRC64_TABLE = np.array(_crc64_table, dtype=np.uint64)
 _MASK64 = np.uint64(0xFFFFFFFFFFFFFFFF)
@@ -76,67 +77,81 @@ def integrity_fatal() -> bool:
 # row hashing (vectorized crc64-ECMA, identical to analyze.checksum_range)
 # ---------------------------------------------------------------------------
 
-# crc64_batch padding bounds: a row longer than _JUMBO_ROW hashes scalar
-# (a dense matrix padded to one huge blob's length would multiply EVERY
-# row's footprint by it), and the padded matrix is processed in slices of
-# at most _MATRIX_BYTES so the transient never scales with the row count
+# A row longer than this hashes scalar: the batch advances every row one
+# byte a step, so one huge blob among small rows would cost a step a byte
+# for itself alone.
 _JUMBO_ROW = 4096
-_MATRIX_BYTES = 16 << 20
 
 
-def crc64_batch(rows: list[bytes]) -> np.ndarray:
-    """crc64-ECMA of every byte string, vectorized ACROSS rows: the carry
-    chain is sequential within a row, so the loop runs over byte positions
-    while each step advances every row at once.  Bit-identical to
-    :func:`..analyze.crc64` per row.  Memory-bounded: jumbo rows fall back
-    to the scalar loop and the padded matrix is sliced, so a skewed value
-    distribution cannot balloon the transient footprint."""
-    n = len(rows)
-    if n == 0:
-        return np.empty(0, dtype=np.uint64)
-    lens = np.fromiter(map(len, rows), dtype=np.int64, count=n)
-    out = np.empty(n, dtype=np.uint64)
-    jumbo = np.flatnonzero(lens > _JUMBO_ROW)
-    if len(jumbo):
-        from .analyze import crc64
-
-        for i in jumbo:
-            out[i] = crc64(rows[int(i)])
-    small = np.flatnonzero(lens <= _JUMBO_ROW) if len(jumbo) else None
-    order = small if small is not None else np.arange(n, dtype=np.int64)
-    step = len(order)
-    if len(order):
-        step = max(1, _MATRIX_BYTES // max(int(lens[order].max()), 1))
+def _crc64_advance(crc: np.ndarray, rows: ByteRows) -> np.ndarray:
+    """Each row's running crc64-ECMA (the register, before the final
+    inversion) advanced over that row's bytes.  Vectorized ACROSS rows: the
+    carry chain is sequential within a row, so the loop runs over byte
+    positions while each step advances every row still that long.  Rows are
+    taken longest first, so the rows a step advances are a prefix and no step
+    masks; the transient is a few arrays of one element a row."""
+    if len(rows) == 0 or int(rows.lens.max()) == 0:
+        return crc
+    flat = rows.flat
+    order = np.argsort(-rows.lens, kind="stable")
+    lens = rows.lens[order]
+    pos = rows.at[order]
+    reg = crc[order]
+    steps = int(lens[0])
+    # rows longer than j, for every step j: lens is descending
+    alive = np.searchsorted(-lens, -np.arange(steps), side="left")
+    low = reg.view(np.uint8)[::8]  # each register's low byte (little-endian)
     eight = np.uint64(8)
-    for s in range(0, len(order), step):
-        sel = order[s:s + step]
-        slens = lens[sel]
-        k = len(sel)
-        crc = np.full(k, 0xFFFFFFFFFFFFFFFF, dtype=np.uint64)
-        m = int(slens.max()) if k else 0
-        if m:
-            chunk = [rows[int(i)] for i in sel]
-            flat = np.frombuffer(b"".join(chunk), dtype=np.uint8)
-            buf = np.zeros((k, m), dtype=np.uint8)
-            row_idx = np.repeat(np.arange(k, dtype=np.int64), slens)
-            col_idx = np.arange(int(slens.sum()), dtype=np.int64) - np.repeat(
-                np.cumsum(slens) - slens, slens
-            )
-            buf[row_idx, col_idx] = flat
-            for j in range(m):
-                active = slens > j
-                idx = ((crc ^ buf[:, j]) & np.uint64(0xFF)).astype(np.int64)
-                crc = np.where(active, _CRC64_TABLE[idx] ^ (crc >> eight), crc)
-        out[sel] = crc ^ _MASK64
+    for c in alive.tolist():
+        r = reg[:c]
+        reg[:c] = _CRC64_TABLE[low[:c] ^ flat[pos[:c]]] ^ (r >> eight)
+        pos[:c] += 1
+    out = np.empty_like(crc)
+    out[order] = reg
     return out
 
 
-def row_checksums(raw_keys: list[bytes], values: list[bytes]) -> np.ndarray:
+def crc64_batch(rows) -> np.ndarray:
+    """crc64-ECMA of every byte string (a list of ``bytes`` or a
+    ``ByteRows``).  Bit-identical to :func:`..analyze.crc64` per row; jumbo
+    rows take that scalar loop."""
+    from .analyze import crc64
+
+    rows = ByteRows.of(rows)
+    jumbo = rows.lens > _JUMBO_ROW
+    small = np.flatnonzero(~jumbo)
+    out = np.empty(len(rows), dtype=np.uint64)
+    out[small] = _crc64_advance(
+        np.full(len(small), _MASK64, dtype=np.uint64), rows[small]) ^ _MASK64
+    for i in np.flatnonzero(jumbo).tolist():
+        out[i] = crc64(rows[i])
+    return out
+
+
+def row_checksums(raw_keys, values) -> np.ndarray:
     """Per-row ``crc64(compact(key) + compact(value))`` — EXACTLY the entry
     ``analyze.checksum_range`` folds, so ``fold(row_checksums(...))`` equals
-    the coprocessor Checksum of the same rows."""
+    the coprocessor Checksum of the same rows.  Keys and values are lists of
+    ``bytes`` or ``ByteRows``, and the chain is never built: each row's
+    register runs over the key's length varint, the key, the value's varint
+    and the value, each where it lies."""
+    from .analyze import crc64
+
+    keys, vals = ByteRows.of(raw_keys), ByteRows.of(values)
+    jumbo = (keys.lens > _JUMBO_ROW) | (vals.lens > _JUMBO_ROW)
+    small = np.flatnonzero(~jumbo)
+    reg = np.full(len(small), _MASK64, dtype=np.uint64)
+    for part in (keys[small], vals[small]):
+        head, head_lens = codec.encode_var_i64_batch(part.lens)
+        reg = _crc64_advance(reg, ByteRows(
+            head.tobytes(), np.cumsum(head_lens) - head_lens, head_lens))
+        reg = _crc64_advance(reg, part)
+    out = np.empty(len(keys), dtype=np.uint64)
+    out[small] = reg ^ _MASK64
     ecb = codec.encode_compact_bytes
-    return crc64_batch([ecb(k) + ecb(v) for k, v in zip(raw_keys, values)])
+    for i in np.flatnonzero(jumbo).tolist():
+        out[i] = crc64(ecb(keys[i]) + ecb(vals[i]))
+    return out
 
 
 def mix_fp(row_fp: np.ndarray, commit_ts) -> np.ndarray:
@@ -290,14 +305,18 @@ def verify_image(cache, key, snap, deep: bool = True, stage: str = "scrub") -> d
     from .mvcc_batch import MvccBatchScanSource
     from .table import RowBatchDecoder, decode_record_handles
 
+    # the same three steps as a cold build (region_cache.py:_build), under
+    # the same stage names: one split serves both
     src = MvccBatchScanSource(snap, ts, list(key[1]), record_versions=True)
     try:
-        keys_raw, values = src._resolve_all()
+        with trace.stage("fill.resolve"):
+            keys_raw, values = src._resolve_all()
     except Exception as exc:  # noqa: BLE001 — locks, faulting engine
         return {"outcome": "error", "error": repr(exc)}
     if not src.versions_exact:
         return {"outcome": "unverifiable"}
-    o_fp = row_checksums(keys_raw, values)
+    with trace.stage("fill.fingerprint"):
+        o_fp = row_checksums(keys_raw, values)
     o_cts = src.row_commit_ts
     # the deep compare's expensive half — handle decode + a full row decode
     # of the oracle values — runs OUTSIDE the manager lock (it touches only
@@ -309,7 +328,10 @@ def verify_image(cache, key, snap, deep: bool = True, stage: str = "scrub") -> d
         try:
             o_handles = decode_record_handles(keys_raw)
             if len(o_handles):
-                o_cols = RowBatchDecoder(schema).decode(o_handles, values)
+                decoder = RowBatchDecoder(schema)
+                with trace.stage("fill.decode") as st:
+                    o_cols = decoder.decode(o_handles, values)
+                    st.tag(rows=len(values), path=decoder.path)
         except Exception as exc:  # noqa: BLE001 — exotic rows: cannot judge
             return {"outcome": "error", "error": repr(exc)}
     with cache._mu:

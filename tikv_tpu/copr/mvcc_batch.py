@@ -32,6 +32,7 @@ from ..storage.mvcc.reader import _check_lock
 from ..storage.txn_types import Key, WriteType
 from ..util import codec
 from . import datum as datum_mod
+from .byterows import ByteRows
 from .executors import ScanSource
 
 _TS_W = 8
@@ -39,16 +40,11 @@ _PUT = int(WriteType.PUT)
 _SHORT_PREFIX = 0x76  # b'v'
 
 
-def _parse_frames(buf: bytes, n: int) -> list[tuple[bytes, bytes]]:
-    from ..native.engine import parse_frames
-
-    return list(parse_frames(buf, n))
-
-
-def _decode_user_keys(key_rows: np.ndarray) -> list[bytes]:
+def _decode_user_keys(key_rows: np.ndarray):
     """Vectorized memcomparable decode of same-width encoded keys: drop the
     marker byte of each 9-byte group and trim the final group's padding
-    (markers verified uniform; per-row fallback otherwise)."""
+    (markers verified uniform; per-row fallback otherwise).  A ``ByteRows``
+    over the decoded matrix, or the fallback's list of ``bytes``."""
     n, w = key_rows.shape
     if w % 9 == 0:
         groups = w // 9
@@ -58,9 +54,36 @@ def _decode_user_keys(key_rows: np.ndarray) -> list[bytes]:
             data_cols = np.concatenate(
                 [key_rows[:, g * 9 : g * 9 + 8] for g in range(groups)], axis=1
             )[:, : len(raw0)]
-            data_cols = np.ascontiguousarray(data_cols)
-            return [r.tobytes() for r in data_cols]
+            return ByteRows.from_matrix(data_cols)
     return [codec.decode_bytes(key_rows[i].tobytes())[0] for i in range(n)]
+
+
+def _short_values(recs: ByteRows):
+    """Where the short value of each write record lies, for the records that
+    are exactly ``[P][varint start_ts][v][len][len bytes]`` (every PUT of a
+    row under 256 bytes that no rollback overlapped and no GC fenced):
+    ``(value_at, value_len, plain)``; the others are for ``Write.from_bytes``."""
+    flat, at, lens = recs.flat, recs.at, recs.lens
+    k = len(at)
+    plain = lens >= 4
+    plain[plain] = flat[at[plain]] == _PUT
+    # the varint's bytes: continuation bytes, then one below 0x80
+    vw = np.ones(k, dtype=np.int64)
+    going = plain.copy()
+    for j in range(1, 11):
+        idx = np.flatnonzero(going)
+        if not len(idx):
+            break
+        more = (j + 1 < lens[idx]) & (flat[at[idx] + j] >= 0x80)
+        going[idx] = more
+        vw[idx[more]] += 1
+    plain &= ~going
+    tag = at + 1 + vw
+    plain &= tag + 2 <= at + lens
+    idx = np.flatnonzero(plain)
+    plain[idx] = (flat[tag[idx]] == _SHORT_PREFIX) & (
+        flat[tag[idx] + 1] == lens[idx] - vw[idx] - 3)
+    return tag + 2, lens - vw - 3, plain
 
 
 class MvccBatchScanSource(ScanSource):
@@ -103,14 +126,14 @@ class MvccBatchScanSource(ScanSource):
                                    statistics=self.stats,
                                    bypass_locks=self.bypass_locks)
 
-    def _resolve_all(self) -> tuple[list[bytes], list[bytes]]:
-        keys_out: list[bytes] = []
-        vals_out: list[bytes] = []
+    def _resolve_all(self):
+        """``(raw keys, values)`` of the visible rows of every range, each a
+        list of ``bytes`` or, from a single range resolved as arrays, a
+        ``ByteRows``."""
+        parts = []
         cts_out: list[np.ndarray] = []
         for start, end in self.ranges:
-            k, v = self._resolve_range(start, end)
-            keys_out.extend(k)
-            vals_out.extend(v)
+            parts.append(self._resolve_range(start, end))
             if self.record_versions:
                 if self._range_cts is None:
                     self.versions_exact = False
@@ -121,9 +144,16 @@ class MvccBatchScanSource(ScanSource):
             self.row_commit_ts = (
                 np.concatenate(cts_out) if cts_out else np.empty(0, dtype=np.int64)
             )
+        if len(parts) == 1:
+            return parts[0]
+        keys_out: list[bytes] = []
+        vals_out: list[bytes] = []
+        for k, v in parts:
+            keys_out.extend(k)
+            vals_out.extend(v)
         return keys_out, vals_out
 
-    def _resolve_range(self, start: bytes, end: bytes) -> tuple[list[bytes], list[bytes]]:
+    def _resolve_range(self, start: bytes, end: bytes):
         # version info for the range just resolved (record_versions bookkeeping)
         self._range_cts: np.ndarray | None = None
         self._range_max_ct = 0
@@ -134,30 +164,16 @@ class MvccBatchScanSource(ScanSource):
             self.stats.lock.next += 1
             _check_lock(v, Key.from_encoded(k).to_raw(), self.ts, self.bypass_locks)
 
-        native = self._native_range(enc_start, enc_end)
-        if native is not None and not isinstance(native, list):
-            n, width, arr, values_arr = native
-            if n == 0:
-                self._range_cts = np.empty(0, dtype=np.int64)
-                return [], []
-            wkeys = None
-            pairs = None
-        else:
-            # native may hand back the already-fetched pairs (variable frames)
-            # so the range is never scanned across the FFI twice
-            pairs = native if native is not None else list(
-                self.snap.scan_cf(CF_WRITE, enc_start, enc_end)
-            )
-            if not pairs:
-                self._range_cts = np.empty(0, dtype=np.int64)
-                return [], []
-            wkeys = [k for k, _ in pairs]
-            width = len(wkeys[0])
-            if any(len(k) != width for k in wkeys):
-                return self._fallback(start, end)
-            n = len(wkeys)
-            arr = np.frombuffer(b"".join(wkeys), dtype=np.uint8).reshape(n, width)
-            values_arr = None
+        # the range's CF_WRITE keys and records, each over one buffer
+        wkeys, recs = self._scan_writes(enc_start, enc_end)
+        n = len(wkeys)
+        if n == 0:
+            self._range_cts = np.empty(0, dtype=np.int64)
+            return [], []
+        arr = wkeys.matrix()
+        if arr is None:
+            return self._fallback(start, end)
+        width = arr.shape[1]
         user = arr[:, : width - _TS_W]
         commit_ts = codec.decode_u64_batch(arr[:, width - _TS_W :]) ^ np.uint64(
             0xFFFFFFFFFFFFFFFF
@@ -184,58 +200,47 @@ class MvccBatchScanSource(ScanSource):
             return [], []
         pick_cts = commit_ts[pick].astype(np.int64)
 
-        if values_arr is not None:
-            varr = np.ascontiguousarray(values_arr[pick])
-            vw = varr.shape[1]
-            simple = self._parse_simple_layout(varr, vw)
-            if simple is not None:
-                self.stats.write.processed_keys += len(pick)
-                key_rows = np.ascontiguousarray(arr[pick, : width - _TS_W])
-                out_keys = _decode_user_keys(key_rows)
-                self._range_cts = pick_cts
-                return out_keys, simple
-            if self.record_versions:
-                return self._exact_picked(
-                    pick, pick_cts, arr, width,
-                    lambda j: varr[j].tobytes(),
-                )
-            return self._fallback(start, end)
-
-        values = [pairs[i][1] for i in pick]
+        picked = recs[pick]
         # vectorized write-record parse: common layout check
-        vlens = np.fromiter((len(v) for v in values), dtype=np.int64, count=len(values))
-        if len(values) and (vlens == vlens[0]).all():
-            vw = int(vlens[0])
-            varr = np.frombuffer(b"".join(values), dtype=np.uint8).reshape(len(values), vw)
-            simple = self._parse_simple_layout(varr, vw)
+        varr = picked.matrix()
+        if varr is not None:
+            simple = self._parse_simple_layout(varr, varr.shape[1])
             if simple is not None:
                 self.stats.write.processed_keys += len(pick)
-                out_keys = [bytes(Key.from_encoded(wkeys[i][: width - _TS_W]).to_raw()) for i in pick]
                 self._range_cts = pick_cts
-                return out_keys, simple
+                return _decode_user_keys(arr[pick, : width - _TS_W]), simple
         if self.record_versions:
-            return self._exact_picked(
-                pick, pick_cts, arr, width, lambda j: values[j]
-            )
+            return self._exact_picked(pick, pick_cts, arr, width, picked)
         # mixed/unusual records: exact per-key resolution for the whole range
         return self._fallback(start, end)
 
-    def _exact_picked(self, pick, pick_cts, arr, width, rec_of):
+    def _exact_picked(self, pick, pick_cts, arr, width, recs: ByteRows):
         """Record-versions build path for ranges whose picked records don't
-        share one layout: the key-space work stays vectorized, and only the
-        picked (newest-visible) record of each key parses exactly — PUTs
-        yield their value, DELETEs drop the key, LOCK/ROLLBACK re-resolve
-        through older versions.  Version fingerprints stay the picked
-        entry's commit_ts, matching ``scan_delta``."""
+        share one layout (rows of mixed length): the key-space work stays
+        vectorized, and the picked (newest-visible) record of each key is
+        read where it lies.  Where every record is a plain PUT with its short
+        value (``_short_values``) the values go on as a ``ByteRows`` over the
+        scan's buffer and nothing is cut a row; otherwise the others parse
+        exactly — PUTs yield their value, DELETEs drop the key, LOCK/ROLLBACK
+        re-resolve through older versions.  Version fingerprints stay the
+        picked entry's commit_ts, matching ``scan_delta``."""
         from ..storage.engine import CF_DEFAULT
         from ..storage.txn_types import Write, append_ts
 
-        key_rows = np.ascontiguousarray(arr[pick, : width - _TS_W])
-        raw_keys = _decode_user_keys(key_rows)
+        raw_keys = _decode_user_keys(arr[pick, : width - _TS_W])
+        val_at, val_len, plain = _short_values(recs)
+        if plain.all():
+            self.stats.write.processed_keys += len(pick)
+            self._range_cts = pick_cts
+            return raw_keys, ByteRows(recs.raw, val_at, val_len)
         keep: list[int] = []
         vals: list[bytes] = []
         for j in range(len(pick)):
-            w = Write.from_bytes(rec_of(j))
+            if plain[j]:
+                keep.append(j)
+                vals.append(recs.raw[val_at[j] : val_at[j] + val_len[j]])
+                continue
+            w = Write.from_bytes(recs[j])
             if w.write_type == WriteType.PUT:
                 v = w.short_value
                 if v is None:
@@ -257,33 +262,18 @@ class MvccBatchScanSource(ScanSource):
         self._range_cts = pick_cts[np.array(keep, dtype=np.int64)] if keep else np.empty(0, dtype=np.int64)
         return [raw_keys[j] for j in keep], vals
 
-    def _native_range(self, enc_start: bytes, enc_end: bytes):
-        """Fixed-stride zero-copy path over a native snapshot's scan buffer:
-        if every (key, value) frame has identical lengths, the whole range
-        reshapes into two byte matrices without per-pair Python."""
-        scan_raw = getattr(self.snap, "scan_raw", None)
-        if scan_raw is None:
-            return None
-        n, buf = scan_raw(CF_WRITE, enc_start, enc_end)
-        if n == 0:
-            return 0, 0, None, None
-        b = np.frombuffer(buf, dtype=np.uint8)
-        klen = int(np.frombuffer(buf[:4], dtype=np.uint32)[0])
-        if len(buf) < 8 + klen:
-            return None
-        vlen = int(np.frombuffer(buf[4 + klen : 8 + klen], dtype=np.uint32)[0])
-        stride = 8 + klen + vlen
-        if len(buf) != n * stride:
-            return _parse_frames(buf, n)  # mixed frame sizes — generic pairs
-        mat = b.reshape(n, stride)
-        # verify the length headers are constant across rows
-        if not (mat[:, :4] == mat[0, :4]).all() or not (
-            mat[:, 4 + klen : 8 + klen] == mat[0, 4 + klen : 8 + klen]
-        ).all():
-            return _parse_frames(buf, n)
-        keys_arr = mat[:, 4 : 4 + klen]
-        values_arr = mat[:, 8 + klen : stride]
-        return n, klen, keys_arr, values_arr
+    def _scan_writes(self, enc_start: bytes, enc_end: bytes) -> tuple[ByteRows, ByteRows]:
+        """The CF_WRITE keys and records of the range.  A native snapshot
+        hands back one framed buffer for the whole range (one FFI crossing)
+        and where the keys and records lie in it (``scan_spans``); other
+        engines give pairs, which are joined."""
+        scan_spans = getattr(self.snap, "scan_spans", None)
+        if scan_spans is None:
+            pairs = list(self.snap.scan_cf(CF_WRITE, enc_start, enc_end))
+            return (ByteRows.of([k for k, _ in pairs]),
+                    ByteRows.of([v for _, v in pairs]))
+        buf, k_at, k_len, v_at, v_len = scan_spans(CF_WRITE, enc_start, enc_end)
+        return ByteRows(buf, k_at, k_len), ByteRows(buf, v_at, v_len)
 
     def _parse_simple_layout(self, varr: np.ndarray, vw: int) -> list[bytes] | None:
         """All records = [P][varint start_ts][v][len][short_value]? Verify the

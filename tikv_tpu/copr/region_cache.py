@@ -85,6 +85,7 @@ from ..storage.txn_types import Key, Write, WriteType, append_ts, split_ts
 from ..util import trace
 from . import encoding as _encoding
 from . import integrity as _integrity
+from .byterows import ByteRows
 from .cache import ColumnBlockCache
 from .datatypes import Column, EvalType
 from .mvcc_batch import MvccBatchScanSource, scan_delta
@@ -293,14 +294,19 @@ class RegionImage:
              raw_keys: list[bytes] | None = None, encode: bool = False) -> None:
         self.handles = handles
         self.row_commit_ts = cts
-        self._init_fingerprint(handles, values, raw_keys)
+        with trace.stage("fill.fingerprint"):
+            self._init_fingerprint(handles, values, raw_keys)
         cache = self.block_cache
         cache.clear_blocks()
         br = self.block_rows
-        for s in range(0, len(values), br):
-            e = min(s + br, len(values))
-            cols = self.decoder.decode(handles[s:e], values[s:e])
-            cache.add(cols, e - s)
+        with trace.stage("fill.decode") as st:
+            paths = set()
+            for s in range(0, len(values), br):
+                e = min(s + br, len(values))
+                cols = self.decoder.decode(handles[s:e], values[s:e])
+                paths.add(self.decoder.path)
+                cache.add(cols, e - s)
+            st.tag(rows=len(values), path=",".join(sorted(paths)))
         cache.filled = True
         # fill-time stats pass (docs/compressed_columns.md): eligible
         # columns become ENCODED residents — dict codes narrowed, runs
@@ -340,11 +346,9 @@ class RegionImage:
                 self.table_id = tid_first
             else:
                 self.table_id = self._table_id_from_ranges()
-            self.row_fp = _integrity.row_checksums(raw_keys, values)
-            self.row_nbytes = np.fromiter(
-                (len(k) + len(v) for k, v in zip(raw_keys, values)),
-                dtype=np.int64, count=len(values),
-            )
+            keys, vals = ByteRows.of(raw_keys), ByteRows.of(values)
+            self.row_fp = _integrity.row_checksums(keys, vals)
+            self.row_nbytes = keys.lens + vals.lens
         except Exception:  # noqa: BLE001 — exotic keys: plane off, serve on
             self.row_fp = np.empty(0, dtype=np.uint64)
             self.row_nbytes = np.empty(0, dtype=np.int64)
@@ -1494,7 +1498,8 @@ class RegionColumnCache:
         with trace.stage("cache.fill", kind="build") as st:
             src = MvccBatchScanSource(snap, start_ts, ranges, statistics=stats,
                                       record_versions=True)
-            keys, values = src._resolve_all()
+            with trace.stage("fill.resolve"):
+                keys, values = src._resolve_all()
             if not src.versions_exact:
                 self.stats.uncacheable += 1
                 self._count("uncacheable")

@@ -22,6 +22,7 @@ from ..util import codec
 from . import datatypes
 from . import datum as datum_mod
 from . import rowv2
+from .byterows import ByteRows
 from .datatypes import Column, ColumnInfo, EvalType
 
 TABLE_PREFIX = b"t"
@@ -45,17 +46,16 @@ def decode_record_key(key: bytes) -> tuple[int, int]:
     return codec.decode_i64(key, 1), codec.decode_i64(key, 11)
 
 
-def decode_record_handles(keys: list[bytes]) -> np.ndarray:
-    """Batch handle decode: one reshape + byte-slice for the whole block."""
-    n = len(keys)
-    if n == 0:
+def decode_record_handles(keys) -> np.ndarray:
+    """Batch handle decode: one reshape + byte-slice for the whole block
+    (a list of ``bytes`` or a ``ByteRows``)."""
+    if len(keys) == 0:
         return np.empty(0, dtype=np.int64)
-    lens = np.fromiter(map(len, keys), dtype=np.int64, count=n)
-    if lens.min() != 19 or lens.max() != 19:
+    rows = ByteRows.of(keys)
+    if rows.lens.min() != 19 or rows.lens.max() != 19:
         # not uniformly record keys; per-key decode surfaces the bad one
         return np.array([decode_record_key(k)[1] for k in keys], dtype=np.int64)
-    arr = np.frombuffer(b"".join(keys), dtype=np.uint8).reshape(n, 19)
-    return codec.decode_i64_batch(arr[:, 11:19])
+    return codec.decode_i64_batch(rows.matrix()[:, 11:19])
 
 
 def index_key(table_id: int, index_id: int, values: list[tuple[int, object]]) -> bytes:
@@ -121,13 +121,20 @@ class RowBatchDecoder:
         # values): lets later blocks dictionary-encode with one searchsorted
         # instead of a fresh np.unique sort
         self._dict_cache: dict[int, tuple[np.ndarray, np.ndarray]] = {}
+        # how the last block decoded: rowv2's path of a block of v2 rows
+        # (uniform, vector, walk), else v1 or mixed
+        self.path = "v1"
 
-    def decode(self, handles: np.ndarray, row_values: list[bytes]) -> list[Column]:
+    def decode(self, handles: np.ndarray, row_values) -> list[Column]:
+        """``row_values``: a list of ``bytes``, or a ``ByteRows``."""
         n = len(row_values)
-        if row_values and all(rowv2.is_v2_row(rv) for rv in row_values):
-            cols = rowv2.decode_rows_v2(self.schema, row_values)
-        elif row_values and any(rowv2.is_v2_row(rv) for rv in row_values):
-            cols = self._mixed_decode(row_values)
+        self.path = "v1"
+        v2 = rowv2.v2_rows(row_values)
+        if n and v2.all():
+            cols, self.path = rowv2.decode_block(self.schema, row_values)
+        elif v2.any():
+            cols = self._mixed_decode(row_values, v2)
+            self.path = "mixed"
         else:
             fast = self._try_fast_decode(row_values)
             cols = fast if fast is not None else self._slow_decode(row_values)
@@ -281,17 +288,16 @@ class RowBatchDecoder:
             dictionary[j] = ub[j].tobytes()
         return codes.astype(np.int64), dictionary
 
-    def _mixed_decode(self, row_values: list[bytes]) -> list[Column]:
+    def _mixed_decode(self, row_values, v2: np.ndarray) -> list[Column]:
         """A block mixing v1 and v2 rows (mid-migration): decode each format
         batch-wise, then interleave back into row order."""
-        v2_idx = [i for i, rv in enumerate(row_values) if rowv2.is_v2_row(rv)]
-        v1_idx = [i for i, rv in enumerate(row_values) if not rowv2.is_v2_row(rv)]
+        v2_idx, v1_idx = np.flatnonzero(v2), np.flatnonzero(~v2)
         v2_cols = rowv2.decode_rows_v2(self.schema, [row_values[i] for i in v2_idx])
         v1_cols = self._slow_decode([row_values[i] for i in v1_idx])
         n = len(row_values)
         order = np.empty(n, dtype=np.int64)
-        order[np.array(v2_idx, dtype=np.int64)] = np.arange(len(v2_idx))
-        order[np.array(v1_idx, dtype=np.int64)] = len(v2_idx) + np.arange(len(v1_idx))
+        order[v2_idx] = np.arange(len(v2_idx))
+        order[v1_idx] = len(v2_idx) + np.arange(len(v1_idx))
         out = []
         for c2, c1 in zip(v2_cols, v1_cols):
             out.append(Column.concat([c2, c1]).take(order))

@@ -16,6 +16,8 @@ import subprocess
 import threading
 from typing import Iterator
 
+import numpy as np
+
 from . import CLOSED, U64_CLOSED, ensure_built, refused
 from ..storage.engine import ALL_CFS, Cursor, KvEngine, Snapshot, WriteBatch
 from ..util.io_limiter import IoType
@@ -195,6 +197,37 @@ def parse_frames(buf: bytes, n: int):
         v = buf[off : off + vlen]
         off += vlen
         yield k, v
+
+
+def frame_spans(buf: bytes, n: int) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """Where the keys and the values of ``n`` scan frames lie in ``buf``:
+    ``(key_at, key_len, value_at, value_len)``, nothing cut.  Where every
+    frame has the first one's two lengths (checked as one byte matrix) they
+    lie at one stride; else the walk ``parse_frames`` makes, reading each
+    frame's lengths."""
+    if n == 0:
+        return (np.empty(0, dtype=np.int64),) * 4
+    unpack = _U32.unpack_from
+    klen = unpack(buf, 0)[0]
+    if len(buf) >= 8 + klen:
+        vlen = unpack(buf, 4 + klen)[0]
+        stride = 8 + klen + vlen
+        if len(buf) == n * stride:
+            mat = np.frombuffer(buf, dtype=np.uint8).reshape(n, stride)
+            if (mat[:, :4] == mat[0, :4]).all() and (
+                    mat[:, 4 + klen : 8 + klen] == mat[0, 4 + klen : 8 + klen]).all():
+                at = np.arange(n, dtype=np.int64) * stride
+                return (at + 4, np.full(n, klen, dtype=np.int64),
+                        at + 8 + klen, np.full(n, vlen, dtype=np.int64))
+    k_at, v_at = [0] * n, [0] * n
+    off = 0
+    for i in range(n):
+        k_at[i] = off = off + 4
+        v_at[i] = off = off + unpack(buf, off - 4)[0] + 4
+        off += unpack(buf, off - 4)[0]
+    k_at = np.array(k_at, dtype=np.int64)
+    v_at = np.array(v_at, dtype=np.int64)
+    return k_at, v_at - 4 - k_at, v_at, np.append(k_at[1:] - 4, off) - v_at
 
 
 def _failed(what: str, r: int) -> RuntimeError:
@@ -377,6 +410,12 @@ class NativeSnapshot(Snapshot):
         buf = _take(self._lib, out, out_len.value)
         self._engine._io(IoType.FOREGROUND_READ, len(buf))
         return n, buf
+
+    def scan_spans(self, cf: str, start: bytes, end: bytes | None):
+        """``scan_raw``'s buffer and where its frames' keys and values lie in
+        it: ``(buf, key_at, key_len, value_at, value_len)``."""
+        n, buf = self.scan_raw(cf, start, end)
+        return (buf, *frame_spans(buf, n))
 
     def scan_cf(self, cf, start, end, limit=None, reverse=False) -> Iterator[tuple[bytes, bytes]]:
         n, buf = self.scan_raw(cf, start, end, limit, reverse)

@@ -82,9 +82,9 @@ class RegionSnapshot(Snapshot):
         self.read_progress: tuple[int, int] | None = None
         self._lower = keys.data_key(region.start_key)
         self._upper = keys.data_end_key(region.end_key)
-        if hasattr(engine_snapshot, "scan_raw"):
+        if hasattr(engine_snapshot, "scan_spans"):
             # offered only over an engine that has it: callers probe for it
-            self.scan_raw = self._scan_raw
+            self.scan_spans = self._scan_spans
 
     def sequence(self) -> int | None:
         return self._snap.sequence()
@@ -111,37 +111,18 @@ class RegionSnapshot(Snapshot):
         for k, v in self._snap.scan_cf(cf, lo, hi, limit, reverse):
             yield keys.origin_key(k), v
 
-    def _scan_raw(self, cf: str, start: bytes, end: bytes | None) -> tuple[int, bytes]:
-        """``(n, frames)`` like the native snapshot's ``scan_raw`` (one FFI
-        crossing for the range; frames are ``klen u32le | key | vlen u32le |
-        value``), clamped to the region, z prefix stripped."""
-        import numpy as np
-
-        from ..native.engine import parse_frames
-
+    def _scan_spans(self, cf: str, start: bytes, end: bytes | None):
+        """The native snapshot's ``scan_spans`` (one FFI crossing for the
+        range, nothing cut or copied), clamped to the region; the keys' spans
+        leave the z prefix out."""
         lo, hi = self._clamp(start, end)
         if lo >= hi:
-            return 0, b""
-        n, buf = self._snap.scan_raw(cf, lo, hi)
-        if n == 0:
-            return 0, b""
+            import numpy as np
+
+            return (b"", *(np.empty(0, dtype=np.int64),) * 4)
+        buf, k_at, k_len, v_at, v_len = self._snap.scan_spans(cf, lo, hi)
         z = len(keys.DATA_PREFIX)
-        klen = int.from_bytes(buf[:4], "little")
-        stride = 8 + klen + int.from_bytes(buf[4 + klen:8 + klen], "little")
-        if len(buf) == n * stride:
-            # one frame size (rows of one table): drop the prefix column
-            mat = np.frombuffer(buf, dtype=np.uint8).reshape(n, stride)
-            heads = np.concatenate([mat[:, :4], mat[:, 4 + klen:8 + klen]], axis=1)
-            if (heads == heads[0]).all():
-                out = np.empty((n, stride - z), dtype=np.uint8)
-                out[:, :4] = np.frombuffer((klen - z).to_bytes(4, "little"), np.uint8)
-                out[:, 4:] = mat[:, 4 + z:]
-                return n, out.tobytes()
-        parts: list[bytes] = []
-        for k, v in parse_frames(buf, n):
-            parts += [(len(k) - z).to_bytes(4, "little"), k[z:],
-                      len(v).to_bytes(4, "little"), v]
-        return n, b"".join(parts)
+        return buf, k_at + z, k_len - z, v_at, v_len
 
     def get_cf(self, cf: str, key: bytes) -> bytes | None:
         dkey = keys.data_key(key)
