@@ -217,11 +217,11 @@ def test_fused_and_xregion_paths_serve_encoded_images():
     caches = [img.block_cache
               for img in warm.region_cache._images.values()]
     assert len(caches) == 2
-    ev = warm._evaluator_for(agg)
+    ev, params = warm._bind(agg)
     before = REGISTRY.counter(
         "tikv_coprocessor_encoded_path_total", "").get(
         path="xregion", decision="encoded")
-    outs = jax_eval.run_xregion_cached(ev, caches)
+    outs = jax_eval.run_xregion_cached(ev, caches, params)
     assert REGISTRY.counter(
         "tikv_coprocessor_encoded_path_total", "").get(
         path="xregion", decision="encoded") == before + 1
@@ -236,13 +236,13 @@ def test_fused_and_xregion_paths_serve_encoded_images():
         Aggregation([], [AggDescriptor("count", None),
                          AggDescriptor("max", col(3))]),
     ])
-    ev2 = warm._evaluator_for(agg2)
+    ev2, params2 = warm._bind(agg2)
     # rebuild a full-range image for the fused pair
     warm.handle_request(_req(agg, 200, 3, region_id=9))
     cache9 = next(img.block_cache
                   for k, img in warm.region_cache._images.items()
                   if k[0] == 9)
-    fused = jax_eval.run_batch_cached([ev, ev2], cache9)
+    fused = jax_eval.run_batch_cached([ev, ev2], cache9, [params, params2])
     assert fused[0].encode() == cold.handle_request(_req(agg, 200, 3)).data
     assert fused[1].encode() == cold.handle_request(_req(agg2, 200, 3)).data
 
@@ -263,8 +263,8 @@ def test_xregion_enc_mismatch_decode_ships_byte_identically():
     before = REGISTRY.counter(
         "tikv_coprocessor_encoded_decline_total", "").get(
         path="xregion", cause="enc_mismatch")
-    ev = warm._evaluator_for(agg)
-    outs = jax_eval.run_xregion_cached(ev, caches)
+    ev, params = warm._bind(agg)
+    outs = jax_eval.run_xregion_cached(ev, caches, params)
     assert REGISTRY.counter(
         "tikv_coprocessor_encoded_decline_total", "").get(
         path="xregion", cause="enc_mismatch") == before + 1

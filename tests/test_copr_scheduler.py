@@ -20,7 +20,8 @@ from tikv_tpu.copr.dag import Aggregation, DagRequest, Limit, Selection, TableSc
 from tikv_tpu.copr.datatypes import ColumnInfo, FieldType
 from tikv_tpu.copr.endpoint import CoprRequest, Endpoint
 from tikv_tpu.copr.rpn import call, col, const_int
-from tikv_tpu.copr.scheduler import SchedulerConfig, plan_signature
+from tikv_tpu.copr.plan_shape import plan_signature, split
+from tikv_tpu.copr.scheduler import SchedulerConfig
 from tikv_tpu.copr.table import encode_row, record_key
 from tikv_tpu.storage.btree_engine import BTreeEngine
 from tikv_tpu.storage.engine import CF_WRITE
@@ -196,7 +197,6 @@ def test_same_plan_riders_are_one_xregion_group_whatever_was_measured(
         seed=3, epsilon=0.0, cold_probe_rate=0.0))
     ep = Endpoint(dev.engine, enable_device=True, block_rows=1024,
                   cost_router=router)
-    sig = plan_signature(_sum_dag(61))
     pair = lambda: [_region_req(r, ROWS_PER, _sum_dag(61)) for r in range(2)]
     ep.handle_batch(pair())  # images and the program
     groups = []
@@ -208,7 +208,7 @@ def test_same_plan_riders_are_one_xregion_group_whatever_was_measured(
 
     monkeypatch.setattr(ep.scheduler, "_group", spy)
     obs.OBSERVATORY.reset()
-    profiles(obs.sig_id(sig))
+    profiles(obs.dag_sig(_sum_dag(61))[0])
     try:
         got = ep.handle_batch(pair())
     finally:
@@ -216,7 +216,8 @@ def test_same_plan_riders_are_one_xregion_group_whatever_was_measured(
     [(exec_groups, rest)] = groups
     assert rest == []
     [(kind, g_sig, slots)] = exec_groups
-    assert (kind, g_sig, len(slots)) == ("xregion", sig, 2)
+    # a group's key is the plan's whole identity: shape and literals
+    assert (kind, g_sig, len(slots)) == ("xregion", split(_sum_dag(61)), 2)
     for req, resp in zip(pair(), got):
         assert resp.from_device
         assert resp.metrics.get("sched_batch") == "xregion"
@@ -240,6 +241,57 @@ def test_xregion_dedupes_identical_requests(engines):
     # 12 requests, but the batch occupancy counts the 12 (shared slots serve
     # every rider), all from one device dispatch
     assert all(r.from_device for r in got)
+
+
+def test_one_shape_is_one_program_and_each_literal_its_own_slot(engines, monkeypatch):
+    """The two keys (docs/copr_scheduler.md).  Identical requests still share
+    one slot's response bytes; requests of one shape and different literals
+    never do: they are separate groups, separate launches of ONE evaluator's
+    program, each answered for its own literal."""
+    dev, cpu = engines
+    a, b = lambda: _sum_dag(41), lambda: _sum_dag(73)
+    assert split(a())[0] == split(b())[0] and split(a()) != split(b())
+    assert plan_signature(a()) != plan_signature(b())
+    pair = [_region_req(r, ROWS_PER, a()) for r in range(2)]
+    dev.handle_batch(pair)  # images, the evaluator and its program
+    shapes = dict(dev._evaluators)
+    groups, group = [], dev.scheduler._group
+
+    def spy(items):
+        groups.append(group(items))
+        return groups[-1]
+
+    monkeypatch.setattr(dev.scheduler, "_group", spy)
+    launched = []
+    launch = jax_eval.launch_xregion_cached
+
+    def count(ev, caches, params=()):
+        launched.append((ev, params))
+        return launch(ev, caches, params)
+
+    monkeypatch.setattr(jax_eval, "launch_xregion_cached", count)
+    # A on region 0 twice (one slot), A on region 1, B on both regions
+    reqs = [_region_req(0, ROWS_PER, a()), _region_req(0, ROWS_PER, a()),
+            _region_req(1, ROWS_PER, a()),
+            _region_req(0, ROWS_PER, b()), _region_req(1, ROWS_PER, b())]
+    got = dev.handle_batch(reqs)
+    [(exec_groups, rest)] = groups
+    assert rest == []
+    by_sig = {sig: slots for kind, sig, slots in exec_groups}
+    assert set(by_sig) == {split(a()), split(b())}
+    assert sorted(len(s.items) for s in by_sig[split(a())]) == [1, 2]
+    assert sorted(len(s.items) for s in by_sig[split(b())]) == [1, 1]
+    # two launches of one evaluator, each with its group's literal
+    assert len(launched) == 2 and launched[0][0] is launched[1][0]
+    assert {p for _ev, p in launched} == {(41,), (73,)}
+    assert dev._evaluators == shapes and split(a())[0] in shapes
+    # the twins share one slot's bytes; nobody else shares anybody's
+    assert got[0].data == got[1].data
+    for req, resp in zip(reqs, got):
+        want = cpu.handle_request(
+            CoprRequest(103, req.dag, req.ranges, req.start_ts, dict(req.context)))
+        assert resp.data == want.data and resp.from_device
+    assert got[0].data != got[3].data and got[2].data != got[4].data
 
 
 def test_mixed_eligibility_batch(engines):
@@ -374,7 +426,7 @@ def test_cold_fill_failure_leaves_no_partial_cache(monkeypatch):
     calls = {"n": 0}
     orig = jax_eval.JaxDagEvaluator.run
 
-    def failing_run(self, source, cache=None):
+    def failing_run(self, source, cache=None, params=()):
         calls["n"] += 1
         if cache is not None and not cache.filled:
             # crash mid-fill, after blocks were appended

@@ -525,7 +525,7 @@ def test_endpoint_falls_back_to_cpu_on_device_failure(monkeypatch):
     dag = DagRequest(executors=[TableScan(TABLE_ID, NUMERIC_COLS), TopN([(col(1), False)], 5)])
     req = lambda: CoprRequest(103, DagRequest(executors=dag.executors), [record_range(TABLE_ID)], 100, context={})
     monkeypatch.setattr(
-        JaxDagEvaluator, "run", lambda self, src, cache=None: (_ for _ in ()).throw(RuntimeError("device lost"))
+        JaxDagEvaluator, "run", lambda self, src, cache=None, params=(): (_ for _ in ()).throw(RuntimeError("device lost"))
     )
     r = ep.handle_request(req())
     assert not r.from_device
@@ -559,7 +559,7 @@ def test_device_failure_does_not_poison_block_cache(monkeypatch):
     # fail mid-fill: the evaluator dies after the cache got partial blocks
     orig_run = JaxDagEvaluator.run
 
-    def failing_run(self, src, cache=None):
+    def failing_run(self, src, cache=None, params=()):
         if cache is not None:
             cache.add([None], 1)  # simulate partial fill before the fault
         raise RuntimeError("transient device fault")

@@ -451,7 +451,7 @@ def test_zone_failure_falls_through_to_generic(monkeypatch):
     zone = ev._zone_evaluator()
     calls = {"n": 0}
 
-    def boom(cache):
+    def boom(cache, params):
         calls["n"] += 1
         raise RuntimeError("simulated backend failure")
 

@@ -35,12 +35,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 import chip_smoke
 import lineitem_fixture as fx
-from tikv_tpu.copr import encoding, jax_eval, observatory
+from tikv_tpu.copr import encoding, jax_eval, observatory, plan_shape
 from tikv_tpu.copr.aggr import AggDescriptor
 from tikv_tpu.copr.dag import Aggregation, DagRequest, Selection, TableScan
 from tikv_tpu.copr.datatypes import ColumnInfo, FieldType
 from tikv_tpu.copr.executors import FixtureScanSource
-from tikv_tpu.copr.rpn import call, col, const_int
+from tikv_tpu.copr.rpn import call, col, const_int, const_real
 from tikv_tpu.copr.table import encode_row, record_key
 from tikv_tpu.parallel import mesh as pmesh
 
@@ -282,6 +282,84 @@ def test_zone_tile_programs_compile(plan, site, one_chip, recorder, images):
     leave every tile straddling one (``jax_zone.partial``)."""
     assert _evaluator(plan)._try_zone(images(False)) is not None
     compile_captured(recorder, one_chip, site)
+
+
+def _bound(name: str):
+    """``(evaluator of the plan's shape, the plan's literals)``, as
+    ``Endpoint._bind`` hands them to the rungs: the programs take the literals
+    as an int64 and a float64 vector (64-bit scalars as inputs)."""
+    dag = chip_smoke.plan_set()[name]
+    _shape, params = plan_shape.split(dag)
+    assert params, f"{name} has no literal to hoist"
+    return jax_eval.JaxDagEvaluator(plan_shape.shape_dag(dag)), params
+
+
+def _param_inputs(rec: Recorder, site: str) -> list:
+    """The (int64, float64) parameter vectors among ``site``'s arguments."""
+    specs = next(specs for s, _fn, specs in rec.programs if s == site)
+    return [x for x in jax.tree.leaves(specs)
+            if getattr(x, "ndim", 0) == 1 and x.dtype in (np.int64, np.float64)]
+
+
+@pytest.mark.parametrize("plan", ["q6", "q1"])
+def test_parameterised_xregion_compiles(plan, one_chip, recorder):
+    """``jax_eval.xregion`` of a plan's SHAPE: the selection's literals are
+    an argument of the program, one vector for all regions of the group."""
+    ev, params = _bound(plan)
+    caches = [_image(True) for _ in range(4)]
+    jax_eval.launch_xregion_cached(ev, caches, params).finalize()
+    compile_captured(recorder, one_chip, "jax_eval.xregion")
+    # dates and decimals ride the int64 lane; no slot is REAL, so no float64
+    # vector is handed over
+    vecs = _param_inputs(recorder, "jax_eval.xregion")
+    assert any(v.shape == (len(params),) and v.dtype == np.int64 for v in vecs)
+    assert not any(v.shape == (len(params),) and v.dtype == np.float64 for v in vecs)
+
+
+def test_parameterised_real_literal_compiles(one_chip, recorder):
+    """A REAL literal beside an INT one: the float64 vector as an input of
+    the cold block step."""
+    cols = [ColumnInfo(1, FieldType.int64(), is_pk_handle=True),
+            ColumnInfo(2, FieldType.double()),
+            ColumnInfo(3, FieldType.int64())]
+    kvs = [(record_key(fx.TABLE_ID, i),
+            encode_row(cols[1:], [i * 0.25, i % 7])) for i in range(256)]
+    dag = DagRequest(executors=[
+        TableScan(fx.TABLE_ID, cols),
+        Selection([call("ge", col(1), const_real(1.5)),
+                   call("lt", col(2), const_int(5))]),
+        Aggregation([], [AggDescriptor("sum", col(1)),
+                         AggDescriptor("count", None)])])
+    _shape, params = plan_shape.split(dag)
+    assert params == (1.5, 5)
+    ev = jax_eval.JaxDagEvaluator(plan_shape.shape_dag(dag))
+    ev.run(FixtureScanSource(kvs), params=params)
+    compile_captured(recorder, one_chip, "jax_eval.agg_step")
+    vecs = _param_inputs(recorder, "jax_eval.agg_step")
+    assert {v.dtype for v in vecs if v.shape == (2,)} == {
+        np.dtype(np.int64), np.dtype(np.float64)}
+
+
+def test_parameterised_partial_tile_program_compiles(one_chip, recorder, images):
+    """``jax_zone.partial`` of Q6's shape: the tiles that straddle a
+    predicate are evaluated row by row against literals read from the
+    program's parameter vectors."""
+    ev, params = _bound("q6")
+    ev._params = params
+    assert ev._try_zone(images(False)) is not None
+    compile_captured(recorder, one_chip, "jax_zone.partial")
+    assert any(v.shape == (len(params),)
+               for v in _param_inputs(recorder, "jax_zone.partial"))
+
+
+@pytest.mark.parametrize("encoded", [False, True], ids=["plain", "encoded"])
+def test_parameterised_warm_scan_compiles(encoded, one_chip, recorder, images):
+    """``jax_eval.scan_coded`` of Q6's shape: the lone rider's program past
+    the zone rung."""
+    ev, params = _bound("q6")
+    ev.route_hint = "unary"
+    ev.run(None, cache=images(encoded), params=params)
+    compile_captured(recorder, one_chip, "jax_eval.scan_coded")
 
 
 def test_real_aggregate_compiles(one_chip, recorder):

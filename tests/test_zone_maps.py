@@ -111,8 +111,8 @@ def test_prune_blocks_matches_brute_force(seed):
             Selection([call(op, col(ci), const_int(const))])])
         warm.handle_request(_req(dag, 200, 3))
         cache = _image(warm).block_cache
-        ev = warm._evaluator_for(dag)
-        keep = Z.prune_blocks(cache, ev.sel_rpns)
+        ev, params = warm._bind(dag)
+        keep = Z.prune_blocks(cache, ev.bound_sel_rpns(params))
         if keep is None:
             continue
         for bi, blk in enumerate(cache.blocks):
@@ -175,10 +175,10 @@ def test_kill_switch_disables_pruning():
         Selection([call("ge", col(0), const_int(199))])])
     warm.handle_request(_req(dag, 200, 3))
     cache = _image(warm).block_cache
-    ev = warm._evaluator_for(dag)
-    assert Z.prune_blocks(cache, ev.sel_rpns) is not None
+    ev, params = warm._bind(dag)
+    assert Z.prune_blocks(cache, ev.bound_sel_rpns(params)) is not None
     Z.set_enabled(False)
-    assert Z.prune_blocks(cache, ev.sel_rpns) is None
+    assert Z.prune_blocks(cache, ev.bound_sel_rpns(params)) is None
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,9 @@ from ..analysis import bufsan as _bufsan
 from ..storage.kv import Engine
 from ..storage.mvcc import Statistics
 from ..util import trace
+from ..util.metrics import REGISTRY
 from . import jax_eval
+from . import plan_shape as _plan_shape
 from .cache import ColumnBlockCache, CopCache
 from .dag import (
     ENC_TYPE_CHUNK,
@@ -40,6 +42,12 @@ _MESH_UNCHECKED = object()  # sentinel: DAG not yet probed for mesh eligibility
 # bucket layout when the endpoint runs before the TCP server imports
 _WIRE_STAGE_BUCKETS = (1e-5, 5e-5, 1e-4, 5e-4, 1e-3, 5e-3, 0.01, 0.05, 0.1,
                       0.5, 1, 5)
+
+
+_PLAN_SHAPES = REGISTRY.counter(
+    "tikv_coprocessor_plan_shape_total",
+    "Device-served tasks by how their plan's shape found its evaluator: "
+    "reused (the shape was known, whatever the literals) or built")
 
 
 class RungProgramError(RuntimeError):
@@ -207,6 +215,9 @@ class Endpoint:
         self.cm = concurrency_manager
         self.slow_log = slow_log or SlowLog()
         self._evaluators: dict = {}
+        # evaluators built, one a plan shape the memo did not hold: it stops
+        # growing once the shapes in use are known, whatever literals come
+        self.plan_shapes_built = 0
         # multi-device serving: a (regions × groups) jax.sharding.Mesh shards
         # eligible aggregation DAGs' row blocks across devices (scale-out
         # analog of region sharding); single-device when None or 1 device
@@ -428,17 +439,18 @@ class Endpoint:
                 # cross-region launcher below — the PR-2 "mesh bypass due to
                 # filled cache" is gone
                 ev = None
+                params = ()
                 if cache is None:
                     ev = self._mesh_evaluator_for(req.dag)
                 if ev is None:
-                    ev = self._evaluator_for(req.dag)
+                    ev, params = self._bind(req.dag)
                 src = None
                 if cache is None or not cache.filled:
                     src = MvccBatchScanSource(snap, req.start_ts, req.ranges)
                 resp = None
                 want_mesh = route is None or route.path == "mesh"
                 if src is None and want_mesh and self._mesh_would_serve(req.dag):
-                    resp = self._run_sharded_cached(ev, cache)
+                    resp = self._run_sharded_cached(ev, cache, params)
                 if resp is None:
                     # routed zone/unary steer the evaluator's rung choice;
                     # set/cleared around run — a concurrent mis-read only
@@ -447,7 +459,7 @@ class Endpoint:
                                      and route.path in ("zone", "unary")
                                      else None)
                     try:
-                        resp = ev.run(src, cache=cache)
+                        resp = ev.run(src, cache=cache, params=params)
                     finally:
                         ev.route_hint = None
                 parts, enc_tp = self._encode_response(resp)
@@ -726,8 +738,8 @@ class Endpoint:
                     info if isinstance(info, str) else "unsupported_plan")
                 self.breaker.release_probe("unary")
                 return None
-            ev = self._evaluator_for(new_dag)
-            resp = ev.run(None, cache=cache)
+            ev, params = self._bind(new_dag)
+            resp = ev.run(None, cache=cache, params=params)
             parts, enc_tp = self._encode_response(resp)
             data = None
             from_device = True
@@ -1056,27 +1068,31 @@ class Endpoint:
                 errors[i] = e
         return results, errors
 
-    def _evaluator_for(self, dag: DagRequest) -> "jax_eval.JaxDagEvaluator":
-        """Reuse compiled evaluators across requests, keyed by plan bytes
-        (each holds its jit caches — recompiling per request throws away the
-        warm XLA programs)."""
-        from ..server import wire
-        from .dag_wire import dag_to_wire
-
-        with trace.stage("copr.evaluator") as st:
-            key = wire.dumps(dag_to_wire(dag))
-            ev = self._evaluators.get(key)
+    def _bind(self, dag: DagRequest, split=None, tasks: int = 1):
+        """``(evaluator, parameters)`` for a request's plan: the plan is
+        taken apart into its shape and its literals (copr/plan_shape.py),
+        and the shape finds its evaluator, which holds the jitted programs
+        (one a shape and geometry, whatever the literals) and no literal;
+        the request's own travel beside it into every entry point.  Stage
+        ``copr.bind`` is what that costs a read; ``split`` is the pair where
+        the caller made it already (the read scheduler keys its slots by it),
+        ``tasks`` the device-served tasks this resolution stands for."""
+        with trace.stage("copr.bind") as st:
+            shape, params = split if split is not None else _plan_shape.split(dag)
+            ev = self._evaluators.get(shape)
+            outcome = "reused"
             if ev is None:
-                st.tag(built=True)
-                if self.block_rows is not None:
-                    ev = jax_eval.JaxDagEvaluator(
-                        dag, block_rows=self.block_rows, breaker=self.breaker)
-                else:
-                    ev = jax_eval.JaxDagEvaluator(dag, breaker=self.breaker)
-                self._evaluators[key] = ev
+                st.tag(built=1)
+                outcome = "built"
+                kw = {} if self.block_rows is None else {"block_rows": self.block_rows}
+                ev = jax_eval.JaxDagEvaluator(
+                    _plan_shape.shape_dag(dag), breaker=self.breaker, **kw)
+                self._evaluators[shape] = ev
+                self.plan_shapes_built += 1
                 while len(self._evaluators) > 64:
                     self._evaluators.pop(next(iter(self._evaluators)))
-        return ev
+            _PLAN_SHAPES.inc(tasks, outcome=outcome)
+        return ev, params
 
     def device_enabled(self) -> bool:
         return self.enable_device and self._gate_ok("device")
@@ -1148,7 +1164,7 @@ class Endpoint:
                 "batch": BATCH_FUSION}[what]
         return self.feature_gate.can_enable(feat)
 
-    def _run_sharded_cached(self, ev, cache):
+    def _run_sharded_cached(self, ev, cache, params=()):
         """Warm cached serving THROUGH the mesh: run the plan over the
         image's device-local shards via the sharded cross-region launcher
         (one region = one slot; a block-spread huge region uses every chip).
@@ -1178,7 +1194,7 @@ class Endpoint:
         # warm path would REBUILD a full default-device pin, paying the
         # whole-image transfer the owner placement exists to avoid.
         try:
-            pending = _je.launch_xregion_sharded(ev, [cache], self.mesh)
+            pending = _je.launch_xregion_sharded(ev, [cache], self.mesh, params)
             resp = pending.finalize()[0]
         except ValueError:
             # documented decline (no merge rule surfaced late, empty blocks)

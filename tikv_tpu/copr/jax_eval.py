@@ -60,7 +60,7 @@ from .dag import (
 from .datatypes import Chunk, Column, EvalType
 from .executors import BatchTopNExecutor, ScanSource
 from .groupby import GroupDict
-from .rpn import ColumnRef, RpnExpression, compile_expr, eval_rpn
+from .rpn import ColumnRef, RpnExpression, bind_rpn, compile_expr, eval_rpn, param_vectors
 from .table import RowBatchDecoder, decode_record_handles
 
 # this module owns the device, so it is the one that mirrors the program's
@@ -81,6 +81,7 @@ _DEVICE_AGG_OPS = {
 }
 _DEVICE_EVAL_TYPES = {EvalType.INT, EvalType.REAL, EvalType.DECIMAL, EvalType.DATETIME, EvalType.DURATION}
 _TOPN_DEVICE_MAX = 2048  # raw TopN carries K rows of state per column
+_PARAM_VECS_MAX = 1024  # sets of literals an evaluator keeps device vectors of
 
 
 # ---------------------------------------------------------------------------
@@ -473,17 +474,19 @@ def _mixed_radix_gids(cols, group_cols, dict_lens, n_rows):
 
 
 def _fused_step(sel_rpns, device_aggs, capacity, n_rows, cols, n_valid, gids, offset, state,
-                track_first: bool = True):
+                track_first: bool = True, params=None):
     """THE block step, shared by every device program: selection predicates →
     active mask; aggregate updates; first-active-row tracker.
 
     ``track_first=False`` skips the per-block first-active-row segment-min:
     with no group-by, finalize outputs the single slot unconditionally, so
-    the tracker is dead work (a whole extra reduction pass per block)."""
+    the tracker is dead work (a whole extra reduction pass per block).
+    ``params``: the request's literals (``rpn.param_vectors``), a traced
+    input of the program wherever the selection has param nodes."""
     first_row, carries = state
     active = jnp.arange(n_rows, dtype=jnp.int64) < n_valid
     for rpn in sel_rpns:
-        d, nl = eval_rpn(rpn, cols, n_rows, xp=jnp)
+        d, nl = eval_rpn(rpn, cols, n_rows, xp=jnp, params=params)
         active = active & (d != 0) & ~nl
     new_carries = tuple(
         da.update(c, cols, n_rows, gids, active, capacity, offset)
@@ -506,7 +509,7 @@ def _masked_nv(blocks, keep):
     return jnp.asarray(nv)
 
 
-def _batch_prune_keep(evaluators, cache):
+def _batch_prune_keep(evaluators, cache, params_list):
     """Fused-batch keep mask: the batch shares one block stream, so a block
     is masked out only when EVERY rider's zone maps prune it.  Returns
     (keep | None, (examined, pruned)) like the unary ``_prune_keep``."""
@@ -515,10 +518,10 @@ def _batch_prune_keep(evaluators, cache):
     if not _zm.enabled() or not cache.blocks:
         return None, (0, 0)
     keep = None
-    for ev in evaluators:
+    for ev, params in zip(evaluators, params_list):
         if not ev.sel_rpns:
             return None, (0, 0)
-        m = _zm.prune_blocks(cache, ev.sel_rpns, path="fused")
+        m = _zm.prune_blocks(cache, ev.bound_sel_rpns(params), path="fused")
         if m is None:
             return None, (0, 0)
         keep = m if keep is None else (keep | m)
@@ -691,7 +694,8 @@ def _topn_chunk_rows(k: int, n_rows: int) -> int:
     return min(size - k, n_rows)
 
 
-def _topn_step(sel_rpns, order_rpns, payload_cols, k, n_rows, cols, n_valid, state):
+def _topn_step(sel_rpns, order_rpns, payload_cols, k, n_rows, cols, n_valid, state,
+               params=None):
     """One block of the running top-K merge: compute sort operands for the
     block's rows, then chunk by chunk (``_topn_chunk_rows``) concatenate the
     chunk with the carried best-K, stable-sort lexicographically (rank,
@@ -702,7 +706,7 @@ def _topn_step(sel_rpns, order_rpns, payload_cols, k, n_rows, cols, n_valid, sta
     ridx = jnp.arange(n_rows, dtype=jnp.int64)
     active = ridx < n_valid
     for rpn in sel_rpns:
-        d, nl = eval_rpn(rpn, cols, n_rows, xp=jnp)
+        d, nl = eval_rpn(rpn, cols, n_rows, xp=jnp, params=params)
         active = active & (d != 0) & ~nl
     rank_blk = jnp.where(active, jnp.int64(0), jnp.int64(1))
     operands_blk = [rank_blk]
@@ -788,7 +792,14 @@ def _unpack_state(packed, state_template):
 
 
 class JaxDagEvaluator:
-    """Run an eligible DAG over a scan source on the device."""
+    """Run an eligible DAG over a scan source on the device.
+
+    ``dag`` may be a plan's *shape* (``plan_shape.shape_dag``): the literals
+    of its selection are then param nodes, the evaluator holds none of them,
+    and every entry point takes the request's ``params``, which the jitted
+    programs get as an argument: one executable per shape and geometry,
+    whatever the literals.  A ``dag`` with its constants in place has no
+    slots and runs with ``params=()``."""
 
     def __init__(self, dag: DagRequest, block_rows: int = DEFAULT_BLOCK_ROWS,
                  breaker=None):
@@ -856,9 +867,52 @@ class JaxDagEvaluator:
             i for i in self.device_cols
             if not (scan.columns_info[i].ftype.flag & NOT_NULL_FLAG)
         ]
+        # slot -> whether its lane is the float vector; the selection alone
+        # may hold param nodes (plan_shape's rule)
+        lanes = {n.index: n.eval_type == EvalType.REAL
+                 for r in self.sel_rpns for n in r.nodes if n.kind == "param"}
+        if sorted(lanes) != list(range(len(lanes))):
+            raise ValueError(f"param slots {sorted(lanes)} are not 0..n-1")
+        self.param_float_slots = tuple(lanes[k] for k in range(len(lanes)))
+        self._param_vecs: dict[tuple, tuple] = {}
         self._capacity = _GROUP_CAPACITY_START if self.group_rpns else 1
         self._agg_fn_cache: dict[int, object] = {}
         self._run_local = threading.local()
+
+    @property
+    def n_params(self) -> int:
+        return len(self.param_float_slots)
+
+    def _check_params(self, params) -> None:
+        if len(params) != self.n_params:
+            raise ValueError(
+                f"plan shape has {self.n_params} parameter slots, "
+                f"the request brought {len(params)}")
+
+    def param_vectors(self, params):
+        """The request's literals as the programs take them: device arrays,
+        kept by value (a launch with literals seen before hands the program
+        arrays that are already there, where a numpy argument is a transfer
+        a launch: 0.08 ms a task on a v5e's host, PERF.md §6, PR 34)."""
+        self._check_params(params)
+        vecs = self._param_vecs.get(params)
+        if vecs is None:
+            if len(self._param_vecs) >= _PARAM_VECS_MAX:
+                self._param_vecs.clear()
+            vecs = self._param_vecs[params] = jax.tree.map(
+                jnp.asarray, param_vectors(params, self.param_float_slots))
+        return vecs
+
+    def bound_sel_rpns(self, params) -> list[RpnExpression]:
+        """The selection with the request's literals back in place, for the
+        host-side readers of a plan's constants (zone maps, tile
+        classification) and for the rungs that still bake them into their
+        programs.  Made per request and never kept: a block pruned by the
+        previous query's date would be a wrong answer, not a slow one."""
+        self._check_params(params)
+        if not params:
+            return self.sel_rpns
+        return [bind_rpn(r, params) for r in self.sel_rpns]
 
     @property
     def _cache(self):
@@ -872,6 +926,15 @@ class JaxDagEvaluator:
     def _cache(self, cache) -> None:
         self._run_local.cache = cache
 
+    @property
+    def _params(self) -> tuple:
+        """The literals of the run in flight ON THIS THREAD (as ``_cache``)."""
+        return getattr(self._run_local, "params", ())
+
+    @_params.setter
+    def _params(self, params) -> None:
+        self._run_local.params = params
+
     # -- jit construction --------------------------------------------------
 
     def _build_mask_fn(self, enc=None):
@@ -884,12 +947,12 @@ class JaxDagEvaluator:
         nullable = self.nullable_cols
         n_rows = self.block_rows
 
-        def mask_fn(col_data, col_nulls, valid, refs):
+        def mask_fn(col_data, col_nulls, valid, refs, params):
             cols = _build_cols(device_cols, nullable, col_data, col_nulls,
                                n_rows, enc, refs)
             active = valid
             for rpn in sel_rpns:
-                d, nl = eval_rpn(rpn, cols, n_rows, xp=jnp)
+                d, nl = eval_rpn(rpn, cols, n_rows, xp=jnp, params=params)
                 active = active & (d != 0) & ~nl
             return active
 
@@ -913,11 +976,11 @@ class JaxDagEvaluator:
         n_rows = self.block_rows
         track_first = bool(self.group_rpns)
 
-        def agg_fn(col_data, col_nulls, n_valid, gids, block_offset, state):
+        def agg_fn(col_data, col_nulls, n_valid, gids, block_offset, state, params):
             cols = _build_cols(device_cols, nullable, col_data, col_nulls, n_rows)
             return _fused_step(
                 sel_rpns, device_aggs, capacity, n_rows, cols, n_valid, gids, block_offset, state,
-                track_first=track_first,
+                track_first=track_first, params=params,
             )
 
         fn = _obs.timed_jit(jax.jit(agg_fn, donate_argnums=(5,)),
@@ -940,7 +1003,7 @@ class JaxDagEvaluator:
         n_rows = self.block_rows
         track_first = bool(self.group_rpns)
 
-        def scan_fn(col_data, col_nulls, n_valids, gids, offsets, refs):
+        def scan_fn(col_data, col_nulls, n_valids, gids, offsets, refs, params):
             state = (
                 jnp.full(capacity, _NO_ROW, dtype=jnp.int64),
                 tuple(da.init_carry(capacity) for da in device_aggs),
@@ -950,7 +1013,7 @@ class JaxDagEvaluator:
                 cd, cn, nv, g, off = xs
                 cols = _build_cols(device_cols, nullable, cd, cn, n_rows, enc, refs)
                 return _fused_step(sel_rpns, device_aggs, capacity, n_rows, cols, nv, g, off, st,
-                                   track_first=track_first), None
+                                   track_first=track_first, params=params), None
 
             state, _ = jax.lax.scan(body, state, (col_data, col_nulls, n_valids, gids, offsets))
             # pack everything into ONE int64 matrix: each device→host pull
@@ -977,7 +1040,7 @@ class JaxDagEvaluator:
         n_rows = self.block_rows
         track_first = bool(self.group_rpns)
 
-        def scan_fn(col_data, col_nulls, n_valids, offsets, refs):
+        def scan_fn(col_data, col_nulls, n_valids, offsets, refs, params):
             state = (
                 jnp.full(capacity, _NO_ROW, dtype=jnp.int64),
                 tuple(da.init_carry(capacity) for da in device_aggs),
@@ -988,7 +1051,7 @@ class JaxDagEvaluator:
                 cols = _build_cols(ship_cols, nullable, cd, cn, n_rows, enc, refs)
                 gids = _mixed_radix_gids(cols, group_cols, dict_lens, n_rows)
                 return _fused_step(sel_rpns, device_aggs, capacity, n_rows, cols, nv, gids, off, st,
-                                   track_first=track_first), None
+                                   track_first=track_first, params=params), None
 
             state, _ = jax.lax.scan(body, state, (col_data, col_nulls, n_valids, offsets))
             return _pack_state(state)
@@ -1087,7 +1150,8 @@ class JaxDagEvaluator:
             if keep is not None:
                 nv_dev = _masked_nv(blocks, keep)
             scan_fn = self._build_scan_fn_coded(dict_lens, capacity, n_blocks, group_cols, enc)
-            packed = scan_fn(col_data, col_nulls, nv_dev, off_dev, refs)
+            packed = scan_fn(col_data, col_nulls, nv_dev, off_dev, refs,
+                             self.param_vectors(self._params))
             with trace.stage("device.pull"):
                 state_np = _unpack_state(packed, self._host_state_template())
 
@@ -1125,7 +1189,8 @@ class JaxDagEvaluator:
         if keep is not None:
             nv_dev = _masked_nv(blocks, keep)
         scan_fn = self._build_scan_fn(capacity, n_blocks, enc)
-        packed = scan_fn(col_data, col_nulls, nv_dev, all_gids, off_dev, refs)
+        packed = scan_fn(col_data, col_nulls, nv_dev, all_gids, off_dev, refs,
+                         self.param_vectors(self._params))
         with trace.stage("device.pull"):
             state_np = _unpack_state(packed, self._host_state_template())
         resp = self._finalize_agg(state_np, n_slots, lambda r: groups.rows[r])
@@ -1143,7 +1208,7 @@ class JaxDagEvaluator:
         zone = self._zone_evaluator()
         if zone is None:
             return None
-        out = zone.try_run(cache)
+        out = zone.try_run(cache, self._params)
         if out is None:
             return None
         state_np, n_slots, key_of = out
@@ -1182,7 +1247,8 @@ class JaxDagEvaluator:
         if cache is None or not getattr(cache, "filled", False) or not cache.blocks:
             return None, (0, 0)
         stats = _zm.PruneStats()
-        keep = _zm.prune_blocks(cache, self.sel_rpns, path=path, stats=stats)
+        keep = _zm.prune_blocks(cache, self.bound_sel_rpns(self._params),
+                                path=path, stats=stats)
         return keep, (stats.examined, stats.pruned)
 
     def _nvoff_device(self, cache, blocks):
@@ -1248,8 +1314,11 @@ class JaxDagEvaluator:
 
     # -- host loop ---------------------------------------------------------
 
-    def run(self, source: ScanSource, cache: "ColumnBlockCache | None" = None) -> SelectResponse:
+    def run(self, source: ScanSource, cache: "ColumnBlockCache | None" = None,
+            params: tuple = ()) -> SelectResponse:
+        self._check_params(params)
         self._cache = cache
+        self._params = params
         if self.plan.agg is not None:
             path = "agg_cached" if (cache is not None and cache.filled
                                     and cache.blocks) else "agg"
@@ -1270,6 +1339,7 @@ class JaxDagEvaluator:
                 return self._run_scan_filter(source)
         finally:
             self._cache = None
+            self._params = ()
 
     def _blocks(self, source: ScanSource | None):
         """Decoded blocks, through the block cache when one is provided.
@@ -1430,6 +1500,7 @@ class JaxDagEvaluator:
         groups = GroupDict()
         capacity = self._capacity
         agg_fn = self._build_agg_fn(capacity)
+        pvec = self.param_vectors(self._params)
         carries = tuple(da.init_carry(capacity) for da in self.device_aggs)
         first_row = jnp.full(capacity, _NO_ROW, dtype=jnp.int64)
         state = (first_row, carries)
@@ -1469,7 +1540,7 @@ class JaxDagEvaluator:
                     agg_fn = self._build_agg_fn(capacity)
             else:
                 gids_np = _ZERO_GIDS.setdefault(self.block_rows, np.zeros(self.block_rows, dtype=np.int32))
-            state = agg_fn(col_data, col_nulls, n_valid, gids_np, offset, state)
+            state = agg_fn(col_data, col_nulls, n_valid, gids_np, offset, state, pvec)
             offset += n_valid
 
         n_slots = len(groups) if self.group_rpns else 1
@@ -1595,11 +1666,12 @@ class JaxDagEvaluator:
         n_rows = self.block_rows
         payload_cols = list(range(len(self.schema)))
 
-        def step(col_data, col_nulls, n_valid, state, refs):
+        def step(col_data, col_nulls, n_valid, state, refs, params):
             cols = _build_cols(device_cols, nullable, col_data, col_nulls,
                                n_rows, enc, refs)
             return _topn_step(
-                sel_rpns, order_rpns, payload_cols, k, n_rows, cols, n_valid, state
+                sel_rpns, order_rpns, payload_cols, k, n_rows, cols, n_valid, state,
+                params=params,
             )
 
         fn = _obs.timed_jit(jax.jit(step, donate_argnums=(3,)),
@@ -1631,6 +1703,7 @@ class JaxDagEvaluator:
         ]
         payload_dicts: dict[int, np.ndarray] = {}
         step = None
+        pvec = self.param_vectors(self._params)
         cache = self._cache
         keep, prune_stats = self._prune_keep(cache, "unary")
         # zone-order early exit (docs/zone_maps.md): with no selection and a
@@ -1677,7 +1750,7 @@ class JaxDagEvaluator:
                 # blocks (images encode image-wide), so the first block
                 # fixes the compiled program
                 step = self._build_topn_fn(k, enc_sig)
-            state = step(col_data, col_nulls, n_valid, state, refs)
+            state = step(col_data, col_nulls, n_valid, state, refs, pvec)
         pack_key = ("packtopn", k)
         pack_fn = self._agg_fn_cache.get(pack_key)
         if pack_fn is None:
@@ -1712,6 +1785,7 @@ class JaxDagEvaluator:
         remaining = self.plan.limit.limit if self.plan.limit else None
         sel_rpns = self.sel_rpns
         mask_jit = None
+        pvec = self.param_vectors(self._params)
         # zone-map pruning (docs/zone_maps.md): blocks whose zones prove no
         # row can pass the conjuncts are skipped before any device dispatch
         # — they contribute zero rows to the stream, so the response bytes
@@ -1733,7 +1807,7 @@ class JaxDagEvaluator:
                 col_data, col_nulls, refs, enc_sig = self._device_block(cols, n_valid)
                 if mask_jit is None:
                     mask_jit = self._build_mask_fn(enc_sig)
-                mask = np.asarray(mask_jit(col_data, col_nulls, valid, refs))
+                mask = np.asarray(mask_jit(col_data, col_nulls, valid, refs, pvec))
             else:
                 mask = valid
             logical = np.flatnonzero(mask[: n_valid])
@@ -1755,7 +1829,8 @@ _BATCH_FN_CACHE: dict = {}
 _BATCH_FN_CACHE_MAX = 32
 
 
-def run_batch_cached(evaluators: list["JaxDagEvaluator"], cache) -> list[SelectResponse]:
+def run_batch_cached(evaluators: list["JaxDagEvaluator"], cache,
+                     params_list=None) -> list[SelectResponse]:
     """Fuse K eligible queries over the same cached region into ONE device
     program — the coprocessor's answer to the reference's ``batch_commands``
     multiplexing (service/kv.rs:891) and ``batch_coprocessor`` surface: the
@@ -1765,7 +1840,14 @@ def run_batch_cached(evaluators: list["JaxDagEvaluator"], cache) -> list[SelectR
     Requirements: every query is an aggregation DAG whose group-by is empty or
     all bare dict-encoded columns with stable dictionaries (the same queries
     the single warm path runs with zero per-row transfers).
+
+    ``params_list``: each query's literals.  This rung still bakes them into
+    its program and keys it by them (one executable per set of literals, as
+    before plans were split into shape and parameters).
     """
+    if params_list is None:
+        params_list = [()] * len(evaluators)
+    sels = [ev.bound_sel_rpns(p) for ev, p in zip(evaluators, params_list)]
     blocks = cache.blocks
     if not blocks:
         raise ValueError("batched evaluation over an empty block cache")
@@ -1778,17 +1860,17 @@ def run_batch_cached(evaluators: list["JaxDagEvaluator"], cache) -> list[SelectR
     # eligibility pre-probe first (no device work), then all-or-nothing
     # execution with finalize deferred until every query served — a decline
     # falls back to the fused program with no wasted zone passes.
-    def _zone_probe(ev):
+    def _zone_probe(ev, params):
         zone = ev._zone_evaluator()
-        if zone is None or cache in zone._declined:
+        if zone is None or zone.declined(cache, params):
             return None
         return zone if zone.eligible(blocks) is not None else None
 
-    zones = [_zone_probe(ev) for ev in evaluators]
+    zones = [_zone_probe(ev, p) for ev, p in zip(evaluators, params_list)]
     if all(z is not None for z in zones):
         outs = []
-        for ev, zone in zip(evaluators, zones):
-            out = zone.try_run(cache)  # crash-fallback lives inside try_run
+        for ev, zone, params in zip(evaluators, zones, params_list):
+            out = zone.try_run(cache, params)  # crash-fallback lives inside try_run
             if out is None:  # late decline (partial-fraction or failure)
                 outs = None
                 break
@@ -1830,7 +1912,7 @@ def run_batch_cached(evaluators: list["JaxDagEvaluator"], cache) -> list[SelectR
     n_rows = base.block_rows
 
     key = (
-        tuple(id(ev) for ev in evaluators),
+        tuple((id(ev), p) for ev, p in zip(evaluators, params_list)),
         n_blocks,
         tuple(ship),
         n_rows,
@@ -1854,11 +1936,11 @@ def run_batch_cached(evaluators: list["JaxDagEvaluator"], cache) -> list[SelectR
                 cd, cn, nv, off = xs
                 cols = _build_cols(ship, nullable, cd, cn, n_rows, enc, refs)
                 new_sts = []
-                for (ev, group_cols, _dicts, dict_lens, capacity, _ns), st in zip(specs, sts):
+                for (ev, group_cols, _dicts, dict_lens, capacity, _ns), sel, st in zip(specs, sels, sts):
                     gids = _mixed_radix_gids(cols, group_cols, dict_lens, n_rows)
                     new_sts.append(
                         _fused_step(
-                            ev.sel_rpns, ev.device_aggs, capacity, n_rows, cols, nv, gids, off, st,
+                            sel, ev.device_aggs, capacity, n_rows, cols, nv, gids, off, st,
                             track_first=bool(ev.group_rpns),
                         )
                     )
@@ -1885,7 +1967,7 @@ def run_batch_cached(evaluators: list["JaxDagEvaluator"], cache) -> list[SelectR
             _BATCH_FN_CACHE.pop(next(iter(_BATCH_FN_CACHE)))
 
     nv_dev, off_dev = base._nvoff_device(cache, blocks)
-    keep, prune_stats = _batch_prune_keep(evaluators, cache)
+    keep, prune_stats = _batch_prune_keep(evaluators, cache, params_list)
     if keep is not None:
         # survivor-count geometry: masked blocks ship n_valid == 0, so the
         # fused step's validity masks exclude every one of their rows while
@@ -2050,7 +2132,8 @@ def _pack_region_leaves(leaves, n_regions: int, capacity: int):
     return int_m, flt_m
 
 
-def launch_xregion_cached(ev: "JaxDagEvaluator", caches) -> XRegionPending:
+def launch_xregion_cached(ev: "JaxDagEvaluator", caches,
+                          params: tuple = ()) -> XRegionPending:
     """ONE aggregation plan over R different region images as ONE device
     program: each region's resident blocks are padded to a shared block
     geometry, stacked along a new leading region axis, and the per-region
@@ -2062,23 +2145,26 @@ def launch_xregion_cached(ev: "JaxDagEvaluator", caches) -> XRegionPending:
     never reaches an aggregate.  Group capacities are shared (the max
     region's, rounded to a power of two) while dictionary radices stay
     per-region DYNAMIC inputs — so regions whose group dictionaries differ
-    still ride one compiled program.
+    still ride one compiled program.  So do the plan's literals: ``params``
+    (the group shares them) is one more input, the same for every region.
 
     Raises ValueError when the plan or any region's data shape is not
     batchable (non-aggregation plan, unstable group dictionaries, empty
     cache); the scheduler sheds those to the per-request path.
     """
     with trace.stage("device.prepare", path="xregion"):
-        return _launch_xregion_cached(ev, caches)
+        return _launch_xregion_cached(ev, caches, params)
 
 
-def _launch_xregion_cached(ev: "JaxDagEvaluator", caches) -> XRegionPending:
+def _launch_xregion_cached(ev: "JaxDagEvaluator", caches, params) -> XRegionPending:
     # everything here but the dispatch itself (a stage of its own, which
     # suspends this one) is host work: eligibility, geometry, the regions'
     # pinned inputs, pruning
     from . import encoding as _encoding
 
     specs, group_cols, capacity = xregion_specs(ev, caches)
+    pvec = ev.param_vectors(params)
+    bound_sel = ev.bound_sel_rpns(params)  # for the zone maps: THIS request's
     ship = ev._ship_cols(group_cols)
     nullable = ev.nullable_cols
     n_rows = ev.block_rows
@@ -2119,7 +2205,7 @@ def _launch_xregion_cached(ev: "JaxDagEvaluator", caches) -> XRegionPending:
         # n_valid == 0 through the dynamic nv input, so the vmapped program
         # skips their rows without perturbing the shared compile key
         pstats = _zm.PruneStats()
-        keep = _zm.prune_blocks(cache, ev.sel_rpns, path="xregion",
+        keep = _zm.prune_blocks(cache, bound_sel, path="xregion",
                                 stats=pstats)
         if keep is not None:
             nv = _masked_nv(cache.blocks, keep)
@@ -2144,11 +2230,11 @@ def _launch_xregion_cached(ev: "JaxDagEvaluator", caches) -> XRegionPending:
                 return a
             return jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
 
-        def xregion_fn(region_inputs, dl_arr, refs_arr):
+        def xregion_fn(region_inputs, dl_arr, refs_arr, pvec):
             padded = [jax.tree.map(pad_b, ri) for ri in region_inputs]
             stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *padded)
 
-            def one_region(ri, dlens, refs_r):
+            def one_region(ri, dlens, refs_r):  # pvec: closed over, not mapped
                 cd_r, cn_r, nv_r, off_r = ri
                 state = (
                     jnp.full(capacity, _NO_ROW, dtype=jnp.int64),
@@ -2168,7 +2254,7 @@ def _launch_xregion_cached(ev: "JaxDagEvaluator", caches) -> XRegionPending:
                         gids = jnp.zeros(n_rows, dtype=jnp.int64)
                     return _fused_step(
                         sel_rpns, device_aggs, capacity, n_rows, cols, nv, gids, off, st,
-                        track_first=track_first,
+                        track_first=track_first, params=pvec,
                     ), None
 
                 state, _ = jax.lax.scan(body, state, (cd_r, cn_r, nv_r, off_r))
@@ -2193,19 +2279,21 @@ def _launch_xregion_cached(ev: "JaxDagEvaluator", caches) -> XRegionPending:
     cur = trace.current()
     if cur is not None:
         cur.tag(encoding="encoded" if plans else "decoded")
-    packed = fn(tuple(region_inputs), dl_arr, refs_arr)
+    packed = fn(tuple(region_inputs), dl_arr, refs_arr, pvec)
     pending = XRegionPending(ev, specs, capacity, packed, order, prunes)
     # observatory encoding label for the riders' profiles
     pending.obs_encoding = "encoded" if plans else "plain"
     return pending
 
 
-def run_xregion_cached(ev: "JaxDagEvaluator", caches) -> list[SelectResponse]:
+def run_xregion_cached(ev: "JaxDagEvaluator", caches,
+                       params: tuple = ()) -> list[SelectResponse]:
     """launch + finalize in one step (tests / single-batch callers)."""
-    return launch_xregion_cached(ev, caches).finalize()
+    return launch_xregion_cached(ev, caches, params).finalize()
 
 
-def launch_xregion_sharded(ev: "JaxDagEvaluator", caches, mesh) -> XRegionPending:
+def launch_xregion_sharded(ev: "JaxDagEvaluator", caches, mesh,
+                           params: tuple = ()) -> XRegionPending:
     """The ``shard_map`` twin of :func:`launch_xregion_cached`: the same
     cross-region batch executed over EVERY device of ``mesh``, each region
     image (or block, for a block-spread huge region) scanned on its owner
@@ -2216,7 +2304,7 @@ def launch_xregion_sharded(ev: "JaxDagEvaluator", caches, mesh) -> XRegionPendin
     as the single-device launcher, plus "no mesh merge rule"."""
     from ..parallel.mesh import launch_xregion_sharded as _impl
 
-    return _impl(ev, caches, mesh)
+    return _impl(ev, caches, mesh, params)
 
 
 class _ChunkExecutor:

@@ -136,10 +136,12 @@ def _rpn_sig(rpn: RpnExpression | None) -> tuple:
 
 
 def _plan_sig(ev) -> tuple:
-    """Everything a zone device program depends on: selection RPNs (with
-    constants), aggregate ops + argument RPNs, and whether grouping is on.
-    Two evaluators with equal signatures compile to identical programs, so
-    they share one cached jitted fn per layout instead of pinning one each."""
+    """Everything the partial-tile program depends on: selection RPNs (of a
+    plan's shape: the literals are param nodes, read from the program's
+    ``params`` argument), aggregate ops + argument RPNs, and whether grouping
+    is on.  Two evaluators with equal signatures compile to identical
+    programs, so they share one cached jitted fn per layout instead of
+    pinning one each, and a new literal finds the one it is."""
     return (
         tuple(_rpn_sig(r) for r in ev.sel_rpns),
         _agg_sig(ev),
@@ -160,6 +162,7 @@ def _agg_sig(ev) -> tuple:
 
 
 _ZONE_FNS_MAX = 32  # distinct plan shapes cached per layout
+_DECLINED_PARAMS_MAX = 256  # literal sets a cache remembers a decline for
 
 
 def _layout_fn_cache(layout) -> dict:
@@ -385,6 +388,9 @@ class ZoneEvaluator:
         # caches we already declined for (partial fraction too high): skip
         # the layout work on every later query against the same cache
         self._declined = weakref.WeakSet()
+        # the partial-tile fraction follows the request's literals, so that
+        # decline is remembered by cache AND literals (a bounded set of them)
+        self._declined_params = weakref.WeakKeyDictionary()
         self.served = 0  # queries answered by the zone path (observability)
         self.failed = 0  # zone-path crashes that fell through (observability)
         self.last_error: str | None = None
@@ -422,17 +428,23 @@ class ZoneEvaluator:
                         return None
         return group_cols, dicts
 
+    def declined(self, cache, params) -> bool:
+        return (cache in self._declined
+                or params in self._declined_params.get(cache, ()))
+
     # -- per-query host classification -------------------------------------
 
-    def _classify_tiles(self, layout):
+    def _classify_tiles(self, layout, sel_rpns):
         """(full_mask, partial_idx) over tiles; empty tiles appear in
         neither.  Forced-partial: pad tiles and tiles with NULLs in any
-        column referenced by selection or aggregate arguments."""
-        ev = self.ev
+        column referenced by selection or aggregate arguments.
+        ``sel_rpns``: the selection with THIS request's literals
+        (``bound_sel_rpns``): a tile called full by another query's date
+        would be a wrong answer."""
         T = layout.n_tiles
         status_full = np.ones(T, dtype=bool)
         status_empty = np.zeros(T, dtype=bool)
-        for rpn in ev.sel_rpns:
+        for rpn in sel_rpns:
             rec = _recognize_conjunct(rpn)
             if rec is None:
                 status_full[:] = False
@@ -593,7 +605,7 @@ class ZoneEvaluator:
         track_first = bool(ev.group_rpns)
         n_sub = pcap * TILE_ROWS
 
-        def fn(dev, pidx, pw):
+        def fn(dev, pidx, pw, params):
             tg = dev["tile_gid"][pidx]
             tg = jnp.where(pw, tg, capacity - 1)  # scratch slot for padding
             cols = {}
@@ -610,7 +622,7 @@ class ZoneEvaluator:
             valid = dev["valid"].reshape(T, TILE_ROWS)[pidx].reshape(n_sub)
             active = valid & jnp.broadcast_to(pw[:, None], (pcap, TILE_ROWS)).reshape(n_sub)
             for rpn in ev.sel_rpns:
-                d, nl = eval_rpn(rpn, cols, n_sub, xp=jnp)
+                d, nl = eval_rpn(rpn, cols, n_sub, xp=jnp, params=params)
                 active = active & (d != 0) & ~nl
             seg = lambda x: jax.ops.segment_sum(x, tg, num_segments=capacity)
 
@@ -663,8 +675,9 @@ class ZoneEvaluator:
 
     # -- merge + run -------------------------------------------------------
 
-    def try_run(self, cache):
-        """Zone-serve the plan over ``cache``, or None to fall back.  A
+    def try_run(self, cache, params: tuple = ()):
+        """Zone-serve the plan over ``cache`` for the request's literals
+        ``params``, or None to fall back.  A
         zone-path FAILURE (unexpected compiler/backend error — e.g. the
         first run on a new accelerator) is caught, recorded, and remembered
         per cache: the fast layer must never take down a query the slower
@@ -676,7 +689,7 @@ class ZoneEvaluator:
             count_path_fallback("zone", "breaker_open")
             return None
         try:
-            out = self._try_run_inner(cache)
+            out = self._try_run_inner(cache, params)
             if breaker is not None:
                 if out is not None:
                     breaker.record_success("zone")
@@ -692,7 +705,7 @@ class ZoneEvaluator:
                 breaker.record_failure("zone")
             return None
 
-    def _try_run_inner(self, cache):
+    def _try_run_inner(self, cache, params):
         from .tracker import count_path_fallback
 
         # host work before the dispatches: eligibility, the layout (built
@@ -701,14 +714,16 @@ class ZoneEvaluator:
         with trace.stage("device.prepare", path="zone"):
             ev = self.ev
             blocks = cache.blocks
-            if cache in self._declined:
+            if self.declined(cache, params):
                 return None
             el = self.eligible(blocks)
             if el is None:
                 return None
             group_cols, dicts = el
-            if self.ev.sel_rpns and all(
-                _recognize_conjunct(r) is None for r in self.ev.sel_rpns
+            # the literals are read from the request, every time
+            sel_rpns = ev.bound_sel_rpns(params)
+            if sel_rpns and all(
+                _recognize_conjunct(r) is None for r in sel_rpns
             ):
                 # no conjunct classifiable → 100% partial tiles: don't pay for a
                 # layout the fallback check would immediately discard
@@ -717,15 +732,18 @@ class ZoneEvaluator:
                 return None
             needed = self._referenced_cols()
             sort_col = None
-            for rpn in ev.sel_rpns:
+            for rpn in sel_rpns:
                 rec = _recognize_conjunct(rpn)
                 if rec is not None and rec[0] not in group_cols and ev.schema[rec[0]][0] != EvalType.REAL:
                     sort_col = rec[0]
                     break
             layout = build_layout(cache, group_cols, dicts, sort_col, needed, ev.schema)
-            full, partial_idx = self._classify_tiles(layout)
+            full, partial_idx = self._classify_tiles(layout, sel_rpns)
             if layout.n_tiles and len(partial_idx) / layout.n_tiles > PARTIAL_FALLBACK:
-                self._declined.add(cache)
+                seen = self._declined_params.setdefault(cache, set())
+                if len(seen) >= _DECLINED_PARAMS_MAX:
+                    seen.clear()
+                seen.add(params)
                 count_path_fallback("zone", "partial_fraction")
                 return None
             n_slots = layout.n_slots
@@ -748,7 +766,8 @@ class ZoneEvaluator:
                 pw = np.zeros(pcap, dtype=bool)
                 pw[: len(partial_idx)] = True
                 fn = self._partial_fn(layout, capacity, pcap)
-                states.append(fn(layout.dev, jnp.asarray(pidx), jnp.asarray(pw)))
+                states.append(fn(layout.dev, jnp.asarray(pidx), jnp.asarray(pw),
+                                 ev.param_vectors(params)))
             if not states:
                 # every tile proved empty: zero contributions
                 states.append(

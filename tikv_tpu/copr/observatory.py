@@ -92,13 +92,15 @@ def sig_id(sig: tuple) -> str:
 
 def dag_sig(dag) -> tuple[str, str]:
     """(sig id, human description) for a DAG: the observatory's profile
-    key.  The id hashes the scheduler's :func:`plan_signature` — the same
-    normalization that decides micro-batch sharing, so two requests that
-    can share a dispatch profile under one sig by construction."""
-    from .scheduler import plan_signature  # lazy: scheduler imports jax_eval
+    key.  The id hashes the plan's SHAPE signature (``plan_shape.split``:
+    the normalization that decides which requests share a program, with the
+    selection's literals taken out), so one statement is one profile
+    whatever literals its executions carry, and the cost router learns a
+    path once per shape."""
+    from .plan_shape import split  # lazy: it imports the region cache
 
-    sig = plan_signature(dag)
-    return sig_id(sig), _describe(sig)
+    shape = split(dag)[0]
+    return sig_id(shape), _describe(shape)
 
 
 def _describe(sig: tuple) -> str:

@@ -40,6 +40,17 @@ class Constant:
 
 
 @dataclass
+class Param:
+    """A constant hoisted out of the plan (``copr/plan_shape.py``): slot
+    ``slot`` of the parameters a request brings.  The type and the frac stay
+    in the plan, the value does not."""
+
+    slot: int
+    eval_type: EvalType
+    frac: int = 0
+
+
+@dataclass
 class FuncCall:
     op: str  # kernel name
     children: list
@@ -48,7 +59,7 @@ class FuncCall:
     frac: int = 0
 
 
-Expr = ColumnRef | Constant | FuncCall
+Expr = ColumnRef | Constant | Param | FuncCall
 
 
 # ---------------------------------------------------------------------------
@@ -57,10 +68,10 @@ Expr = ColumnRef | Constant | FuncCall
 
 @dataclass
 class RpnNode:
-    kind: str  # "col" | "const" | "fn"
+    kind: str  # "col" | "const" | "param" | "fn"
     eval_type: EvalType
     frac: int = 0
-    index: int = 0  # col index
+    index: int = 0  # col index; a param's slot
     value: object = None  # const value
     op: str = ""  # fn kernel name
     arity: int = 0
@@ -106,6 +117,9 @@ def _compile(expr: Expr, schema, nodes: list[RpnNode]) -> tuple[EvalType, int]:
         return et, frac
     if isinstance(expr, Constant):
         nodes.append(RpnNode("const", expr.eval_type, expr.frac, value=expr.value))
+        return expr.eval_type, expr.frac
+    if isinstance(expr, Param):
+        nodes.append(RpnNode("param", expr.eval_type, expr.frac, index=expr.slot))
         return expr.eval_type, expr.frac
     if isinstance(expr, FuncCall):
         if expr.op not in KERNELS:
@@ -209,11 +223,44 @@ _DTYPE = {
 }
 
 
-def eval_rpn(rpn: RpnExpression, columns: list, n_rows: int, xp=np):
+def param_vectors(values, float_slots) -> tuple:
+    """A request's parameters as ``eval_rpn`` reads them: an int64 and a
+    float64 vector, both indexed by slot (a slot uses the one its lane says,
+    ``float_slots[slot]``; the other holds 0).  A lane no slot uses is None,
+    so that a program is handed no argument it does not read.  A SET mask
+    above 2^63 rides the int64 lane wrapped and is cast back where it is
+    read."""
+    ints = floats = None
+    if not all(float_slots):
+        ints = np.zeros(len(values), dtype=np.int64)
+    if any(float_slots):
+        floats = np.zeros(len(values), dtype=np.float64)
+    for k, v in enumerate(values):
+        if float_slots[k]:
+            floats[k] = v
+        else:
+            ints[k] = v - (1 << 64) if v >= 1 << 63 else v
+    return ints, floats
+
+
+def bind_rpn(rpn: RpnExpression, values) -> RpnExpression:
+    """``rpn`` with every param node turned back into the constant a request
+    brought for its slot: what host-side readers of a plan's literals (zone
+    maps, tile classification) and the programs that still bake them read."""
+    return RpnExpression([
+        RpnNode("const", n.eval_type, n.frac, value=values[n.index])
+        if n.kind == "param" else n
+        for n in rpn.nodes
+    ])
+
+
+def eval_rpn(rpn: RpnExpression, columns: list, n_rows: int, xp=np, params=None):
     """Evaluate over column (data, nulls) pairs. Returns (data, nulls).
 
     ``columns`` holds per-column (data, nulls) arrays (only referenced indices
     need to be present).  With ``xp=jax.numpy`` the arrays may be tracers.
+    ``params`` is the request's ``param_vectors`` pair, read by param nodes:
+    under ``jax.numpy`` a traced input, so one program serves every literal.
     """
     stack: list[tuple[object, object]] = []
     for node in rpn.nodes:
@@ -232,6 +279,11 @@ def eval_rpn(rpn: RpnExpression, columns: list, n_rows: int, xp=np):
                 data = xp.full(n_rows, node.value, dtype=dtype)
                 nulls = xp.zeros(n_rows, dtype=bool)
             stack.append((data, nulls))
+        elif node.kind == "param":
+            dtype = _DTYPE[node.eval_type]
+            v = params[1 if dtype is np.float64 else 0][node.index]
+            stack.append((xp.full(n_rows, v.astype(dtype), dtype=dtype),
+                          xp.zeros(n_rows, dtype=bool)))
         else:
             _, _, fn = KERNELS[node.op]
             args = stack[-node.arity :]

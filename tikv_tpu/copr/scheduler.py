@@ -6,10 +6,13 @@ with high/normal/low priorities.  This module is the device-serving
 re-expression: instead of sharing CPU workers, concurrent device-eligible
 DAG requests share **XLA dispatches**.
 
-* Requests are keyed by their **plan signature** (:func:`plan_signature` —
+* Requests are keyed by their **plan signature** (``copr/plan_shape.py`` —
   scalar ops normalized through ``sig_map`` so wire-level ScalarFuncSig
-  spellings and kernel names key identically).  Same signature = same
-  compiled program shape.
+  spellings and kernel names key identically), held as the pair
+  ``(shape signature, parameters)`` that ``plan_shape.split`` returns: the
+  whole pair is the identity of a slot and of an xregion group, its first
+  half (the plan without its selection's literals) keys what is per
+  program: evaluators and the eligibility memo.
 * Requests with the same signature but different regions batch into ONE
   device program: each region's cached column image (PR 1's
   ``region_cache.py``) is padded to a shared block geometry and stacked
@@ -63,17 +66,7 @@ from . import observatory as _obs
 from . import overload as _overload
 from ..util.retry import DeadlineExceeded, ServerBusyError, deadline_from_context
 from . import jax_eval
-from .dag import (
-    Aggregation,
-    DagRequest,
-    IndexScan,
-    Join,
-    Limit,
-    Projection,
-    Selection,
-    TableScan,
-    TopN,
-)
+from .dag import Aggregation
 from .endpoint import (
     REQ_TYPE_DAG,
     CoprRequest,
@@ -81,9 +74,8 @@ from .endpoint import (
     resolve_encode_type,
     stale_read_ctx,
 )
-from .region_cache import _epoch_of, schema_sig
-from .rpn import ColumnRef, Constant, FuncCall
-from .sig_map import resolve_sig
+from .plan_shape import split
+from .region_cache import _epoch_of
 
 LANES = ("high", "normal", "low")
 
@@ -145,71 +137,6 @@ def _clamped_lane(req: CoprRequest, cfg: SchedulerConfig, overload) -> str:
     if eff != lane:
         _overload.count_demotion(_overload.tenant_of(req.context), eff)
     return eff
-
-
-def _expr_sig(e):
-    """Canonical, hashable form of a scalar expression tree."""
-    if e is None:
-        return None
-    if isinstance(e, ColumnRef):
-        return ("col", e.index)
-    if isinstance(e, Constant):
-        v = e.value
-        if not isinstance(v, (int, float, bytes, str, bool, type(None))):
-            v = repr(v)
-        return ("const", e.eval_type, e.frac, v)
-    if isinstance(e, FuncCall):
-        op = e.op
-        # wire-format ScalarFuncSig spellings fold onto kernel names, so a
-        # tipb-bridged DAG and a natively-built DAG with the same plan key
-        # into the same micro-batch (sig_map is the single source of truth)
-        mapped = resolve_sig(op)
-        if mapped is not None and not mapped.startswith("~"):
-            op = mapped
-        return ("fn", op, tuple(_expr_sig(c) for c in e.children))
-    return ("?", repr(e))
-
-
-def _exec_sig(ex) -> tuple:
-    """One executor descriptor's shape key.  A Join recurses into its
-    build chain but deliberately EXCLUDES the build ranges and region
-    context — those vary per request without changing the compiled
-    program shape, exactly like the probe ranges."""
-    if isinstance(ex, TableScan):
-        return ("tablescan", ex.table_id, schema_sig(ex.columns_info))
-    if isinstance(ex, IndexScan):
-        return ("indexscan", ex.table_id, ex.index_id,
-                schema_sig(ex.columns_info))
-    if isinstance(ex, Selection):
-        return ("sel", tuple(_expr_sig(c) for c in ex.conditions))
-    if isinstance(ex, Aggregation):
-        return ("agg", bool(ex.streamed),
-                tuple(_expr_sig(g) for g in ex.group_by),
-                tuple((a.op, _expr_sig(a.expr)) for a in ex.agg_funcs))
-    if isinstance(ex, TopN):
-        return ("topn", ex.limit,
-                tuple((_expr_sig(e), bool(d)) for e, d in ex.order_by))
-    if isinstance(ex, Limit):
-        return ("limit", ex.limit)
-    if isinstance(ex, Projection):
-        return ("proj", tuple(_expr_sig(e) for e in ex.exprs))
-    if isinstance(ex, Join):
-        return ("join", ex.join_type, ex.left_key, ex.right_key,
-                tuple(_exec_sig(b) for b in ex.build))
-    return (type(ex).__name__,)
-
-
-def plan_signature(dag: DagRequest) -> tuple:
-    """The micro-batch key: two DAGs with equal signatures compile to the
-    same device program shape, so their executions can share one dispatch
-    (over different region images)."""
-    parts = [_exec_sig(ex) for ex in dag.executors]
-    # encode_type is part of the slot identity: identical requests share one
-    # slot's RESPONSE BYTES, and a datum and a chunk request with the same
-    # plan must never share those (mirrors the service parse-memo rule)
-    parts.append(("out", tuple(dag.output_offsets or ()), dag.chunk_rows,
-                  dag.encode_type))
-    return tuple(parts)
 
 
 @dataclass
@@ -279,13 +206,11 @@ class CoprReadScheduler:
         # the store's count of reads on their way here (util/inbound.py);
         # None = nobody counts them, so a partial batch lingers to its end
         self._inbound = None
-        # per-signature memos: device eligibility (supports() re-analyzes the
-        # whole plan) and the compiled evaluator (endpoint._evaluator_for
-        # keys on serialized plan bytes — ~1ms of wire encoding per lookup
-        # that a batch of identical-signature requests should pay once)
+        # per-shape memo of device eligibility (supports() re-analyzes the
+        # whole plan; the literals do not change its verdict).  Evaluators
+        # are the endpoint's, found by the same shape (Endpoint._bind)
         self._memo_mu = make_lock("copr.scheduler.memo")
         self._supports: dict[tuple, bool] = {}
-        self._evs: dict[tuple, object] = {}
 
     def reconfigure(self, changed: dict) -> None:
         """Online scheduler geometry (POST /config ``coprocessor.*`` via
@@ -708,7 +633,8 @@ class CoprReadScheduler:
         by_sig: dict[tuple, dict[tuple, _Slot]] = {}
         rest = []
         for it in items:
-            sig = self._batchable_sig(it.req)
+            # the gates are asked again; the split admission made is kept
+            sig = self._batchable_sig(it.req, it.sig)
             if sig is None:
                 rest.append(it)
                 continue
@@ -750,9 +676,11 @@ class CoprReadScheduler:
     def _batchable(self, req: CoprRequest) -> bool:
         return self._batchable_sig(req) is not None
 
-    def _batchable_sig(self, req: CoprRequest) -> tuple | None:
-        """The request's plan signature when it can join a device batch,
-        else None.  supports() verdicts memoize per signature."""
+    def _batchable_sig(self, req: CoprRequest, sig: tuple | None = None) -> tuple | None:
+        """The request's plan signature, as the pair ``(shape signature,
+        parameters)``, when it can join a device batch, else None.
+        supports() verdicts memoize per shape.  ``sig``: the pair, where an
+        earlier call made it for this request already."""
         if (req.tp != REQ_TYPE_DAG or req.dag is None
                 or not self.ep.device_enabled()
                 or not any(isinstance(e, Aggregation) for e in req.dag.executors)):
@@ -764,29 +692,20 @@ class CoprReadScheduler:
             # pin demotion — its work must not join a device batch (the
             # per-request path CPU-falls-back for the same reason)
             return None
-        sig = plan_signature(req.dag)
-        ok = self._supports.get(sig)
+        if sig is None:
+            with trace.stage("copr.bind"):
+                sig = split(req.dag)
+        ok = self._supports.get(sig[0])
         if ok is None:
             ok = jax_eval.supports(req.dag)
             # memo mutation under its own lock: _batchable runs on client
             # threads AND the dispatcher; racing evictions of the same key
             # would KeyError
             with self._memo_mu:
-                self._supports[sig] = ok
+                self._supports[sig[0]] = ok
                 while len(self._supports) > 256:
                     self._supports.pop(next(iter(self._supports)))
         return sig if ok else None
-
-    def _evaluator_for(self, sig: tuple, dag: DagRequest):
-        with trace.stage("copr.evaluator"):
-            ev = self._evs.get(sig)
-            if ev is None:
-                ev = self.ep._evaluator_for(dag)
-                with self._memo_mu:
-                    self._evs[sig] = ev
-                    while len(self._evs) > 64:
-                        self._evs.pop(next(iter(self._evs)))
-        return ev
 
     def _region_key(self, req: CoprRequest) -> tuple:
         ctx = req.context or {}
@@ -912,7 +831,10 @@ class CoprReadScheduler:
         # breaker verdicts, the padding shed (zone pruning included)
         with trace.attach(live[0].items[0].trace_ctx), \
                 trace.stage("sched.group", slots=len(live)):
-            ev = self._evaluator_for(sig, live[0].items[0].req.dag)
+            # riders of one group share shape AND literals (sig is both)
+            ev, params = self.ep._bind(
+                live[0].items[0].req.dag, split=sig,
+                tasks=sum(len(s.items) for s in live))
             mesh = self._sharded_mesh(ev)
             breaker = self.ep.breaker
             if mesh is not None and not breaker.allow("mesh"):
@@ -952,7 +874,8 @@ class CoprReadScheduler:
             )
             n_reqs = max(n_batch - n_filled, 1)
             kind = "xregion" if mesh is None else "xregion_sharded"
-            waste = self._padding_waste(live, ev=ev) if mesh is None else sh_waste
+            waste = (self._padding_waste(live, ev=ev, params=params)
+                     if mesh is None else sh_waste)
         # fan-in linkage (docs/tracing.md): ONE device-dispatch span — its
         # own one-span trace naming every participating parent trace — and
         # each rider links back to it.  A shared dispatch can't be a child
@@ -976,10 +899,10 @@ class CoprReadScheduler:
             with bsp.active():
                 if mesh is not None:
                     pending = jax_eval.launch_xregion_sharded(
-                        ev, [s.cache for s in live], mesh)
+                        ev, [s.cache for s in live], mesh, params)
                 else:
                     pending = jax_eval.launch_xregion_cached(
-                        ev, [s.cache for s in live])
+                        ev, [s.cache for s in live], params)
         except ValueError:
             # "not batchable" (empty blocks, unstable dictionaries) is a
             # documented decline, not a device failure — shed without
@@ -1119,10 +1042,12 @@ class CoprReadScheduler:
             kind="fused", plans=len(uniq), occupancy=len(todo))
         t0 = time.perf_counter()
         try:
-            evs = [self._evaluator_for(sig, group[0].req.dag)
-                   for sig, group in uniq.items()]
+            bound = [self.ep._bind(group[0].req.dag, split=sig, tasks=len(group))
+                     for sig, group in uniq.items()]
+            evs = [ev for ev, _params in bound]
             with bsp.active():
-                resps = jax_eval.run_batch_cached(evs, cache)
+                resps = jax_eval.run_batch_cached(
+                    evs, cache, [params for _ev, params in bound])
         except ValueError:
             # a documented decline (non-stable group dictionaries, empty
             # cache) — per-request path, no device-failure attribution
@@ -1202,7 +1127,7 @@ class CoprReadScheduler:
     # -- admission ----------------------------------------------------------
 
     @staticmethod
-    def _padding_waste(slots: list[_Slot], ev=None) -> float:
+    def _padding_waste(slots: list[_Slot], ev=None, params=()) -> float:
         if not slots:
             return 0.0
         counts = [len(s.cache.blocks) for s in slots]
@@ -1215,9 +1140,10 @@ class CoprReadScheduler:
             # never changes the padded shapes, so shedding can't recover it.
             from . import zone_maps as _zm
 
+            sel_rpns = ev.bound_sel_rpns(params)
             counts = [
                 int(keep.sum()) if (keep := _zm.prune_blocks(
-                    s.cache, ev.sel_rpns, count=False)) is not None else c
+                    s.cache, sel_rpns, count=False)) is not None else c
                 for s, c in zip(slots, counts)
             ]
         return 1.0 - sum(counts) / (len(counts) * b)
@@ -1331,7 +1257,7 @@ class CoprReadScheduler:
         with trace.attach(it.trace_ctx), trace.stage("copr.obs"):
             sig = getattr(ev, "obs_sig", "")
             if not sig and it.sig is not None:
-                sig = _obs.sig_id(it.sig)
+                sig = _obs.sig_id(it.sig[0])
             qwait = (max(dispatch_t - it.enqueue_t, 0.0)
                      if dispatch_t is not None and it.enqueue_t else 0.0)
             prune = getattr(resp, "_obs_prune", None) or (0, 0)
@@ -1347,7 +1273,7 @@ class CoprReadScheduler:
         self._count_shed(reason)
         it0 = slot.items[0] if slot.items else None
         _obs.OBSERVATORY.record_decline(
-            _obs.sig_id(it0.sig) if it0 is not None and it0.sig is not None
+            _obs.sig_id(it0.sig[0]) if it0 is not None and it0.sig is not None
             else None,
             path, reason)
         for it in slot.items:

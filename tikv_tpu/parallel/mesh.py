@@ -804,16 +804,19 @@ def device_slab_load(caches, mesh) -> dict[int, int]:
 
 
 def _xshard_program(ev: JaxDagEvaluator, flat: Mesh, R: int, capacity: int,
-                    ship: tuple, nullable: tuple, group_cols, enc):
+                    ship: tuple, nullable: tuple, group_cols, enc,
+                    params: tuple = ()):
     """The jitted ``shard_map`` program of :func:`launch_xregion_sharded`
     over the 1-D ``regions`` mesh ``flat``: arguments are the per-ship-column
     slab stacks and null masks, slab metadata (region slot, n_valid, row
     offset), all sharded over ``regions``, then the replicated per-region
     dictionary radices and frame-of-reference rows.  Apart from the mesh it
     depends on shapes only, so it also compiles for a mesh of described
-    devices (tests/test_tpu_compile.py)."""
+    devices (tests/test_tpu_compile.py).  The plan's literals (``params``)
+    are baked in: this rung is one program per set of them, as before plans
+    were split into shape and parameters (copr/plan_shape.py)."""
     device_aggs = ev.device_aggs
-    sel_rpns = ev.sel_rpns
+    sel_rpns = ev.bound_sel_rpns(params)
     track_first = bool(ev.group_rpns)
     n_rows = ev.block_rows
     cap_total = R * capacity
@@ -879,7 +882,8 @@ def _xshard_program(ev: JaxDagEvaluator, flat: Mesh, R: int, capacity: int,
     return _obs.timed_jit(jax.jit(xfn), "mesh.xshard", "mesh", ev.obs_sig)
 
 
-def launch_xregion_sharded(ev: JaxDagEvaluator, caches, mesh: Mesh) -> XRegionPending:
+def launch_xregion_sharded(ev: JaxDagEvaluator, caches, mesh: Mesh,
+                           params: tuple = ()) -> XRegionPending:
     """ONE aggregation plan over R cached region images as ONE ``shard_map``
     program over EVERY device of ``mesh`` — the sharded twin of
     ``jax_eval.launch_xregion_cached``.
@@ -941,10 +945,11 @@ def launch_xregion_sharded(ev: JaxDagEvaluator, caches, mesh: Mesh) -> XRegionPe
 
     region_keeps = []
     region_prunes = []
+    bound_sel = ev.bound_sel_rpns(params)
     for cache in caches:
         ps = _zm.PruneStats()
         region_keeps.append(
-            _zm.prune_blocks(cache, ev.sel_rpns, path="mesh", stats=ps))
+            _zm.prune_blocks(cache, bound_sel, path="mesh", stats=ps))
         region_prunes.append((ps.examined, ps.pruned))
 
     region_offsets = []
@@ -1032,11 +1037,11 @@ def launch_xregion_sharded(ev: JaxDagEvaluator, caches, mesh: Mesh) -> XRegionPe
     )
 
     key = ("xshard", tuple(d.id for d in devices), S, R, capacity,
-           ship, nullable, len(group_cols), enc)
+           ship, nullable, len(group_cols), enc, params)
     fn = ev._agg_fn_cache.get(key)
     if fn is None:
         fn = ev._agg_fn_cache[key] = _xshard_program(
-            ev, flat, R, capacity, ship, nullable, group_cols, enc)
+            ev, flat, R, capacity, ship, nullable, group_cols, enc, params)
         xkeys = [k for k in ev._agg_fn_cache if isinstance(k, tuple)
                  and k and k[0] == "xshard"]
         while len(xkeys) > 16:
@@ -1051,9 +1056,10 @@ def launch_xregion_sharded(ev: JaxDagEvaluator, caches, mesh: Mesh) -> XRegionPe
     return pending
 
 
-def run_xregion_sharded(ev: JaxDagEvaluator, caches, mesh: Mesh):
+def run_xregion_sharded(ev: JaxDagEvaluator, caches, mesh: Mesh,
+                        params: tuple = ()):
     """launch + finalize in one step (tests / single-batch callers)."""
-    return launch_xregion_sharded(ev, caches, mesh).finalize()
+    return launch_xregion_sharded(ev, caches, mesh, params).finalize()
 
 
 class MeshServingRunner:
@@ -1110,10 +1116,14 @@ class MeshServingRunner:
         )
         return (jnp.asarray(new_first), new_carries)
 
-    def run(self, source, cache=None) -> "SelectResponse":
+    def run(self, source, cache=None, params: tuple = ()) -> "SelectResponse":
         """Same signature as JaxDagEvaluator.run; the block cache is a
         single-device HBM concept and is ignored here (Endpoint routes cached
-        requests down the single-device path)."""
+        requests down the single-device path).  The runner is built from the
+        whole plan, literals included (keyed by its wire bytes), so a request
+        brings it no ``params``."""
+        if params:
+            raise ValueError("a mesh runner holds its plan's literals")
         from ..copr.groupby import GroupDict
         from ..copr.jax_eval import _ZERO_GIDS
 
