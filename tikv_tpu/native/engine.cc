@@ -1760,6 +1760,90 @@ uint64_t ckpt_load(Engine* e) {
   return 0;
 }
 
+// The frame a batched read answers an absent key with, in place of a length.
+constexpr uint32_t kAbsent = 0xFFFFFFFFu;
+
+// The n keys of a batched read: repeated (klen u32 | key); false when the
+// buffer does not hold exactly n of them.
+bool parse_keys(const uint8_t* p, uint64_t len, uint64_t n,
+                std::vector<std::string>* keys) {
+  const uint8_t* end = p + len;
+  keys->reserve(n);
+  for (uint64_t i = 0; i < n; i++) {
+    if (end - p < 4) return false;
+    uint32_t klen = read_u32(p);
+    if (static_cast<uint64_t>(end - p) < klen) return false;
+    keys->emplace_back(reinterpret_cast<const char*>(p), klen);
+    p += klen;
+  }
+  return p == end;
+}
+
+long hand_out(const std::string& buf, long n, uint8_t** out, uint64_t* out_len) {
+  *out = static_cast<uint8_t*>(malloc(buf.size() ? buf.size() : 1));
+  memcpy(*out, buf.data(), buf.size());
+  *out_len = buf.size();
+  return n;
+}
+
+// The visible values of keys at snap_seq: res[i] HIT with vals[i], or not
+// (MISS/TOMB: absent).  One short critical section for all of them:
+// memtable resolve + the (memory-only) range-tombstone check + ONE
+// shared_ptr copy of the run list.  Run probing does file IO (pread + crc)
+// and must NOT hold the engine lock — runs are immutable and the copied
+// shared_ptrs keep their files alive across a concurrent merge swap.  A
+// memtable MISS stays valid after unlock: only versions newer than snap can
+// appear, and a flush moving versions to a run moves none visible at snap
+// (they would have resolved HIT/TOMB here).  0, or <0 on a run read error.
+int get_many(Engine* e, int cf, uint64_t snap_seq,
+             const std::vector<std::string>& ks, std::vector<std::string>* vals,
+             std::vector<Res>* res) {
+  size_t n = ks.size();
+  res->assign(n, Res::MISS);
+  vals->assign(n, std::string());
+  std::vector<uint64_t> rts(n, 0);  // newest covering range delete <= snap
+  std::vector<std::shared_ptr<Run>> runs_copy;
+  {
+    std::shared_lock lk(e->mu);
+    const Table& t = e->cfs[cf];
+    for (size_t i = 0; i < n; i++) {
+      e->perf.gets.fetch_add(1, std::memory_order_relaxed);
+      const std::string* v = nullptr;
+      uint64_t v_seq = 0;
+      auto it = t.find(ks[i]);
+      if (it != t.end()) (*res)[i] = resolve3(it->second, snap_seq, &v, &v_seq);
+      if ((*res)[i] == Res::TOMB) continue;
+      rts[i] = rtomb_covering(e->mem_rtombs[cf], ks[i], snap_seq);
+      for (const auto& run : e->runs[cf]) {
+        uint64_t s = rtomb_covering(run->rtombs, ks[i], snap_seq);
+        if (s > rts[i]) rts[i] = s;
+      }
+      if ((*res)[i] == Res::HIT) {
+        e->perf.memtable_hits.fetch_add(1, std::memory_order_relaxed);
+        // a range delete masks the memtable value; copy under the lock, the
+        // chain may mutate after
+        if (rts[i] >= v_seq) (*res)[i] = Res::TOMB;
+        else (*vals)[i] = *v;
+      }
+    }
+    runs_copy = e->runs[cf];
+  }
+  for (size_t i = 0; i < n; i++) {
+    if ((*res)[i] != Res::MISS) continue;
+    // newest run first; a hit or tombstone in a newer run masks older ones
+    for (const auto& run : runs_copy) {
+      uint64_t v_seq = 0;
+      int rr = run_get(*run, ks[i], snap_seq, &(*vals)[i], &v_seq, &e->perf);
+      if (rr < 0) return -3;
+      if (rr == 0) continue;
+      // a range delete masks the run value too
+      (*res)[i] = (rr == 1 && rts[i] < v_seq) ? Res::HIT : Res::TOMB;
+      break;
+    }
+  }
+  return 0;
+}
+
 }  // namespace
 
 extern "C" {
@@ -2171,61 +2255,14 @@ int eng_get(void* h, int cf, const uint8_t* key, uint64_t klen,
             uint64_t snap_seq, uint8_t** out, uint64_t* out_len) {
   ENG_OR(guard::kClosed);
   if (cf < 0 || cf >= kNumCfs) return -2;
-  std::string k(reinterpret_cast<const char*>(key), klen);
-  std::string mem_val;
-  uint64_t v_seq = 0;
-  uint64_t rts = 0;  // newest covering range-delete seq <= snap
-  Res r = Res::MISS;
-  std::vector<std::shared_ptr<Run>> runs_copy;
-  {
-    // short critical section: memtable resolve + the (memory-only) range-
-    // tombstone check + a shared_ptr copy of the run list.  Run probing
-    // does file IO (pread + crc) and must NOT hold the engine lock — runs
-    // are immutable and the copied shared_ptrs keep their files alive
-    // across a concurrent merge swap.  A memtable MISS stays valid after
-    // unlock: only versions newer than snap can appear, and a flush moving
-    // versions to a run moves none visible at snap (they would have
-    // resolved HIT/TOMB here).
-    std::shared_lock lk(e->mu);
-    e->perf.gets.fetch_add(1, std::memory_order_relaxed);
-    const Table& t = e->cfs[cf];
-    const std::string* v = nullptr;
-    auto it = t.find(k);
-    if (it != t.end()) r = resolve3(it->second, snap_seq, &v, &v_seq);
-    if (r == Res::TOMB) return 0;
-    rts = rtomb_covering(e->mem_rtombs[cf], k, snap_seq);
-    for (const auto& run : e->runs[cf]) {
-      uint64_t s = rtomb_covering(run->rtombs, k, snap_seq);
-      if (s > rts) rts = s;
-    }
-    if (r == Res::HIT) {
-      e->perf.memtable_hits.fetch_add(1, std::memory_order_relaxed);
-      if (rts >= v_seq) return 0;  // range delete masks the memtable value
-      mem_val = *v;  // copy under the lock; the chain may mutate after
-    } else {
-      runs_copy = e->runs[cf];
-    }
-  }
-  std::string run_val;
-  const std::string* v = (r == Res::HIT) ? &mem_val : nullptr;
-  if (r == Res::MISS) {
-    // newest run first; a hit or tombstone in a newer run masks older ones
-    for (const auto& run : runs_copy) {
-      int rr = run_get(*run, k, snap_seq, &run_val, &v_seq, &e->perf);
-      if (rr < 0) return -3;
-      if (rr == 2) return 0;  // tombstone
-      if (rr == 1) {
-        v = &run_val;
-        r = Res::HIT;
-        break;
-      }
-    }
-  }
-  if (r != Res::HIT) return 0;
-  if (rts >= v_seq) return 0;  // range delete masks the run value
-  *out = static_cast<uint8_t*>(malloc(v->size()));
-  memcpy(*out, v->data(), v->size());
-  *out_len = v->size();
+  std::vector<std::string> ks{std::string(reinterpret_cast<const char*>(key), klen)};
+  std::vector<std::string> vals;
+  std::vector<Res> res;
+  if (get_many(e, cf, snap_seq, ks, &vals, &res) != 0) return -3;
+  if (res[0] != Res::HIT) return 0;
+  *out = static_cast<uint8_t*>(malloc(vals[0].size()));
+  memcpy(*out, vals[0].data(), vals[0].size());
+  *out_len = vals[0].size();
   return 1;
 }
 
@@ -2311,6 +2348,93 @@ int eng_seek(void* h, int cf, uint64_t snap_seq, const uint8_t* target,
   memcpy(*vout, v.data(), v.size());
   *vout_len = v.size();
   return 1;
+}
+
+// eng_get of n keys at one snapshot, in one crossing and one hold of the
+// shared lock (get_many).  Keys in: repeated (klen u32 | key).  Out, a frame
+// a key in order: (vlen u32 | val), or kAbsent.  Returns n, <0 on error;
+// caller eng_free.
+long eng_multi_get(void* h, int cf, uint64_t snap_seq, const uint8_t* keys,
+                   uint64_t keys_len, uint64_t n, uint8_t** out,
+                   uint64_t* out_len) {
+  ENG_OR(guard::kClosed);
+  if (cf < 0 || cf >= kNumCfs) return -2;
+  std::vector<std::string> ks, vals;
+  std::vector<Res> res;
+  if (!parse_keys(keys, keys_len, n, &ks)) return -4;
+  if (get_many(e, cf, snap_seq, ks, &vals, &res) != 0) return -3;
+  std::string buf;
+  for (uint64_t i = 0; i < n; i++) {
+    if (res[i] != Res::HIT) {
+      append_u32(buf, kAbsent);
+      continue;
+    }
+    append_u32(buf, static_cast<uint32_t>(vals[i].size()));
+    buf.append(vals[i]);
+  }
+  return hand_out(buf, static_cast<long>(n), out, out_len);
+}
+
+// For each of n user keys, what eng_seek gives at user_key ++ desc(ts)
+// within [lower, upper) if its user-key part (all but the last 8 bytes) is
+// user_key: the key's newest version at or below ts.  Only that key's
+// versions can answer, so each merge is bounded to them; the memtable walks
+// of all n are taken under ONE hold of the shared lock (a key with more
+// versions than a walk's cap continues as eng_seek's chunked merge does).
+// Keys in: repeated (klen u32 | key).  Out, a frame a key in order:
+// (klen u32 | key | vlen u32 | val), or kAbsent.  Returns n, <0 on error;
+// caller eng_free.
+long eng_multi_seek_newest(void* h, int cf, uint64_t snap_seq,
+                           const uint8_t* keys, uint64_t keys_len, uint64_t n,
+                           uint64_t ts, const uint8_t* lower,
+                           uint64_t lower_len, const uint8_t* upper,
+                           uint64_t upper_len, int has_upper, uint8_t** out,
+                           uint64_t* out_len) {
+  ENG_OR(guard::kClosed);
+  if (cf < 0 || cf >= kNumCfs) return -2;
+  std::vector<std::string> ks;
+  if (!parse_keys(keys, keys_len, n, &ks)) return -4;
+  std::string lo(reinterpret_cast<const char*>(lower), lower_len);
+  std::string up(reinterpret_cast<const char*>(upper), upper_len);
+  char desc[8];
+  for (int b = 0; b < 8; b++)
+    desc[b] = static_cast<char>((~ts >> (56 - 8 * b)) & 0xFF);
+  constexpr uint64_t kVersionsWalk = 64;  // memtable entries a key, locked
+  std::vector<std::string> starts(n), ends(n);
+  std::vector<MergeIter> its(n);
+  {
+    std::shared_lock lk(e->mu);
+    for (uint64_t i = 0; i < n; i++) {
+      std::string tg = ks[i] + std::string(desc, 8);
+      starts[i] = tg < lo ? lo : tg;
+      // past the key's oldest possible version; nothing beyond has it as
+      // its user-key part
+      ends[i] = ks[i] + std::string(8, '\xff') + std::string(1, '\0');
+      if (has_upper && up < ends[i]) ends[i] = up;
+      its[i].mem_cap = kVersionsWalk;
+      its[i].mem_bytes_cap = kMemChunkBytes;
+      its[i].init(e, cf, snap_seq, starts[i], ends[i], true);
+    }
+  }
+  std::string buf, k, v;
+  for (uint64_t i = 0; i < n; i++) {
+    bool found = its[i].next(&k, &v);
+    if (!found && its[i].truncated) {
+      ChunkedMerge cm(e, cf, snap_seq, its[i].resume_key, ends[i], true,
+                      kVersionsWalk * 4);
+      found = cm.next(&k, &v);
+    }
+    if (!found || k.size() != ks[i].size() + 8 ||
+        k.compare(0, ks[i].size(), ks[i]) != 0) {
+      append_u32(buf, kAbsent);
+      continue;
+    }
+    append_u32(buf, static_cast<uint32_t>(k.size()));
+    buf.append(k);
+    append_u32(buf, static_cast<uint32_t>(v.size()));
+    buf.append(v);
+  }
+  return hand_out(buf, static_cast<long>(n), out, out_len);
 }
 
 void eng_free(uint8_t* p) { free(p); }

@@ -102,6 +102,20 @@ def _load():
             ctypes.POINTER(u8p), ctypes.POINTER(ctypes.c_uint64),
         ]
         lib.eng_seek.restype = ctypes.c_int
+        lib.eng_multi_get.argtypes = [
+            ctypes.c_void_p, ctypes.c_int, ctypes.c_uint64,
+            ctypes.c_char_p, ctypes.c_uint64, ctypes.c_uint64,
+            ctypes.POINTER(u8p), ctypes.POINTER(ctypes.c_uint64),
+        ]
+        lib.eng_multi_get.restype = ctypes.c_long
+        lib.eng_multi_seek_newest.argtypes = [
+            ctypes.c_void_p, ctypes.c_int, ctypes.c_uint64,
+            ctypes.c_char_p, ctypes.c_uint64, ctypes.c_uint64, ctypes.c_uint64,
+            ctypes.c_char_p, ctypes.c_uint64,
+            ctypes.c_char_p, ctypes.c_uint64, ctypes.c_int,
+            ctypes.POINTER(u8p), ctypes.POINTER(ctypes.c_uint64),
+        ]
+        lib.eng_multi_seek_newest.restype = ctypes.c_long
         lib.eng_free.argtypes = [u8p]
         lib.eng_stats_keys.argtypes = [ctypes.c_void_p, ctypes.c_int]
         lib.eng_stats_keys.restype = ctypes.c_uint64
@@ -197,6 +211,45 @@ def parse_frames(buf: bytes, n: int):
         v = buf[off : off + vlen]
         off += vlen
         yield k, v
+
+
+# what a batched read's frame holds in place of a length for a key it did not
+# find (engine.cc: kAbsent)
+_ABSENT = 0xFFFFFFFF
+
+
+def _frame_keys(keys) -> bytes:
+    """The keys of a batched read as the native side takes them: repeated
+    (klen u32le | key)."""
+    pack = _U32.pack
+    return b"".join([part for k in keys for part in (pack(len(k)), k)])
+
+
+def _parse_batch(buf: bytes, n: int, pairs: bool) -> list:
+    """The answers of a batched read, one a key in order: its value (``pairs``
+    false: eng_multi_get's frames, vlen u32le | val) or its (key, value)
+    (``pairs`` true: eng_multi_seek_newest's, klen | key | vlen | val), or
+    None where the frame's first length is ``_ABSENT``."""
+    unpack = _U32.unpack_from
+    out = []
+    ap = out.append
+    off = 0
+    for _ in range(n):
+        (ln,) = unpack(buf, off)
+        off += 4
+        if ln == _ABSENT:
+            ap(None)
+            continue
+        first = buf[off : off + ln]
+        off += ln
+        if pairs:
+            (ln,) = unpack(buf, off)
+            off += 4
+            ap((first, buf[off : off + ln]))
+            off += ln
+        else:
+            ap(first)
+    return out
 
 
 def frame_spans(buf: bytes, n: int) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
@@ -390,6 +443,40 @@ class NativeSnapshot(Snapshot):
         if r < 0:
             raise _failed("eng_get", r)
         return None
+
+    def multi_get_cf(self, cf: str, keys: list[bytes]) -> list[bytes | None]:
+        """``get_cf`` of every key in one crossing (eng_multi_get)."""
+        out = ctypes.POINTER(ctypes.c_uint8)()
+        out_len = ctypes.c_uint64()
+        frames = _frame_keys(keys)
+        n = self._lib.eng_multi_get(
+            self._handle, _CF_IDS[cf], self._seq, frames, len(frames),
+            len(keys), ctypes.byref(out), ctypes.byref(out_len),
+        )
+        if n < 0:
+            raise _failed("eng_multi_get", n)
+        buf = _take(self._lib, out, out_len.value)
+        # the values' bytes: every frame but its length
+        self._engine._io(IoType.FOREGROUND_READ, len(buf) - 4 * n)
+        return _parse_batch(buf, n, pairs=False)
+
+    def newest_versions_cf(self, cf: str, user_keys: list[bytes], ts: int,
+                           lower: bytes | None = None,
+                           upper: bytes | None = None) -> list[tuple[bytes, bytes] | None]:
+        """The trait's answer in one crossing (eng_multi_seek_newest)."""
+        out = ctypes.POINTER(ctypes.c_uint8)()
+        out_len = ctypes.c_uint64()
+        frames = _frame_keys(user_keys)
+        lower = lower or b""
+        n = self._lib.eng_multi_seek_newest(
+            self._handle, _CF_IDS[cf], self._seq, frames, len(frames),
+            len(user_keys), ts, lower, len(lower),
+            upper or b"", len(upper or b""), 1 if upper is not None else 0,
+            ctypes.byref(out), ctypes.byref(out_len),
+        )
+        if n < 0:
+            raise _failed("eng_multi_seek_newest", n)
+        return _parse_batch(_take(self._lib, out, out_len.value), n, pairs=True)
 
     def cursor_cf(self, cf: str, lower: bytes | None = None, upper: bytes | None = None) -> Cursor:
         return _NativeCursor(self, _CF_IDS[cf], lower, upper)

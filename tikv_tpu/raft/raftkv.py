@@ -130,12 +130,31 @@ class RegionSnapshot(Snapshot):
             return None
         return self._snap.get_cf(cf, dkey)
 
-    def cursor_cf(self, cf: str, lower: bytes | None = None, upper: bytes | None = None) -> Cursor:
+    def multi_get_cf(self, cf: str, ks: list[bytes]) -> list[bytes | None]:
+        """``get_cf`` of every key: one read of the engine for those inside
+        the region, None for the others."""
+        dkeys = [keys.data_key(k) for k in ks]
+        inside = [i for i, d in enumerate(dkeys) if self._lower <= d < self._upper]
+        out: list[bytes | None] = [None] * len(dkeys)
+        for i, v in zip(inside, self._snap.multi_get_cf(cf, [dkeys[i] for i in inside])):
+            out[i] = v
+        return out
+
+    def newest_versions_cf(self, cf: str, user_keys: list[bytes], ts: int,
+                           lower: bytes | None = None, upper: bytes | None = None):
+        """The trait's answer under ``cursor_cf``'s bounds, z prefix stripped."""
+        lo, hi = self._bounds(lower, upper)
+        found = self._snap.newest_versions_cf(
+            cf, [keys.data_key(k) for k in user_keys], ts, lo, hi)
+        return [None if f is None else (keys.origin_key(f[0]), f[1]) for f in found]
+
+    def _bounds(self, lower: bytes | None, upper: bytes | None) -> tuple[bytes, bytes]:
         lo = keys.data_key(lower) if lower is not None else self._lower
         hi = keys.data_key(upper) if upper is not None else self._upper
-        lo = max(lo, self._lower)
-        hi = min(hi, self._upper)
-        return _PrefixCursor(self._snap.cursor_cf(cf, lo, hi))
+        return max(lo, self._lower), min(hi, self._upper)
+
+    def cursor_cf(self, cf: str, lower: bytes | None = None, upper: bytes | None = None) -> Cursor:
+        return _PrefixCursor(self._snap.cursor_cf(cf, *self._bounds(lower, upper)))
 
 
 class RaftKv(Engine):

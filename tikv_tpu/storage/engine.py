@@ -17,6 +17,8 @@ from __future__ import annotations
 import abc
 from typing import Iterator
 
+from ..util.codec import encode_u64_desc
+
 CF_DEFAULT = "default"
 CF_LOCK = "lock"
 CF_WRITE = "write"
@@ -91,6 +93,37 @@ class Snapshot(abc.ABC):
         b.sequence())`` therefore proves that both read the same ``cf``
         (docs/write_path.md).  None where the engine cannot say."""
         return None
+
+    def multi_get_cf(self, cf: str, keys: list[bytes]) -> list[bytes | None]:
+        """``get_cf`` of every key, in order.  An engine that can answer them
+        in one call overrides this default, which asks once a key."""
+        return [self.get_cf(cf, k) for k in keys]
+
+    def newest_versions_cf(
+        self,
+        cf: str,
+        user_keys: list[bytes],
+        ts: int,
+        lower: bytes | None = None,
+        upper: bytes | None = None,
+    ) -> list[tuple[bytes, bytes] | None]:
+        """For each user key, in order: the first (key, value) of ``cf`` at or
+        after ``user_key ++ desc(ts)`` within [lower, upper) if that key is
+        the user key and an 8-byte version suffix, else None; for CF_WRITE
+        that is the key's newest version at or below ``ts``
+        (``MvccReader.seek_write``).  An engine that can answer them in one
+        call overrides this default, which seeks a cursor once a key."""
+        suffix = encode_u64_desc(ts)
+        cur = self.cursor_cf(cf, lower, upper)
+        out: list[tuple[bytes, bytes] | None] = []
+        for uk in user_keys:
+            found = None
+            if cur.seek(uk + suffix):
+                k = cur.key()
+                if len(k) == len(uk) + 8 and k.startswith(uk):
+                    found = (k, cur.value())
+            out.append(found)
+        return out
 
     def scan_cf(
         self,

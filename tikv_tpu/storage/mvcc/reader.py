@@ -120,6 +120,12 @@ class MvccReader:
         raw = self.snap.get_cf(CF_LOCK, key.encoded)
         return Lock.from_bytes(raw) if raw is not None else None
 
+    def load_locks(self, keys: list[Key]) -> list[Lock | None]:
+        """``load_lock`` of every key, in one read of the snapshot."""
+        self.stats.lock.get += len(keys)
+        raws = self.snap.multi_get_cf(CF_LOCK, [k.encoded for k in keys])
+        return [Lock.from_bytes(r) if r is not None else None for r in raws]
+
     def scan_locks(
         self,
         start: Key | None,
@@ -151,6 +157,13 @@ class MvccReader:
         if user_key != key.encoded:
             return None
         return commit_ts, Write.from_bytes(cur.value())
+
+    def seek_writes(self, keys: list[Key], ts: int) -> list[tuple[int, Write] | None]:
+        """``seek_write(key, ts)`` of every key, in one read of the snapshot."""
+        self.stats.write.seek += len(keys)
+        found = self.snap.newest_versions_cf(CF_WRITE, [k.encoded for k in keys], ts)
+        return [None if f is None else (split_ts(f[0])[1], Write.from_bytes(f[1]))
+                for f in found]
 
     def get_txn_commit_record(self, key: Key, start_ts: int) -> list[tuple[int, Write]]:
         """All writes of txn ``start_ts`` on ``key`` (commit/rollback search)."""

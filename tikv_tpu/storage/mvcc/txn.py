@@ -115,14 +115,18 @@ def prewrite_key(
     mutation: Mutation,
     ctx: PrewriteContext,
     is_pessimistic_lock: bool = False,
+    *,
+    lock: Lock | None,
+    newest: tuple[int, Write] | None,
 ) -> int:
     """Prewrite one mutation. Returns min_commit_ts for async commit (0 else).
 
     ``is_pessimistic_lock``: this key was locked by AcquirePessimisticLock
-    earlier in the same txn (pessimistic prewrite path).
+    earlier in the same txn (pessimistic prewrite path).  ``lock`` and
+    ``newest``: the key's ``load_lock`` and ``seek_write(key, MAX_TS)``, which
+    the command reads for all its keys at once.
     """
     key = mutation.key
-    lock = reader.load_lock(key)
     if lock is not None:
         if lock.ts != ctx.start_ts:
             if ctx.is_pessimistic and is_pessimistic_lock:
@@ -136,24 +140,22 @@ def prewrite_key(
         raise PessimisticLockNotFoundError(f"pessimistic lock missing on {key!r}")
 
     skip_conflict_check = ctx.is_pessimistic and is_pessimistic_lock
-    if not skip_conflict_check:
-        rec = reader.seek_write(key, MAX_TS)
-        if rec is not None:
-            commit_ts, write = rec
-            if commit_ts >= ctx.start_ts:
-                # a commit above us: write conflict (optimistic) — except a
-                # rollback of our own ts, which means we were rolled back
-                raise WriteConflictError(key.to_raw(), ctx.start_ts, write.start_ts, commit_ts)
-        if mutation.should_not_exists():
-            _check_not_exists(reader, key, ctx.start_ts)
-    else:
-        if mutation.should_not_exists():
-            _check_not_exists(reader, key, ctx.start_ts)
-
-    # our own rollback marker ⇒ the txn has been rolled back already
-    for commit_ts, write in reader.get_txn_commit_record(key, ctx.start_ts):
-        if write.write_type == WriteType.ROLLBACK:
-            raise WriteConflictError(key.to_raw(), ctx.start_ts, ctx.start_ts, commit_ts)
+    if not skip_conflict_check and newest is not None:
+        commit_ts, write = newest
+        if commit_ts >= ctx.start_ts:
+            # a commit above us: write conflict (optimistic) — except a
+            # rollback of our own ts, which means we were rolled back
+            raise WriteConflictError(key.to_raw(), ctx.start_ts, write.start_ts, commit_ts)
+    if mutation.should_not_exists():
+        _check_not_exists(reader, key, newest)
+    if skip_conflict_check:
+        # our own rollback marker ⇒ the txn has been rolled back already
+        # (past the conflict check there is none to find: every record is
+        # older than start_ts, and this txn's commit or rollback is not;
+        # prewrite.rs decides both from the one seek)
+        for commit_ts, write in reader.get_txn_commit_record(key, ctx.start_ts):
+            if write.write_type == WriteType.ROLLBACK:
+                raise WriteConflictError(key.to_raw(), ctx.start_ts, ctx.start_ts, commit_ts)
 
     if mutation.mutation_type.value == "check_not_exists":
         return 0
@@ -182,8 +184,9 @@ def prewrite_key(
     return min_commit_ts
 
 
-def _check_not_exists(reader: MvccReader, key: Key, start_ts: int) -> None:
-    rec = reader.seek_write(key, MAX_TS)
+def _check_not_exists(reader: MvccReader, key: Key, rec: tuple[int, Write] | None) -> None:
+    """AlreadyExists where the key's newest PUT or DELETE, walking down from
+    its newest record ``rec``, is a PUT."""
     while rec is not None:
         commit_ts, write = rec
         if write.write_type == WriteType.PUT:
@@ -244,8 +247,16 @@ def acquire_pessimistic_lock(
 # commit (actions/commit.rs)
 # ---------------------------------------------------------------------------
 
-def commit_key(txn: MvccTxn, reader: MvccReader, key: Key, start_ts: int, commit_ts: int) -> Lock | None:
-    lock = reader.load_lock(key)
+# a read the caller did not make for the action: the action makes it
+_READ = object()
+
+
+def commit_key(txn: MvccTxn, reader: MvccReader, key: Key, start_ts: int,
+               commit_ts: int, lock=_READ) -> Lock | None:
+    """``lock``: the key's ``load_lock`` where the caller read it for all its
+    keys at once."""
+    if lock is _READ:
+        lock = reader.load_lock(key)
     if lock is None or lock.ts != start_ts:
         # committed already? look for the write record
         for cts, w in reader.get_txn_commit_record(key, start_ts):

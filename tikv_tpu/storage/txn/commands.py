@@ -11,8 +11,10 @@ producing (WriteBatch, result) — executed by the Scheduler under latches.
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 
+from ...util.metrics import REGISTRY
 from ..engine import Snapshot
 from ..mvcc.reader import IsolationLevel, KeyIsLockedError, MvccReader, WriteConflictError
 from ..mvcc.txn import (
@@ -27,7 +29,51 @@ from ..mvcc.txn import (
     prewrite_key,
     rollback_key,
 )
-from ..txn_types import Key, Lock, Mutation, WriteType
+from ..txn_types import MAX_TS, Key, Lock, Mutation, WriteType
+
+_ACTIONS_SECONDS = REGISTRY.counter(
+    "tikv_storage_txn_actions_seconds_total",
+    "Wall time of prewrite's and commit's process_write, by command")
+_ACTIONS_KEYS = REGISTRY.counter(
+    "tikv_storage_txn_actions_keys_total",
+    "Keys through prewrite's and commit's process_write, by command")
+_BATCHED_READ_KEYS = REGISTRY.counter(
+    "tikv_storage_txn_batched_read_keys_total",
+    "Keys of prewrite and commit by how their action read the engine: "
+    "batch = its command's batched reads alone, walk = point reads besides")
+
+
+class _ActionReads:
+    """What a prewrite's or commit's keys cost: the wall time of the
+    command's process_write, and how many of its keys needed point reads of
+    their own beyond the command's batched ones (a key whose action moved
+    the reader's lock gets or write seeks)."""
+
+    def __init__(self, cmd: str, reader: MvccReader):
+        self.cmd = cmd
+        self.stats = reader.stats
+        self.t0 = time.perf_counter()
+        self.keys = self.walked = self.seen = 0
+
+    def _reads(self) -> int:
+        return self.stats.lock.get + self.stats.write.seek
+
+    def next_key(self) -> None:
+        """Called before each key's action, after the batched reads: the
+        point reads since the last call were the previous key's."""
+        seen = self._reads()
+        if self.keys and seen != self.seen:
+            self.walked += 1
+        self.seen = seen
+        self.keys += 1
+
+    def done(self) -> None:
+        if self.keys and self._reads() != self.seen:
+            self.walked += 1
+        _ACTIONS_SECONDS.inc(time.perf_counter() - self.t0, cmd=self.cmd)
+        _ACTIONS_KEYS.inc(self.keys, cmd=self.cmd)
+        _BATCHED_READ_KEYS.inc(self.keys - self.walked, cmd=self.cmd, how="batch")
+        _BATCHED_READ_KEYS.inc(self.walked, cmd=self.cmd, how="walk")
 
 
 class Command:
@@ -70,6 +116,7 @@ class Prewrite(Command):
     def process_write(self, snapshot: Snapshot):
         txn = MvccTxn(self.start_ts)
         reader = MvccReader(snapshot)
+        acts = _ActionReads("prewrite", reader)
         ctx = PrewriteContext(
             primary=self.primary,
             start_ts=self.start_ts,
@@ -82,13 +129,21 @@ class Prewrite(Command):
         )
         min_commit_ts = 0
         errors: list[Exception] = []
-        for i, m in enumerate(self.mutations):
-            flag = self.pessimistic_flags[i] if i < len(self.pessimistic_flags) else False
-            try:
-                ts = prewrite_key(txn, reader, m, ctx, is_pessimistic_lock=flag)
-                min_commit_ts = max(min_commit_ts, ts)
-            except (KeyIsLockedError, WriteConflictError, TxnError) as e:
-                errors.append(e)
+        keys = [m.key for m in self.mutations]
+        try:
+            locks = reader.load_locks(keys)
+            newest = reader.seek_writes(keys, MAX_TS)
+            for i, m in enumerate(self.mutations):
+                flag = self.pessimistic_flags[i] if i < len(self.pessimistic_flags) else False
+                acts.next_key()
+                try:
+                    ts = prewrite_key(txn, reader, m, ctx, is_pessimistic_lock=flag,
+                                      lock=locks[i], newest=newest[i])
+                    min_commit_ts = max(min_commit_ts, ts)
+                except (KeyIsLockedError, WriteConflictError, TxnError) as e:
+                    errors.append(e)
+        finally:
+            acts.done()
         if errors:
             # keys that prewrote fine stay locked (the reference persists the
             # successful locks alongside the KeyError vec; the client retries
@@ -111,8 +166,14 @@ class Commit(Command):
     def process_write(self, snapshot: Snapshot):
         txn = MvccTxn(self.start_ts)
         reader = MvccReader(snapshot)
-        for k in self.keys:
-            commit_key(txn, reader, k, self.start_ts, self.commit_ts)
+        acts = _ActionReads("commit", reader)
+        try:
+            locks = reader.load_locks(self.keys)
+            for k, lock in zip(self.keys, locks):
+                acts.next_key()
+                commit_key(txn, reader, k, self.start_ts, self.commit_ts, lock=lock)
+        finally:
+            acts.done()
         return txn, {"commit_ts": self.commit_ts}
 
 
