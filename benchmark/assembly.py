@@ -126,7 +126,10 @@ class Deployment:
                 "keys": [m["key"] for m in muts], "start_version": ts,
                 "commit_version": self.pd.get_tso()})
 
-    def split(self) -> None:
+    def split(self, to_table_end: bool = False) -> None:
+        """``to_table_end``: the last region's task covers the table's record
+        space to its end, where rows a writer appends land (a mix with a
+        refresh stream); otherwise it ends past the last loaded row."""
         from tikv_tpu.copr.table import record_key
 
         n, rpr = self.regions, self.rows_per_region
@@ -145,9 +148,19 @@ class Deployment:
                            for k in range(n)]
         if len(set(self.region_ids)) != n:
             raise RuntimeError(f"split left {self.region_ids} for {n} ranges")
-        # one task per region covers the region's whole range of handles
-        self.ranges = [(record_key(self.table_id, first[k]),
-                        record_key(self.table_id, first[k + 1])) for k in range(n)]
+        self.ranges = self.task_ranges(to_table_end)
+
+    def task_ranges(self, to_table_end: bool) -> list[tuple[bytes, bytes]]:
+        """One task per region covers the region's whole range of handles."""
+        from tikv_tpu.copr.table import record_key, record_range
+
+        n, rpr = self.regions, self.rows_per_region
+        first = [k * rpr + 1 for k in range(n + 1)]
+        ranges = [(record_key(self.table_id, first[k]),
+                   record_key(self.table_id, first[k + 1])) for k in range(n)]
+        if to_table_end:
+            ranges[-1] = (ranges[-1][0], record_range(self.table_id)[1])
+        return ranges
 
     def load(self) -> None:
         """Regions grow together, batch by batch (``chip_smoke.load_order``)."""
@@ -178,6 +191,23 @@ class Deployment:
             if [tuple(p) for p in r["pairs"]] != want:
                 raise RuntimeError(f"region {self.region_ids[k]} does not read "
                                    "back what was written")
+
+    def read_back(self, want: dict[int, dict[bytes, bytes | None]],
+                  batch: int = 2000) -> int:
+        """Every key of ``want`` (per region: the value a read must find, or
+        ``None`` for a key that must be absent) read through the socket at one
+        fresh timestamp; returns how many read otherwise."""
+        ts = self.pd.get_tso()
+        missing = 0
+        for k, keys in sorted(want.items()):
+            items = sorted(keys.items())
+            for s in range(0, len(items), batch):
+                part = items[s:s + batch]
+                r = self.call(self.region_ids[k], "kv_batch_get", {
+                    "keys": [key for key, _v in part], "version": ts})
+                got = {bytes(p[0]): bytes(p[1]) for p in r["pairs"] if p and p[1]}
+                missing += sum(got.get(key) != v for key, v in part)
+        return missing
 
     def cold_fill(self, dags) -> None:
         """One pass of each of ``dags`` over every region builds the image of

@@ -27,6 +27,15 @@ Traffic parameters (``benchmark/traffic/<mix>.json``):
   qgen makes one set for each query stream), ``"query"`` anew for each query.
 - ``prewarm``: ``{"alone": n, "together": m}``, what a run's first client
   sends before its window starts (``Traffic.prewarm``).
+- ``refresh`` (optional): ``{"orders": n, "transactions": t}``, TPC-H's
+  refresh stream beside the query streams: one writer, its own connections,
+  RF1/RF2 pairs (``refresh.py``) one after another until the window closes,
+  each transaction ``kv_prewrite`` then ``kv_commit`` with timestamps from PD,
+  its mutations made just before it is sent.  The job's ``first_pair`` is the
+  first pair's number: a run's clients go on where the last one stopped.
+
+A task answered ``locked`` is sent again at the same ``start_ts`` after TiDB's
+lock back-off, until the grace runs out; its ``lock_retries`` counts them.
 """
 
 from __future__ import annotations
@@ -48,6 +57,10 @@ import numpy as np  # noqa: E402
 
 CALL_TIMEOUT_S = 180.0  # a call that no deadline governs
 ANSWER_GRACE_S = 75.0   # an answer is waited for this long past the window's close
+# TiDB 5.1's back-off for a read that met a lock (client-go ``BoTxnLockFast``:
+# ``tidb_backoff_lock_fast`` = 10 ms, doubled each attempt, capped at 3 s;
+# its jitter is left out)
+LOCK_BACKOFF_S = (0.010, 3.0)
 
 
 class Conn:
@@ -100,6 +113,11 @@ def error_of(resp) -> dict | None:
     return resp.get("error") or resp.get("errors") or None
 
 
+def lock_backoff(attempt: int) -> float:
+    base, cap = LOCK_BACKOFF_S
+    return min(cap, base * 2 ** attempt)
+
+
 class Traffic:
     def __init__(self, job: dict):
         self.job = job
@@ -117,6 +135,8 @@ class Traffic:
         self.queries: list[dict] = []
         self.answers: dict[str, str] = {}
         self.errors: list[str] = []
+        self.txns: list[dict] = []
+        self.pairs: list[dict] = []
         self.t0 = 0.0
         self.give_up_at = float("inf")
 
@@ -193,12 +213,16 @@ class Traffic:
                 ts = tso(pd)
                 tasks = [{"region": k} for k in range(len(self.region_ids))]
                 waiting: dict[int, dict] = {}
-                for task in tasks:
+
+                def send(task):
                     k = task["region"]
                     rid = store.send("coprocessor", {
                         "dag": dag, "ranges": [self.ranges[k]], "start_ts": ts,
                         "context": {"region_id": self.region_ids[k]}})
                     waiting[rid] = task
+
+                for task in tasks:
+                    send(task)
                 while waiting:
                     try:
                         rid, resp = store.recv(until=self.give_up_at)
@@ -211,6 +235,17 @@ class Traffic:
                         return
                     task = waiting.pop(rid)
                     err = error_of(resp)
+                    n = task.get("lock_retries", 0)
+                    pause = lock_backoff(n)
+                    if (isinstance(err, dict) and "locked" in err
+                            and time.perf_counter() + pause < self.give_up_at):
+                        # as TiDB's client does: back off, then the same task
+                        # again at the same start_ts (the query waits for it
+                        # anyway; the other answers wait in the socket)
+                        task["lock_retries"] = n + 1
+                        time.sleep(pause)
+                        send(task)
+                        continue
                     if err is not None:
                         task["error"] = repr(err)[:300]
                         continue
@@ -235,6 +270,58 @@ class Traffic:
             store.close()
             pd.close()
 
+    def refresh_stream(self, ref) -> None:
+        """RF1/RF2 pairs one after another, as TPC-H's refresh stream runs
+        them, from the job's ``first_pair`` until the window closes.  A failed
+        write ends the run."""
+        store, pd = Conn(self.job["store"]), Conn(self.job["pd"])
+        try:
+            i = int(self.job["first_pair"])
+            while time.perf_counter() - self.t0 < self.seconds and not self.stop:
+                started = time.perf_counter() - self.t0
+                for txn in ref.pair(i):
+                    self.commit(store, pd, txn, ref.mutations(txn))
+                with self.mu:
+                    self.pairs.append({"pair": i, "started": started,
+                                       "done": time.perf_counter() - self.t0})
+                i += 1
+        except Exception as e:  # noqa: BLE001 - reported in the log, run fails
+            with self.mu:
+                self.errors.append(f"refresh stream: {e!r}")
+                self.stop = True
+        finally:
+            store.close()
+            pd.close()
+
+    def commit(self, store: Conn, pd: Conn, txn: dict, muts: list) -> None:
+        """Percolator's two phases as a TiDB session sends them for one
+        region's transaction: prewrite every key (the first is the primary),
+        a commit timestamp from PD, commit every key."""
+        ctx = {"region_id": self.region_ids[int(txn["region"])]}
+        start_ts = tso(pd)
+        sent = time.perf_counter() - self.t0
+        r = store.call("kv_prewrite", {
+            "mutations": muts, "primary_lock": muts[0]["key"],
+            "start_version": start_ts, "context": ctx})
+        if error_of(r) is not None:
+            raise RuntimeError(f"prewrite of {txn['function']} pair {txn['pair']} "
+                               f"txn {txn['txn']}: {error_of(r)!r}"[:400])
+        commit_ts = tso(pd)
+        r = store.call("kv_commit", {
+            "keys": [m["key"] for m in muts], "start_version": start_ts,
+            "commit_version": commit_ts, "context": ctx})
+        if error_of(r) is not None:
+            raise RuntimeError(f"commit of {txn['function']} pair {txn['pair']} "
+                               f"txn {txn['txn']}: {error_of(r)!r}"[:400])
+        acked = time.perf_counter() - self.t0
+        with self.mu:
+            self.txns.append({
+                "function": txn["function"], "pair": int(txn["pair"]),
+                "txn": int(txn["txn"]), "region": int(txn["region"]),
+                "start_ts": start_ts, "commit_ts": commit_ts,
+                "sent": sent, "acked": acked,
+                "handles": [int(h) for h in txn["handles"]]})
+
     def record(self, s, plan, params, ts, t_issue, tasks) -> None:
         with self.mu:
             self.queries.append({
@@ -245,6 +332,16 @@ class Traffic:
     def run(self) -> dict:
         threads = [threading.Thread(target=self.query_stream, args=(s,))
                    for s in range(int(self.mix["query_streams"]))]
+        refresh = "refresh" in self.mix
+        if refresh:
+            from benchmark import table as tbl
+            from benchmark.refresh import Refresh
+
+            rpr = int(self.job["rows_per_region"])
+            ref = Refresh(self.mix["refresh"], self.seed, self.table_id,
+                          len(self.region_ids) * rpr, rpr, len(self.region_ids))
+            tbl.text_pool()  # RF1's comments are cut from it
+            threads.append(threading.Thread(target=self.refresh_stream, args=(ref,)))
         if self.job.get("prewarm"):
             self.prewarm()
         print("START", flush=True)
@@ -254,13 +351,16 @@ class Traffic:
             t.start()
         for t in threads:
             t.join()
-        return {
+        log = {
             "seed": self.seed, "seconds": self.seconds,
             "closed_after": time.perf_counter() - self.t0,
             "queries": self.queries,
             "answers": {d: json.loads(a) for d, a in self.answers.items()},
             "errors": self.errors,
         }
+        if refresh:
+            log.update(txns=self.txns, pairs=self.pairs)
+        return log
 
 
 def main(argv) -> int:

@@ -108,6 +108,10 @@ class Run:
         self.dep = Deployment(self.config, self.seed)
         self.child = None
         self.n_clients = 0
+        # a mix with a refresh stream: what it wrote, warm-ups included
+        self.refresh = None
+        self.txns: list[dict] = []
+        self.pairs_run = 0
 
     # -- the client process --------------------------------------------------
 
@@ -119,6 +123,8 @@ class Run:
         log_path = os.path.join(self.dep.tmp, f"client-{self.n_clients}.json")
         job = dict(self.dep.job(), traffic=self.mix, seed=self.seed,
                    seconds=seconds, log=log_path, prewarm=self.n_clients == 1)
+        if self.refresh is not None:
+            job["first_pair"] = self.pairs_run
         job_path = log_path + ".job"
         with open(job_path, "w") as f:
             json.dump(job, f)
@@ -144,6 +150,8 @@ class Run:
         log = load_json(log_path)
         if log["errors"]:
             raise RuntimeError(f"the client process failed: {log['errors']}")
+        self.txns.extend(log.get("txns", []))
+        self.pairs_run += len(log.get("pairs", []))
         return log
 
     # -- set-up ----------------------------------------------------------------
@@ -155,8 +163,14 @@ class Run:
         dep = self.dep
         tbl.selfcheck(dep.table_id)
         dep.start()
-        dep.split()
+        dep.split(to_table_end="refresh" in self.mix)
         dep.load()
+        if "refresh" in self.mix:
+            from benchmark.refresh import Refresh
+
+            self.refresh = Refresh(
+                self.mix["refresh"], self.seed, dep.table_id,
+                dep.regions * dep.rows_per_region, dep.rows_per_region, dep.regions)
         # every image the mix's plans read, built before any stream asks
         fills = [check.plan_module(p) for p in self.mix["plans"]]
         dep.cold_fill([f.dag(dep.table_id, f.DEFAULTS) for f in fills])
@@ -247,12 +261,21 @@ class Run:
             "last_device_error": ep.last_device_error,
         }
         base = self.dep.base
+        missing = None
+        if self.refresh is not None:
+            # every acknowledged write read back before the store stops
+            t_rb = time.perf_counter()
+            missing = self.dep.read_back(self.refresh.final_state(self.txns))
+            read_back_s = time.perf_counter() - t_rb
         # the program's state goes before the reference runs
         self.dep.stop()
 
         t_ref = time.perf_counter()
-        held = check.compare(log, base, int(self.config["load_batch_rows"]))
+        held = check.compare(log, base, int(self.config["load_batch_rows"]),
+                             self.txns, self.refresh)
         ref_s = time.perf_counter() - t_ref
+        if missing is not None:
+            held["numbers"]["acked_rows_missing"] = missing
 
         work = {p: check.plan_module(p).work(self.dep.rows_per_region)
                 for p in self.mix["plans"]}
@@ -288,7 +311,8 @@ class Run:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
         numbers = held["numbers"]
-        compared, correct = check.judge(numbers)
+        compared, correct = check.judge(
+            numbers, check.WRITE_LIMITS if self.refresh is not None else None)
         attempted = numbers["compared"] + numbers["unanswered"]
         # A task answered from the CPU after a device fault, or turned away
         # from the device by an open breaker, was answered behind the
@@ -325,6 +349,9 @@ class Run:
                 for key in sorted(after_snap[name])
                 if after_snap[name][key] != before.get(name, {}).get(key, 0.0)},
         }
+        if self.refresh is not None:
+            result["detail"]["refresh"] = refresh_detail(
+                log, self.txns, held["states"], read_back_s)
         result["compared"] = compared
         return result
 
@@ -345,6 +372,28 @@ def by_plan(log: dict) -> dict:
         out.setdefault(q["plan"], []).append((q["done"] - q["issued"]) * 1e3)
     return {p: {"n": len(v), "p50": percentile(v, 50), "max": max(v)}
             for p, v in sorted(out.items())}
+
+
+def refresh_detail(log: dict, txns: list, states: int, read_back_s: float) -> dict:
+    """The refresh stream as the window saw it, for the reader of a run."""
+    from benchmark.reduce import commit_ms, percentile
+
+    ms = commit_ms(log)
+    pair_s = [p["done"] - p["started"] for p in log["pairs"]]
+    retries = [t["lock_retries"] for _q in log["queries"] for t in _q["tasks"]
+               if t.get("lock_retries")]
+    return {
+        "pairs": len(log["pairs"]), "txns": len(log["txns"]),
+        "txns_before_window": len(txns) - len(log["txns"]),
+        "rows_written": sum(len(t["handles"]) for t in log["txns"]),
+        "commit_p50_ms": percentile(ms, 50) if ms else None,
+        "commit_p95_ms": percentile(ms, 95) if ms else None,
+        "commit_max_ms": max(ms, default=None),
+        "pair_max_s": max(pair_s, default=None),
+        "lock_retries": sum(retries), "tasks_retried": len(retries),
+        "lock_retries_max": max(retries, default=0),
+        "states_compared": states, "read_back_s": read_back_s,
+    }
 
 
 def run_cell(args, device: dict, bench: dict, overrides=None) -> dict:

@@ -25,6 +25,7 @@ the program's own encoder and decoder.
 from __future__ import annotations
 
 import datetime
+import functools
 from dataclasses import dataclass, fields
 
 import numpy as np
@@ -65,8 +66,10 @@ _POOL_WORDS = (
     "after against along among around the of to").split()
 
 
+@functools.lru_cache(maxsize=1)
 def text_pool(size: int = 1 << 20) -> np.ndarray:
-    """The text comments are cut from: the same for every seed."""
+    """The text comments are cut from: the same for every seed (read-only,
+    made once a process)."""
     rng = np.random.default_rng(19920101)
     words = rng.choice(np.array(_POOL_WORDS), size=size // 5)
     return np.frombuffer(" ".join(words).encode()[:size], dtype=np.uint8)
@@ -118,18 +121,60 @@ class Table:
     def take(self, idx) -> "Table":
         return Table(*(getattr(self, f.name)[idx] for f in fields(self)))
 
+    @staticmethod
+    def concat(parts: list["Table"]) -> "Table":
+        return Table(*(np.concatenate([getattr(p, f.name) for p in parts])
+                       for f in fields(Table)))
+
+
+def order_key(order, slot: int = 0) -> np.ndarray:
+    """dbgen's sparse order keys (cl. 4.2.3): of every 32 keys the load uses
+    the first 8 (``slot`` 0); slots 1-3 are the gaps kept free for the update
+    sets' new orders (RF1)."""
+    order = np.asarray(order, dtype=np.int64)
+    return (order // 8) * 32 + 8 * slot + order % 8 + 1
+
+
+def order_of_key(key) -> np.ndarray:
+    """The order's place in its slot's sequence: ``order_key``'s inverse."""
+    key = np.asarray(key, dtype=np.int64) - 1
+    return (key // 32) * 8 + key % 8
+
 
 def build_table(n: int, seed: int, scale_factor: float | None = None) -> Table:
     """``n`` rows of LINEITEM as dbgen shapes them at ``scale_factor`` (by
     default the one at which the table has ``n`` rows)."""
     rng = np.random.default_rng([int(seed), 0])
     sf = scale_factor if scale_factor is not None else n / SF1_ROWS
-    parts = max(1, round(sf * 200_000))
-    supps = max(4, round(sf * 10_000))
+    lines = _draw_lines(rng, n)
+    return order_lines(rng, lines, np.arange(len(lines)), 0, 1, sf, n)
+
+
+def _draw_lines(rng, n: int) -> np.ndarray:
     n_orders = n // 4 + 64
     lines = rng.integers(1, 8, n_orders)
     while lines.sum() < n:
         lines = np.concatenate([lines, rng.integers(1, 8, n_orders)])
+    return lines
+
+
+def order_starts(n: int, seed: int) -> np.ndarray:
+    """The row at which each order of ``build_table(n, seed)`` starts, and
+    ``n`` after the last: its line counts alone, without building the rows."""
+    cum = np.concatenate([[0], np.cumsum(_draw_lines(np.random.default_rng([int(seed), 0]), n))])
+    cum = np.minimum(cum, n)
+    return cum[:int(np.searchsorted(cum, n)) + 1]
+
+
+def order_lines(rng, lines, order_ids, slot: int, first_handle: int,
+                sf: float, n: int | None = None) -> Table:
+    """The lines of orders ``order_ids`` (``lines[i]`` of order i, the first
+    ``n`` lines in all where ``n`` is given) by dbgen's rules, each order's
+    date and every line's values drawn from ``rng`` in this order; handles
+    count up from ``first_handle``."""
+    parts = max(1, round(sf * 200_000))
+    supps = max(4, round(sf * 10_000))
+    n = int(lines.sum()) if n is None else n
     order = np.repeat(np.arange(len(lines)), lines)[:n]
     first = np.concatenate([[0], np.cumsum(lines)[:-1]])
     orderdate = rng.integers(START_DAY, END_DAY - 151 + 1, len(lines))[order]
@@ -140,8 +185,8 @@ def build_table(n: int, seed: int, scale_factor: float | None = None) -> Table:
     receiptdate = shipdate + rng.integers(1, 31, n)
     returned = rng.integers(0, 2, n) * 2           # "A" or "R"
     return Table(
-        handle=np.arange(1, n + 1, dtype=np.int64),
-        orderkey=(order // 8) * 32 + order % 8 + 1,
+        handle=np.arange(first_handle, first_handle + n, dtype=np.int64),
+        orderkey=order_key(np.asarray(order_ids)[order], slot),
         partkey=partkey,
         suppkey=(partkey + rng.integers(0, 4, n)
                  * (supps // 4 + (partkey - 1) // supps)) % supps + 1,
