@@ -8,7 +8,8 @@ sanitizer gets in test_sanitizer.py.  The race test is the regression half:
 write-through folds hammering a region image while a client streams chunk
 responses off it over a real socket must stay byte-identical to the CPU
 oracle with ZERO violations, because the fixed tree copies-on-export
-(chunk slabs are immutable bytes) and defers pin patches to scatter_update.
+(chunk slabs are immutable bytes) and a fold makes a new version of the
+image instead of writing into the one a reader holds.
 """
 
 from __future__ import annotations
@@ -93,8 +94,8 @@ def test_strike_wire_part_mutated_before_send():
 
 
 def test_strike_device_pin_bypass_write():
-    """A host write that bypasses scatter_update while the array is pinned:
-    caught by the release verify at drop_device."""
+    """A host write into an array while it is pinned: caught by the
+    release verify at drop_device."""
     with sanitizer.force():
         cache = ColumnBlockCache()
         cache.add([], 0)
@@ -102,7 +103,7 @@ def test_strike_device_pin_bypass_write():
         host = np.arange(256, dtype=np.int64)
         cache.device_arrays(blk, ("striketest", 0), lambda b: (host,))
         assert bufsan.exposed_kinds() == {"device_pin": 1}
-        host[:3] = 7  # the strike: not routed through scatter_update
+        host[:3] = 7  # the strike: a write into a pinned array
         cache.drop_device()
         reps = bufsan.reports()
     assert len(reps) == 1
@@ -134,24 +135,6 @@ def test_note_mutation_excludes_device_pins():
         bufsan.note_mutation([arr], site="t.fold")
         assert not bufsan.reports()
         bufsan.clear()
-
-
-def test_scatter_update_reregisters_pins_no_false_positive():
-    """The real coordinated path: pin, mutate host, scatter_update patches
-    and re-registers — the later drop must verify clean."""
-    with sanitizer.force():
-        cache = ColumnBlockCache()
-        cache.add([], 0)
-        blk = cache.blocks[0]
-        host = np.arange(64, dtype=np.int64)
-        # unknown-kind sig: scatter_update drops (releases) it, and the
-        # release verify runs against the pre-mutation sample... so the
-        # coordinated order is mutate-AFTER-release here, like _apply_updates
-        cache.device_arrays(blk, ("striketest", 1), lambda b: (host,))
-        cache.scatter_update({})  # drops + releases the unknown-kind pin
-        host[:3] = -1  # host write lands after the pin released: legal
-        cache.drop_device()
-        assert not bufsan.reports()
 
 
 # ---------------------------------------------------------------------------
@@ -210,13 +193,13 @@ def test_disabled_is_noop():
 def test_wt_fold_races_chunk_serving_byte_identical():
     """4 client threads pull chunk responses off the warm image over a real
     socket while writer threads fold write-through deltas into the same
-    region — the fold's in-place column writes racing the ``sendmsg``
-    gather writes on the serve side.  The racing commits are IDEMPOTENT
+    region — the folds' new versions racing the ``sendmsg`` gather writes
+    on the serve side.  The racing commits are IDEMPOTENT
     (same row, same value, climbing commit ts), so every read at a high ts
     sees the same visible bytes: each warm-served chunk must byte-match the
     cold CPU oracle, real folds must have happened, and bufsan (armed for
-    the whole test) must stay silent because chunk slabs are copies and pin
-    patches defer to scatter_update."""
+    the whole test) must stay silent because chunk slabs are copies and a
+    fold writes into no version a reader holds."""
     BIG_TS = 1 << 40
     eng = LocalEngine(_engine(v2=True))
     warm = Endpoint(eng, enable_device=True)

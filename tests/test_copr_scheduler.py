@@ -483,10 +483,10 @@ def test_padding_budget_sheds_block_count_outlier():
 
 def test_aliased_image_slots_keep_snapshot_isolation():
     """Two requests over the SAME region at different start_ts around a
-    write: the region cache holds ONE mutable image per (region, ranges,
-    schema), so resolving the later request delta-applies it in place.
-    Only the last resolution may batch; the earlier one must shed and still
-    return the bytes its snapshot demands."""
+    write: the region cache holds ONE image per (region, ranges, schema),
+    and resolving the later request repairs it into a new version.  Only
+    the last resolution batches; the earlier one sheds and still returns
+    the bytes its snapshot demands."""
     rows = ROWS_PER * 2
     eng = _engine(rows, seed=13)
     dev = Endpoint(LocalEngine(eng), enable_device=True, block_rows=1024)

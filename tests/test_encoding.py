@@ -85,21 +85,6 @@ def test_real_columns_stay_plain():
 
 # -- in-place patch vs demote ------------------------------------------------
 
-def test_bitpack_patch_in_range_and_demote_out_of_range():
-    c = _col([10, 20, 30] * 100)
-    e = E._encode_one(c, 300)
-    assert e.try_patch(np.array([1]), np.array([25]), np.array([False]))
-    assert int(e.data[1]) == 25
-    # out of the int8 frame → encoding broken
-    assert not e.try_patch(np.array([2]), np.array([1 << 40]), np.array([False]))
-
-
-def test_rle_never_patches_in_place():
-    e = E._encode_one(_col([5] * 1000), 1000)
-    assert e.kind == "rle"
-    assert not e.try_patch(np.array([0]), np.array([6]), np.array([False]))
-
-
 def test_demote_column_counts_and_drops_pins():
     cache = _cache_with([[_col([1, 1, 1, 1])]], [4])
     E.encode_blocks(cache, None)
@@ -136,19 +121,6 @@ def test_encode_blocks_uniform_choice_and_dictionary():
     assert np.array_equal(dcol.data[:6], [1, 0, 2, 1, 0, 2])
     assert dcol.data.dtype == np.int8
 
-
-def test_ensure_code_capacity_widens_lanes():
-    codes = np.array([0, 1, 2], dtype=np.int8)
-    d = np.empty(3, dtype=object)
-    d[:] = [b"a", b"b", b"c"]
-    cache = _cache_with(
-        [[Column(EvalType.BYTES, codes, np.zeros(3, bool), 0, d)]], [3])
-    assert not E.ensure_code_capacity(cache.blocks, 0, 100)   # fits
-    assert E.ensure_code_capacity(cache.blocks, 0, 1 << 20)   # widens
-    assert cache.blocks[0].cols[0].data.dtype.itemsize >= 4
-
-
-# -- device plans / eligibility matrix --------------------------------------
 
 def _encoded_cache(seed=0, n=256):
     rng = np.random.default_rng(seed)

@@ -373,8 +373,8 @@ def _scan_would_say(warm, img, snap, ts) -> int:
     from tikv_tpu.storage.mvcc.reader import Statistics
 
     bare = RegionImage(img.key, img.epoch, img.schema, img.block_rows)
-    return warm.region_cache._check_locks(
-        bare, snap, list(img.key[1]), ts, Statistics())
+    return len(warm.region_cache._check_locks(
+        bare, snap, list(img.key[1]), ts, Statistics()))
 
 
 def test_memo_second_rising_hit_skips_the_scan():

@@ -124,22 +124,6 @@ def test_prune_blocks_matches_brute_force(seed):
             assert not hits.any(), (op, ci, const, bi)
 
 
-def test_fold_update_widens_and_marks_stale():
-    z = Z.ColumnZone(10, 20, 0, 0, 8)
-    zones = {0: z, 1: Z.ColumnZone(None, None, 8, 8, 8)}
-    Z.fold_update(zones, {0: (np.array([5, 30]), np.array([False, False])),
-                          1: (np.array([7, 7]), np.array([True, False]))})
-    assert (z.lo, z.hi) == (5, 30) and z.stale
-    assert z.null_lo == 0 and z.null_hi == 0
-    z1 = zones[1]
-    assert (z1.lo, z1.hi) == (7, 7)
-    assert z1.null_lo == 7 and z1.null_hi == 8  # one non-null write landed
-    # an object (decoded-bytes) write stops tracking that column
-    Z.fold_update(zones, {0: (np.array([b"x"], dtype=object),
-                              np.array([False]))})
-    assert 0 not in zones
-
-
 def _zblock(lo, hi, n, nulls=0):
     b = _Block(cols=[], n_valid=n)
     b.zones = {3: Z.ColumnZone(lo, hi, nulls, nulls, n)}

@@ -2,7 +2,7 @@
 
 The chunk wire path ships column slabs as zero-copy memoryview parts
 (``wire.dumps_parts`` → ``server.write_frame_parts`` → ``sendmsg``) while
-write-through delta folds and ``scatter_update`` mutate cached columns
+write-through delta folds make new versions of cached columns
 concurrently.  That is a *buffer lifetime* property no lock can express: a
 buffer handed to the kernel (or pinned on a device, or held for a shadow
 compare) must stay bit-stable until the hand-off completes.  This module is
@@ -19,11 +19,10 @@ Mechanics (docs/static_analysis.md has the design note):
 * :func:`release` pops the entry at the matching release boundary (send
   completion in ``write_frame_parts``, pin drop, shadow-compare finish) and
   re-hashes the sample: a mismatch means the buffer mutated while exposed.
-* :func:`note_mutation` is called from the mutation choke points
-  (``RegionImage._apply_updates``, block repack, ``scatter_update``) with
-  the arrays about to be written; any byte overlap with a live exposed
-  buffer is reported immediately — BEFORE the torn bytes can reach a
-  client.
+* :func:`note_mutation` is called from a mutation choke point with the
+  arrays about to be written in place; any byte overlap with a live
+  exposed buffer is reported immediately — BEFORE the torn bytes can reach
+  a client.
 
 Reports carry BOTH stacks (export + mutation/release), ride the lock
 sanitizer's report channel under kind ``buffer-mutation-while-exposed``,
@@ -34,10 +33,10 @@ check — the hot paths pay nothing beyond the call.
 False-positive policy: chunk column slabs are immutable ``bytes`` copies
 (``chunk_codec.encode_np_column`` joins), so legitimate serving never
 trips the verify; device pins are excluded from :func:`note_mutation`
-overlap checks because ``_apply_updates`` → ``scatter_update`` is the
-*coordinated* host-mutate-then-patch path (the pin sample is re-registered
-when the patch lands).  A pin whose sample fails at drop therefore means a
-host/device write bypassed the scatter path.
+overlap checks (a pin is re-registered wherever its arrays are replaced:
+``ColumnBlockCache.successor``).  A region image's fold writes into no
+array a reader may hold — it makes a new version — so a pin whose sample
+fails at drop means a host write behind the cache's back.
 """
 
 from __future__ import annotations
@@ -307,7 +306,7 @@ def release_parts(parts, site: str = "") -> None:
 def note_mutation(bufs, site: str = "") -> None:
     """Mutation choke point: ``bufs`` are about to take in-place writes.
     Any byte overlap with a live exposed buffer (device pins excepted —
-    scatter_update re-registers those after the coordinated patch) is a
+    they are re-registered wherever their arrays are replaced) is a
     violation, reported with the export stack AND this mutation stack."""
     if not _san.enabled():
         return

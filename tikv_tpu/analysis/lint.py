@@ -135,7 +135,7 @@ _BUFSAN_BOUNDARY = {
     "tikv_tpu/server/wire.py": ("dumps_parts",),
     "tikv_tpu/server/server.py": ("write_frame_parts",),
     "tikv_tpu/copr/dag.py": ("encode_parts",),
-    "tikv_tpu/copr/cache.py": ("device_arrays", "drop_device", "scatter_update"),
+    "tikv_tpu/copr/cache.py": ("device_arrays", "drop_device", "successor"),
 }
 _BUFSAN_MODULES = ("bufsan", "_bufsan")
 _BUFSAN_CALLS = {"export", "release", "release_parts", "note_mutation",

@@ -17,6 +17,7 @@ version (apply index / max commit ts), so any write produces a new key.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -48,6 +49,71 @@ def _entry_nbytes(entry) -> int:
                for leaf in jax.tree.leaves(tree))
 
 
+@functools.lru_cache(maxsize=1)
+def _set_rows_program():
+    import jax
+
+    def set_rows(stacks, rows, k):
+        return jax.tree.map(
+            lambda s, r: jax.lax.dynamic_update_index_in_dim(s, r, k, 0), stacks, rows)
+
+    return jax.jit(set_rows)
+
+
+def _set_rows(stacks, rows, i: int):
+    """``stacks`` (a tree of ``(B, ...)`` device arrays) with row ``i`` of
+    each replaced by the matching leaf of ``rows``: new device arrays in one
+    dispatch (the old ones are left whole for whoever holds them).  One
+    program per tree of shapes and dtypes, whatever ``i``."""
+    import jax
+
+    rows = jax.tree.map(lambda s, r: np.asarray(r).astype(s.dtype, copy=False),
+                        stacks, rows)
+    return _set_rows_program()(stacks, rows, np.int32(i))
+
+
+def _padded(arr, rows: int, fill) -> np.ndarray:
+    arr = np.asarray(arr)
+    if len(arr) == rows:
+        return arr
+    if arr.dtype == object:
+        ext = np.empty(rows - len(arr), dtype=object)
+        ext[:] = b""
+        return np.concatenate([arr, ext])
+    return np.concatenate([arr, np.full(rows - len(arr), fill, dtype=arr.dtype)])
+
+
+def _patch_plain_stack(entry, sig, blocks, rows: list):
+    """sig = ("stacked", ship_cols, nullable, block_rows): the rows of the
+    changed blocks, padded as ``jax_eval._stacked_device`` pads them."""
+    from .encoding import decoded_data, decoded_nulls
+
+    _, ship_cols, nullable, n = sig
+    for r in rows:
+        cols = blocks[r].cols
+        entry = _set_rows(entry, (
+            tuple(_padded(decoded_data(cols[ci]), n, 0) for ci in ship_cols),
+            tuple(_padded(decoded_nulls(cols[ci]), n, True) for ci in nullable)), r)
+    return entry
+
+
+def _patch_enc_stack(entry, sig, plan, blocks, rows: list):
+    """sig = ("stackedenc", ship, nullable, block_rows, plan sig, null sig):
+    the changed blocks' encoded payloads, as ``stack_block_payloads`` lays
+    them out."""
+    from .encoding import block_null_payload, block_payload
+
+    _, ship_cols, nullable, n = sig[:4]
+    stacks = (entry[0], entry[1])
+    for r in rows:
+        cols = blocks[r].cols
+        stacks = _set_rows(stacks, (
+            tuple(tuple(block_payload(cols[ci], n)) if plan.sig[j][0] == "rle"
+                  else block_payload(cols[ci], n) for j, ci in enumerate(ship_cols)),
+            tuple(block_null_payload(cols[ci], n) for ci in nullable)), r)
+    return stacks[0], stacks[1], entry[2]
+
+
 @dataclass
 class _Block:
     cols: list  # list[Column] (host)
@@ -74,6 +140,10 @@ class ColumnBlockCache:
         # demotion, code-lane widening) — the device-plan memo and the
         # encoded pin signatures key on it (copr/encoding.py)
         self.enc_version = 0
+        # a version made by ``successor``: its image takes writes, so
+        # layouts rebuilt from the whole image per version (the zone
+        # layout) are not worth building for it
+        self.folded = False
         self._mu = make_lock("copr.block_cache")
 
     def add(self, cols, n_valid: int) -> None:
@@ -113,9 +183,9 @@ class ColumnBlockCache:
 
             if added:
                 OBSERVATORY.note_pin(_pin_kind(sig), _entry_nbytes(built))
-                # pins are exposures: the host arrays behind them must only
-                # change through scatter_update (which re-registers); a pin
-                # whose sample fails at drop took a bypass write
+                # pins are exposures: the host arrays behind them never
+                # change (a fold makes a new version); a pin whose sample
+                # fails at drop took a write behind the cache's back
                 _bufsan.export("device_pin", built, site="cache.device_arrays")
             for old_sig, entry in dropped:
                 OBSERVATORY.note_pin(_pin_kind(old_sig), -_entry_nbytes(entry))
@@ -183,95 +253,77 @@ class ColumnBlockCache:
                 OBSERVATORY.note_pin(_pin_kind(sig), -_entry_nbytes(entry))
                 _bufsan.release(entry, site="cache.drop_device")
 
-    def scatter_update(self, updates: dict) -> None:
-        """Patch pinned device arrays in place after an in-place host update.
 
-        ``updates``: block_idx -> (row_positions int array, {col_idx:
-        (values ndarray, nulls ndarray)}).  Host column arrays must already
-        hold the new values.  Understands the two pinned layouts the
-        evaluators build — the per-cache stacked arrays and per-block column
-        lists — and patches them with ``.at[].set`` scatters (a device-side
-        op; the base arrays never round-trip to host).  Any other signature
-        (zone layouts, mesh ``shardslab`` stacks; nvoff is kept — row counts
-        are unchanged) is dropped so it rebuilds from the updated host
-        blocks on its owner device."""
-        from . import zone_maps as _zm
+    def successor(self, blocks: list, changed: set,
+                  account: bool = True) -> "ColumnBlockCache":
+        """The next version of this cache, over ``blocks``: the blocks whose
+        index is in ``changed`` are new objects, every other block is this
+        version's own (shared, pins and all, and never written again).  This
+        version stays whole for the readers that hold it.
 
-        released, repinned = [], []
+        The cache-wide pins live on block 0, which the successor always gets
+        as a fresh object: the stacked layouts (plain and encoded) carry over
+        with ONLY the changed blocks' rows re-placed on the device, one
+        dispatch per changed block (``_set_rows``: compiled once per layout);
+        a stacked pin whose block count or encoding no longer matches, the
+        n_valid/offset pin and every other layout drop and rebuild lazily.
+        Changed blocks start with no pins.  ``account``: the pins this
+        version gives up and the ones it re-places move the observatory's
+        accounting (not for a version made for one reader beside the
+        image's own)."""
+        from . import encoding as _enc
+
+        new = ColumnBlockCache(self.key)
+        new.enc_version = self.enc_version
+        new.filled = True
+        new.folded = True
+        old0 = self.blocks[0] if self.blocks else None
+        if blocks and old0 is not None and blocks[0] is old0:
+            blocks = [_Block(old0.cols, old0.n_valid, zones=old0.zones)] + list(blocks[1:])
+        new.blocks = list(blocks)
+        if old0 is None or not blocks:
+            return new
+        same_shape = len(blocks) == len(self.blocks)
+        rows_changed = sorted(i for i in changed if i < len(blocks))
+        kept, released, added = {}, [], []
         with self._mu:
-            for bi, blk in enumerate(self.blocks):
-                upd = updates.get(bi)
-                if upd is not None and blk.zones is not None:
-                    # widen the block's zone map with the incoming values —
-                    # stale-but-sound maintenance (docs/zone_maps.md); the
-                    # host columns already hold these values
-                    _zm.fold_update(blk.zones, upd[1])
-                for sig in list(blk.device):
-                    kind = sig[0]
-                    if kind == "nvoff":
-                        continue  # in-place updates never change row counts
-                    if kind in ("stackedenc", "blockenc"):
-                        # encoded pins hold narrow/run payloads: a decoded-
-                        # domain scatter cannot patch them in place (the
-                        # ref/run structure lives in the encoding) — drop,
-                        # and the next serve re-pins from the updated host
-                        # payload (which try_patch/demote kept truthful)
-                        released.append(blk.device.pop(sig))
-                    elif kind == "stacked":
-                        old = blk.device[sig]
-                        blk.device[sig] = self._patch_stacked(old, sig, updates)
-                        repinned.append((old, blk.device[sig]))
-                    elif isinstance(kind, tuple):
-                        if upd is None:
-                            continue
-                        old = blk.device[sig]
-                        blk.device[sig] = self._patch_block(old, sig, upd)
-                        repinned.append((old, blk.device[sig]))
-                    else:
-                        released.append(blk.device.pop(sig))
-        # the mutation choke point for pins: scatter IS the coordinated
-        # host-mutate-then-patch path, so patched pins re-register (new
-        # sample) and dropped pins release-verify (docs/static_analysis.md)
-        for entry in released:
-            _bufsan.release(entry, site="cache.scatter_update")
-        for old, new in repinned:
-            _bufsan.release(old, site="cache.scatter_update")
-            _bufsan.export("device_pin", new, site="cache.scatter_update")
+            entries = list(old0.device.items())
+        for sig, entry in entries:
+            kind = sig[0] if sig and isinstance(sig[0], str) else None
+            out = None
+            if kind is None or kind == "blockenc":
+                # block 0's own per-block pin: good while block 0 is
+                if 0 in changed:
+                    released.append((sig, entry))
+                else:
+                    kept[sig] = entry
+                continue
+            if kind == "stacked" and same_shape:
+                out = _patch_plain_stack(entry, sig, blocks, rows_changed)
+            elif kind == "stackedenc" and same_shape:
+                plan = _enc.device_plan(new, sig[1], sig[2])
+                if plan is not None and (plan.sig, plan.null_sig) == (sig[4], sig[5]) \
+                        and np.array_equal(np.asarray(plan.refs), np.asarray(entry[2])):
+                    out = _patch_enc_stack(entry, sig, plan, blocks, rows_changed)
+            released.append((sig, entry))
+            if out is not None:
+                kept[sig] = out
+                added.append((sig, out))
+        new.blocks[0].device.update(kept)
+        for i in changed:
+            if 0 < i < len(self.blocks):
+                with self._mu:
+                    released.extend(self.blocks[i].device.items())
+        if account and (released or added):
+            from .observatory import OBSERVATORY
 
-    @staticmethod
-    def _patch_stacked(entry, sig, updates):
-        """sig = ("stacked", ship_cols, nullable, block_rows); entry =
-        (data_tuple[(B, rows)] per ship col, nulls_tuple per nullable col)."""
-        _, ship_cols, nullable, _rows = sig
-        data, nulls = entry
-        data = list(data)
-        nulls = list(nulls)
-        for bi, (pos, cols) in updates.items():
-            for ci, (vals, nl) in cols.items():
-                if ci in ship_cols:
-                    j = ship_cols.index(ci)
-                    vals = np.asarray(vals).astype(data[j].dtype, copy=False)
-                    data[j] = data[j].at[bi, pos].set(vals)
-                if ci in nullable:
-                    j = nullable.index(ci)
-                    nulls[j] = nulls[j].at[bi, pos].set(np.asarray(nl))
-        return tuple(data), tuple(nulls)
-
-    @staticmethod
-    def _patch_block(entry, sig, upd):
-        """sig = (device_cols, nullable_cols, block_rows); entry =
-        ([data per device col], [nulls per nullable col]) for ONE block."""
-        dev_cols, nullable, _rows = sig
-        pos, cols = upd
-        data, nulls = list(entry[0]), list(entry[1])
-        for ci, (vals, nl) in cols.items():
-            if ci in dev_cols:
-                j = dev_cols.index(ci)
-                data[j] = data[j].at[pos].set(np.asarray(vals))
-            if ci in nullable:
-                j = nullable.index(ci)
-                nulls[j] = nulls[j].at[pos].set(np.asarray(nl))
-        return data, nulls
+            for sig, entry in released:
+                OBSERVATORY.note_pin(_pin_kind(sig), -_entry_nbytes(entry))
+                _bufsan.release(entry, site="cache.successor")
+            for sig, entry in added:
+                OBSERVATORY.note_pin(_pin_kind(sig), _entry_nbytes(entry))
+                _bufsan.export("device_pin", entry, site="cache.successor")
+        return new
 
 
 class CopCache:

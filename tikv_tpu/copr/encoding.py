@@ -281,27 +281,6 @@ class EncodedColumn(Column):
         return (self.run_values.nbytes + self.run_ends.nbytes
                 + self.run_nulls.nbytes)
 
-    def try_patch(self, rows: np.ndarray, vals: np.ndarray,
-                  nls: np.ndarray) -> bool:
-        """In-place update of the encoded payload; False = encoding broken
-        (caller demotes).  Any in-place write to an RLE column breaks its
-        runs; a bitpacked write survives while the new values fit the
-        lanes."""
-        if self.kind != "bp":
-            return False
-        info = np.iinfo(self.packed.dtype)
-        v = np.asarray(vals, dtype=np.int64)
-        live = ~np.asarray(nls, dtype=bool)
-        rel = v - self.ref
-        if live.any() and (int(rel[live].min()) < info.min
-                           or int(rel[live].max()) > info.max):
-            return False
-        self.packed[rows] = np.where(live, rel, 0).astype(self.packed.dtype)
-        self._nulls[rows] = nls
-        if self._data is not None:
-            self._data[rows] = np.where(live, v, 0)
-        return True
-
 
 def decoded_data(col: Column):
     """The decoded data array WITHOUT populating the column's decode cache
@@ -409,25 +388,6 @@ def narrow_dict_codes(col: Column) -> Column:
         return col
     col.data = d.astype(dt)
     return col
-
-
-def ensure_code_capacity(blocks, ci: int, max_code: int) -> bool:
-    """Widen a narrowed dict-code column (image-wide) so ``max_code`` fits;
-    returns True when lanes changed (callers drop device pins)."""
-    c0 = blocks[0].cols[ci]
-    d0 = np.asarray(c0.data)
-    if d0.dtype == object or d0.dtype.kind not in "iu":
-        return False
-    if max_code <= np.iinfo(d0.dtype).max:
-        return False
-    dt = _narrow_lane(0, 2 * max_code, 0) or np.int64
-    for b in blocks:
-        b.cols[ci].data = np.asarray(b.cols[ci].data).astype(dt)
-    if np.dtype(dt).itemsize >= 8:
-        # only the widen-to-int64 case ENDS the encoding; int8→int16/32
-        # stays a narrowed 'code' resident and must not read as a demotion
-        count_demote("code", "code_overflow")
-    return True
 
 
 def encode_blocks(cache, schema) -> dict:

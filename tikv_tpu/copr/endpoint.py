@@ -502,10 +502,16 @@ class Endpoint:
                     data_parts=parts, encode_type=enc_tp,
                 )
             except Exception as exc:
+                from ..storage.mvcc.reader import KeyIsLockedError
                 from .integrity import IntegrityMismatch
 
                 if isinstance(exc, (IntegrityMismatch, RungProgramError)):
                     raise  # fatal integrity / program bug: surface, never mask
+                if isinstance(exc, KeyIsLockedError):
+                    # the reader met a lock: the answer is ``locked`` (the
+                    # client backs off and retries at the same start_ts), as
+                    # the CPU scan would give it — not a device fault
+                    raise
                 # device/runtime failure (compiler, runtime, OOM): the CPU
                 # pipeline is the correctness oracle and always available —
                 # re-run there off the same immutable snapshot rather than

@@ -1121,7 +1121,10 @@ class JaxDagEvaluator:
         blocks = cache.blocks
         n_blocks = len(blocks)
 
-        zone_resp = None if self.route_hint == "unary" else self._try_zone(cache)
+        # an image that takes writes answers from its stacked blocks: the
+        # zone layout is rebuilt from the whole image, per version
+        zone_resp = (None if self.route_hint == "unary" or getattr(cache, "folded", False)
+                     else self._try_zone(cache))
         if zone_resp is not None:
             # observatory path marker (docs/observatory.md): the endpoint
             # reads which warm rung actually served, per response
@@ -2188,8 +2191,8 @@ def _launch_xregion_cached(ev: "JaxDagEvaluator", caches, params) -> XRegionPend
     n_blocks = tuple(len(c.blocks) for c in caches)
     B = max(n_blocks)
     # per-region inputs are the caches' ALREADY-PINNED device arrays (the
-    # same pins the per-request warm path uses, kept fresh by delta
-    # scatter_update / drop_device) — zero per-row host→device traffic, and
+    # same pins the per-request warm path uses, carried into each new
+    # version by ColumnBlockCache.successor) — zero per-row host→device traffic, and
     # no cross-cache pin that could go stale behind a region's back
     from . import zone_maps as _zm
 

@@ -58,6 +58,20 @@ def _decode_user_keys(key_rows: np.ndarray):
     return [codec.decode_bytes(key_rows[i].tobytes())[0] for i in range(n)]
 
 
+def _min_ct(a: int | None, b: int | None) -> int | None:
+    return b if a is None else a if b is None else min(a, b)
+
+
+def _version_bounds(commit_ts: np.ndarray, visible: np.ndarray):
+    """(newest commit_ts at or below the read's ts, oldest above it or None)
+    over every CF_WRITE version of a range: the timestamps between them read
+    the same rows."""
+    vis = commit_ts[visible]
+    hid = commit_ts[~visible]
+    return (int(vis.max()) if len(vis) else 0,
+            int(hid.min()) if len(hid) else None)
+
+
 def _short_values(recs: ByteRows):
     """Where the short value of each write record lies, for the records that
     are exactly ``[P][varint start_ts][v][len][len bytes]`` (every PUT of a
@@ -86,16 +100,34 @@ def _short_values(recs: ByteRows):
     return tag + 2, lens - vw - 3, plain
 
 
+def scan_writes(snap: Snapshot, enc_start: bytes,
+                enc_end: bytes) -> tuple[ByteRows, ByteRows]:
+    """The CF_WRITE keys and records of a range.  A native snapshot hands
+    back one framed buffer for the whole range (one FFI crossing) and where
+    the keys and records lie in it (``scan_spans``); other engines give
+    pairs, which are joined."""
+    scan_spans = getattr(snap, "scan_spans", None)
+    if scan_spans is None:
+        pairs = list(snap.scan_cf(CF_WRITE, enc_start, enc_end))
+        return (ByteRows.of([k for k, _ in pairs]),
+                ByteRows.of([v for _, v in pairs]))
+    buf, k_at, k_len, v_at, v_len = scan_spans(CF_WRITE, enc_start, enc_end)
+    return ByteRows(buf, k_at, k_len), ByteRows(buf, v_at, v_len)
+
+
 class MvccBatchScanSource(ScanSource):
     """Drop-in ScanSource resolving whole ranges vectorized.
 
     With ``record_versions=True`` the vectorized paths additionally record a
     per-output-row version fingerprint (the commit_ts of the newest CF_WRITE
-    entry at or below ``ts``) plus the range's overall max commit_ts — the
-    raw material the region column cache needs to detect deltas later.  A
-    range that takes the exact per-key fallback clears ``versions_exact``;
-    callers wanting version info must then decline (the cache simply does
-    not form).
+    entry at or below ``ts``) plus the range's overall max commit_ts, the
+    newest commit_ts at or below ``ts`` (``visible_max_ct``) and the oldest
+    above it (``hidden_min_ct``, None when there is none) — the raw material
+    the region column cache needs to detect deltas later and to know which
+    timestamps its image answers for.  ``locks_seen`` counts the CF_LOCK
+    entries the ranges held.  A range that takes the exact per-key fallback
+    clears ``versions_exact``; callers wanting version info must then decline
+    (the cache simply does not form).
     """
 
     def __init__(
@@ -116,6 +148,9 @@ class MvccBatchScanSource(ScanSource):
         self.versions_exact = True
         self.row_commit_ts: np.ndarray | None = None
         self.max_commit_ts = 0
+        self.visible_max_ct = 0
+        self.hidden_min_ct: int | None = None
+        self.locks_seen = 0
         self._resolved: tuple[list[bytes], list[bytes]] | None = None
         self._pos = 0
 
@@ -140,6 +175,9 @@ class MvccBatchScanSource(ScanSource):
                 else:
                     cts_out.append(self._range_cts)
                     self.max_commit_ts = max(self.max_commit_ts, self._range_max_ct)
+                    self.visible_max_ct = max(self.visible_max_ct, self._range_vis_ct)
+                    self.hidden_min_ct = _min_ct(self.hidden_min_ct,
+                                                 self._range_hidden_ct)
         if self.record_versions and self.versions_exact:
             self.row_commit_ts = (
                 np.concatenate(cts_out) if cts_out else np.empty(0, dtype=np.int64)
@@ -157,11 +195,14 @@ class MvccBatchScanSource(ScanSource):
         # version info for the range just resolved (record_versions bookkeeping)
         self._range_cts: np.ndarray | None = None
         self._range_max_ct = 0
+        self._range_vis_ct = 0
+        self._range_hidden_ct = None
         enc_start = Key.from_raw(start).encoded
         enc_end = Key.from_raw(end).encoded
         # lock checks, same rule as the scanner
         for k, v in self.snap.scan_cf(CF_LOCK, enc_start, enc_end):
             self.stats.lock.next += 1
+            self.locks_seen += 1
             _check_lock(v, Key.from_encoded(k).to_raw(), self.ts, self.bypass_locks)
 
         # the range's CF_WRITE keys and records, each over one buffer
@@ -195,6 +236,7 @@ class MvccBatchScanSource(ScanSource):
         pick_arr[gid[vis_idx][::-1]] = vis_idx[::-1]
         pick = pick_arr[pick_arr >= 0]  # keys with at least one visible version
         self._range_max_ct = int(commit_ts.max())
+        self._range_vis_ct, self._range_hidden_ct = _version_bounds(commit_ts, visible)
         if len(pick) == 0:
             self._range_cts = np.empty(0, dtype=np.int64)
             return [], []
@@ -263,17 +305,7 @@ class MvccBatchScanSource(ScanSource):
         return [raw_keys[j] for j in keep], vals
 
     def _scan_writes(self, enc_start: bytes, enc_end: bytes) -> tuple[ByteRows, ByteRows]:
-        """The CF_WRITE keys and records of the range.  A native snapshot
-        hands back one framed buffer for the whole range (one FFI crossing)
-        and where the keys and records lie in it (``scan_spans``); other
-        engines give pairs, which are joined."""
-        scan_spans = getattr(self.snap, "scan_spans", None)
-        if scan_spans is None:
-            pairs = list(self.snap.scan_cf(CF_WRITE, enc_start, enc_end))
-            return (ByteRows.of([k for k, _ in pairs]),
-                    ByteRows.of([v for _, v in pairs]))
-        buf, k_at, k_len, v_at, v_len = scan_spans(CF_WRITE, enc_start, enc_end)
-        return ByteRows(buf, k_at, k_len), ByteRows(buf, v_at, v_len)
+        return scan_writes(self.snap, enc_start, enc_end)
 
     def _parse_simple_layout(self, varr: np.ndarray, vw: int) -> list[bytes] | None:
         """All records = [P][varint start_ts][v][len][short_value]? Verify the
@@ -360,7 +392,11 @@ def scan_delta(
     ``deleted_handles`` are image rows with no visible version anymore;
     ``changed_values`` align with ``changed_handles`` and are the exact MVCC
     values (a changed key that resolves to nothing joins the deleted set
-    instead).  Lock checks run over each whole range, like the scanners.
+    instead).  ``visible_max_ct`` / ``hidden_min_ct`` bound the timestamps
+    that read the same rows (``MvccBatchScanSource``), ``locks_seen`` counts
+    the ranges' CF_LOCK entries.  Lock checks run over each whole range, like
+    the scanners.  The CF_WRITE range comes out of the engine as one buffer
+    (``scan_writes``); only the changed keys' records are cut out of it.
     """
     from .table import decode_record_handles
 
@@ -368,23 +404,24 @@ def scan_delta(
     vis_handles: list[np.ndarray] = []
     vis_cts: list[np.ndarray] = []
     vis_enc_keys: list[np.ndarray] = []  # (k, keylen) byte matrix per range
-    vis_pick_vals: list[list] = []  # lazily-fetched picked record values
-    max_ct = 0
+    vis_pick_recs: list[ByteRows] = []  # the picked records, cut on demand
+    max_ct = vis_ct = 0
+    hidden_ct = None
+    locks_seen = 0
     for start, end in ranges:
         enc_start = Key.from_raw(start).encoded
         enc_end = Key.from_raw(end).encoded
         for k, v in snap.scan_cf(CF_LOCK, enc_start, enc_end):
             stats.lock.next += 1
+            locks_seen += 1
             _check_lock(v, Key.from_encoded(k).to_raw(), ts, bypass_locks)
-        pairs = list(snap.scan_cf(CF_WRITE, enc_start, enc_end))
-        if not pairs:
+        wkeys, recs = scan_writes(snap, enc_start, enc_end)
+        if not len(wkeys):
             continue
-        wkeys = [k for k, _ in pairs]
-        width = len(wkeys[0])
-        if any(len(k) != width for k in wkeys):
+        arr = wkeys.matrix()
+        if arr is None:
             return None
-        n = len(wkeys)
-        arr = np.frombuffer(b"".join(wkeys), dtype=np.uint8).reshape(n, width)
+        n, width = arr.shape
         user = arr[:, : width - _TS_W]
         commit_ts = codec.decode_u64_batch(arr[:, width - _TS_W :]) ^ np.uint64(
             0xFFFFFFFFFFFFFFFF
@@ -397,6 +434,9 @@ def scan_delta(
         gid = np.cumsum(first) - 1
         n_keys = int(gid[-1]) + 1
         visible = commit_ts <= np.uint64(ts)
+        r_vis, r_hid = _version_bounds(commit_ts, visible)
+        vis_ct = max(vis_ct, r_vis)
+        hidden_ct = _min_ct(hidden_ct, r_hid)
         pick_arr = np.full(n_keys, -1, dtype=np.int64)
         vis_idx = np.flatnonzero(visible)
         pick_arr[gid[vis_idx][::-1]] = vis_idx[::-1]
@@ -413,7 +453,7 @@ def scan_delta(
         vis_handles.append(handles)
         vis_cts.append(commit_ts[pick_arr[has_vis]].astype(np.int64))
         vis_enc_keys.append(key_rows)
-        vis_pick_vals.append([pairs[i][1] for i in pick_arr[has_vis]])
+        vis_pick_recs.append(recs[pick_arr[has_vis]])
 
     if vis_handles:
         handles = np.concatenate(vis_handles)
@@ -456,7 +496,7 @@ def scan_delta(
         enc_user = vis_enc_keys[ri][local].tobytes()
         # fast path: the picked record is a plain PUT with a short value
         val = None
-        rec = vis_pick_vals[ri][local]
+        rec = vis_pick_recs[ri][local]
         if rec and rec[0] == _PUT:
             try:
                 w = _parse_write_short(rec)
@@ -481,6 +521,9 @@ def scan_delta(
         "changed_commit_ts": np.array(changed_cts, dtype=np.int64),
         "deleted_handles": np.array(sorted(deleted), dtype=np.int64),
         "max_commit_ts": max_ct,
+        "visible_max_ct": vis_ct,
+        "hidden_min_ct": hidden_ct,
+        "locks_seen": locks_seen,
         "n_visible": int(len(handles)),
     }
 

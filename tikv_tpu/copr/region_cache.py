@@ -16,12 +16,13 @@ deltas move.
   fixed-width column blocks; the evaluators pin them on device on first use.
 * hit: same ``apply_index`` ⇒ the engine cannot have changed; serve the
   resident blocks as-is (zero scan, zero decode, zero transfer).
-* delta: a newer ``apply_index`` (or a later ``start_ts`` while future
-  versions exist) triggers ``mvcc_batch.scan_delta``: one vectorized pass
-  over the CF_WRITE *keys* finds rows whose version fingerprint moved; only
-  those rows re-resolve and re-decode.  Pure in-place updates patch the
-  pinned device arrays with ``.at[].set`` scatters; inserts/deletes repack
-  the host blocks (still no KV decode) and drop the pins to rebuild lazily.
+* delta: a newer ``apply_index`` folds the write-through chain's prefix
+  (below); where the chain cannot say, ``mvcc_batch.scan_delta`` makes one
+  vectorized pass over the CF_WRITE *keys* and finds rows whose version
+  fingerprint moved; only those rows re-resolve and re-decode.  Either way
+  the rows go into a NEW version of the image, block by block: the blocks
+  they touch are copied, the rest shared, pins and all
+  (``RegionImage.apply_delta``).
 * below the snapshot: a reader another session overtook (its ``start_ts``
   lies below the ``snapshot_ts`` a later reader raised) is a hit whenever the
   image provably holds nothing it may not see — same ``apply_index`` and
@@ -47,22 +48,22 @@ device pin costs about one host copy per pinned plan signature).
 Write-through deltas: the raft apply path
 (``raft/store.py`` ``_apply_run`` / ``_exec_data_cmd``) calls
 :func:`notify_region_write` with every committed data batch's ops and the
-entry's apply index.  The parsed delta (changed handles/values/commit_ts,
-deleted handles, lock touches) is buffered on the image as a PENDING delta;
-the next warm read folds it in under the manager lock and serves WITHOUT
-any CF_WRITE scan — ``scan_delta`` stays as the fallback whenever emission
-is off (``apply_emit_write_delta`` failpoint, config), an op is not
-vectorizable, or the pending chain has a gap (detected via the per-region
+entry's apply index.  The parsed batch (changed handles/values/commit_ts,
+deleted handles, lock changes) is appended to the image's PENDING chain; a
+warm read folds the chain's prefix up to its own apply index — the writes
+committed at or below its ``start_ts`` — and serves WITHOUT any CF_WRITE
+scan, leaving later writes pending.  ``scan_delta`` stays as the fallback
+whenever emission is off (``apply_emit_write_delta`` failpoint, config), an
+op is not vectorizable, or the chain has a gap (detected via the per-region
 notify watermark; see docs/write_path.md for the contract).
 
-Concurrency: cache resolution (lookup / build / delta apply) serializes
-under the manager lock, but the evaluator reads the image's blocks after
-``serve`` returns — a delta applying concurrently with another request's
-read of the SAME image could tear that read.  Deltas mutate blocks only on
-the serve path (write-through emission merely buffers pending rows), so
-this needs a reader still in flight when a LATER read's fold-in lands;
-endpoints that serve a region from multiple threads should serialize per
-region.  ``apply_index`` is propagated end-to-end: ``RegionSnapshot``
+Concurrency (docs/region_column_cache.md, "Concurrency"): the manager lock
+guards the image table and its bookkeeping and is held only briefly; a
+repair runs under the image's own lock, so it holds up neither readers of
+other images nor the apply path's notifies.  A repair never writes into a
+version a reader was handed — it makes a new one — so the evaluator may
+read a version's blocks after ``serve`` returns.  ``apply_index`` is
+propagated end-to-end: ``RegionSnapshot``
 carries the peer's applied index, and the endpoint reads region identity,
 epoch and apply index straight off the snapshot — raft-backed deployments
 need no context plumbing (explicit context still wins for tests and
@@ -71,6 +72,7 @@ embedded engines).
 
 from __future__ import annotations
 
+import copy
 import time
 import weakref
 
@@ -80,13 +82,13 @@ from ..analysis import bufsan as _bufsan
 from ..analysis.sanitizer import make_rlock
 from ..storage.engine import CF_DEFAULT, CF_LOCK, CF_WRITE
 from ..storage.mvcc import Statistics
-from ..storage.mvcc.reader import _check_lock
-from ..storage.txn_types import Key, Write, WriteType, append_ts, split_ts
+from ..storage.mvcc.reader import KeyIsLockedError, _check_lock
+from ..storage.txn_types import Key, Lock, Write, WriteType, append_ts, split_ts
 from ..util import trace
 from . import encoding as _encoding
 from . import integrity as _integrity
 from .byterows import ByteRows
-from .cache import ColumnBlockCache
+from .cache import ColumnBlockCache, _Block
 from .datatypes import Column, EvalType
 from .mvcc_batch import MvccBatchScanSource, scan_delta
 from .table import RowBatchDecoder, decode_record_handles, decode_record_key, record_key
@@ -140,19 +142,23 @@ def notify_region_write_lost(region_id: int, apply_index: int,
 
 
 def _parse_write_ops(ops, get_default):
-    """Parse a committed batch's ops into ``(writes, lock_keys)`` —
+    """Parse a committed batch's ops into ``(writes, lock_ops)`` —
     ``writes`` = [(raw_key, commit_ts, value | None-for-delete)] in batch
-    order, ``lock_keys`` = raw keys whose CF_LOCK state changed.  Returns
-    None when any CF_WRITE op is not expressible as an incremental row
-    change (delete/delete_range on CF_WRITE, exotic records, a missing
-    CF_DEFAULT value) — the caller then degrades to the scan_delta path."""
+    order, ``lock_ops`` = [(raw_key, Lock | None-for-delete)] of every
+    CF_LOCK change, in batch order.  Returns None when any op is not
+    expressible as an incremental change (delete_range, delete on CF_WRITE,
+    exotic records, a missing CF_DEFAULT value, an unreadable lock) — the
+    caller then degrades to the scan_delta path."""
     writes: list[tuple[bytes, int, bytes | None]] = []
-    lock_keys: list[bytes] = []
+    lock_ops: list[tuple[bytes, Lock | None]] = []
     for op, cf, key, val in ops:
         if cf == CF_LOCK:
+            if op not in ("put", "delete"):
+                return None
             try:
-                lock_keys.append(Key.from_encoded(key).to_raw())
-            except Exception:  # noqa: BLE001 — undecodable lock key
+                lock_ops.append((Key.from_encoded(key).to_raw(),
+                                 Lock.from_bytes(val) if op == "put" else None))
+            except Exception:  # noqa: BLE001 — undecodable lock key or record
                 return None
             continue
         if cf != CF_WRITE:
@@ -182,7 +188,50 @@ def _parse_write_ops(ops, get_default):
         # LOCK / ROLLBACK records change no visible row data: skip.  Their
         # fingerprint drift is repaired by the scan_delta fallback if a
         # reader ever diffs this range again.
-    return writes, lock_keys
+    return writes, lock_ops
+
+
+def _fold_writes(writes) -> dict:
+    """Chain writes ``[(apply_index, handle, commit_ts, value | None)]`` as
+    the row delta ``RegionImage.apply_delta`` takes: each handle's newest
+    write wins."""
+    last: dict[int, tuple] = {}
+    for _i, h, cts, v in writes:
+        if h not in last or cts >= last[h][0]:
+            last[h] = (cts, v)
+    changed = sorted(h for h, (_c, v) in last.items() if v is not None)
+    return {
+        "changed_handles": np.array(changed, dtype=np.int64),
+        "changed_values": [last[h][1] for h in changed],
+        "changed_commit_ts": np.array([last[h][0] for h in changed], dtype=np.int64),
+        "deleted_handles": np.array(sorted(h for h, (_c, v) in last.items() if v is None),
+                                    dtype=np.int64),
+        "max_commit_ts": max(w[2] for w in writes),
+    }
+
+
+def _lock_in(snap, raw: bytes, lock: Lock) -> bool:
+    """Whether ``snap`` holds ``lock`` on ``raw`` (a lock removed behind the
+    write-through chain's back, by a range destroy, must not block a reader
+    forever)."""
+    v = snap.get_cf(CF_LOCK, Key.from_raw(raw).encoded)
+    return v is not None and Lock.from_bytes(v).ts == lock.ts
+
+
+class _Held:
+    """``with``-exit for a lock already acquired (``_locked``)."""
+
+    __slots__ = ("_lock",)
+
+    def __init__(self, lock):
+        self._lock = lock
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        self._lock.release()
+        return False
 
 
 def _in_ranges(raw: bytes, ranges) -> bool:
@@ -219,6 +268,109 @@ def schema_sig(columns_info) -> tuple:
     )
 
 
+_STATE_KEYS = ("block_cache", "handles", "row_commit_ts", "row_fp", "row_nbytes",
+               "fp_value", "fp_integrity", "fp_valid", "nbytes", "apply_index",
+               "max_commit_ts", "hidden_ct", "reserved", "encodings")
+_BASE_EVERY = 256   # chain writes folded between two kept base versions
+_BASES_KEPT = 4
+
+
+class _Splice:
+    """Rows ``dpos`` out of an ``n``-row array and new rows in at ``at``
+    (positions in the array without them), copying each array once:
+    contiguous deletes and appends at the end, the shapes the refresh
+    functions make, go through slices."""
+
+    def __init__(self, n: int, dpos: np.ndarray, at: np.ndarray):
+        self.n = n
+        self.dpos = np.sort(dpos)
+        self.at = at
+        self.span = (len(self.dpos) and int(self.dpos[-1]) - int(self.dpos[0]) + 1
+                     == len(self.dpos))
+        self.tail = bool(len(at)) and bool((at >= n - len(dpos)).all())
+
+    def __call__(self, arr: np.ndarray, new: np.ndarray) -> np.ndarray:
+        if len(self.dpos):
+            if self.span:
+                a, b = int(self.dpos[0]), int(self.dpos[-1]) + 1
+                arr = np.concatenate([arr[:a], arr[b:]])
+            else:
+                arr = np.delete(arr, self.dpos)
+        if not len(self.at):
+            return arr
+        if self.tail:
+            return np.concatenate([arr, np.asarray(new, dtype=arr.dtype)])
+        return np.insert(arr, self.at, new)
+
+
+class _Repack(Exception):
+    """A block-local fold cannot hold the delta: repack the whole image."""
+
+
+def _pow2(n: int) -> int:
+    p = 1
+    while p < n:
+        p *= 2
+    return p
+
+
+def _empty_col(col: Column) -> Column:
+    """A zero-row column in ``col``'s representation (encoding, lane,
+    dictionary), for the empty blocks an image reserves."""
+    E = _encoding.EncodedColumn
+    if isinstance(col, E):
+        if col.kind == "bp":
+            return E(col.eval_type, col.frac, "bp", 0,
+                     packed=np.empty(0, dtype=col.packed.dtype), ref=col.ref,
+                     nulls=np.empty(0, dtype=bool))
+        return E(col.eval_type, col.frac, "rle", 0,
+                 run_values=np.empty(0, dtype=col.run_values.dtype),
+                 run_ends=np.empty(0, dtype=np.int64),
+                 run_nulls=np.empty(0, dtype=bool), k_cap=col.k_cap)
+    return Column(col.eval_type, np.empty(0, dtype=np.asarray(col.data).dtype),
+                  np.empty(0, dtype=bool), col.frac, col.dictionary)
+
+
+def _empty_block(template: _Block) -> _Block:
+    return _Block([_empty_col(c) for c in template.cols], 0)
+
+
+def _encode_like(col: Column, d: np.ndarray, nl: np.ndarray):
+    """Host values ``d`` (nulls ``nl``) in ``col``'s encoding and lane, or
+    None where they do not fit it (a bitpacked value off its lane, more runs
+    than the column's run capacity, a code past its narrowed lane)."""
+    E = _encoding.EncodedColumn
+    if isinstance(col, E):
+        a = d.astype(np.int64, copy=False)
+        live = ~nl
+        if col.kind == "bp":
+            info = np.iinfo(col.packed.dtype)
+            rel = a - col.ref
+            if live.any() and (int(rel[live].min()) < info.min
+                               or int(rel[live].max()) > info.max):
+                return None
+            return E(col.eval_type, col.frac, "bp", len(a),
+                     packed=np.where(live, rel, 0).astype(col.packed.dtype),
+                     ref=col.ref, nulls=nl)
+        n = len(a)
+        change = np.ones(n, dtype=bool)
+        if n > 1:
+            change[1:] = (a[1:] != a[:-1]) | (nl[1:] != nl[:-1])
+        starts = np.flatnonzero(change)
+        if len(starts) > col.k_cap:
+            return None
+        ends = np.append(starts[1:], n).astype(np.int64)
+        return E(col.eval_type, col.frac, "rle", n,
+                 run_values=a[starts].astype(col.run_values.dtype), run_ends=ends,
+                 run_nulls=nl[starts].copy(), k_cap=col.k_cap)
+    d0 = np.asarray(col.data)
+    if (col.dictionary is not None and d0.dtype != object and d0.dtype.kind in "iu"
+            and len(d) and int(d.max()) > np.iinfo(d0.dtype).max):
+        return None
+    return Column(col.eval_type, d.astype(d0.dtype, copy=False), nl, col.frac,
+                  col.dictionary)
+
+
 class RegionImage:
     """One region's decoded, device-pinnable columnar state."""
 
@@ -231,9 +383,20 @@ class RegionImage:
         # request built this image — HBM partition accounting and the
         # memory-pressure ladder key on it
         self.tenant = "default"
+        # serializes this image's repairs and the reads that judge it; the
+        # cache's manager lock is never waited for while this one is held
+        # except briefly, and never the other way round (Concurrency)
+        self.mu = make_rlock("copr.region_image")
+        self.dropped = False  # invalidated or evicted: no longer resident
         self.apply_index = -1
-        self.snapshot_ts = -1
+        self.snapshot_ts = -1  # the highest start_ts the image has served
+        # the image holds the engine's rows at apply_index as a read at any
+        # timestamp in [max_commit_ts, hidden_ct) sees them: max_commit_ts is
+        # the newest commit it holds, hidden_ct the oldest version at
+        # apply_index it does not hold and no pending batch carries (None:
+        # there is none)
         self.max_commit_ts = 0
+        self.hidden_ct: int | None = None
         self.handles = np.empty(0, dtype=np.int64)
         self.row_commit_ts = np.empty(0, dtype=np.int64)
         self.block_cache = ColumnBlockCache(key=key)
@@ -244,14 +407,30 @@ class RegionImage:
         self.encode_enabled = False
         self.encodings: dict[int, str] = {}
         # bytes->code maps for dict-encoded columns, built on first delta
-        self._dict_maps: dict[int, dict] = {}
-        # write-through pending delta (apply_write buffers; serve folds in):
-        # {"base", "apply_index", "changed": {handle: (value, cts)},
-        #  "deleted": set[handle], "max_ct"} or None
-        self.wt_pending: dict | None = None
+        self._dict_maps: dict[int, tuple] = {}
+        # the first append at the tail reserved empty blocks behind it
+        self.reserved = False
+        # write-through chain (the cache's manager lock guards it): every
+        # committed batch that touched these ranges since apply index
+        # chain_from, in apply order, as (apply_index, [(handle, commit_ts,
+        # value | None)], [(raw key, Lock | None)]).  Batches at or below
+        # apply_index hold only writes above the image's timestamps (held
+        # back for later readers); chain_from None = gapped, and chain_gap
+        # says why a read cannot fold
+        self.chain: list = []
+        self.chain_from: int | None = None
+        self.chain_gap = "gapped"
+        # every CF_LOCK entry in range at apply_index (raw key -> Lock), or
+        # None where that is not known
+        self.locks: dict | None = None
+        # earlier versions a reader below max_commit_ts is folded onto
+        # (``_serve_base``): [(state, position in ``since``)], oldest first,
+        # and every chain write folded in since the oldest, in fold order
+        self.bases: list = []
+        self.since: list = []
         # a write-through batch touched CF_LOCK in range: the next warm
-        # serve must re-scan locks even at an unchanged start_ts.  Cleared
-        # only when a lock-free scan ran on a snapshot at/after the batch
+        # serve must check locks even at an unchanged start_ts.  Cleared
+        # only when a lock-free check ran on a snapshot at/after the batch
         # that dirtied it (locks_dirty_at) — an older snapshot proves
         # nothing about that batch's lock.
         self.locks_dirty = False
@@ -278,9 +457,24 @@ class RegionImage:
     def n_rows(self) -> int:
         return len(self.handles)
 
-    def _offsets(self) -> np.ndarray:
-        nv = np.array([b.n_valid for b in self.block_cache.blocks], dtype=np.int64)
-        return np.concatenate([[0], np.cumsum(nv)])
+    @property
+    def wt_pending(self) -> dict | None:
+        """The pending chain's row changes, newest per handle (a view for
+        debugging surfaces), or None where no batch is pending."""
+        writes = [w for _i, ws, _l in self.chain for w in ws]
+        if self.chain_from is None or not writes:
+            return None
+        changed, deleted = {}, set()
+        for h, cts, v in writes:
+            if v is None:
+                changed.pop(h, None)
+                deleted.add(h)
+            else:
+                deleted.discard(h)
+                changed[h] = (v, cts)
+        return {"base": self.chain_from, "apply_index": self.chain[-1][0],
+                "changed": changed, "deleted": deleted,
+                "max_ct": max(c for _h, c, _v in writes)}
 
     def _recount(self) -> None:
         self.nbytes = (
@@ -291,7 +485,8 @@ class RegionImage:
 
     def fill(self, handles: np.ndarray, values: list[bytes], cts: np.ndarray,
              max_commit_ts: int, apply_index: int, start_ts: int,
-             raw_keys: list[bytes] | None = None, encode: bool = False) -> None:
+             raw_keys: list[bytes] | None = None, encode: bool = False,
+             hidden_ct: int | None = None) -> None:
         self.handles = handles
         self.row_commit_ts = cts
         with trace.stage("fill.fingerprint"):
@@ -320,8 +515,9 @@ class RegionImage:
         self.apply_index = apply_index
         self.snapshot_ts = start_ts
         self.max_commit_ts = max_commit_ts
-        self.wt_pending = None  # a rebuild reflects the engine directly
+        self.hidden_ct = hidden_ct
         self._recount()
+        self.rebase()
 
     # -- integrity fingerprint ---------------------------------------------
 
@@ -389,80 +585,155 @@ class RegionImage:
             return None
         return self.fp_value, self.n_rows, int(self.row_nbytes.sum())
 
+    # -- versions ----------------------------------------------------------
+
+    def state(self) -> dict:
+        """The version as it stands: what ``apply_delta`` replaces (it never
+        writes into these arrays or blocks), so a copy of the references
+        keeps the version whole."""
+        return {k: getattr(self, k) for k in _STATE_KEYS}
+
+    def rebase(self) -> None:
+        """The version as it stands is the only base; nothing folded since."""
+        self.bases = [(self.state(), 0)]
+        self.since = []
+
+    def note_folded(self, writes) -> None:
+        """``writes`` [(apply_index, handle, commit_ts, value)] were folded
+        into the image: keep them, and every ``_BASE_EVERY`` of them the
+        version they made as one more base (the oldest beyond
+        ``_BASES_KEPT`` goes, with the writes before the next one)."""
+        self.since.extend(writes)
+        if len(self.since) - self.bases[-1][1] >= _BASE_EVERY:
+            self.bases.append((self.state(), len(self.since)))
+            if len(self.bases) > _BASES_KEPT:
+                cut = self.bases[1][1]
+                self.since = self.since[cut:]
+                self.bases = [(st, pos - cut) for st, pos in self.bases[1:]]
+
+    def fork(self, state: dict, delta: dict):
+        """``delta`` folded into the version ``state`` — a new block cache
+        for one reader; the image is left as it stands."""
+        shell = copy.copy(self)
+        shell.__dict__.update(state)
+        shell.apply_delta(delta, fork=True)
+        return shell.block_cache
+
     # -- delta -------------------------------------------------------------
 
-    def apply_delta(self, delta: dict, apply_index: int, start_ts: int) -> int:
-        """Apply a ``mvcc_batch.scan_delta`` result; returns rows touched."""
-        ch = delta["changed_handles"]
-        dh = delta["deleted_handles"]
-        n_touched = len(ch) + len(dh)
-        if n_touched:
-            pos = np.searchsorted(self.handles, ch)
-            pos_c = np.minimum(pos, max(self.n_rows - 1, 0))
-            in_place = (
-                len(dh) == 0
-                and self.n_rows > 0
-                and bool((self.handles[pos_c] == ch).all())
-            )
-            cols = (
-                self.decoder.decode(ch, delta["changed_values"]) if len(ch) else None
-            )
-            # fingerprint fold (docs/integrity.md): hash the delta rows off
-            # the RAW value chain before decode touches them — the fold
-            # tracks what the image will CONTAIN, the scrubber's oracle says
-            # what it SHOULD contain
-            new_fp = new_nb = None
-            if self.fp_valid:
-                try:
-                    dkeys = [record_key(self.table_id, int(h)) for h in ch]
-                    new_fp = _integrity.row_checksums(dkeys, delta["changed_values"])
-                    new_nb = np.fromiter(
-                        (len(k) + len(v)
-                         for k, v in zip(dkeys, delta["changed_values"])),
-                        dtype=np.int64, count=len(ch),
-                    )
-                except Exception:  # noqa: BLE001 — unhashable: plane off
-                    self._invalidate_fp()
-            if in_place:
-                if self.fp_valid:
-                    cts_new = np.asarray(delta["changed_commit_ts"], dtype=np.int64)
-                    old_fp = self.row_fp[pos]
-                    old_mix = _integrity.mix_fp(old_fp, self.row_commit_ts[pos])
-                    self.fp_value ^= _integrity.fold(old_fp) ^ _integrity.fold(new_fp)
-                    self.fp_integrity ^= _integrity.fold(old_mix) ^ _integrity.fold(
-                        _integrity.mix_fp(new_fp, cts_new)
-                    )
-                    self.row_fp[pos] = new_fp
-                    self.row_nbytes[pos] = new_nb
-                self._apply_updates(pos, cols, ch, delta["changed_commit_ts"])
-            else:
-                self._apply_structural(ch, cols, delta["changed_commit_ts"], dh,
-                                       new_fp, new_nb)
-        self.apply_index = apply_index
-        self.snapshot_ts = start_ts
-        self.max_commit_ts = delta["max_commit_ts"]
+    def apply_delta(self, delta: dict, fork: bool = False) -> int:
+        """Fold a row delta (``mvcc_batch.scan_delta``'s shape, which the
+        write-through chain's fold builds too) into a NEW version of the
+        image; returns the rows touched.  The caller holds the image's lock
+        and keeps the version bookkeeping (apply index, timestamps).
+
+        Copy-on-write, block by block: an update rewrites its rows in a copy
+        of its block, a delete drops its rows from a copy of its block (the
+        block's ``n_valid`` shrinks: every program reads ``n_valid`` per
+        block), an insert goes into its block where that has room, and one
+        past the image's last handle into the tail block and the empty
+        blocks behind it.  The image's first tail append reserves room for
+        as many rows again, the block count rounded up to a power of two, so
+        the block count (part of every program's key) moves once and not
+        with every append.  Untouched blocks are shared with the previous
+        version, device pins and all (``ColumnBlockCache.successor``), and a
+        reader that holds the previous version keeps it whole.  An insert
+        into a full block, a value its column's encoding cannot hold, or a
+        new dictionary entry repacks the whole image into the new version
+        instead (``_repack``).  ``fork``: a version for one reader
+        (``fork``), which leaves the pins' accounting to the image's own."""
+        ch = np.asarray(delta["changed_handles"], dtype=np.int64)
+        dh = np.asarray(delta["deleted_handles"], dtype=np.int64)
+        if not len(ch) + len(dh):
+            return 0
+        values = delta["changed_values"]
+        cts = np.asarray(delta["changed_commit_ts"], dtype=np.int64)
+        cols = self.decoder.decode(ch, values) if len(ch) else None
+        # fingerprint fold (docs/integrity.md): hash the delta rows off the
+        # RAW value chain — the fold tracks what the image will CONTAIN, the
+        # scrubber's oracle says what it SHOULD contain
+        new_fp = new_nb = None
+        if self.fp_valid and len(ch):
+            try:
+                dkeys = [record_key(self.table_id, int(h)) for h in ch]
+                new_fp = _integrity.row_checksums(dkeys, values)
+                new_nb = np.fromiter((len(k) + len(v) for k, v in zip(dkeys, values)),
+                                     dtype=np.int64, count=len(ch))
+            except Exception:  # noqa: BLE001 — unhashable: plane off
+                self._invalidate_fp()
+        n = self.n_rows
+        h = self.handles
+        dpos = np.empty(0, dtype=np.int64)
+        if len(dh) and n:
+            dp = np.searchsorted(h, dh)
+            ok = dp < n
+            ok[ok] = h[dp[ok]] == dh[ok]
+            dpos = dp[ok]
+        pos = np.searchsorted(h, ch)
+        upd = pos < n
+        upd[upd] = h[pos[upd]] == ch[upd]
+        ins = ~upd
+        grow: dict[int, list] = {}
+        try:
+            new_cache = self._edit_blocks(ch, cols, pos, upd, dpos, fork)
+        except _Repack:
+            new_cache = None
+        if new_cache is None:
+            new_cache = self._repack(ch, cols, upd, dpos, grow)
+        # the row arrays, in the order the new blocks hold the rows
+        upd_pos = pos[upd]
+        row_cts = self.row_commit_ts
+        if len(upd_pos):
+            row_cts = row_cts.copy()
+            row_cts[upd_pos] = cts[upd]
+        if self.fp_valid:
+            if new_fp is None:
+                new_fp = np.empty(0, dtype=np.uint64)
+                new_nb = np.empty(0, dtype=np.int64)
+            gone = np.concatenate([upd_pos, dpos])
+            old_fp, old_cts = self.row_fp[gone], self.row_commit_ts[gone]
+            add_fp = np.concatenate([new_fp[upd], new_fp[ins]])
+            add_cts = np.concatenate([cts[upd], cts[ins]])
+            self.fp_value ^= _integrity.fold(old_fp) ^ _integrity.fold(add_fp)
+            self.fp_integrity ^= (_integrity.fold(_integrity.mix_fp(old_fp, old_cts))
+                                  ^ _integrity.fold(_integrity.mix_fp(add_fp, add_cts)))
+            fp, nb = self.row_fp, self.row_nbytes
+            if len(upd_pos):
+                fp, nb = fp.copy(), nb.copy()
+                fp[upd_pos] = new_fp[upd]
+                nb[upd_pos] = new_nb[upd]
+        splice = _Splice(n, dpos, np.searchsorted(np.delete(h, dpos), ch[ins])
+                         if len(dpos) else pos[ins])
+        self.handles = splice(h, ch[ins])
+        self.row_commit_ts = splice(row_cts, cts[ins])
+        if self.fp_valid:
+            self.row_fp = splice(fp, new_fp[ins])
+            self.row_nbytes = splice(nb, new_nb[ins])
+        self.block_cache = new_cache
         self._recount()
-        return n_touched
+        return len(ch) + len(dh)
 
-    def _code_of(self, ci: int, blocks, value: bytes) -> int:
-        """Image dictionary code for ``value`` on column ``ci``, appending a
-        new entry (shared across every block) when unseen."""
-        dmap = self._dict_maps.get(ci)
+    def _code_of(self, ci: int, blocks, value: bytes, grow: dict | None) -> int:
+        """Image dictionary code for ``value`` on column ``ci``.  An unseen
+        value raises ``_Repack``, or with ``grow`` gets the next code of the
+        grown dictionary ``_repack`` gives every block."""
         dictionary = blocks[0].cols[ci].dictionary
-        if dmap is None:
-            dmap = self._dict_maps[ci] = {bytes(v): j for j, v in enumerate(dictionary)}
-        code = dmap.get(value)
-        if code is None:
-            code = len(dmap)
-            dmap[value] = code
-            grown = np.empty(code + 1, dtype=object)
-            grown[:code] = dictionary
-            grown[code] = value
-            for b in blocks:
-                b.cols[ci].dictionary = grown
-        return code
+        dmap = self._dict_maps.get(ci)
+        if dmap is None or dmap[0] is not dictionary:
+            dmap = self._dict_maps[ci] = (
+                dictionary, {bytes(v): j for j, v in enumerate(dictionary)})
+        code = dmap[1].get(value)
+        if code is not None:
+            return code
+        if grow is None:
+            raise _Repack
+        extra = grow.setdefault(ci, [])
+        if value in extra:
+            return len(dictionary) + extra.index(value)
+        extra.append(value)
+        return len(dictionary) + len(extra) - 1
 
-    def _delta_cell(self, ci: int, blocks, col: Column, r: int):
+    def _delta_cell(self, ci: int, blocks, col: Column, r: int, grow=None):
         """(value, is_null) of delta row ``r`` in the image's representation."""
         nl = bool(np.asarray(col.nulls)[r])
         image_col = blocks[0].cols[ci] if blocks else None
@@ -483,235 +754,288 @@ class RegionImage:
             return (b"" if obj_col and not dict_encoded else 0), True
         v = col.decoded().data[r] if col.is_dict_encoded else col.data[r]
         if dict_encoded:
-            return self._code_of(ci, blocks, bytes(v)), False
+            return self._code_of(ci, blocks, bytes(v), grow), False
         return v, False
 
-    def _apply_updates(self, pos: np.ndarray, cols, ch: np.ndarray, cts: np.ndarray) -> None:
-        """In-place row updates: mutate host arrays (patching encoded
-        payloads where the encoding survives — docs/compressed_columns.md),
-        scatter device pins."""
-        blocks = self.block_cache.blocks
-        offsets = self._offsets()
-        bi_arr = np.searchsorted(offsets, pos, side="right") - 1
-        if _bufsan.enabled():
-            # mutation choke point: the fold is about to write these host
-            # arrays in place — any of them still exposed (wire part mid
-            # sendmsg, shadow-read snapshot) is a violation.  Encoded
-            # columns list their payload arrays, never ``.data`` (the
-            # property would cache a full decode).
-            bufs: list = [self.row_commit_ts]
-            for bi in np.unique(bi_arr):
-                for col in blocks[int(bi)].cols:
-                    if isinstance(col, _encoding.EncodedColumn):
-                        bufs.extend(a for a in (col.packed, col.run_values,
-                                                col.run_ends, col.run_nulls)
-                                    if a is not None)
-                    else:
-                        bufs.append(col.data)
-                        bufs.append(col.nulls)
-            _bufsan.note_mutation(bufs, site="region_cache._apply_updates")
-        # any in-place update to an RLE column breaks its runs: demote it
-        # image-wide up front (decode-on-next-serve), so the assignments
-        # below land on plain decoded arrays
+    def _delta_columns(self, blocks, cols, n: int, grow=None):
+        """Per image column, the changed rows' (values, nulls) in the
+        image's host representation: a numeric column at once, a bytes or
+        dictionary one row by row (``_delta_cell``)."""
+        out = []
         for ci in range(len(self.schema)):
-            if self.schema[ci].is_pk_handle:
+            col = cols[ci]
+            image_col = blocks[0].cols[ci]
+            data = None if col.is_dict_encoded or image_col.is_dict_encoded else col.data
+            if isinstance(data, np.ndarray) and data.dtype.kind in "iuf":
+                nls = np.asarray(col.nulls, dtype=bool)
+                out.append((np.where(nls, 0, data), nls))
                 continue
-            c0 = blocks[0].cols[ci] if blocks else None
-            if isinstance(c0, _encoding.EncodedColumn) and c0.kind == "rle":
-                _encoding.demote_column(self.block_cache, ci, "inplace_update")
-        updates: dict[int, tuple[np.ndarray, dict]] = {}
-        for bi in np.unique(bi_arr):
-            sel = np.flatnonzero(bi_arr == bi)
-            rows = (pos[sel] - offsets[bi]).astype(np.int64)
-            per_col: dict[int, tuple[np.ndarray, np.ndarray]] = {}
-            for ci, col in enumerate(cols):
-                if self.schema[ci].is_pk_handle:
-                    continue  # handles are the row identity — never change
-                image_col = blocks[int(bi)].cols[ci]
-                vals = np.empty(len(sel), dtype=_encoding.host_dtype(image_col))
-                nls = np.zeros(len(sel), dtype=bool)
-                for j, si in enumerate(sel):
-                    v, nl = self._delta_cell(ci, blocks, col, int(si))
-                    vals[j] = v
-                    nls[j] = nl
-                if isinstance(image_col, _encoding.EncodedColumn):
-                    if not image_col.try_patch(rows, vals, nls):
-                        # the new values don't fit the narrow lanes: demote
-                        # the column image-wide and write decoded
-                        _encoding.demote_column(
-                            self.block_cache, ci, "value_range")
-                        image_col = blocks[int(bi)].cols[ci]
-                        image_col.data[rows] = vals.astype(
-                            image_col.data.dtype, copy=False)
-                        image_col.nulls[rows] = nls
-                else:
-                    d = np.asarray(image_col.data)
-                    if (image_col.dictionary is not None and d.dtype != object
-                            and d.dtype.kind in "iu" and d.dtype.itemsize < 8
-                            and len(vals)
-                            and _encoding.ensure_code_capacity(
-                                blocks, ci, int(vals.max()))):
-                        # narrowed code lanes widened (a delta grew the
-                        # dictionary past them) — pins rebuild from host
-                        self.block_cache.enc_version += 1
-                        self.block_cache.drop_device()
-                        image_col = blocks[int(bi)].cols[ci]
-                    image_col.data[rows] = vals.astype(
-                        np.asarray(image_col.data).dtype, copy=False)
-                    image_col.nulls[rows] = nls
-                per_col[ci] = (vals, nls)
-            updates[int(bi)] = (rows, per_col)
-        self.row_commit_ts[pos] = cts
-        self.block_cache.scatter_update(updates)
+            vals, nls = [], np.zeros(n, dtype=bool)
+            for r in range(n):
+                v, nl = self._delta_cell(ci, blocks, col, r, grow)
+                vals.append(v)
+                nls[r] = nl
+            out.append((vals, nls))
+        return out
 
-    def _apply_structural(self, ch: np.ndarray, cols, cts: np.ndarray, dh: np.ndarray,
-                          new_fp: np.ndarray | None = None,
-                          new_nb: np.ndarray | None = None) -> None:
-        """Inserts and/or deletes: repack host blocks from the resident
-        columns (no KV decode) and drop device pins to rebuild lazily.
-        ``new_fp``/``new_nb`` are the changed rows' integrity hashes/sizes —
-        mirrored through the same delete/update/insert index math as
-        ``row_commit_ts`` so the fingerprint arrays stay row-aligned."""
-        # repacks build NEW arrays (concatenate copies) so exposed buffers
-        # are never written — but the old image is about to be replaced, so
-        # sweep the ledger once: anything already corrupted reports here
-        # with its export stack instead of at a far-away release
-        _bufsan.verify_all(site="region_cache._apply_structural")
-        if self.fp_valid and new_fp is None and len(ch):
-            self._invalidate_fp()
-        fp = self.row_fp if self.fp_valid else None
-        nb = self.row_nbytes if self.fp_valid else None
-        blocks = self.block_cache.blocks
-        n_old = self.n_rows
-        # global view of each column, preserving dictionary codes
+    def _edit_blocks(self, ch, cols, pos, upd, dpos, fork=False):
+        """The block-local fold (``apply_delta``): the new version, or None
+        where an insert finds no room in its block."""
+        old = self.block_cache
+        blocks = list(old.blocks)
+        if not blocks:
+            return None
+        br = self.block_rows
+        n = self.n_rows
+        nv = np.array([b.n_valid for b in blocks], dtype=np.int64)
+        off = np.concatenate([[0], np.cumsum(nv)])
+
+        def block_of(p):
+            return np.searchsorted(off, p, side="right") - 1
+
+        edits: dict[int, dict] = {}
+
+        def edit(b: int) -> dict:
+            return edits.setdefault(int(b), {"upd": [], "del": [], "ins": []})
+
+        for i in np.flatnonzero(upd):
+            b = int(block_of(pos[i]))
+            edit(b)["upd"].append((int(pos[i] - off[b]), int(i)))
+        for p in dpos:
+            b = int(block_of(p))
+            edit(b)["del"].append(int(p - off[b]))
+        ins_idx = np.flatnonzero(~upd)
+        tail = [int(i) for i in ins_idx if pos[i] >= n]
+        for i in ins_idx:
+            if pos[i] < n:
+                b = int(block_of(pos[i]))
+                edit(b)["ins"].append((int(pos[i] - off[b]), int(i)))
+
+        def final(b: int) -> int:
+            e = edits.get(b)
+            base = int(nv[b]) if b < len(nv) else 0
+            return base if e is None else base - len(e["del"]) + len(e["ins"])
+
+        if any(final(b) > br for b in edits):
+            return None
+        if tail:
+            last = int(np.flatnonzero(nv)[-1]) if nv.any() else 0
+            n_after = n - len(dpos) + len(ins_idx)
+            free = sum(br - final(b) for b in range(last, len(blocks)))
+            if not self.reserved or free < len(tail):
+                # room for as many rows again, the block count a power of two
+                want = max(len(blocks), _pow2(-(-2 * n_after // br)))
+                free += (want - len(blocks)) * br
+                while free < len(tail):
+                    free += want * br
+                    want *= 2
+                blocks += [_empty_block(blocks[0]) for _ in range(want - len(blocks))]
+                self.reserved = True
+            b = last
+            for i in tail:
+                while final(b) >= br:
+                    b += 1
+                edit(b)["ins"].append((int(nv[b]) if b < len(nv) else 0, i))
+        n_ch = len(ch)
+        dvals = self._delta_columns(blocks, cols, n_ch) if n_ch else None
+        changed = set()
+        for b, e in edits.items():
+            blk = blocks[b]
+            new_cols = []
+            for ci, col in enumerate(blk.cols):
+                c = self._edit_column(ci, col, e, ch, dvals)
+                if c is None:
+                    raise _Repack
+                new_cols.append(c)
+            blocks[b] = _Block(new_cols, final(b))
+            changed.add(b)
+        changed.update(range(len(old.blocks), len(blocks)))
+        return old.successor(blocks, changed, account=not fork)
+
+    def _edit_column(self, ci: int, col: Column, e: dict, ch, dvals):
+        """One block column with the block's edits applied, in the column's
+        own encoding and lane (a bitpacked or coded column is edited in its
+        narrow payload); None where the lane cannot hold the new values."""
+        E = _encoding.EncodedColumn
+        if isinstance(col, E) and col.kind == "rle":
+            return self._edit_decoded(ci, col, e, ch, dvals)
+        if self.schema[ci].is_pk_handle:
+            def new_vals(idx):
+                return (np.asarray([int(ch[i]) for i in idx], dtype=np.int64),
+                        np.zeros(len(idx), dtype=bool))
+        else:
+            vals, nls = dvals[ci] if dvals is not None else ((), ())
+
+            def new_vals(idx):
+                if isinstance(vals, np.ndarray):
+                    return vals[idx], nls[idx]
+                v = np.empty(len(idx), dtype=_encoding.host_dtype(col))
+                for j, i in enumerate(idx):
+                    v[j] = vals[i]
+                return v, nls[idx]
+        if isinstance(col, E):
+            stored, nl = col.packed, col._nulls
+            info = np.iinfo(stored.dtype)
+
+            def lane(v, vn):
+                live = ~vn
+                rel = np.asarray(v).astype(np.int64) - col.ref
+                if live.any() and (int(rel[live].min()) < info.min
+                                   or int(rel[live].max()) > info.max):
+                    raise _Repack
+                return np.where(live, rel, 0).astype(stored.dtype)
+        else:
+            stored, nl = np.asarray(col.data), np.asarray(col.nulls, dtype=bool)
+            coded = (col.dictionary is not None and stored.dtype != object
+                     and stored.dtype.kind in "iu")
+
+            def lane(v, vn):
+                v = np.asarray(v)
+                if coded and len(v) and int(v.max()) > np.iinfo(stored.dtype).max:
+                    raise _Repack
+                return v.astype(stored.dtype, copy=False)
+        if e["upd"] and not self.schema[ci].is_pk_handle:
+            rows = np.asarray([r for r, _i in e["upd"]], dtype=np.int64)
+            v, vn = new_vals(np.asarray([i for _r, i in e["upd"]], dtype=np.int64))
+            stored, nl = stored.copy(), nl.copy()
+            stored[rows] = lane(v, vn)
+            nl[rows] = vn
+        dels = np.sort(np.asarray(e["del"], dtype=np.int64))
+        at = np.asarray([r for r, _i in e["ins"]], dtype=np.int64)
+        if len(at):
+            at = at - np.searchsorted(dels, at, side="left")
+        splice = _Splice(len(stored), dels, at)
+        if len(at):
+            v, vn = new_vals(np.asarray([i for _r, i in e["ins"]], dtype=np.int64))
+            stored, nl = splice(stored, lane(v, vn)), splice(nl, vn)
+        elif len(dels):
+            stored, nl = splice(stored, None), splice(nl, None)
+        if isinstance(col, E):
+            return E(col.eval_type, col.frac, "bp", len(stored), packed=stored,
+                     ref=col.ref, nulls=nl)
+        return Column(col.eval_type, stored, nl, col.frac, col.dictionary)
+
+    def _edit_decoded(self, ci: int, col: Column, e: dict, ch, dvals):
+        """``_edit_column`` through the decoded values, for a run-length
+        column: its runs are made again from the edited rows."""
+        work = _encoding.host_dtype(col)
+        d = np.asarray(_encoding.decoded_data(col)).astype(work, copy=True)
+        nl = np.asarray(_encoding.decoded_nulls(col), dtype=bool).copy()
+        vals, nls = dvals[ci] if dvals is not None else ((), ())
+
+        def new_vals(idx):
+            v = np.empty(len(idx), dtype=work)
+            for j, i in enumerate(idx):
+                v[j] = vals[i]
+            return v, nls[list(idx)]
+
+        if e["upd"]:
+            rows = [r for r, _i in e["upd"]]
+            v, vn = new_vals([i for _r, i in e["upd"]])
+            d[rows] = v
+            nl[rows] = vn
+        dels = np.sort(np.asarray(e["del"], dtype=np.int64))
+        if len(dels):
+            keep = np.ones(len(d), dtype=bool)
+            keep[dels] = False
+            d, nl = d[keep], nl[keep]
+        if e["ins"]:
+            at = np.asarray([r for r, _i in e["ins"]], dtype=np.int64)
+            at = at - np.searchsorted(dels, at, side="left")
+            v, vn = new_vals([i for _r, i in e["ins"]])
+            d = np.insert(d, at, v)
+            nl = np.insert(nl, at, vn)
+        return _encode_like(col, d, nl)
+
+    def _repack(self, ch, cols, upd, dpos, grow):
+        """The whole image re-chunked into full blocks from its resident
+        columns (no KV decode) and re-encoded: a new version with no pins.
+        The block count never falls below the current one."""
+        old = self.block_cache
+        blocks = list(old.blocks)
+        n_ch = len(ch)
+        dvals = self._delta_columns(blocks, cols, n_ch, grow) if n_ch else None
+        n = self.n_rows
+        h = self.handles
+        keep = np.ones(n, dtype=bool)
+        keep[dpos] = False
+        pos = np.searchsorted(h, ch)
+        ins = ~upd
+        kept_h = h[keep]
+        ins_at = np.searchsorted(kept_h, ch[ins])
         gdata, gnulls = [], []
         for ci in range(len(self.schema)):
             if blocks:
-                g = np.concatenate([np.asarray(b.cols[ci].data) for b in blocks])
-                if (blocks[0].cols[ci].dictionary is not None
-                        and g.dtype != object and g.dtype.kind in "iu"
-                        and g.dtype.itemsize < 8):
-                    # narrowed code lanes widen for the repack math (new
-                    # codes may exceed them); re-encode below re-narrows
-                    g = g.astype(np.int64)
-                gdata.append(g)
-                gnulls.append(np.concatenate([np.asarray(b.cols[ci].nulls) for b in blocks]))
+                parts = [np.asarray(_encoding.decoded_data(b.cols[ci])) for b in blocks]
+                work = _encoding.host_dtype(blocks[0].cols[ci])
+                g = np.concatenate(parts).astype(work, copy=True) if parts else np.empty(0, work)
+                gn = np.concatenate([np.asarray(_encoding.decoded_nulls(b.cols[ci]), dtype=bool)
+                                     for b in blocks])
             else:
                 et = self.schema[ci].ftype.eval_type
-                dtype = (
-                    object if et in (EvalType.BYTES, EvalType.JSON)
-                    else np.float64 if et == EvalType.REAL
-                    else np.int64
-                )
-                gdata.append(np.empty(0, dtype=dtype))
-                gnulls.append(np.empty(0, dtype=bool))
-        handles = self.handles
-        row_cts = self.row_commit_ts
-        if len(dh) and n_old:
-            keep = np.ones(n_old, dtype=bool)
-            dpos = np.searchsorted(handles, dh)
-            ok = dpos < n_old
-            ok &= handles[np.minimum(dpos, n_old - 1)] == dh
-            keep[dpos[ok]] = False
-            sel = np.flatnonzero(keep)
-            handles = handles[sel]
-            row_cts = row_cts[sel]
-            if fp is not None:
-                fp = fp[sel]
-                nb = nb[sel]
-            gdata = [d[sel] for d in gdata]
-            gnulls = [nl[sel] for nl in gnulls]
-        if len(ch):
-            # split changed rows into updates of surviving rows vs inserts
-            pos = np.searchsorted(handles, ch)
-            pos_c = np.minimum(pos, max(len(handles) - 1, 0))
-            is_upd = (len(handles) > 0) & (handles[pos_c] == ch) if len(handles) else (
-                np.zeros(len(ch), dtype=bool)
-            )
-            new_vals: list[list] = [[] for _ in self.schema]
-            new_nulls: list[list] = [[] for _ in self.schema]
-            for r in range(len(ch)):
-                for ci, col in enumerate(cols):
-                    if self.schema[ci].is_pk_handle:
-                        v, nl = int(ch[r]), False
-                    else:
-                        v, nl = self._delta_cell(ci, blocks, col, r)
-                    new_vals[ci].append(v)
-                    new_nulls[ci].append(nl)
-            upd_idx = np.flatnonzero(np.asarray(is_upd))
-            for ci in range(len(self.schema)):
-                if len(upd_idx) and not self.schema[ci].is_pk_handle:
-                    gdata[ci][pos_c[upd_idx]] = np.array(
-                        [new_vals[ci][int(i)] for i in upd_idx], dtype=gdata[ci].dtype
-                    )
-                    gnulls[ci][pos_c[upd_idx]] = np.array(
-                        [new_nulls[ci][int(i)] for i in upd_idx], dtype=bool
-                    )
-            if len(upd_idx):
-                row_cts = row_cts.copy()
-                row_cts[pos_c[upd_idx]] = cts[upd_idx]
-                if fp is not None:
-                    fp = fp.copy()
-                    nb = nb.copy()
-                    fp[pos_c[upd_idx]] = new_fp[upd_idx]
-                    nb[pos_c[upd_idx]] = new_nb[upd_idx]
-            ins_idx = np.flatnonzero(~np.asarray(is_upd))
-            if len(ins_idx):
-                ins_h = ch[ins_idx]
-                ins_at = np.searchsorted(handles, ins_h)
-                handles = np.insert(handles, ins_at, ins_h)
-                row_cts = np.insert(row_cts, ins_at, cts[ins_idx])
-                if fp is not None:
-                    fp = np.insert(fp, ins_at, new_fp[ins_idx])
-                    nb = np.insert(nb, ins_at, new_nb[ins_idx])
-                for ci in range(len(self.schema)):
-                    ivals = np.array(
-                        [new_vals[ci][int(i)] for i in ins_idx], dtype=gdata[ci].dtype
-                    )
-                    gdata[ci] = np.insert(gdata[ci], ins_at, ivals)
-                    gnulls[ci] = np.insert(
-                        gnulls[ci], ins_at, np.array([new_nulls[ci][int(i)] for i in ins_idx], dtype=bool)
-                    )
-        self.handles = handles
-        self.row_commit_ts = row_cts
-        if fp is not None:
-            self.row_fp = fp
-            self.row_nbytes = nb
-            # the repack is already O(n): a vectorized re-fold is simpler
-            # than incrementally retiring the deleted rows' contributions
-            self._refold()
-        # re-chunk into blocks (views over the global arrays) and drop pins
+                work = (object if et in (EvalType.BYTES, EvalType.JSON)
+                        else np.float64 if et == EvalType.REAL else np.int64)
+                g, gn = np.empty(0, dtype=work), np.empty(0, dtype=bool)
+            if n_ch:
+                if self.schema[ci].is_pk_handle:
+                    v, vn = ch.astype(g.dtype), np.zeros(n_ch, dtype=bool)
+                else:
+                    v = np.empty(n_ch, dtype=g.dtype)
+                    for j, x in enumerate(dvals[ci][0]):
+                        v[j] = x
+                    vn = dvals[ci][1]
+                    g[pos[upd]] = v[upd]
+                    gn[pos[upd]] = vn[upd]
+                g = np.insert(g[keep], ins_at, v[ins])
+                gn = np.insert(gn[keep], ins_at, vn[ins])
+            else:
+                g, gn = g[keep], gn[keep]
+            gdata.append(g)
+            gnulls.append(gn)
         templates = [blocks[0].cols[ci] if blocks else None for ci in range(len(self.schema))]
-        self.block_cache.clear_blocks()  # drops pins WITH accounting
+        dicts = {}
+        for ci, extra in grow.items():
+            base = templates[ci].dictionary
+            grown = np.empty(len(base) + len(extra), dtype=object)
+            grown[: len(base)] = base
+            grown[len(base):] = extra
+            dicts[ci] = grown
+        new = ColumnBlockCache(key=old.key)
         br = self.block_rows
-        n = len(handles)
-        for s in range(0, n, br):
-            e = min(s + br, n)
+        total = len(gdata[0]) if gdata else 0
+        for s in range(0, total, br):
+            e = min(s + br, total)
             bcols = []
             for ci in range(len(self.schema)):
                 t = templates[ci]
+                d = gdata[ci][s:e]
+                if t is not None and t.dictionary is not None and d.dtype != object:
+                    d = d.astype(np.int64)
                 bcols.append(Column(
                     t.eval_type if t is not None else self.schema[ci].ftype.eval_type,
-                    gdata[ci][s:e],
-                    gnulls[ci][s:e],
+                    d, gnulls[ci][s:e],
                     t.frac if t is not None else self.schema[ci].ftype.decimal,
-                    t.dictionary if t is not None else None,
+                    dicts.get(ci, t.dictionary if t is not None else None),
                 ))
-            self.block_cache.add(bcols, e - s)
-        self.block_cache.filled = True
-        if self.encode_enabled:
-            # structural repacks re-run the stats pass: the rebuilt plain
-            # blocks re-encode from fresh value ranges/runs (no KV decode —
-            # the repack above already stayed on resident columns)
-            self.encodings = _encoding.encode_blocks(self.block_cache, self.schema)
-        self.block_cache.drop_device()
+            new.add(bcols, e - s)
+        if self.encode_enabled and new.blocks:
+            # the stats pass again, over fresh value ranges and runs (no KV
+            # decode: the repack stayed on resident columns)
+            self.encodings = _encoding.encode_blocks(new, self.schema)
+        while len(new.blocks) < len(blocks) or not new.blocks:
+            template = new.blocks[0] if new.blocks else blocks[0]
+            new.blocks.append(_empty_block(template))
+        new.filled = True
+        new.folded = True
+        new.enc_version = old.enc_version + 1
+        return new
 
 
 class RegionCacheStats:
     __slots__ = ("hits", "misses", "deltas", "delta_rows", "stale",
                  "below_snapshot", "uncacheable",
                  "evictions", "invalidations", "bytes_pinned",
-                 "wt_deltas", "wt_rows", "wt_lost")
+                 "wt_deltas", "wt_rows", "wt_lost", "prefix_folds")
 
     def __init__(self):
         self.hits = 0
@@ -727,6 +1051,9 @@ class RegionCacheStats:
         self.wt_deltas = 0   # write-through folds (zero CF_WRITE scans)
         self.wt_rows = 0
         self.wt_lost = 0     # emission gaps forcing a scan_delta repair
+        # reads answered by folding the chain's prefix up to their apply
+        # index (each one also a wt_delta)
+        self.prefix_folds = 0
 
     def to_dict(self) -> dict:
         return {k: getattr(self, k) for k in self.__slots__}
@@ -833,8 +1160,9 @@ class RegionColumnCache:
 
         Returns ``(block_cache | None, outcome, delta_rows)``; a None block
         cache means "serve through the normal path" (outcome says why)."""
-        # the stage is the whole call LESS the lock check and any build or
-        # delta repair: those are stages of their own, and suspend this one
+        # the stage is the whole call LESS the lock check, any build or
+        # repair and any wait for the manager lock: those are stages of
+        # their own, and suspend this one
         with trace.stage("cache.lookup") as st:
             *out, below = self._serve(snap, context, columns_info, ranges,
                                       start_ts, statistics)
@@ -842,6 +1170,12 @@ class RegionColumnCache:
             if below:
                 st.tag(below_snapshot=1)
         return tuple(out)
+
+    def _locked(self):
+        """The manager lock, its wait timed as stage ``cache.mu_wait``."""
+        with trace.stage("cache.mu_wait"):
+            self._mu.acquire()
+        return _Held(self._mu)
 
     def _serve(self, snap, context, columns_info, ranges, start_ts, statistics):
         """``serve``'s answer, and last whether the reader came below the
@@ -854,12 +1188,12 @@ class RegionColumnCache:
         tenant = str((context or {}).get("tenant") or "default")
         key = (region_id, tuple(ranges), schema_sig(columns_info))
         stats = statistics or Statistics()
-        with self._mu:
+        with self._locked():
             if self._wt_token is _TOKEN_UNSET:
                 # bind to the engine behind the first served snapshot —
                 # from here on, only ITS write-through notifies are accepted.
-                # Notifies BEFORE this bind were dropped unseen, so pending
-                # creation stays gated until the stream re-anchors.
+                # Notifies BEFORE this bind were dropped unseen, so chains
+                # stay gated until the stream re-anchors.
                 self._wt_token = getattr(snap, "data_token", None)
                 self._wt_late_bound = True
             img = self._images.get(key)
@@ -878,134 +1212,271 @@ class RegionColumnCache:
             return *self._build(key, epoch, snap, columns_info, ranges,
                                 start_ts, apply_index, stats,
                                 tenant=tenant), False
-        with self._mu:
-            if self._images.get(key) is not img or img.epoch != epoch:
+        # one image's repair waits for that image alone: readers of other
+        # images, and apply_write, take only the manager lock, briefly
+        with img.mu:
+            if img.dropped or img.epoch != epoch:
                 # raced with an invalidation between lookup and here
                 self.stats.uncacheable += 1
                 self._count("uncacheable")
                 return None, "uncacheable", 0, False
-            below = start_ts < img.snapshot_ts
-            fresh = self._hit_fresh_locked(img, apply_index, start_ts, snap,
-                                           ranges, stats)
+            # img.mu is this image's own repair lock: a walk must be atomic
+            # with the image's version bump, and no reader of another image
+            # and no notify waits on it (Concurrency)
+            # lint: allow(lock-blocking-call) -- one image's repair, off _mu
+            out = self._resolve(img, key, epoch, snap, columns_info, ranges,
+                                start_ts, apply_index, stats, tenant)
+        if out[1] in ("wt_delta", "delta"):
+            with self._locked():
+                if not img.dropped:
+                    if self.devices:
+                        # a structural fold can change the block count and
+                        # bytes: keep owner_devices block-aligned
+                        self._unplace(img)
+                        self._place(img)
+                    self._enforce_budget(keep=key)
+                    self._gauge_bytes(full=False)
+        return out
+
+    def _resolve(self, img, key, epoch, snap, columns_info, ranges, start_ts,
+                 apply_index, stats, tenant):
+        """``_serve`` for a resident image, under the image's lock: a hit, a
+        fold of the pending chain's prefix up to the reader's apply index, a
+        walk of the range's CF_WRITE (``scan_delta``), a rebuild, or stale.
+
+        The image holds the rows a read at apply index ``I`` sees at any
+        timestamp in ``[max_commit_ts, hi)``, ``hi`` the oldest version at
+        ``I`` it does not hold (``hidden_ct``, or a write the chain holds
+        back).  A reader at apply index ``A >= I`` and ``start_ts`` S in that
+        interval is answered by folding every chain write of a batch at or
+        below ``A`` committed at or below S, in apply order (per key, apply
+        order is commit order): that is the engine's own state at ``A`` as S
+        reads it.  A write committed above S stays pending for later
+        readers.  A reader below ``max_commit_ts`` is folded onto a kept
+        earlier version (``_serve_base``) or is stale, as is one below the
+        image's apply index; a gapped chain or a version the chain never
+        carried sends the reader to the walk."""
+        below = start_ts < img.snapshot_ts
+        I = img.apply_index
+        if self._hit_fresh_locked(img, apply_index, start_ts, snap, ranges, stats):
             if below:
-                self._count_below_snapshot(served=fresh)
-            if fresh:
-                self.stats.hits += 1
-                self._count("hit")
-                return img.block_cache, "hit", 0, below
+                self._count_below_snapshot(served=True)
+            self.stats.hits += 1
+            self._count("hit")
+            return img.block_cache, "hit", 0, below
+        with self._locked():
+            chain_ok = img.chain_from is not None and img.chain_from <= I
+            batches = ([b for b in img.chain if b[0] <= apply_index]
+                       if chain_ok else [])
+            gap = img.chain_gap if not chain_ok else None
+        if apply_index < I or start_ts < img.max_commit_ts:
+            out = (self._serve_base(img, snap, ranges, start_ts, apply_index,
+                                    batches, chain_ok, stats)
+                   if apply_index >= I else None)
             if below:
-                # the image may hold rows this reader must not see: only a
-                # fresh scan can answer it
+                self._count_below_snapshot(served=out is not None)
+            if out is not None:
+                cache, n = out
+                self.stats.wt_deltas += 1
+                self.stats.wt_rows += n
+                self.stats.prefix_folds += 1
+                self._count_repair("fold")
+                self._count("wt_delta")
+                self._count_delta_rows(n)
+                return cache, "wt_delta", n, below
+            # the image holds rows this reader must not see (or its snapshot
+            # predates the image): only a fresh scan can answer it
+            self._count_fold_miss("reader_below_image")
+            self.stats.stale += 1
+            self._count("stale")
+            return None, "stale", 0, below
+        walk_why = None
+        if apply_index > I and not chain_ok:
+            walk_why = gap
+        elif img.hidden_ct is not None and start_ts >= img.hidden_ct:
+            walk_why = "gapped"  # a version at I that no pending batch carries
+        if walk_why is not None:
+            self._count_fold_miss(walk_why)
+            if below:
+                # a walk would rewind the image under the readers above it
+                self._count_below_snapshot(served=False)
                 self.stats.stale += 1
                 self._count("stale")
                 return None, "stale", 0, True
-            pend = img.wt_pending
-            if (pend is not None
-                    and img.apply_index > apply_index):
-                # reader's snapshot predates the image: the scan_delta below
-                # would rewind the image under the pending chain's base —
-                # keep the pending for current readers, serve this one cold
-                self.stats.stale += 1
-                self._count("stale")
-                return None, "stale", 0, False
-            if (pend is not None
-                    and apply_index >= pend["apply_index"]
-                    and img.apply_index >= pend["base"]
-                    and img.max_commit_ts <= img.snapshot_ts
-                    and start_ts >= pend["max_ct"]):
-                # write-through fast path: every data batch between the
-                # image's state and the reader's snapshot is buffered here —
-                # fold it in and serve with ZERO CF_WRITE scans.  Locks are
-                # the one thing a buffered batch cannot prove absent, so a
-                # dirty lock state re-scans CF_LOCK (tiny) first.
-                if img.locks_dirty or start_ts > img.snapshot_ts:
-                    seen = self._check_locks(img, snap, ranges, start_ts, stats)
-                    if seen == 0 and apply_index >= img.locks_dirty_at:
-                        img.locks_dirty = False
-                n_touch = len(pend["changed"]) + len(pend["deleted"])
-                if n_touch == 0:
-                    # the batches touched nothing in this image's ranges
-                    # (another table/index in the region, lock-only traffic):
-                    # advance the version bookkeeping and serve a plain HIT —
-                    # no fold, no device re-placement churn
-                    img.apply_index = apply_index
-                    img.snapshot_ts = max(img.snapshot_ts, start_ts)
-                    img.max_commit_ts = max(img.max_commit_ts, pend["max_ct"])
-                    img.wt_pending = None
-                    self.stats.hits += 1
-                    self._count("hit")
-                    return img.block_cache, "hit", 0, False
-                if img.n_rows and n_touch > _REBUILD_FRACTION * img.n_rows:
-                    self._drop(key, reason="delta_too_big")
-                    return *self._build(key, epoch, snap, columns_info,
-                                        ranges, start_ts, apply_index, stats,
-                                        tenant=tenant), False
-                handles = np.array(sorted(pend["changed"]), dtype=np.int64)
-                delta = {
-                    "changed_handles": handles,
-                    "changed_values": [pend["changed"][int(h)][0] for h in handles],
-                    "changed_commit_ts": np.array(
-                        [pend["changed"][int(h)][1] for h in handles], dtype=np.int64),
-                    "deleted_handles": np.array(sorted(pend["deleted"]), dtype=np.int64),
-                    "max_commit_ts": max(img.max_commit_ts, pend["max_ct"]),
-                }
-                with trace.stage("cache.fill", kind="wt_delta") as st:
-                    n = img.apply_delta(delta, apply_index, start_ts)
-                    img.wt_pending = None
-                    if self.devices:
-                        self._unplace(img)
-                        self._place(img)
-                    st.tag(rows=n)
-                self.stats.wt_deltas += 1
-                self.stats.wt_rows += n
-                self._count("wt_delta")
-                self._count_delta_rows(n)
-                self._enforce_budget(keep=key)
-                self._gauge_bytes(full=False)
-                return img.block_cache, "wt_delta", n, False
-            with trace.stage("cache.fill", kind="scan_delta"):
-                # lint: allow(lock-blocking-call) -- the fold-in must be atomic
-                # with the image version bump (docs: Concurrency); the scan is
-                # bounded by the delta size, and cold BUILDS run outside the lock
-                delta = scan_delta(snap, start_ts, ranges, img.handles,
-                                   img.row_commit_ts, statistics=stats)
+            return self._walk(img, key, epoch, snap, columns_info, ranges,
+                              start_ts, apply_index, stats, tenant)
+        writes = [(i, *w) for i, ws, _l in batches for w in ws if w[1] <= start_ts]
+        locks = self._check_locks(img, snap, ranges, start_ts, stats,
+                                  known=self._locks_at(img, batches, apply_index))
+        if not locks and apply_index >= img.locks_dirty_at:
+            # this snapshot contains the dirtying batch and the range is
+            # lock-free — safe to stop checking.  An OLDER snapshot seeing
+            # no locks proves nothing.
+            img.locks_dirty = False
+        if below:
+            self._count_below_snapshot(served=True)
+        if not writes:
+            # nothing this reader may see is missing: a hit (the batches up
+            # to its apply index, if any, are all above its timestamp)
+            self._advance(img, apply_index, start_ts, locks)
+            self.stats.hits += 1
+            self._count("hit")
+            return img.block_cache, "hit", 0, below
+        delta = _fold_writes(writes)
+        n_touch = len(delta["changed_handles"]) + len(delta["deleted_handles"])
+        if img.n_rows and n_touch > _REBUILD_FRACTION * img.n_rows:
+            return self._rebuild(key, epoch, snap, columns_info, ranges,
+                                 start_ts, apply_index, stats, tenant)
+        with trace.stage("cache.repair", how="fold") as st:
+            n = img.apply_delta(delta)
+            st.tag(rows=n)
+        img.max_commit_ts = max(img.max_commit_ts, delta["max_commit_ts"])
+        self._advance(img, apply_index, start_ts, locks)
+        img.note_folded(writes)
+        self.stats.wt_deltas += 1
+        self.stats.wt_rows += n
+        self.stats.prefix_folds += 1
+        self._count_repair("fold")
+        self._count("wt_delta")
+        self._count_delta_rows(n)
+        return img.block_cache, "wt_delta", n, below
+
+    def _serve_base(self, img, snap, ranges, start_ts, apply_index, batches,
+                    chain_ok, stats):
+        """A reader at or past the image's apply index whose start_ts lies
+        below a commit the image holds: the newest kept base version at or
+        below the reader's apply index and timestamp, with every write since
+        it that the reader sees folded in (the writes the image has folded
+        since, and the chain's), as a version of its own — ``(block cache,
+        rows)``, or None where no base can answer.  The image is left as it
+        stands."""
+        for state, pos in reversed(img.bases):
+            if (state["apply_index"] <= apply_index
+                    and state["max_commit_ts"] <= start_ts
+                    and (state["hidden_ct"] is None or start_ts < state["hidden_ct"])):
+                break
+        else:
+            return None
+        if img.hidden_ct is not None and start_ts >= img.hidden_ct:
+            return None  # a version a dropped chain held back
+        if apply_index > img.apply_index and not chain_ok:
+            return None  # batches past the image nobody carried
+        writes = [w for w in img.since[pos:] if w[0] <= apply_index and w[2] <= start_ts]
+        writes += [(i, *w) for i, ws, _l in batches for w in ws if w[1] <= start_ts]
+        self._check_locks(img, snap, ranges, start_ts, stats,
+                          known=self._locks_at(img, batches, apply_index))
+        if not writes:
+            return state["block_cache"], 0
+        delta = _fold_writes(writes)
+        with trace.stage("cache.repair", how="fold") as st:
+            cache = img.fork(state, delta)
+            n = len(delta["changed_handles"]) + len(delta["deleted_handles"])
+            st.tag(rows=n, base=1)
+        return cache, n
+
+    def _locks_at(self, img, batches, apply_index) -> dict | None:
+        """Every CF_LOCK entry of the image's ranges at ``apply_index``: the
+        image's own at its apply index with the lock changes of the chain's
+        batches up to ``apply_index`` laid over them; None where that is not
+        known."""
+        if img.locks is None:
+            return None
+        if apply_index == img.apply_index:
+            return img.locks
+        locks = dict(img.locks)
+        for i, _ws, ls in batches:
+            if i > img.apply_index:
+                for raw, lock in ls:
+                    if lock is None:
+                        locks.pop(raw, None)
+                    else:
+                        locks[raw] = lock
+        return locks
+
+    def _advance(self, img, apply_index, start_ts, locks) -> None:
+        """The image now answers for ``apply_index``: its pending chain keeps
+        only the writes above ``start_ts`` of the batches up to it (held back
+        for later readers) and the batches after it.  Caller holds the
+        image's lock; the apply index moves under the manager lock with the
+        chain, so a notify sees them agree."""
+        with self._locked():
+            kept = []
+            for i, ws, ls in img.chain:
+                if i > apply_index:
+                    kept.append((i, ws, ls))
+                    continue
+                held = [w for w in ws if w[1] > start_ts]
+                if held:
+                    # its lock changes are in ``locks`` now
+                    kept.append((i, held, []))
+            img.chain = kept
+            if not kept:
+                # nothing pending: the next notify anchors a new chain (a
+                # read past the image with none in between walks)
+                img.chain_from = None
+                img.chain_gap = "gapped"
+            if apply_index > img.apply_index:
+                img.apply_index = apply_index
+                img.locks = locks
+            elif locks is not None:
+                img.locks = locks
+        # never lowered: a reader below it leaves it where it was
+        img.snapshot_ts = max(img.snapshot_ts, start_ts)
+
+    def _walk(self, img, key, epoch, snap, columns_info, ranges, start_ts,
+              apply_index, stats, tenant):
+        """Repair by diffing the range's CF_WRITE against the image
+        (``scan_delta``): the chain is gapped, late-bound or carried a batch
+        it could not parse, or the reader may see a version no pending batch
+        carries."""
+        with trace.stage("cache.repair", how="walk") as st:
+            delta = scan_delta(snap, start_ts, ranges, img.handles,
+                               img.row_commit_ts, statistics=stats)
             if delta is None:
                 self.stats.uncacheable += 1
                 self._count("uncacheable")
-                self._drop(key, reason="unvectorizable")
+                with self._locked():
+                    self._drop(key, reason="unvectorizable")
                 return None, "uncacheable", 0, False
             n_touch = len(delta["changed_handles"]) + len(delta["deleted_handles"])
             if img.n_rows and n_touch > _REBUILD_FRACTION * img.n_rows:
-                self._drop(key, reason="delta_too_big")
-                return *self._build(key, epoch, snap, columns_info, ranges,
-                                    start_ts, apply_index, stats,
-                                    tenant=tenant), False
-            with trace.stage("cache.fill", kind="delta") as st:
-                n = img.apply_delta(delta, apply_index, start_ts)
-                st.tag(rows=n)
-            if apply_index >= img.locks_dirty_at:
-                # scan_delta lock-checked the ranges on a snapshot that
-                # contains the dirtying batch
-                img.locks_dirty = False
-            pend = img.wt_pending
-            if pend is not None and (pend["apply_index"] <= img.apply_index
-                                     or img.apply_index < pend["base"]):
-                # the scan repaired past the pending chain (or rewound under
-                # its base): replaying it would regress rows — drop it
-                img.wt_pending = None
-            if self.devices:
-                # a structural repack can change the block count and bytes:
-                # refresh the placement so owner_devices stays block-aligned
-                with trace.stage("cache.fill", kind="place"):
-                    self._unplace(img)
-                    self._place(img)
-            self.stats.deltas += 1
-            self.stats.delta_rows += n
-            self._count("delta")
-            self._count_delta_rows(n)
-            self._enforce_budget(keep=key)
-            self._gauge_bytes(full=False)
-            return img.block_cache, "delta", n, False
+                st.tag(rows=n_touch, rebuild=1)
+                return self._rebuild(key, epoch, snap, columns_info, ranges,
+                                     start_ts, apply_index, stats, tenant)
+            n = img.apply_delta(delta)
+            st.tag(rows=n)
+        img.max_commit_ts = delta["visible_max_ct"]
+        img.hidden_ct = delta["hidden_min_ct"]
+        img.snapshot_ts = max(img.snapshot_ts, start_ts)
+        img.locks = {} if delta["locks_seen"] == 0 else None
+        if apply_index >= img.locks_dirty_at:
+            # scan_delta lock-checked the ranges on a snapshot that
+            # contains the dirtying batch
+            img.locks_dirty = False
+        with self._locked():
+            # the scan saw every batch up to apply_index: what the chain held
+            # of them is in the image or behind hidden_ct now
+            img.apply_index = apply_index
+            img.chain = [b for b in img.chain if b[0] > apply_index]
+            if not img.chain:
+                img.chain_from = None
+                img.chain_gap = "gapped"
+        img.rebase()
+        self.stats.deltas += 1
+        self.stats.delta_rows += n
+        self._count_repair("walk")
+        self._count("delta")
+        self._count_delta_rows(n)
+        return img.block_cache, "delta", n, False
+
+    def _rebuild(self, key, epoch, snap, columns_info, ranges, start_ts,
+                 apply_index, stats, tenant):
+        """A delta over a quarter of the image: build it anew."""
+        self._count_repair("rebuild")
+        with self._locked():
+            self._drop(key, reason="delta_too_big")
+        return self._build(key, epoch, snap, columns_info, ranges, start_ts,
+                           apply_index, stats, tenant=tenant)
 
     # -- integrity plane (docs/integrity.md) ---------------------------------
 
@@ -1079,7 +1550,7 @@ class RegionColumnCache:
         else None (the CPU-oracle scan serves).  The per-row hash is the
         checksum_range entry by construction, so warm and cold answers are
         byte-identical.  Locks are the one thing the fingerprint cannot
-        prove absent — a dirty/newer-ts serve re-scans CF_LOCK exactly like
+        prove absent — a dirty/newer-ts serve checks CF_LOCK exactly like
         the hit path (and raises KeyIsLocked exactly like the oracle scan
         would)."""
         region_id = (context or {}).get("region_id")
@@ -1090,26 +1561,26 @@ class RegionColumnCache:
         rkey = tuple(ranges)
         stats = Statistics()
         below = False  # below the snapshot_ts of an image it was held to
-        with self._mu:
-            for key, img in self._images.items():
-                if key[0] != region_id or key[1] != rkey:
-                    continue
+        with self._locked():
+            imgs = [img for key, img in self._images.items()
+                    if key[0] == region_id and key[1] == rkey]
+        for img in imgs:
+            with img.mu:
                 if img.epoch != epoch or not img.fp_valid:
                     continue
                 img_below = start_ts < img.snapshot_ts
-                # the hit path's exact freshness + stale-guard + lock rules
-                # (ONE definition — _hit_fresh_locked — so the warm
-                # Checksum path can never drift from what a served hit
-                # would have answered)
+                # the hit path's exact freshness + lock rules (ONE
+                # definition — _hit_fresh_locked — so the warm Checksum path
+                # can never drift from what a served hit would have answered)
                 if self._hit_fresh_locked(img, apply_index, start_ts, snap,
                                           ranges, stats):
                     if img_below:
                         self._count_below_snapshot(served=True)
                     return img.checksum_parts()
                 below = below or img_below
-            if below:
-                # one reader, once: no image of the region served it
-                self._count_below_snapshot(served=False)
+        if below:
+            # one reader, once: no image of the region served it
+            self._count_below_snapshot(served=False)
         return None
 
     def invalidate_region(self, region_id: int, reason: str = "epoch") -> None:
@@ -1123,25 +1594,27 @@ class RegionColumnCache:
             self._wt_seen.pop(region_id, None)
             self._rebalance()
 
-    # -- write-through intake (raft apply -> pending deltas) -----------------
+    # -- write-through intake (raft apply -> pending chains) -----------------
 
     def apply_write(self, region_id: int, parse_once, apply_index: int,
                     token=None) -> None:
-        """Buffer a committed batch's row changes on every resident image of
-        ``region_id``.  Raft applies a region's entries in order on one
-        worker, so notifies arrive in apply-index order per region; an index
-        at or below the watermark is a replica's replay of a batch already
-        merged (identical ops by raft) and is skipped.  ``parse_once`` is
-        the notify's memoized op parser — invoked OUTSIDE the manager lock
-        (it may read CF_DEFAULT), at most once across every live cache."""
-        with self._mu:
+        """Append a committed batch's row and lock changes to the chain of
+        every resident image of ``region_id``.  Raft applies a region's
+        entries in order on one worker, so notifies arrive in apply-index
+        order per region; an index at or below the watermark is a replica's
+        replay of a batch already merged (identical ops by raft) and is
+        skipped.  ``parse_once`` is the notify's memoized op parser — invoked
+        OUTSIDE the manager lock (it may read CF_DEFAULT), at most once
+        across every live cache.  Only the manager lock is taken, and only
+        briefly: an image under repair does not hold this up."""
+        with self._locked():
             if self._wt_token is _TOKEN_UNSET or token != self._wt_token:
                 return  # not this cache's engine (or cache never served yet)
             prev = self._wt_seen.get(region_id, -1)
             if apply_index <= prev:
                 return
             # the watermark advances even with write_through off: flipping
-            # it back on must not let a pending start across unseen batches
+            # it back on must not let a chain start across unseen batches
             self._wt_seen[region_id] = apply_index
             if not self.write_through:
                 # an unbuffered batch gaps any surviving chain — drop it,
@@ -1151,35 +1624,34 @@ class RegionColumnCache:
             if not any(k[0] == region_id for k in self._images):
                 return
         parsed = parse_once()
-        with self._mu:
-            # images may have churned while parsing: re-list.  A freshly
-            # built image already containing this batch just replays it
-            # idempotently; the ``prev`` creation check below still blocks
-            # any image whose snapshot predates an unbuffered notify.
+        with self._locked():
+            # images may have churned while parsing: re-list.  The ``prev``
+            # check in _merge_pending still blocks any image whose state
+            # predates an unbuffered notify.
             imgs = [img for k, img in self._images.items() if k[0] == region_id]
             if not imgs:
                 return
             if parsed is None:
-                # not expressible as row changes: pendings are now gapped
+                # not expressible as row changes: the chains are now gapped
                 for img in imgs:
-                    img.wt_pending = None
+                    self._drop_chain(img, "unparsable")
                 self.stats.wt_lost += 1
                 self._count_wt_lost()
                 return
-            writes, lock_keys = parsed
+            writes, lock_ops = parsed
             for img in imgs:
-                self._merge_pending(img, writes, lock_keys, prev, apply_index)
+                self._merge_pending(img, writes, lock_ops, prev, apply_index)
 
     def note_write_lost(self, region_id: int, apply_index: int,
                         token=None) -> None:
         """A data change of unknown content landed (emission off, raft
         snapshot apply, merge catch-up, OR a notify that faulted after the
-        watermark already advanced): drop pendings unconditionally — a
-        dropped chain only costs a scan_delta repair, while a chain kept
-        across an unbuffered batch serves wrong rows forever — and advance
-        the watermark so no pending restarts until a read catches the image
-        up past ``apply_index``."""
-        with self._mu:
+        watermark already advanced): drop chains unconditionally — a dropped
+        chain only costs a scan_delta repair, while a chain kept across an
+        unbuffered batch serves wrong rows forever — and advance the
+        watermark so no chain restarts until a read catches the image up
+        past ``apply_index``."""
+        with self._locked():
             if self._wt_token is _TOKEN_UNSET or token != self._wt_token:
                 return
             if apply_index > self._wt_seen.get(region_id, -1):
@@ -1189,67 +1661,76 @@ class RegionColumnCache:
     def _drop_pendings_locked(self, region_id: int) -> None:
         dropped = False
         for k, img in self._images.items():
-            if k[0] == region_id and img.wt_pending is not None:
-                img.wt_pending = None
-                dropped = True
+            if k[0] == region_id and img.chain_from is not None:
+                dropped = bool(img.chain) or dropped
+                self._drop_chain(img, "gapped")
         if dropped:
             self.stats.wt_lost += 1
             self._count_wt_lost()
 
-    def _merge_pending(self, img, writes, lock_keys, prev: int,
+    @staticmethod
+    def _drop_chain(img, why: str) -> None:
+        """Gap ONE image's chain (caller holds the manager lock).  What it
+        held back is a version the image may come to answer for without
+        holding it (a fold under way advances the image past batches it
+        held back): every write it held moves into ``hidden_ct``."""
+        held = [c for _i, ws, _l in img.chain for _h, c, _v in ws]
+        if held:
+            img.hidden_ct = min(held) if img.hidden_ct is None else min(img.hidden_ct, *held)
+        img.chain = []
+        img.chain_from = None
+        img.chain_gap = why
+
+    def _merge_pending(self, img, writes, lock_ops, prev: int,
                        apply_index: int) -> None:
         ranges = img.key[1]
-        if any(_in_ranges(rk, ranges) for rk in lock_keys):
+        in_locks = [(raw, lock) for raw, lock in lock_ops if _in_ranges(raw, ranges)]
+        if in_locks:
             img.locks_dirty = True
             img.locks_dirty_at = max(img.locks_dirty_at, apply_index)
-        pend = img.wt_pending
-        if pend is None:
+        if img.chain_from is None:
             if prev > img.apply_index or apply_index <= img.apply_index:
                 # a batch between the image's state and this one was never
                 # buffered (image built mid-stream, or emission was off):
                 # this image repairs through scan_delta, not a gapped chain
+                img.chain_gap = "gapped"
                 return
             if self._wt_late_bound and prev < 0:
                 # first observed notify for this region on a LATE-bound
                 # cache: earlier notifies may have been dropped unseen
                 # while unbound, so this chain cannot anchor — the next
                 # read repairs via scan_delta, re-anchoring the stream
+                img.chain_gap = "late_bound"
                 return
-            pend = img.wt_pending = {
-                "base": img.apply_index, "apply_index": apply_index,
-                "changed": {}, "deleted": set(), "max_ct": 0,
-            }
-        else:
-            pend["apply_index"] = apply_index
-        for raw, cts, v in writes:
-            if not _in_ranges(raw, ranges):
-                continue
-            if len(raw) != 19:
-                # non-record key inside a record range: not foldable
-                self._drop_pending_img(img)
-                return
-            try:
-                h = int(decode_record_handles([raw])[0])
-            except Exception:  # noqa: BLE001
-                self._drop_pending_img(img)
-                return
-            if v is None:
-                pend["changed"].pop(h, None)
-                pend["deleted"].add(h)
-            else:
-                pend["deleted"].discard(h)
-                pend["changed"][h] = (v, cts)
-            pend["max_ct"] = max(pend["max_ct"], cts)
-        if len(pend["changed"]) + len(pend["deleted"]) > max(1024, img.n_rows):
-            # pending outgrew the image: a rebuild will beat replaying it
+            img.chain_from = img.apply_index
+        elif apply_index <= img.apply_index:
+            # a batch the image is already past: replaying it could put an
+            # older version over a newer one
+            self._drop_pending_img(img)
+            return
+        mine = [w for w in writes if _in_ranges(w[0], ranges)]
+        if any(len(raw) != 19 for raw, _c, _v in mine):
+            # non-record key inside a record range: not foldable
+            self._drop_pending_img(img, "unparsable")
+            return
+        try:
+            hs = decode_record_handles([raw for raw, _c, _v in mine]).tolist()
+        except Exception:  # noqa: BLE001
+            self._drop_pending_img(img, "unparsable")
+            return
+        rows = [(h, cts, v) for h, (_raw, cts, v) in zip(hs, mine)]
+        if rows or in_locks:
+            img.chain.append((apply_index, rows, in_locks))
+        if sum(len(ws) for _i, ws, _l in img.chain) > max(1024, img.n_rows):
+            # the chain outgrew the image: a rebuild will beat replaying it
             self._drop_pending_img(img)
 
-    def _drop_pending_img(self, img) -> None:
-        """Drop ONE image's pending chain, keeping the wt_lost accounting in
-        step with every other drop path (the Grafana emission-gap series
-        must see these, or a rising scan_delta rate is undiagnosable)."""
-        if img.wt_pending is not None:
-            img.wt_pending = None
+    def _drop_pending_img(self, img, why: str = "gapped") -> None:
+        """Drop ONE image's chain, keeping the wt_lost accounting in step
+        with every other drop path (the Grafana emission-gap series must see
+        these, or a rising scan_delta rate is undiagnosable)."""
+        if img.chain_from is not None:
+            self._drop_chain(img, why)
             self.stats.wt_lost += 1
             self._count_wt_lost()
 
@@ -1512,9 +1993,11 @@ class RegionColumnCache:
                 return None, "uncacheable", 0
             img = RegionImage(key, epoch, list(columns_info), self.block_rows)
             img.tenant = tenant
-            img.fill(handles, values, src.row_commit_ts, src.max_commit_ts,
+            img.fill(handles, values, src.row_commit_ts, src.visible_max_ct,
                      apply_index, start_ts, raw_keys=keys,
-                     encode=self.encode_columns)
+                     encode=self.encode_columns, hidden_ct=src.hidden_min_ct)
+            # the build's lock check met no lock: none at apply_index
+            img.locks = {} if src.locks_seen == 0 else None
             if img.nbytes > self.byte_budget:
                 self.stats.uncacheable += 1
                 self._count("too_big")
@@ -1526,6 +2009,7 @@ class RegionColumnCache:
                 if (existing is None or existing.epoch != epoch
                         or existing.apply_index <= apply_index):
                     if existing is not None:
+                        existing.dropped = True
                         self._unplace(existing)
                     self._images[key] = img
                     self._place(img)
@@ -1537,52 +2021,55 @@ class RegionColumnCache:
 
     def _hit_fresh_locked(self, img, apply_index, start_ts, snap, ranges,
                           stats) -> bool:
-        """ONE definition of hit-path freshness (serve()'s hits AND the
-        warm Checksum path): True iff the image may serve ``start_ts``
-        as-is at ``apply_index``.  Re-scans CF_LOCK when it must (raising
-        on a blocking lock, exactly like the oracle scan would) and
-        maintains ``locks_dirty`` / ``snapshot_ts`` like a served hit.
-        Caller holds the manager lock."""
-        below = start_ts < img.snapshot_ts
-        # below: a reader another session overtook.  snapshot_ts was raised
-        # past this start_ts, the rows were not: at apply_index the image
-        # holds exactly the rows of commits at or below max_commit_ts, so a
-        # reader of the same engine state at or above that timestamp sees
-        # those rows and no others (docs/region_column_cache.md, "Readers
-        # below the snapshot").  Any other reader below the snapshot may be
-        # shown rows committed above its timestamp.
-        if not (apply_index == img.apply_index and (
-                start_ts >= img.max_commit_ts if below
-                else (start_ts == img.snapshot_ts
-                      or img.max_commit_ts <= img.snapshot_ts))):
+        """ONE definition of hit-path freshness (the warm Checksum path, and
+        what ``_resolve`` serves as a hit): True iff the image may serve
+        ``start_ts`` as-is at ``apply_index`` — the same apply index, and a
+        timestamp that reads the rows the image holds: at or above
+        ``max_commit_ts`` (the newest commit it holds), below ``hidden_ct``
+        and below every write the chain holds back.  Checks CF_LOCK when it
+        must (raising on a blocking lock, exactly like the oracle scan
+        would) and maintains ``locks_dirty`` / ``snapshot_ts`` like a served
+        hit.  Caller holds the image's lock."""
+        if apply_index != img.apply_index or start_ts < img.max_commit_ts:
             return False
+        if img.hidden_ct is not None and start_ts >= img.hidden_ct:
+            return False
+        if img.chain:
+            with self._locked():
+                if any(w[1] <= start_ts for _i, ws, _l in img.chain for w in ws):
+                    return False
         if start_ts != img.snapshot_ts or img.locks_dirty:
             # at the READER's start_ts, below the snapshot as above it
-            seen = self._check_locks(img, snap, ranges, start_ts, stats)
-            if seen == 0 and apply_index >= img.locks_dirty_at:
+            locks = self._check_locks(img, snap, ranges, start_ts, stats,
+                                      known=img.locks)
+            img.locks = locks
+            if not locks and apply_index >= img.locks_dirty_at:
                 # this snapshot contains the dirtying batch and the range is
-                # lock-free — safe to stop re-scanning.  An OLDER snapshot
+                # lock-free — safe to stop re-checking.  An OLDER snapshot
                 # seeing no locks proves nothing.
                 img.locks_dirty = False
             # never lowered: a reader below it leaves it where it was
             img.snapshot_ts = max(img.snapshot_ts, start_ts)
         return True
 
-    def _check_locks(self, img, snap, ranges, ts, stats) -> int:
-        """Raise on a blocking lock; return how many locks the ranges hold
-        (0 lets callers clear a dirty-lock flag).
+    def _check_locks(self, img, snap, ranges, ts, stats, known=None) -> dict:
+        """Raise on a blocking lock; return every CF_LOCK entry of the ranges
+        in ``snap`` (raw key -> Lock).
 
-        The CF_LOCK scan is skipped (``how=memo``) only where ``snap`` cannot
+        Answered three ways, counted by ``how``.  ``memo``: ``snap`` cannot
         differ, in CF_LOCK, from a snapshot whose scan of these ranges met no
-        lock: an empty range blocks no reader whatever its timestamp.  The
+        lock (an empty range blocks no reader whatever its timestamp).  The
         witness is the engine's own: ``snap`` reads at sequence S, the memo's
         snapshot read at S0, and no batch has touched CF_LOCK after the older
         of the two (``cf_touched_seq``, read after both were taken, so it can
-        only be too high).  A snapshot that cannot say, a dirty image, or
-        any CF_LOCK write anywhere in the store since, scans as before."""
+        only be too high).  ``chain``: ``known`` holds the ranges' locks at
+        the snapshot's apply index, as the write-through chain carried them
+        (``_locks_at``), and the snapshot is a raft snapshot that vouches for
+        that index; a known lock that would block is read back from the
+        snapshot before it is believed (``_lock_in``).  ``scan``: the walk of the ranges'
+        CF_LOCK, for everything else."""
         from ..util.metrics import REGISTRY
 
-        seen = 0
         walked0 = stats.lock.next
         with trace.stage("cache.lock_check") as st:
             seq = snap.sequence()
@@ -1592,34 +2079,55 @@ class RegionColumnCache:
                 touched = snap.cf_touched_seq(CF_LOCK)
                 if touched is not None and touched <= min(seq, memo):
                     how = "memo"
-            # tagged and counted before the scan, which may raise
+            blocking = ()
+            if (how == "scan" and known is not None
+                    and getattr(snap, "apply_index", None) is not None):
+                blocking = [(raw, lock) for raw, lock in known.items()
+                            if not lock.is_visible_to(ts, frozenset())]
+                if all(_lock_in(snap, raw, lock) for raw, lock in blocking):
+                    how = "chain"
+            # tagged and counted before the check, which may raise
             st.tag(how=how)
             REGISTRY.counter(
                 "tikv_coprocessor_region_cache_lock_check_total",
                 "Warm-hit lock checks, by how they were answered (memo: the "
-                "snapshot proved CF_LOCK unchanged since a lock-free scan)",
+                "snapshot proved CF_LOCK unchanged since a lock-free scan; "
+                "chain: the write-through chain's locks at the apply index)",
             ).inc(how=how)
-            if how == "scan":
+            if how == "memo":
+                seen = {}
+            elif how == "chain":
+                seen = known
+                if blocking:
+                    raise KeyIsLockedError(*blocking[0])
+                if not seen and seq is not None:
+                    # the snapshot's CF_LOCK holds no lock in range: what a
+                    # lock-free scan of it would have recorded
+                    img.lock_free_seq = seq if memo is None else max(memo, seq)
+            else:
+                seen = {}
                 for start, end in ranges:
                     enc_start = Key.from_raw(start).encoded
                     enc_end = Key.from_raw(end).encoded
                     for k, v in snap.scan_cf(CF_LOCK, enc_start, enc_end):
                         stats.lock.next += 1
-                        seen += 1
-                        _check_lock(v, Key.from_encoded(k).to_raw(), ts, frozenset())
-                if seen == 0 and seq is not None:
+                        raw = Key.from_encoded(k).to_raw()
+                        _check_lock(v, raw, ts, frozenset())
+                        seen[raw] = Lock.from_bytes(v)
+                if not seen and seq is not None:
                     # the SNAPSHOT's sequence, never a stamp read after it: a
                     # lock written since is not in what was scanned.  A lock
                     # that did not block this ts may block the next: seen > 0
                     # records nothing
                     img.lock_free_seq = seq if memo is None else max(memo, seq)
-            st.tag(locks_seen=seen, keys_walked=stats.lock.next - walked0)
+            st.tag(locks_seen=len(seen), keys_walked=stats.lock.next - walked0)
         return seen
 
     def _drop(self, key, reason: str) -> None:
         img = self._images.pop(key, None)
         if img is None:
             return
+        img.dropped = True
         self._unplace(img)
         img.block_cache.clear_blocks()
         img.block_cache.filled = False
@@ -1644,6 +2152,7 @@ class RegionColumnCache:
             if victim is None:
                 break
             img = self._images.pop(victim)
+            img.dropped = True
             self._unplace(img)
             img.block_cache.clear_blocks()
             img.block_cache.filled = False
@@ -1690,6 +2199,26 @@ class RegionColumnCache:
             "Readers below an image's snapshot_ts, by whether the image "
             "served them (served) or they went on to stale (refused)",
         ).inc(outcome="served" if served else "refused")
+
+    def _count_repair(self, how: str) -> None:
+        from ..util.metrics import REGISTRY
+
+        REGISTRY.counter(
+            "tikv_coprocessor_region_cache_repair_total",
+            "Repairs of a resident image behind the engine, by how (fold: the "
+            "write-through chain's prefix; walk: scan_delta over CF_WRITE; "
+            "rebuild: a delta over a quarter of the image)",
+        ).inc(how=how)
+
+    def _count_fold_miss(self, why: str) -> None:
+        from ..util.metrics import REGISTRY
+
+        REGISTRY.counter(
+            "tikv_coprocessor_region_cache_fold_miss_total",
+            "Reads behind a resident image that the write-through chain "
+            "could not answer, by why (gapped, late_bound, unparsable, "
+            "reader_below_image)",
+        ).inc(why=why)
 
     def _count_wt_lost(self) -> None:
         from ..util.metrics import REGISTRY

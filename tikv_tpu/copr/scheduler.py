@@ -794,11 +794,19 @@ class CoprReadScheduler:
         program, slabs on their owner devices) when the endpoint has one,
         else the single-device vmapped program.  Returns the finalize
         closure."""
+        from ..storage.mvcc.reader import KeyIsLockedError
+
         live: list[_Slot] = []
         for slot in slots:
             ok = False
             try:
                 ok = self._resolve_slot(slot)
+            except KeyIsLockedError as exc:
+                # its readers met a lock: that is their answer (the client
+                # backs off and retries), not a reason to serve them again
+                for it in slot.items:
+                    errors[it.index] = exc
+                continue
             except Exception:  # noqa: BLE001 — resolution must not kill the batch
                 ok = False
             if ok:
@@ -812,19 +820,22 @@ class CoprReadScheduler:
                 self._shed(slot, "no_cache", results, errors)
         # two slots (different start_ts / apply_index) can resolve to the
         # SAME region image — the region cache keys images on (region_id,
-        # ranges, schema) only, and resolving the later slot delta-applies
-        # the image IN PLACE, retroactively changing what the earlier slot's
-        # resolution saw.  Only the LAST resolution's view is current, so
-        # only that slot may batch; earlier aliases shed to the per-request
-        # path, where serve() re-resolves them (a now-stale start_ts takes
-        # the stale fallback) — snapshot isolation over bytes saved.
-        by_image: dict[int, _Slot] = {}
+        # ranges, schema) only.  Each holds the version its own resolution
+        # saw (a repair makes a new version and leaves the old one whole),
+        # but a batch with more slots than images is a block-count tuple no
+        # warm-up built: only the LAST resolution's slot batches, earlier
+        # aliases shed to the per-request path, where serve() resolves them
+        # again at their own snapshots.
+        def image_of(slot):
+            return slot.cache.key if slot.cache.key is not None else id(slot.cache)
+
+        by_image: dict = {}
         for slot in live:
-            prev = by_image.get(id(slot.cache))
+            prev = by_image.get(image_of(slot))
             if prev is not None:
                 self._shed(prev, "aliased_image", results, errors)
-            by_image[id(slot.cache)] = slot
-        live = [s for s in live if by_image.get(id(s.cache)) is s]
+            by_image[image_of(slot)] = slot
+        live = [s for s in live if by_image.get(image_of(s)) is s]
         if not live:
             return None
         # admission to the batch is still forming it: evaluator, mesh and

@@ -39,7 +39,7 @@ from .rpn import RpnExpression
 from ..util.metrics import REGISTRY
 
 __all__ = [
-    "ColumnZone", "build_block_zones", "ensure_zones", "fold_update",
+    "ColumnZone", "build_block_zones", "ensure_zones",
     "prune_blocks", "count_prune", "enabled", "set_enabled", "PruneStats",
 ]
 
@@ -164,36 +164,6 @@ def ensure_zones(cache) -> bool:
     return True
 
 
-def fold_update(zones: dict[int, ColumnZone] | None, col_updates: dict) -> None:
-    """Fold one in-place write-through delta into a block's zones
-    (``cache.scatter_update`` calls this — the single host mutation funnel
-    for in-place updates).  Widening only: incoming non-null values widen
-    ``lo``/``hi``; the null bounds widen by how many written rows could
-    have flipped null-ness either way.  The zone goes stale because an
-    overwrite may have removed the old extremal row."""
-    if not zones:
-        return
-    for ci, (vals, nls) in col_updates.items():
-        z = zones.get(ci)
-        if z is None:
-            continue
-        nls = np.asarray(nls, dtype=bool)
-        k = int(len(nls))
-        k_null = int(nls.sum())
-        live = ~nls
-        if live.any():
-            v = np.asarray(vals)[live]
-            if v.dtype == object:
-                zones.pop(ci, None)  # decoded-object write: stop tracking
-                continue
-            lo, hi = _scalar(v.min()), _scalar(v.max())
-            z.lo = lo if z.lo is None else min(z.lo, lo)
-            z.hi = hi if z.hi is None else max(z.hi, hi)
-        z.null_hi = min(z.n, z.null_hi + k_null)
-        z.null_lo = max(0, z.null_lo - (k - k_null))
-        z.stale = True
-
-
 # ---------------------------------------------------------------------------
 # Conjunct recognition + per-block emptiness tests
 # ---------------------------------------------------------------------------
@@ -303,7 +273,9 @@ def prune_blocks(cache, sel_rpns, path: str = "unary",
     conjuncts (AND semantics: any conjunct that proves a block empty prunes
     it).  Returns None when pruning is off / inapplicable / proves nothing
     — callers then keep their exact pre-zone-map code path."""
-    if not enabled() or not sel_rpns:
+    if not enabled() or not sel_rpns or getattr(cache, "folded", False):
+        # a version a write-through fold made: its blocks change with the
+        # commits, and its zones would be rebuilt per version
         return None
     recs = [r for r in (_recognize(rpn) for rpn in sel_rpns) if r is not None]
     if not recs:

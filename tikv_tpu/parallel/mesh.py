@@ -699,9 +699,9 @@ def _slab_pins(ev, cache, assign: dict, by_id: dict, ship, nullable,
     ``assign``: device id -> ascending block indices.  Returns {device_id:
     (data_tuple[(B_d, rows)] per ship col, nulls_tuple per nullable col)},
     each leaf COMMITTED to its owner device.  Pinned on the cache under a
-    ``shardslab`` signature, so repeat batches pay zero transfer; a delta
-    apply drops the pins (cache.scatter_update treats the kind as opaque)
-    and they rebuild here from the updated host blocks.
+    ``shardslab`` signature, so repeat batches pay zero transfer; a fold's
+    new version starts without them (``ColumnBlockCache.successor``) and
+    they rebuild here from its host blocks.
 
     With an encoding ``plan`` (copr/encoding.py — every cache in the batch
     carries the same signature, RLE excluded), bitpacked/narrow-code lanes

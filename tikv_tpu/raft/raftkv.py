@@ -59,6 +59,21 @@ class _PrefixCursor(Cursor):
         return self._c.value()
 
 
+def _applied_snapshot(peer, engine):
+    """``(apply_index, engine snapshot)`` sampled as one step: the apply
+    path writes a run and advances the peer's apply_index under
+    ``apply_mu``, so the snapshot holds exactly the applied entries up to
+    the index it is given — neither fewer (a too-high claim would mark
+    missing writes as present) nor more (a later commit the index does not
+    claim would be read under an image that lacks it)."""
+    mu = getattr(peer, "apply_mu", None)
+    if mu is None:
+        return peer.apply_index, engine.snapshot()
+    with mu:
+        # lint: allow(lock-blocking-call) -- a snapshot is a refcount, not I/O
+        return peer.apply_index, engine.snapshot()
+
+
 class RegionSnapshot(Snapshot):
     def __init__(self, engine_snapshot: Snapshot, region: Region,
                  apply_index: int | None = None, data_token=None):
@@ -256,8 +271,8 @@ class RaftKv(Engine):
         peer = self.store.peers.get(region_id)
         if peer is None:
             raise NotLeaderError(region_id, None)
-        applied = peer.apply_index  # before the freeze — see stale path
-        return RegionSnapshot(self.store.engine.snapshot(), peer.region.clone(),
+        applied, frozen = _applied_snapshot(peer, self.store.engine)
+        return RegionSnapshot(frozen, peer.region.clone(),
                               apply_index=applied,
                               data_token=self.data_token)
 
@@ -266,13 +281,12 @@ class RaftKv(Engine):
         ctx = ctx or {}
         if ctx.get("stale_read"):
             resolved, required_idx = self._stale_ready(peer, ctx)
-            # apply_index SAMPLED BEFORE the engine freeze: the snapshot may
-            # contain later applies, but must never claim an index whose data
-            # it lacks — the region cache stamps images with this index and a
-            # too-high claim would mark missing writes as present
-            # (docs/write_path.md apply_index contract)
-            applied = peer.apply_index
-            snap = RegionSnapshot(self.store.engine.snapshot(), peer.region.clone(),
+            # apply_index and the engine freeze as one step: the snapshot
+            # holds exactly the data of the index it claims — the region
+            # cache stamps images with this index (docs/write_path.md
+            # apply_index contract)
+            applied, frozen = _applied_snapshot(peer, self.store.engine)
+            snap = RegionSnapshot(frozen, peer.region.clone(),
                                   apply_index=applied,
                                   data_token=self.data_token)
             snap.stale = True
@@ -292,8 +306,8 @@ class RaftKv(Engine):
         # (apply_index, not node.applied — the pipeline may still be writing),
         # reads skip the ReadIndex round entirely
         if peer.node.lease_valid() and peer.apply_index >= peer.node.commit:
-            applied = peer.apply_index  # before the freeze — see stale path
-            return RegionSnapshot(self.store.engine.snapshot(), peer.region.clone(),
+            applied, frozen = _applied_snapshot(peer, self.store.engine)
+            return RegionSnapshot(frozen, peer.region.clone(),
                                   apply_index=applied,
                                   data_token=self.data_token)
         return self._read_index_barrier(peer)
@@ -315,8 +329,8 @@ class RaftKv(Engine):
         self._pump_until(done, peer.region.id)
         if err:
             raise err[0]
-        applied = peer.apply_index  # before the freeze — see stale path
-        return RegionSnapshot(self.store.engine.snapshot(), peer.region.clone(),
+        applied, frozen = _applied_snapshot(peer, self.store.engine)
+        return RegionSnapshot(frozen, peer.region.clone(),
                               apply_index=applied,
                               data_token=self.data_token)
 

@@ -395,6 +395,11 @@ class StorePeer:
         # run on service threads, acks on apply workers, reads on the raft
         # thread
         self._cb_mu = make_lock("raft.peer.cb", label=f"region-{region.id}")
+        # an applied run's engine write and its apply_index advance are one
+        # step to a snapshot: RaftKv samples (apply_index, engine snapshot)
+        # under this lock, so a snapshot never holds data its index does
+        # not claim (docs/write_path.md)
+        self.apply_mu = make_lock("raft.peer.apply", label=f"region-{region.id}")
         self.pending_read_states: list[tuple[bytes, int]] = []
 
     # -- raft driving ------------------------------------------------------
@@ -734,15 +739,17 @@ class StorePeer:
         if applied is not None:
             new_apply = max(self.apply_index, applied)
             wb.put_cf(CF_RAFT, keys.apply_state_key(self.region.id), codec.encode_u64(new_apply))
-            eng.write(wb)
             if executed:
-                # write-through delta BEFORE apply_index becomes visible: a
+                # write-through delta BEFORE the batch reaches the engine: a
                 # snapshot carrying new_apply can then only be taken after
-                # the region cache buffered this batch (no gap window)
+                # the region cache buffered this batch (no gap window), and
+                # one carrying an older index holds none of it
                 self._emit_write_delta(
                     [op for _e, c in executed for op in c["ops"]], new_apply
                 )
-            self.apply_index = new_apply
+            with self.apply_mu:
+                eng.write(wb)
+                self.apply_index = new_apply
         elif wb.ops:
             eng.write(wb)
         for _e, cmd in executed:
@@ -907,8 +914,10 @@ class StorePeer:
         the normal apply path and commit-merge catch-up).  With an
         ``apply_index``, the committed batch also flows into the region
         column cache as a write-through delta — emission runs BEFORE the
-        caller advances the peer's visible apply_index, so a snapshot that
-        reports this index can only exist after its delta was buffered."""
+        batch reaches the engine, and the engine write and the apply_index
+        advance are one step to a snapshot (``apply_mu``): a snapshot that
+        reports this index can only exist after its delta was buffered, and
+        one that reports an older index holds none of it."""
         wb = WriteBatch()
         for op, cf, key, val in cmd["ops"]:
             dkey = keys.data_key(key)
@@ -918,9 +927,13 @@ class StorePeer:
                 wb.delete_cf(cf, dkey)
             elif op == "delete_range":
                 wb.delete_range_cf(cf, dkey, keys.data_key(val))
-        self.store.engine.write(wb)
         if cmd["ops"] and apply_index is not None:
             self._emit_write_delta(cmd["ops"], apply_index)
+        with self.apply_mu:
+            # lint: allow(lock-blocking-call) -- the write and the index are one step
+            self.store.engine.write(wb)
+            if apply_index is not None:
+                self.apply_index = max(self.apply_index, apply_index)
         self.store.on_applied(region, cmd)
 
     def _ack(self, e: Entry, result, err) -> None:

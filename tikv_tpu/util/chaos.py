@@ -693,17 +693,19 @@ def corrupt_image(cache, rng, region_id: int | None = None,
         if mode == "pending":
             if not has_pending:
                 return None
-            pend = img.wt_pending
-            handles = sorted(pend["changed"])
+            handles = sorted(img.wt_pending["changed"])
             h = handles[rng.randrange(len(handles))]
-            v, cts = pend["changed"][h]
+            # the handle's newest write in the chain is the one a fold takes
+            bi, wi = max((bi, wi) for bi, (_i, ws, _l) in enumerate(img.chain)
+                         for wi, w in enumerate(ws) if w[0] == h)
+            _h, cts, v = img.chain[bi][1][wi]
             if not v:
                 return None
             ba = bytearray(v)
             for _ in range(max(bits, 1)):
                 i = rng.randrange(len(ba))
                 ba[i] ^= 1 << rng.randrange(8)
-            pend["changed"][h] = (bytes(ba), cts)
+            img.chain[bi][1][wi] = (h, cts, bytes(ba))
             return {"mode": "pending", "region_id": key[0], "handle": int(h)}
         blocks = img.block_cache.blocks
         if not blocks:
