@@ -62,6 +62,8 @@ LAZY_SERIES = {
     "tikv_coprocessor_region_cache_below_snapshot_total",
     "tikv_coprocessor_region_cache_repair_total",
     "tikv_coprocessor_region_cache_fold_miss_total",
+    "tikv_coprocessor_region_cache_fold_place_total",
+    "tikv_coprocessor_region_cache_fold_part_seconds",
     "tikv_coprocessor_integrity_mismatch_total",
     "tikv_coprocessor_integrity_quarantine_total",
     "tikv_coprocessor_integrity_scrub_total",

@@ -64,16 +64,22 @@ class _Held(LocalEngine):
 
 class Stream:
     """One region under an RF1/RF2-shaped writer, a warm endpoint over it
-    and the cache-off reference, one timestamp oracle."""
+    and the cache-off reference, one timestamp oracle.  ``row`` makes row
+    ``i``'s value (``bump`` makes another one for the same handle);
+    ``handles`` are the loaded rows' handles."""
 
-    def __init__(self, seed: int, n: int = N_ROWS, mk_dag=_scan_dag):
+    def __init__(self, seed: int, n: int = N_ROWS, mk_dag=_scan_dag, row=None,
+                 block_rows: int = BLOCK_ROWS, encode: bool = True, handles=None):
         self.rng = np.random.default_rng(seed)
+        self.row = row or (lambda i, bump=0: _row(i + bump))
         self.kv = BTreeEngine()
-        for i in range(n):
-            put_committed(self.kv, record_key(TABLE_ID, i), _row(i), 90, 100)
+        for i in (range(n) if handles is None else handles):
+            put_committed(self.kv, record_key(TABLE_ID, i), self.row(i), 90, 100)
         self.eng = _Held(self.kv)
-        self.warm = Endpoint(self.eng, enable_device=True, block_rows=BLOCK_ROWS)
+        self.warm = Endpoint(self.eng, enable_device=True, block_rows=block_rows,
+                             encode_columns=encode)
         self.cold = Endpoint(self.eng, enable_device=True, enable_region_cache=False)
+        self.fresh_args = {"block_rows": block_rows, "encode_columns": encode}
         self.mk_dag = mk_dag
         self.tso = 200
         self.apply_index = 3
@@ -119,7 +125,7 @@ class Stream:
     def rf1(self, k: int) -> int:
         hs = range(self.next_handle, self.next_handle + k)
         self.next_handle += k
-        return self.txn([(h, _row(h)) for h in hs])
+        return self.txn([(h, self.row(h)) for h in hs])
 
     def rf2(self, k: int) -> int:
         hs = range(self.oldest, self.oldest + k)
@@ -153,8 +159,9 @@ class Stream:
 def test_folded_image_equals_a_fresh_build_after_every_commit(seed):
     """Every commit of an RF1/RF2 stream, read below it (it stays pending),
     at it and after it at the reader's own apply index, and through a
-    snapshot from before its batches: each answer equals the cache-off
-    endpoint's, and no read walks the region's CF_WRITE."""
+    snapshot from before its batches (folded onto a kept earlier version at
+    that snapshot's apply index, never a fresh scan): each answer equals the
+    cache-off endpoint's, and no read walks the region's CF_WRITE."""
     s = Stream(seed)
     assert s.read(s.ts()) == "miss"
     for _ in range(8):
@@ -165,7 +172,7 @@ def test_folded_image_equals_a_fresh_build_after_every_commit(seed):
                 s.read(ts)
             s.read(s.ts(), frozen=frozen)
     st = s.warm.region_cache.stats
-    assert st.deltas == 0, (st.to_dict(), s.outcomes)
+    assert st.deltas == 0 and st.stale == 0, (st.to_dict(), s.outcomes)
     assert s.outcomes.get("wt_delta", 0) >= 16 and st.prefix_folds >= 16, s.outcomes
     # the appends reserved room: the block count moved once, to a power of two
     img = s.image()
@@ -496,3 +503,307 @@ def test_an_insert_into_a_full_block_repacks_and_keeps_the_block_count():
     s.rf2(5)
     assert s.read(s.ts()) == "wt_delta"
     assert s.warm.region_cache.stats.deltas == 0
+
+
+# -- a fold re-places the rows it changed, on the host and on the device ------
+
+from tikv_tpu.copr.datatypes import ColumnInfo, FieldType  # noqa: E402
+
+LAYOUT_COLUMNS = [
+    ColumnInfo(1, FieldType.int64(), is_pk_handle=True),
+    ColumnInfo(2, FieldType.varchar()),  # one of three words: dictionary-coded
+    ColumnInfo(3, FieldType.int64()),    # 0..22, no runs: bitpacked
+    ColumnInfo(4, FieldType.int64()),    # handle // 50: run-length
+]
+
+
+def _layout_row(i: int, bump: int = 0) -> bytes:
+    """Row ``i``; ``bump`` changes its word and its number, never its run."""
+    name = [b"apple", b"banana", b"cherry"][(i + bump) % 3]
+    return encode_row(LAYOUT_COLUMNS[1:], [name, (i * 7 + bump) % 23, i // 50])
+
+
+def _layout_dag(group: bool, summed):
+    aggs = ([AggDescriptor("sum", col(summed))] if summed is not None else []) + [
+        AggDescriptor("count", None)]
+    return lambda: DagRequest(executors=[
+        TableScan(TABLE_ID, LAYOUT_COLUMNS), Aggregation([col(1)] if group else [], aggs)])
+
+
+# layout -> (images encoded, the plan (what its stacked pin ships), how a
+# small edit is re-placed: a pin with a run-length leaf ships the block)
+LAYOUTS = {
+    "plain": (False, _layout_dag(True, 2), "edit"),
+    "bitpacked": (True, _layout_dag(False, 2), "edit"),
+    "dictionary": (True, _layout_dag(True, None), "edit"),
+    "run_length": (True, _layout_dag(False, 3), "block"),
+}
+FOLD_EDITS = ("tail_append", "append_into_next_block", "head_delete",
+              "mid_block_update", "mid_block_insert", "scattered_insert", "large_insert",
+              "fork")
+
+
+def _placed() -> dict:
+    c = REGISTRY.counter("tikv_coprocessor_region_cache_fold_place_total", "")
+    return {how: c.get(how=how) for how in ("edit", "block")}
+
+
+def _pins_equal_a_fresh_build(ep, dag, cache) -> None:
+    """The stacked pins and the n_valid/offset pin ``cache`` carries equal
+    the ones the evaluator builds afresh from the same host blocks."""
+    import jax
+
+    from tikv_tpu.copr import encoding
+    from tikv_tpu.copr.cache import ColumnBlockCache, _Block
+
+    ev, _params = ep._bind(dag)
+    fresh = ColumnBlockCache(cache.key)
+    fresh.blocks = [_Block(b.cols, b.n_valid) for b in cache.blocks]
+    fresh.filled, fresh.enc_version = True, cache.enc_version
+    pins = dict(cache.blocks[0].device)
+    kinds = {sig[0] for sig in pins if isinstance(sig[0], str)}
+    assert "nvoff" in kinds and kinds & {"stacked", "stackedenc"}, kinds
+    for sig, entry in pins.items():
+        if sig[0] == "nvoff":
+            want = ev._nvoff_device(fresh, fresh.blocks)
+        elif sig[0] in ("stacked", "stackedenc"):
+            plan = (None if sig[0] == "stacked"
+                    else encoding.device_plan(fresh, list(sig[1]), list(sig[2])))
+            want = ev._stacked_device(fresh, fresh.blocks, list(sig[1]), list(sig[2]),
+                                      plan=plan)[:2]
+            entry = entry[:2]
+        else:
+            continue
+        got, want = jax.tree.leaves(entry), jax.tree.leaves(want)
+        assert len(got) == len(want)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=str(sig))
+
+
+def _image_equals_a_fresh_build(s) -> None:
+    """The warm image's rows, row arrays and fingerprints equal those of an
+    image built afresh from the engine at the same timestamp, and the
+    scrubber's deep check of it passes."""
+    from tikv_tpu.copr import integrity
+
+    img = s.image()
+    cache = s.warm.region_cache
+    (key,) = cache._images
+    assert integrity.verify_image(cache, key, s.kv.snapshot())["outcome"] == "ok"
+    fresh_ep = Endpoint(s.eng, enable_device=True, **s.fresh_args)
+    req = CoprRequest(103, s.mk_dag(), [record_range(TABLE_ID)], img.snapshot_ts, context={
+        "region_id": REGION, "region_epoch": (1, 1), "apply_index": s.apply_index})
+    assert fresh_ep.handle_request(req).metrics["region_cache"] == "miss"
+    (fresh,) = fresh_ep.region_cache._images.values()
+    for name in ("handles", "row_commit_ts", "row_fp", "row_nbytes"):
+        np.testing.assert_array_equal(getattr(img, name), getattr(fresh, name), err_msg=name)
+    assert (img.fp_value, img.fp_integrity) == (fresh.fp_value, fresh.fp_integrity)
+    assert img.checksum_parts() == fresh.checksum_parts()
+    for ci in range(len(img.schema)):
+        def rows(image):
+            data, nulls = [], []
+            for b in image.block_cache.blocks:
+                c = b.cols[ci].decoded()
+                data += np.asarray(c.data)[: b.n_valid].tolist()
+                nulls += np.asarray(c.nulls)[: b.n_valid].tolist()
+            return data, nulls
+        assert rows(img) == rows(fresh), ci
+
+
+@pytest.mark.parametrize("edit", FOLD_EDITS)
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_folded_image_and_its_pins_equal_a_fresh_build(layout, edit, monkeypatch):
+    """After each commit of an RF1/RF2-shaped stream, in each column layout:
+    the folded image equals an image built afresh (rows, row arrays,
+    fingerprints, the scrubber's deep check), and the device pins it carries
+    (stacked, n_valid/offset) equal the ones built afresh from its blocks.
+    The commit of each case re-places its changed blocks the way its shape
+    and layout call for: an edit of one run of deletes, one run of inserts
+    and a few updated rows sends the device those rows; a pin with a
+    run-length leaf, inserts scattered between rows, or more new rows than
+    the largest bucket ship the block."""
+    encode, mk_dag, small = LAYOUTS[layout]
+    forks = []
+    if edit == "fork":
+        # one 4,096-row block; a base every 225 chain writes
+        monkeypatch.setattr(rc, "_BASE_EVERY", 225)
+        real_fork = rc.RegionImage.fork
+        monkeypatch.setattr(rc.RegionImage, "fork", lambda self, state, delta: forks.append(
+            real_fork(self, state, delta)) or forks[-1])
+        s = Stream(41, mk_dag=mk_dag, row=_layout_row, block_rows=4096, encode=encode)
+    elif edit in ("mid_block_insert", "scattered_insert"):
+        s = Stream(41, mk_dag=mk_dag, row=_layout_row, encode=encode,
+                   handles=range(0, 10 * N_ROWS, 10))
+        s.next_handle = 10 * N_ROWS
+    else:
+        s = Stream(41, mk_dag=mk_dag, row=_layout_row, encode=encode)
+    assert s.read(s.ts()) == "miss"
+    # the stream going: the first append reserves room (the tail block of
+    # 64 rows holds 44, then 49), and the stacked pins ride every version
+    for k in (3, 2):
+        s.rf1(k)
+        assert s.read(s.ts()) == "wt_delta"
+    s.rf2(2)
+    assert s.read(s.ts()) == "wt_delta"
+    before = _placed()
+    if edit == "tail_append":
+        s.rf1(6)
+        want = {small: 1}
+    elif edit == "append_into_next_block":
+        s.rf1(12)
+        assert s.read(s.ts()) == "wt_delta"
+        before = _placed()
+        s.rf1(10)  # three rows close the tail block, seven open the next
+        want = {small: 2}
+    elif edit == "head_delete":
+        s.rf2(10)
+        want = {small: 1}
+    elif edit == "mid_block_update":
+        s.txn([(h, s.row(h, bump=1)) for h in range(80, 85)])
+        want = {small: 1}
+    elif edit in ("mid_block_insert", "scattered_insert"):
+        s.rf2(60)  # every tenth handle: six rows out of the head block
+        assert s.read(s.ts()) == "wt_delta"
+        before = _placed()
+        if edit == "mid_block_insert":  # one run of rows between two
+            s.txn([(h, s.row(h)) for h in (71, 72, 73, 74)])
+            want = {small: 1}
+        else:  # rows between several: not one run, so the block ships whole
+            s.txn([(h, s.row(h)) for h in (71, 81, 91, 101)])
+            want = {"block": 1}
+    elif edit == "large_insert":
+        s.rf1(15)  # closes the tail block
+        assert s.read(s.ts()) == "wt_delta"
+        before = _placed()
+        s.rf1(17)  # into the next, past the 16-row bucket
+        want = {"block": 1}
+    else:
+        for _ in range(9 + 8):
+            s.rf1(25)
+            assert s.read(s.ts()) == "wt_delta"
+        reader_ts = s.ts()
+        s.rf1(25)
+        assert s.read(s.ts()) == "wt_delta"  # the 18th: a base at 450 writes
+        before = _placed()
+        assert s.read(reader_ts) == "wt_delta"  # below it: 200 rows onto the base at 225
+        assert len(forks) == 1 and sum(b.n_valid for b in forks[0].blocks) == N_ROWS + 5 - 2 + 17 * 25
+        assert {h: n - before[h] for h, n in _placed().items() if n > before[h]} == {small: 1}
+        _pins_equal_a_fresh_build(s.warm, s.mk_dag(), forks[0])
+        _image_equals_a_fresh_build(s)
+        return
+    assert s.read(s.ts()) == "wt_delta"
+    assert {h: n - before[h] for h, n in _placed().items() if n > before[h]} == want
+    _pins_equal_a_fresh_build(s.warm, s.mk_dag(), s.image().block_cache)
+    _image_equals_a_fresh_build(s)
+
+
+def test_a_fold_shares_what_it_did_not_touch_and_leaves_the_old_version_whole():
+    """Across a fold that appends, deletes at the head and updates: every
+    block it did not touch is the previous version's own object, with its
+    per-row pieces and its pins; a reader holding the previous version reads
+    it whole afterwards — its host arrays and its device pins as they were,
+    and its answer unchanged — though the appends wrote into the room behind
+    the tail block's arrays."""
+    import jax
+
+    from tikv_tpu.copr.dag import TopN
+
+    s = Stream(51, mk_dag=_agg_dag)
+    s.read(s.ts())
+    for _ in range(2):
+        s.rf1(4)
+        s.rf2(3)
+        s.read(s.ts())
+        # a TopN over the same image pins its blocks one by one
+        s.mk_dag = lambda: DagRequest(executors=[
+            TableScan(TABLE_ID, PRODUCT_COLUMNS), TopN([(col(3), True)], 5)])
+        s.read(s.ts())
+        s.mk_dag = _agg_dag
+    img = s.image()
+    old, old_rows = img.block_cache, img.rows
+    ev, params = s.warm._bind(_agg_dag())
+    answer = ev.run(None, cache=old, params=params).encode()
+
+    def held(cache, rows):
+        host = [[np.array(a) for c in b.cols for a in (
+            getattr(c, "packed", None) if getattr(c, "packed", None) is not None else c.data,
+            c.nulls)] for b in cache.blocks]
+        pieces = [np.array(a) for a in rows.h + rows.cts + rows.fp + rows.nb]
+        pins = {sig: [np.asarray(x) for x in jax.tree.leaves(e)]
+                for sig, e in cache.blocks[0].device.items()}
+        return host, pieces, pins
+
+    before = held(old, old_rows)
+    assert any(sig[0] in ("stacked", "stackedenc") for sig in before[2])
+    s.rf1(5)
+    s.rf2(4)
+    s.txn([(h, _row(h + 1)) for h in (150, 151)])
+    assert s.read(s.ts()) == "wt_delta"
+    new, new_rows = img.block_cache, img.rows
+    touched = {0, 2, 4}  # the head, the update, the tail
+    assert [b.n_valid for b in new.blocks[:5]] != [b.n_valid for b in old.blocks[:5]]
+    for b in range(1, len(old.blocks)):
+        if b in touched:
+            assert new.blocks[b] is not old.blocks[b]
+            continue
+        assert new.blocks[b] is old.blocks[b]
+        assert all(getattr(new_rows, name)[b] is getattr(old_rows, name)[b]
+                   for name in ("h", "cts", "fp", "nb"))
+    assert any(old.blocks[b].device for b in range(1, len(old.blocks)) if b not in touched)
+    after = held(old, old_rows)
+    for x, y in zip(before[0], after[0]):
+        for a, b in zip(x, y):
+            np.testing.assert_array_equal(a, b)
+    for a, b in zip(before[1], after[1]):
+        np.testing.assert_array_equal(a, b)
+    assert before[2].keys() == after[2].keys()
+    for sig in before[2]:
+        for a, b in zip(before[2][sig], after[2][sig]):
+            np.testing.assert_array_equal(a, b)
+    assert ev.run(None, cache=old, params=params).encode() == answer
+    # the image's bytes count the room's spare rows behind the tail block
+    assert img._room.nbytes() > 0
+    assert img.nbytes == new.nbytes() + 16 * new_rows.n + img._room.nbytes()
+
+
+def test_fifty_folds_leave_checksum_and_scrub_equal_to_a_fresh_build():
+    """Fifty folds of RF1/RF2 commits: the warm Checksum answer
+    (``checksum_parts``), the fingerprints and the scrubber's deep check all
+    agree with an image built afresh from the engine."""
+    s = Stream(61, mk_dag=_agg_dag)
+    s.read(s.ts())
+    for i in range(25):
+        s.rf1(int(s.rng.integers(3, 12)))
+        s.read(s.ts())
+        s.rf2(int(s.rng.integers(2, 9)))
+        s.read(s.ts())
+    assert s.outcomes.get("wt_delta", 0) == 50
+    _image_equals_a_fresh_build(s)
+
+
+def test_the_place_counter_and_fold_edit_share():
+    """A 50-row commit re-places its block by edit, one that fills most of a
+    256-row block ships it whole; ``fold_edit_share`` reads the share of
+    edits over the window, and None where nothing was re-placed."""
+    from benchmark import counters
+    from benchmark.layer_metrics import fold_edit_share
+
+    s = Stream(71, n=800, mk_dag=_agg_dag, block_rows=256)
+    s.read(s.ts())
+    s.rf1(3)
+    s.read(s.ts())
+    s.rf2(2)
+    s.read(s.ts())
+    start = counters.snapshot()
+    assert fold_edit_share.read({"before": start, "after": counters.snapshot()}) is None
+    before = _placed()
+    s.rf1(50)
+    assert s.read(s.ts()) == "wt_delta"
+    mid = counters.snapshot()
+    assert _placed() == {"edit": before["edit"] + 1, "block": before["block"]}
+    assert fold_edit_share.read({"before": start, "after": mid}) == 100.0
+    s.rf1(150)  # 85 rows in the tail block, 150 more: most of the block
+    assert s.read(s.ts()) == "wt_delta"
+    assert _placed() == {"edit": before["edit"] + 1, "block": before["block"] + 1}
+    assert fold_edit_share.read({"before": start, "after": counters.snapshot()}) == 50.0
+    _pins_equal_a_fresh_build(s.warm, s.mk_dag(), s.image().block_cache)

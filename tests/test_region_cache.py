@@ -872,10 +872,12 @@ def test_below_snapshot_reader_leaves_a_pending_chain_alone():
     r = warm.handle_request(_req(_scan_dag(), 500, 4))
     assert r.metrics["region_cache"] == "wt_delta"
     assert r.data == cold.handle_request(_req(_scan_dag(), 500, 4)).data
-    # now the image is ahead of that reader's snapshot: stale, as before
+    # now the image is ahead of that reader's snapshot: the reader is folded
+    # onto the kept version at its own apply index, without the write
     eng.held = predates
     r = warm.handle_request(_req(_scan_dag(), 470, 3))
-    assert r.metrics["region_cache"] == "stale"
+    assert r.metrics["region_cache"] == "wt_delta"
+    assert warm.region_cache.stats.below_image == 1
     assert r.data == cold.handle_request(_req(_scan_dag(), 470, 3)).data
 
 

@@ -46,6 +46,7 @@ from ..analysis.sanitizer import note_blocking
 from ..util import trace
 from . import observatory as _obs
 from .aggr import AggDescriptor, AggState
+from .cache import nv_off
 from .dag import (
     Aggregation,
     DagRequest,
@@ -1260,8 +1261,7 @@ class JaxDagEvaluator:
 
         def build(_blk):
             note_blocking("device.pin:nvoff")
-            nv = np.array([b.n_valid for b in blocks], dtype=np.int64)
-            off = np.concatenate([[0], np.cumsum(nv)[:-1]]).astype(np.int64)
+            nv, off = nv_off(blocks)
             return jax.block_until_ready((jnp.asarray(nv), jnp.asarray(off)))
 
         return cache.device_arrays(blocks[0], sig, build)

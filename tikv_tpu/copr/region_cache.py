@@ -72,6 +72,7 @@ embedded engines).
 
 from __future__ import annotations
 
+import contextlib
 import copy
 import time
 import weakref
@@ -88,7 +89,7 @@ from ..util import trace
 from . import encoding as _encoding
 from . import integrity as _integrity
 from .byterows import ByteRows
-from .cache import ColumnBlockCache, _Block
+from .cache import BlockEdit, ColumnBlockCache, _Block
 from .datatypes import Column, EvalType
 from .mvcc_batch import MvccBatchScanSource, scan_delta
 from .table import RowBatchDecoder, decode_record_handles, decode_record_key, record_key
@@ -99,6 +100,24 @@ _REBUILD_FRACTION = 0.25  # delta bigger than this fraction of rows ⇒ rebuild
 _TOKEN_UNSET = object()  # cache not yet bound to an engine's data_token
 
 _CACHES: "weakref.WeakSet[RegionColumnCache]" = weakref.WeakSet()
+
+
+@contextlib.contextmanager
+def _fold_part(part: str):
+    """Time one part of a fold into
+    ``tikv_coprocessor_region_cache_fold_part_seconds{part}``.  Not a stage:
+    the parts lie inside ``cache.repair``, which keeps the whole fold."""
+    from ..util.metrics import REGISTRY
+
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        REGISTRY.histogram(
+            "tikv_coprocessor_region_cache_fold_part_seconds",
+            "Wall time of the parts of a fold inside cache.repair, by part: "
+            "decode, rows, blocks, place",
+        ).observe(time.perf_counter() - t0, part=part)
 
 
 def notify_region_epoch_change(region_id: int, reason: str = "epoch") -> None:
@@ -268,18 +287,123 @@ def schema_sig(columns_info) -> tuple:
     )
 
 
-_STATE_KEYS = ("block_cache", "handles", "row_commit_ts", "row_fp", "row_nbytes",
-               "fp_value", "fp_integrity", "fp_valid", "nbytes", "apply_index",
-               "max_commit_ts", "hidden_ct", "reserved", "encodings")
+_STATE_KEYS = ("block_cache", "rows", "fp_value", "fp_integrity", "fp_valid",
+               "nbytes", "apply_index", "max_commit_ts", "hidden_ct", "reserved",
+               "encodings")
 _BASE_EVERY = 256   # chain writes folded between two kept base versions
 _BASES_KEPT = 4
+_ROW_DTYPES = {"h": np.int64, "cts": np.int64, "fp": np.uint64, "nb": np.int64}
+
+
+class _Rows:
+    """One version's per-row arrays — handle (``h``), commit_ts (``cts``),
+    integrity fingerprint (``fp``) and (key, value) bytes (``nb``) — as one
+    piece per block, beside the block: a fold replaces the pieces of the
+    blocks it changes and shares the others with the previous version, as it
+    shares the blocks.  ``fp``/``nb`` are None where the fingerprint plane is
+    off.  The whole-image arrays are made on first use (``whole``), for the
+    readers off the warm path: the scrubber, the CF_WRITE walk, a repack."""
+
+    __slots__ = ("h", "cts", "fp", "nb", "n", "_index", "_whole")
+
+    def __init__(self, h: list, cts: list, fp: list | None = None,
+                 nb: list | None = None):
+        self.h, self.cts, self.fp, self.nb = h, cts, fp, nb
+        self.n = sum(len(a) for a in h)
+        self._index = None
+        self._whole: dict = {}
+
+    @classmethod
+    def split(cls, counts, h, cts, fp=None, nb=None) -> "_Rows":
+        """Whole-image arrays cut into pieces of ``counts`` rows (views),
+        the arrays kept as the version's whole ones."""
+        cuts = np.cumsum(counts)[:-1]
+
+        def cut(a):
+            return None if a is None else (np.split(a, cuts) if len(counts) else [])
+
+        rows = cls(cut(h), cut(cts), cut(fp), cut(nb))
+        if len(counts):
+            rows._whole = {name: a for name, a in zip(("h", "cts", "fp", "nb"), (h, cts, fp, nb))
+                           if a is not None}
+        return rows
+
+    def whole(self, name: str) -> np.ndarray:
+        got = self._whole.get(name)
+        if got is None:
+            parts = getattr(self, name) or []
+            got = self._whole[name] = (np.concatenate(parts) if parts
+                                       else np.empty(0, dtype=_ROW_DTYPES[name]))
+        return got
+
+    def locate(self, hs: np.ndarray):
+        """``(positions, found)``: where each handle of ``hs`` is, or would
+        go, among the whole image's rows (``np.searchsorted`` over them), and
+        whether it is there — looked up in the one block it can lie in, by
+        the index of each non-empty block's first handle."""
+        if self._index is None:
+            ne = np.array([b for b, a in enumerate(self.h) if len(a)], dtype=np.int64)
+            self._index = (ne, np.array([self.h[b][0] for b in ne], dtype=np.int64),
+                           np.concatenate([[0], np.cumsum([len(a) for a in self.h])]))
+        ne, first, off = self._index
+        pos = np.zeros(len(hs), dtype=np.int64)
+        found = np.zeros(len(hs), dtype=bool)
+        if not len(ne) or not len(hs):
+            return pos, found
+        k = np.searchsorted(first, hs, side="right") - 1
+        for kk in np.unique(k[k >= 0]).tolist():
+            b = int(ne[kk])
+            sel = k == kk
+            piece, want = self.h[b], hs[sel]
+            lp = np.searchsorted(piece, want)
+            pos[sel] = off[b] + lp
+            found[sel] = piece[np.minimum(lp, len(piece) - 1)] == want
+        return pos, found
+
+
+class _Room:
+    """Spare rows behind the tail of an image's block arrays.  An append at
+    a block's end writes past the rows of every version that holds the
+    array (each holds a view that ends where its own rows end), so no
+    version is written and the block is not copied.  It may do so only from
+    the array that ends where the buffer's rows end (``ends``); any other
+    (an older version, a fork for one reader, a block that lost its tail)
+    copies into a buffer of its own.  Callers hold the image's lock."""
+
+    def __init__(self, rows: int):
+        self.rows = rows
+        self.ends: dict = {}  # key -> (buffer, rows of it some version holds)
+
+    def append(self, key, arr: np.ndarray, new) -> np.ndarray:
+        new = np.asarray(new, dtype=arr.dtype)
+        k = len(new)
+        got = self.ends.get(key)
+        if got is not None and len(arr) and arr.base is got[0]:
+            buf, end = got
+            at = (arr.__array_interface__["data"][0]
+                  - buf.__array_interface__["data"][0]) // buf.itemsize
+            if at + len(arr) == end and end + k <= len(buf):
+                buf[end:end + k] = new
+                self.ends[key] = (buf, end + k)
+                return buf[at:end + k]
+        buf = np.empty(max(self.rows, len(arr) + k), dtype=arr.dtype)
+        buf[:len(arr)] = arr
+        buf[len(arr):len(arr) + k] = new
+        self.ends[key] = (buf, len(arr) + k)
+        return buf[:len(arr) + k]
+
+    def nbytes(self) -> int:
+        """Bytes of the spare rows, past every version's, that the room's
+        buffers hold: a version's own rows are counted with its blocks."""
+        return sum((len(buf) - end) * buf.itemsize for buf, end in self.ends.values())
 
 
 class _Splice:
     """Rows ``dpos`` out of an ``n``-row array and new rows in at ``at``
-    (positions in the array without them), copying each array once:
-    contiguous deletes and appends at the end, the shapes the refresh
-    functions make, go through slices."""
+    (positions in the array without them).  The shapes the refresh
+    functions make cost nothing of the array's size: a run of deletes at its
+    head or its end is a view, and an append at its end writes into the
+    ``_Room`` behind it where one is given."""
 
     def __init__(self, n: int, dpos: np.ndarray, at: np.ndarray):
         self.n = n
@@ -289,18 +413,65 @@ class _Splice:
                      == len(self.dpos))
         self.tail = bool(len(at)) and bool((at >= n - len(dpos)).all())
 
-    def __call__(self, arr: np.ndarray, new: np.ndarray) -> np.ndarray:
+    def __call__(self, arr: np.ndarray, new, room: _Room | None = None,
+                 key=None) -> np.ndarray:
         if len(self.dpos):
-            if self.span:
-                a, b = int(self.dpos[0]), int(self.dpos[-1]) + 1
+            a, b = int(self.dpos[0]), int(self.dpos[-1]) + 1
+            if self.span and a == 0:
+                arr = arr[b:]
+            elif self.span and b == len(arr):
+                arr = arr[:a]
+            elif self.span:
                 arr = np.concatenate([arr[:a], arr[b:]])
             else:
                 arr = np.delete(arr, self.dpos)
         if not len(self.at):
             return arr
         if self.tail:
+            if room is not None:
+                return room.append(key, arr, new)
             return np.concatenate([arr, np.asarray(new, dtype=arr.dtype)])
         return np.insert(arr, self.at, new)
+
+
+class _Plan:
+    """A block-local fold's plan: the new version's block list (the empty
+    blocks a first tail append reserves included), each changed block's
+    ``_Edit``, and the indexes of the blocks that are new objects."""
+
+    __slots__ = ("blocks", "edits", "changed")
+
+    def __init__(self, blocks: list, edits: dict):
+        self.blocks, self.edits = blocks, edits
+        self.changed = set(edits)
+
+
+class _Edit:
+    """One block's share of a fold (``RegionImage._plan``), in rows of the
+    block: updated rows (``upd``, with their delta indexes ``upd_idx``),
+    deleted rows (``dels``, sorted), and inserts at ``at`` (positions among
+    the rows the deletes leave, with their delta indexes ``ins_idx``)."""
+
+    __slots__ = ("b", "old_nv", "new_nv", "upd", "upd_idx", "dels", "at", "ins_idx")
+
+    def __init__(self, b: int, old_nv: int, upd, upd_idx, dels, at, ins_idx):
+        self.b, self.old_nv = b, old_nv
+        self.upd, self.upd_idx = upd, upd_idx
+        self.dels = np.sort(dels)
+        # ``at`` comes as positions among the block's rows before the deletes
+        self.at = at - np.searchsorted(self.dels, at, side="left")
+        self.ins_idx = ins_idx
+        self.new_nv = old_nv - len(self.dels) + len(self.at)
+
+    def device(self) -> BlockEdit:
+        """The edit as the new block's rows hold it (``BlockEdit``): a kept
+        row moves up past the deletes before it and down past the inserts at
+        or before it; the j-th insert lands at ``at[j] + j``."""
+        ins = self.at + np.arange(len(self.at))
+        k = self.upd - np.searchsorted(self.dels, self.upd, side="left")
+        upd = k + np.searchsorted(self.at, k, side="right")
+        return BlockEdit(self.old_nv, self.new_nv, self.dels, ins,
+                         np.sort(np.concatenate([ins, upd])))
 
 
 class _Repack(Exception):
@@ -397,8 +568,10 @@ class RegionImage:
         # there is none)
         self.max_commit_ts = 0
         self.hidden_ct: int | None = None
-        self.handles = np.empty(0, dtype=np.int64)
-        self.row_commit_ts = np.empty(0, dtype=np.int64)
+        # the version's per-row arrays, one piece per block (_Rows), and the
+        # spare rows behind the blocks' tails that appends write into
+        self.rows = _Rows([], [])
+        self._room = _Room(block_rows)
         self.block_cache = ColumnBlockCache(key=key)
         self.decoder = RowBatchDecoder(schema)
         self.nbytes = 0
@@ -448,14 +621,30 @@ class RegionImage:
         # image: the scrubber reports it unverifiable, checksum serves cold.
         self.fp_valid = False
         self.table_id: int | None = None
-        self.row_fp = np.empty(0, dtype=np.uint64)
-        self.row_nbytes = np.empty(0, dtype=np.int64)
         self.fp_value = 0      # fold(row_fp): the warm Checksum answer
         self.fp_integrity = 0  # fold(mix_fp(row_fp, row_commit_ts))
 
     @property
     def n_rows(self) -> int:
-        return len(self.handles)
+        return self.rows.n
+
+    # the whole-image row arrays (``_Rows.whole``): none of their readers is
+    # on the warm path
+    @property
+    def handles(self) -> np.ndarray:
+        return self.rows.whole("h")
+
+    @property
+    def row_commit_ts(self) -> np.ndarray:
+        return self.rows.whole("cts")
+
+    @property
+    def row_fp(self) -> np.ndarray:
+        return self.rows.whole("fp")
+
+    @property
+    def row_nbytes(self) -> np.ndarray:
+        return self.rows.whole("nb")
 
     @property
     def wt_pending(self) -> dict | None:
@@ -477,9 +666,9 @@ class RegionImage:
                 "max_ct": max(c for _h, c, _v in writes)}
 
     def _recount(self) -> None:
-        self.nbytes = (
-            self.block_cache.nbytes() + self.handles.nbytes + self.row_commit_ts.nbytes
-        )
+        # handles and commit_ts, and the room's spare rows
+        self.nbytes = (self.block_cache.nbytes() + 16 * self.rows.n
+                       + self._room.nbytes())
 
     # -- build -------------------------------------------------------------
 
@@ -487,10 +676,8 @@ class RegionImage:
              max_commit_ts: int, apply_index: int, start_ts: int,
              raw_keys: list[bytes] | None = None, encode: bool = False,
              hidden_ct: int | None = None) -> None:
-        self.handles = handles
-        self.row_commit_ts = cts
         with trace.stage("fill.fingerprint"):
-            self._init_fingerprint(handles, values, raw_keys)
+            fp, nb = self._init_fingerprint(handles, values, raw_keys)
         cache = self.block_cache
         cache.clear_blocks()
         br = self.block_rows
@@ -503,6 +690,12 @@ class RegionImage:
                 cache.add(cols, e - s)
             st.tag(rows=len(values), path=",".join(sorted(paths)))
         cache.filled = True
+        cache.takes_folds = True
+        self.rows = _Rows.split([b.n_valid for b in cache.blocks], handles, cts, fp, nb)
+        self._room = _Room(self.block_rows)
+        if self.fp_valid:
+            self.fp_value = _integrity.fold(fp)
+            self.fp_integrity = _integrity.fold(_integrity.mix_fp(fp, cts))
         # fill-time stats pass (docs/compressed_columns.md): eligible
         # columns become ENCODED residents — dict codes narrowed, runs
         # collapsed to RLE, narrow ranges bitpacked — and the recount below
@@ -521,37 +714,36 @@ class RegionImage:
 
     # -- integrity fingerprint ---------------------------------------------
 
-    def _init_fingerprint(self, handles, values, raw_keys) -> None:
-        """Compute the per-row integrity hashes at build time.  Delta folds
-        reconstruct raw keys from (table_id, handle), so a single-table
-        range is required — raw record keys ARE (table_id, handle) encoded,
-        making the reconstruction exact."""
+    def _init_fingerprint(self, handles, values, raw_keys):
+        """The per-row integrity hashes and (key, value) bytes at build time,
+        or ``(None, None)`` with the plane off.  Delta folds reconstruct raw
+        keys from (table_id, handle), so a single-table range is required —
+        raw record keys ARE (table_id, handle) encoded, making the
+        reconstruction exact."""
         self.fp_valid = False
         self.table_id = None
+        self.fp_value = self.fp_integrity = 0
         try:
             if raw_keys is None:
                 self.table_id = self._table_id_from_ranges()
                 if self.table_id is None:
-                    return
+                    return None, None
                 raw_keys = [record_key(self.table_id, int(h)) for h in handles]
             elif len(raw_keys):
                 tid_first = decode_record_key(raw_keys[0])[0]
                 # keys are sorted: same first/last table prefix = one table
                 if decode_record_key(raw_keys[-1])[0] != tid_first:
-                    return
+                    return None, None
                 self.table_id = tid_first
             else:
                 self.table_id = self._table_id_from_ranges()
             keys, vals = ByteRows.of(raw_keys), ByteRows.of(values)
-            self.row_fp = _integrity.row_checksums(keys, vals)
-            self.row_nbytes = keys.lens + vals.lens
+            fp = _integrity.row_checksums(keys, vals)
+            nb = keys.lens + vals.lens
         except Exception:  # noqa: BLE001 — exotic keys: plane off, serve on
-            self.row_fp = np.empty(0, dtype=np.uint64)
-            self.row_nbytes = np.empty(0, dtype=np.int64)
-            self.fp_value = self.fp_integrity = 0
-            return
+            return None, None
         self.fp_valid = True
-        self._refold()
+        return fp, np.asarray(nb, dtype=np.int64)
 
     def _table_id_from_ranges(self) -> int | None:
         from ..util import codec as _codec
@@ -563,18 +755,11 @@ class RegionImage:
             tids.add(_codec.decode_i64(start, 1))
         return tids.pop() if len(tids) == 1 else None
 
-    def _refold(self) -> None:
-        self.fp_value = _integrity.fold(self.row_fp)
-        self.fp_integrity = _integrity.fold(
-            _integrity.mix_fp(self.row_fp, self.row_commit_ts)
-        )
-
     def _invalidate_fp(self) -> None:
         """An unhashable delta landed: the fingerprint plane turns off for
         this image (it would otherwise drift silently)."""
         self.fp_valid = False
-        self.row_fp = np.empty(0, dtype=np.uint64)
-        self.row_nbytes = np.empty(0, dtype=np.int64)
+        self.rows = _Rows(self.rows.h, self.rows.cts)
         self.fp_value = self.fp_integrity = 0
 
     def checksum_parts(self) -> tuple[int, int, int] | None:
@@ -583,7 +768,7 @@ class RegionImage:
         fingerprint plane is off for this image."""
         if not self.fp_valid:
             return None
-        return self.fp_value, self.n_rows, int(self.row_nbytes.sum())
+        return self.fp_value, self.n_rows, sum(int(a.sum()) for a in self.rows.nb)
 
     # -- versions ----------------------------------------------------------
 
@@ -627,91 +812,151 @@ class RegionImage:
         image; returns the rows touched.  The caller holds the image's lock
         and keeps the version bookkeeping (apply index, timestamps).
 
-        Copy-on-write, block by block: an update rewrites its rows in a copy
-        of its block, a delete drops its rows from a copy of its block (the
-        block's ``n_valid`` shrinks: every program reads ``n_valid`` per
-        block), an insert goes into its block where that has room, and one
-        past the image's last handle into the tail block and the empty
-        blocks behind it.  The image's first tail append reserves room for
-        as many rows again, the block count rounded up to a power of two, so
-        the block count (part of every program's key) moves once and not
-        with every append.  Untouched blocks are shared with the previous
-        version, device pins and all (``ColumnBlockCache.successor``), and a
-        reader that holds the previous version keeps it whole.  An insert
-        into a full block, a value its column's encoding cannot hold, or a
-        new dictionary entry repacks the whole image into the new version
-        instead (``_repack``).  ``fork``: a version for one reader
-        (``fork``), which leaves the pins' accounting to the image's own."""
+        Copy-on-write, block by block, in four parts (``_fold_part``):
+        ``decode`` decodes and fingerprints the delta's rows; ``rows`` finds
+        each row's block through the index of the blocks' first handles and,
+        once the blocks are edited, edits the changed blocks' pieces of the
+        per-row arrays (``_Rows``); ``blocks`` edits the changed blocks'
+        columns in their own encodings; ``place`` re-places the changed rows
+        on the device (``ColumnBlockCache.successor``).  An update
+        rewrites its rows in a copy of its block, a delete drops its rows
+        from its block (the block's ``n_valid`` shrinks: every program reads
+        ``n_valid`` per block; a run at the block's head is a view), an
+        insert goes into its block where that has room, and one past the
+        image's last handle into the tail block and the empty blocks behind
+        it, written past the rows every version holds (``_Room``).  The
+        image's first tail append reserves room for as many rows again, the
+        block count rounded up to a power of two, so the block count (part
+        of every program's key) moves once and not with every append.
+        Untouched blocks and their row pieces are shared with the previous
+        version, device pins and all, and a reader that holds the previous
+        version keeps it whole.  An insert into a full block, a value its
+        column's encoding cannot hold, or a new dictionary entry repacks the
+        whole image into the new version instead (``_repack``).  ``fork``: a
+        version for one reader (``fork``), which writes into no room and
+        leaves the pins' accounting to the image's own."""
         ch = np.asarray(delta["changed_handles"], dtype=np.int64)
         dh = np.asarray(delta["deleted_handles"], dtype=np.int64)
         if not len(ch) + len(dh):
             return 0
         values = delta["changed_values"]
         cts = np.asarray(delta["changed_commit_ts"], dtype=np.int64)
-        cols = self.decoder.decode(ch, values) if len(ch) else None
-        # fingerprint fold (docs/integrity.md): hash the delta rows off the
-        # RAW value chain — the fold tracks what the image will CONTAIN, the
-        # scrubber's oracle says what it SHOULD contain
-        new_fp = new_nb = None
-        if self.fp_valid and len(ch):
-            try:
-                dkeys = [record_key(self.table_id, int(h)) for h in ch]
-                new_fp = _integrity.row_checksums(dkeys, values)
-                new_nb = np.fromiter((len(k) + len(v) for k, v in zip(dkeys, values)),
-                                     dtype=np.int64, count=len(ch))
-            except Exception:  # noqa: BLE001 — unhashable: plane off
-                self._invalidate_fp()
-        n = self.n_rows
-        h = self.handles
-        dpos = np.empty(0, dtype=np.int64)
-        if len(dh) and n:
-            dp = np.searchsorted(h, dh)
-            ok = dp < n
-            ok[ok] = h[dp[ok]] == dh[ok]
-            dpos = dp[ok]
-        pos = np.searchsorted(h, ch)
-        upd = pos < n
-        upd[upd] = h[pos[upd]] == ch[upd]
-        ins = ~upd
-        grow: dict[int, list] = {}
-        try:
-            new_cache = self._edit_blocks(ch, cols, pos, upd, dpos, fork)
-        except _Repack:
-            new_cache = None
-        if new_cache is None:
-            new_cache = self._repack(ch, cols, upd, dpos, grow)
-        # the row arrays, in the order the new blocks hold the rows
-        upd_pos = pos[upd]
-        row_cts = self.row_commit_ts
-        if len(upd_pos):
-            row_cts = row_cts.copy()
-            row_cts[upd_pos] = cts[upd]
-        if self.fp_valid:
-            if new_fp is None:
+        with _fold_part("decode"):
+            cols = self.decoder.decode(ch, values) if len(ch) else None
+            # fingerprint fold (docs/integrity.md): hash the delta rows off the
+            # RAW value chain — the fold tracks what the image will CONTAIN, the
+            # scrubber's oracle says what it SHOULD contain
+            new_fp = new_nb = None
+            if self.fp_valid and len(ch):
+                try:
+                    dkeys = [record_key(self.table_id, int(h)) for h in ch]
+                    new_fp = _integrity.row_checksums(dkeys, values)
+                    new_nb = np.fromiter((len(k) + len(v) for k, v in zip(dkeys, values)),
+                                         dtype=np.int64, count=len(ch))
+                except Exception:  # noqa: BLE001 — unhashable: plane off
+                    self._invalidate_fp()
+            if self.fp_valid and new_fp is None:
                 new_fp = np.empty(0, dtype=np.uint64)
                 new_nb = np.empty(0, dtype=np.int64)
-            gone = np.concatenate([upd_pos, dpos])
-            old_fp, old_cts = self.row_fp[gone], self.row_commit_ts[gone]
-            add_fp = np.concatenate([new_fp[upd], new_fp[ins]])
-            add_cts = np.concatenate([cts[upd], cts[ins]])
-            self.fp_value ^= _integrity.fold(old_fp) ^ _integrity.fold(add_fp)
-            self.fp_integrity ^= (_integrity.fold(_integrity.mix_fp(old_fp, old_cts))
-                                  ^ _integrity.fold(_integrity.mix_fp(add_fp, add_cts)))
-            fp, nb = self.row_fp, self.row_nbytes
-            if len(upd_pos):
-                fp, nb = fp.copy(), nb.copy()
-                fp[upd_pos] = new_fp[upd]
-                nb[upd_pos] = new_nb[upd]
-        splice = _Splice(n, dpos, np.searchsorted(np.delete(h, dpos), ch[ins])
-                         if len(dpos) else pos[ins])
-        self.handles = splice(h, ch[ins])
-        self.row_commit_ts = splice(row_cts, cts[ins])
-        if self.fp_valid:
-            self.row_fp = splice(fp, new_fp[ins])
-            self.row_nbytes = splice(nb, new_nb[ins])
+        room = None if fork else self._room
+        with _fold_part("rows"):
+            dpos = np.empty(0, dtype=np.int64)
+            if len(dh):
+                dp, found = self.rows.locate(dh)
+                dpos = dp[found]
+            pos, upd = self.rows.locate(ch)
+        with _fold_part("blocks"):
+            plan = self._plan(pos, upd, dpos)
+            blocks = None
+            if plan is not None:
+                try:
+                    blocks = self._edit_blocks(plan, ch, cols, room)
+                except _Repack:
+                    pass
+            if blocks is None:
+                new_cache = self._repack(ch, cols, upd, dpos, {})
+        with _fold_part("rows"):
+            if blocks is None:
+                self._repack_rows(ch, cts, pos, upd, dpos, new_fp, new_nb, new_cache)
+                if not fork:
+                    self._room = _Room(self.block_rows)
+            else:
+                self._edit_rows(plan, ch, cts, new_fp, new_nb, room)
+        if blocks is not None:
+            with _fold_part("place"):
+                old = self.block_cache
+                edits = {b: e.device() for b, e in plan.edits.items() if b < len(old.blocks)}
+                new_cache = old.successor(blocks, plan.changed, account=not fork,
+                                          edits=edits)
         self.block_cache = new_cache
         self._recount()
         return len(ch) + len(dh)
+
+    def _fold_fp(self, gone_fp, gone_cts, add_fp, add_cts) -> None:
+        """The fingerprints less the rows a fold takes out, plus the rows it
+        puts in (XOR folds: order does not matter)."""
+        cat = np.concatenate
+        gone_fp, add_fp = cat(gone_fp), cat(add_fp)
+        gone_cts, add_cts = cat(gone_cts), cat(add_cts)
+        self.fp_value ^= _integrity.fold(gone_fp) ^ _integrity.fold(add_fp)
+        self.fp_integrity ^= (_integrity.fold(_integrity.mix_fp(gone_fp, gone_cts))
+                              ^ _integrity.fold(_integrity.mix_fp(add_fp, add_cts)))
+
+    def _edit_rows(self, plan, ch, cts, new_fp, new_nb, room) -> None:
+        """The new version's per-row pieces: each changed block's pieces
+        edited as its columns were, every other piece shared; and the
+        fingerprints folded."""
+        names = ("h", "cts", "fp", "nb") if self.fp_valid else ("h", "cts")
+        pieces = {}
+        for name in names:
+            got = list(getattr(self.rows, name))
+            got += [np.empty(0, dtype=_ROW_DTYPES[name])] * (len(plan.blocks) - len(got))
+            pieces[name] = got
+        new = {"h": ch, "cts": cts, "fp": new_fp, "nb": new_nb}
+        gone_fp, gone_cts, add_fp, add_cts = [], [], [], []
+        for b, e in plan.edits.items():
+            if self.fp_valid:
+                gone = np.concatenate([e.upd, e.dels])
+                gone_fp.append(pieces["fp"][b][gone])
+                gone_cts.append(pieces["cts"][b][gone])
+                add_fp += [new_fp[e.upd_idx], new_fp[e.ins_idx]]
+                add_cts += [cts[e.upd_idx], cts[e.ins_idx]]
+            splice = _Splice(e.old_nv, e.dels, e.at)
+            for name in names:
+                arr = pieces[name][b]
+                if len(e.upd) and name != "h":
+                    arr = arr.copy()
+                    arr[e.upd] = new[name][e.upd_idx]
+                pieces[name][b] = (splice(arr, new[name][e.ins_idx], room, (b, name))
+                                   if len(e.at) else splice(arr, None))
+        if self.fp_valid:
+            self._fold_fp(gone_fp, gone_cts, add_fp, add_cts)
+        self.rows = _Rows(*(pieces.get(name) for name in ("h", "cts", "fp", "nb")))
+
+    def _repack_rows(self, ch, cts, pos, upd, dpos, new_fp, new_nb, new_cache) -> None:
+        """``_edit_rows`` for a repacked version: the whole-image arrays
+        edited, then cut into pieces as the new blocks hold the rows."""
+        old = self.rows
+        h = old.whole("h")
+        ins = ~upd
+        upd_pos = pos[upd]
+        whole = {"cts": old.whole("cts")}
+        if self.fp_valid:
+            whole["fp"], whole["nb"] = old.whole("fp"), old.whole("nb")
+            gone = np.concatenate([upd_pos, dpos])
+            self._fold_fp([whole["fp"][gone]], [whole["cts"][gone]],
+                          [new_fp[upd], new_fp[ins]], [cts[upd], cts[ins]])
+        new = {"cts": cts, "fp": new_fp, "nb": new_nb}
+        splice = _Splice(len(h), dpos, np.searchsorted(np.delete(h, dpos), ch[ins])
+                         if len(dpos) else pos[ins])
+        out = {"h": splice(h, ch[ins])}
+        for name, arr in whole.items():
+            if len(upd_pos):
+                arr = arr.copy()
+                arr[upd_pos] = new[name][upd]
+            out[name] = splice(arr, new[name][ins])
+        self.rows = _Rows.split([b.n_valid for b in new_cache.blocks], out["h"],
+                                out["cts"], out.get("fp"), out.get("nb"))
 
     def _code_of(self, ci: int, blocks, value: bytes, grow: dict | None) -> int:
         """Image dictionary code for ``value`` on column ``ci``.  An unseen
@@ -733,95 +978,71 @@ class RegionImage:
         extra.append(value)
         return len(dictionary) + len(extra) - 1
 
-    def _delta_cell(self, ci: int, blocks, col: Column, r: int, grow=None):
-        """(value, is_null) of delta row ``r`` in the image's representation."""
-        nl = bool(np.asarray(col.nulls)[r])
-        image_col = blocks[0].cols[ci] if blocks else None
-        dict_encoded = image_col is not None and image_col.is_dict_encoded
-        if isinstance(image_col, _encoding.EncodedColumn):
-            # int-family lanes by construction — and the ``.data`` probe
-            # below would permanently cache a full decode the encoded byte
-            # budget never accounted for
-            obj_col = False
-        else:
-            obj_col = (
-                image_col.data.dtype == object
-                if image_col is not None and isinstance(image_col.data, np.ndarray)
-                else self.schema[ci].ftype.eval_type in (EvalType.BYTES, EvalType.JSON)
-                and not dict_encoded
-            )
-        if nl:
-            return (b"" if obj_col and not dict_encoded else 0), True
-        v = col.decoded().data[r] if col.is_dict_encoded else col.data[r]
-        if dict_encoded:
-            return self._code_of(ci, blocks, bytes(v), grow), False
-        return v, False
-
     def _delta_columns(self, blocks, cols, n: int, grow=None):
         """Per image column, the changed rows' (values, nulls) in the
         image's host representation: a numeric column at once, a bytes or
-        dictionary one row by row (``_delta_cell``)."""
+        dictionary one row by row (a dictionary column's rows as the image's
+        codes, ``_code_of``)."""
         out = []
         for ci in range(len(self.schema)):
             col = cols[ci]
             image_col = blocks[0].cols[ci]
+            nls = np.asarray(col.nulls, dtype=bool)
             data = None if col.is_dict_encoded or image_col.is_dict_encoded else col.data
             if isinstance(data, np.ndarray) and data.dtype.kind in "iuf":
-                nls = np.asarray(col.nulls, dtype=bool)
                 out.append((np.where(nls, 0, data), nls))
                 continue
-            vals, nls = [], np.zeros(n, dtype=bool)
-            for r in range(n):
-                v, nl = self._delta_cell(ci, blocks, col, r, grow)
-                vals.append(v)
-                nls[r] = nl
+            coded = image_col.is_dict_encoded
+            if isinstance(image_col, _encoding.EncodedColumn):
+                # int-family lanes by construction — and the ``.data`` probe
+                # below would permanently cache a full decode the encoded byte
+                # budget never accounted for
+                obj_col = False
+            elif isinstance(image_col.data, np.ndarray):
+                obj_col = image_col.data.dtype == object
+            else:
+                obj_col = (self.schema[ci].ftype.eval_type in (EvalType.BYTES, EvalType.JSON)
+                           and not coded)
+            null_value = b"" if obj_col and not coded else 0
+            vals = []
+            for v, nl in zip(col.decoded().data if col.is_dict_encoded else col.data, nls):
+                if nl:
+                    vals.append(null_value)
+                elif coded:
+                    vals.append(self._code_of(ci, blocks, bytes(v), grow))
+                else:
+                    vals.append(v)
             out.append((vals, nls))
         return out
 
-    def _edit_blocks(self, ch, cols, pos, upd, dpos, fork=False):
-        """The block-local fold (``apply_delta``): the new version, or None
-        where an insert finds no room in its block."""
-        old = self.block_cache
-        blocks = list(old.blocks)
+    def _plan(self, pos, upd, dpos):
+        """The block-local fold's plan (``apply_delta``): for each block the
+        delta touches, its ``_Edit``, with the empty blocks a first tail
+        append reserves; or None where the image has no blocks or an insert
+        finds no room in its block."""
+        blocks = list(self.block_cache.blocks)
         if not blocks:
             return None
         br = self.block_rows
         n = self.n_rows
         nv = np.array([b.n_valid for b in blocks], dtype=np.int64)
         off = np.concatenate([[0], np.cumsum(nv)])
-
-        def block_of(p):
-            return np.searchsorted(off, p, side="right") - 1
-
-        edits: dict[int, dict] = {}
-
-        def edit(b: int) -> dict:
-            return edits.setdefault(int(b), {"upd": [], "del": [], "ins": []})
-
-        for i in np.flatnonzero(upd):
-            b = int(block_of(pos[i]))
-            edit(b)["upd"].append((int(pos[i] - off[b]), int(i)))
-        for p in dpos:
-            b = int(block_of(p))
-            edit(b)["del"].append(int(p - off[b]))
+        upd_idx = np.flatnonzero(upd)
         ins_idx = np.flatnonzero(~upd)
-        tail = [int(i) for i in ins_idx if pos[i] >= n]
-        for i in ins_idx:
-            if pos[i] < n:
-                b = int(block_of(pos[i]))
-                edit(b)["ins"].append((int(pos[i] - off[b]), int(i)))
-
-        def final(b: int) -> int:
-            e = edits.get(b)
-            base = int(nv[b]) if b < len(nv) else 0
-            return base if e is None else base - len(e["del"]) + len(e["ins"])
-
-        if any(final(b) > br for b in edits):
+        inner, tail = ins_idx[pos[ins_idx] < n], ins_idx[pos[ins_idx] >= n]
+        # a position's block: the last one that starts at or before it
+        ub, db, ib = (np.searchsorted(off, p, side="right") - 1
+                      for p in (pos[upd_idx], dpos, pos[inner]))
+        final = nv.copy()
+        np.subtract.at(final, db, 1)
+        np.add.at(final, ib, 1)
+        if (final > br).any():
             return None
-        if tail:
+        tb = np.empty(0, dtype=np.int64)
+        if len(tail):
             last = int(np.flatnonzero(nv)[-1]) if nv.any() else 0
             n_after = n - len(dpos) + len(ins_idx)
-            free = sum(br - final(b) for b in range(last, len(blocks)))
+            free = int((br - final[last:]).sum())
             if not self.reserved or free < len(tail):
                 # room for as many rows again, the block count a power of two
                 want = max(len(blocks), _pow2(-(-2 * n_after // br)))
@@ -831,29 +1052,41 @@ class RegionImage:
                     want *= 2
                 blocks += [_empty_block(blocks[0]) for _ in range(want - len(blocks))]
                 self.reserved = True
-            b = last
-            for i in tail:
-                while final(b) >= br:
-                    b += 1
-                edit(b)["ins"].append((int(nv[b]) if b < len(nv) else 0, i))
+            room = np.concatenate([br - final[last:], np.full(len(blocks) - len(nv), br)])
+            tb = last + np.searchsorted(np.cumsum(room), np.arange(len(tail)), side="right")
+        edits = {}
+        for b in sorted(set(ub.tolist()) | set(db.tolist()) | set(ib.tolist())
+                        | set(tb.tolist())):
+            # a reserved block holds no row yet: only tail rows land in it
+            old_nv, o = (int(nv[b]), int(off[b])) if b < len(nv) else (0, n)
+            at = np.concatenate([pos[inner[ib == b]] - o,
+                                 np.full(int((tb == b).sum()), old_nv)]).astype(np.int64)
+            edits[b] = _Edit(b, old_nv, pos[upd_idx[ub == b]] - o, upd_idx[ub == b],
+                             dpos[db == b] - o, at,
+                             np.concatenate([inner[ib == b], tail[tb == b]]))
+        plan = _Plan(blocks, edits)
+        plan.changed.update(range(len(nv), len(blocks)))
+        return plan
+
+    def _edit_blocks(self, plan, ch, cols, room):
+        """The plan's blocks with each changed block's columns edited (new
+        block objects), the rest shared; raises ``_Repack`` where a column's
+        lane cannot hold the new values."""
+        blocks = list(plan.blocks)
         n_ch = len(ch)
         dvals = self._delta_columns(blocks, cols, n_ch) if n_ch else None
-        changed = set()
-        for b, e in edits.items():
-            blk = blocks[b]
+        for b, e in plan.edits.items():
             new_cols = []
-            for ci, col in enumerate(blk.cols):
-                c = self._edit_column(ci, col, e, ch, dvals)
+            for ci, col in enumerate(blocks[b].cols):
+                c = self._edit_column(ci, col, e, ch, dvals, room)
                 if c is None:
                     raise _Repack
                 new_cols.append(c)
-            blocks[b] = _Block(new_cols, final(b))
-            changed.add(b)
-        changed.update(range(len(old.blocks), len(blocks)))
-        return old.successor(blocks, changed, account=not fork)
+            blocks[b] = _Block(new_cols, e.new_nv)
+        return blocks
 
-    def _edit_column(self, ci: int, col: Column, e: dict, ch, dvals):
-        """One block column with the block's edits applied, in the column's
+    def _edit_column(self, ci: int, col: Column, e: "_Edit", ch, dvals, room=None):
+        """One block column with the block's edit applied, in the column's
         own encoding and lane (a bitpacked or coded column is edited in its
         narrow payload); None where the lane cannot hold the new values."""
         E = _encoding.EncodedColumn
@@ -861,8 +1094,7 @@ class RegionImage:
             return self._edit_decoded(ci, col, e, ch, dvals)
         if self.schema[ci].is_pk_handle:
             def new_vals(idx):
-                return (np.asarray([int(ch[i]) for i in idx], dtype=np.int64),
-                        np.zeros(len(idx), dtype=bool))
+                return ch[idx].astype(np.int64), np.zeros(len(idx), dtype=bool)
         else:
             vals, nls = dvals[ci] if dvals is not None else ((), ())
 
@@ -894,28 +1126,24 @@ class RegionImage:
                 if coded and len(v) and int(v.max()) > np.iinfo(stored.dtype).max:
                     raise _Repack
                 return v.astype(stored.dtype, copy=False)
-        if e["upd"] and not self.schema[ci].is_pk_handle:
-            rows = np.asarray([r for r, _i in e["upd"]], dtype=np.int64)
-            v, vn = new_vals(np.asarray([i for _r, i in e["upd"]], dtype=np.int64))
+        if len(e.upd) and not self.schema[ci].is_pk_handle:
+            v, vn = new_vals(e.upd_idx)
             stored, nl = stored.copy(), nl.copy()
-            stored[rows] = lane(v, vn)
-            nl[rows] = vn
-        dels = np.sort(np.asarray(e["del"], dtype=np.int64))
-        at = np.asarray([r for r, _i in e["ins"]], dtype=np.int64)
-        if len(at):
-            at = at - np.searchsorted(dels, at, side="left")
-        splice = _Splice(len(stored), dels, at)
-        if len(at):
-            v, vn = new_vals(np.asarray([i for _r, i in e["ins"]], dtype=np.int64))
-            stored, nl = splice(stored, lane(v, vn)), splice(nl, vn)
-        elif len(dels):
+            stored[e.upd] = lane(v, vn)
+            nl[e.upd] = vn
+        splice = _Splice(len(stored), e.dels, e.at)
+        if len(e.at):
+            v, vn = new_vals(e.ins_idx)
+            stored = splice(stored, lane(v, vn), room, (e.b, ci, "data"))
+            nl = splice(nl, vn, room, (e.b, ci, "nulls"))
+        elif len(e.dels):
             stored, nl = splice(stored, None), splice(nl, None)
         if isinstance(col, E):
             return E(col.eval_type, col.frac, "bp", len(stored), packed=stored,
                      ref=col.ref, nulls=nl)
         return Column(col.eval_type, stored, nl, col.frac, col.dictionary)
 
-    def _edit_decoded(self, ci: int, col: Column, e: dict, ch, dvals):
+    def _edit_decoded(self, ci: int, col: Column, e: "_Edit", ch, dvals):
         """``_edit_column`` through the decoded values, for a run-length
         column: its runs are made again from the edited rows."""
         work = _encoding.host_dtype(col)
@@ -927,24 +1155,20 @@ class RegionImage:
             v = np.empty(len(idx), dtype=work)
             for j, i in enumerate(idx):
                 v[j] = vals[i]
-            return v, nls[list(idx)]
+            return v, nls[idx]
 
-        if e["upd"]:
-            rows = [r for r, _i in e["upd"]]
-            v, vn = new_vals([i for _r, i in e["upd"]])
-            d[rows] = v
-            nl[rows] = vn
-        dels = np.sort(np.asarray(e["del"], dtype=np.int64))
-        if len(dels):
+        if len(e.upd):
+            v, vn = new_vals(e.upd_idx)
+            d[e.upd] = v
+            nl[e.upd] = vn
+        if len(e.dels):
             keep = np.ones(len(d), dtype=bool)
-            keep[dels] = False
+            keep[e.dels] = False
             d, nl = d[keep], nl[keep]
-        if e["ins"]:
-            at = np.asarray([r for r, _i in e["ins"]], dtype=np.int64)
-            at = at - np.searchsorted(dels, at, side="left")
-            v, vn = new_vals([i for _r, i in e["ins"]])
-            d = np.insert(d, at, v)
-            nl = np.insert(nl, at, vn)
+        if len(e.at):
+            v, vn = new_vals(e.ins_idx)
+            d = np.insert(d, e.at, v)
+            nl = np.insert(nl, e.at, vn)
         return _encode_like(col, d, nl)
 
     def _repack(self, ch, cols, upd, dpos, grow):
@@ -1027,6 +1251,7 @@ class RegionImage:
             new.blocks.append(_empty_block(template))
         new.filled = True
         new.folded = True
+        new.takes_folds = True
         new.enc_version = old.enc_version + 1
         return new
 
@@ -1035,7 +1260,8 @@ class RegionCacheStats:
     __slots__ = ("hits", "misses", "deltas", "delta_rows", "stale",
                  "below_snapshot", "uncacheable",
                  "evictions", "invalidations", "bytes_pinned",
-                 "wt_deltas", "wt_rows", "wt_lost", "prefix_folds")
+                 "wt_deltas", "wt_rows", "wt_lost", "prefix_folds",
+                 "below_image")
 
     def __init__(self):
         self.hits = 0
@@ -1054,6 +1280,9 @@ class RegionCacheStats:
         # reads answered by folding the chain's prefix up to their apply
         # index (each one also a wt_delta)
         self.prefix_folds = 0
+        # readers whose snapshot the image had passed (below its apply
+        # index), folded onto a kept earlier version
+        self.below_image = 0
 
     def to_dict(self) -> dict:
         return {k: getattr(self, k) for k in self.__slots__}
@@ -1252,10 +1481,10 @@ class RegionColumnCache:
         below ``A`` committed at or below S, in apply order (per key, apply
         order is commit order): that is the engine's own state at ``A`` as S
         reads it.  A write committed above S stays pending for later
-        readers.  A reader below ``max_commit_ts`` is folded onto a kept
-        earlier version (``_serve_base``) or is stale, as is one below the
-        image's apply index; a gapped chain or a version the chain never
-        carried sends the reader to the walk."""
+        readers.  A reader below ``max_commit_ts``, or below the image's
+        apply index, is folded onto a kept earlier version (``_serve_base``)
+        or is stale; a gapped chain or a version the chain never carried
+        sends the reader to the walk."""
         below = start_ts < img.snapshot_ts
         I = img.apply_index
         if self._hit_fresh_locked(img, apply_index, start_ts, snap, ranges, stats):
@@ -1270,9 +1499,8 @@ class RegionColumnCache:
                        if chain_ok else [])
             gap = img.chain_gap if not chain_ok else None
         if apply_index < I or start_ts < img.max_commit_ts:
-            out = (self._serve_base(img, snap, ranges, start_ts, apply_index,
-                                    batches, chain_ok, stats)
-                   if apply_index >= I else None)
+            out = self._serve_base(img, snap, ranges, start_ts, apply_index,
+                                   batches, chain_ok, stats)
             if below:
                 self._count_below_snapshot(served=out is not None)
             if out is not None:
@@ -1280,12 +1508,13 @@ class RegionColumnCache:
                 self.stats.wt_deltas += 1
                 self.stats.wt_rows += n
                 self.stats.prefix_folds += 1
+                self.stats.below_image += apply_index < I
                 self._count_repair("fold")
                 self._count("wt_delta")
                 self._count_delta_rows(n)
                 return cache, "wt_delta", n, below
-            # the image holds rows this reader must not see (or its snapshot
-            # predates the image): only a fresh scan can answer it
+            # the image holds rows this reader must not see, and no kept
+            # version is old enough: only a fresh scan can answer it
             self._count_fold_miss("reader_below_image")
             self.stats.stale += 1
             self._count("stale")
@@ -1343,12 +1572,16 @@ class RegionColumnCache:
 
     def _serve_base(self, img, snap, ranges, start_ts, apply_index, batches,
                     chain_ok, stats):
-        """A reader at or past the image's apply index whose start_ts lies
-        below a commit the image holds: the newest kept base version at or
-        below the reader's apply index and timestamp, with every write since
-        it that the reader sees folded in (the writes the image has folded
-        since, and the chain's), as a version of its own — ``(block cache,
-        rows)``, or None where no base can answer.  The image is left as it
+        """A reader whose start_ts lies below a commit the image holds, or
+        whose snapshot is older than the image (a read another thread's fold
+        overtook between its snapshot and its turn at the image's lock): the
+        newest kept base version at or below the reader's apply index and
+        timestamp, with every write since it that the reader sees folded in
+        (the writes the image has folded since, and the chain's, each at or
+        below the reader's apply index), as a version of its own — ``(block
+        cache, rows)``, or None where no base can answer.  The locks of a
+        reader below the image's apply index are not the chain's to tell:
+        they are checked on its own snapshot.  The image is left as it
         stands."""
         for state, pos in reversed(img.bases):
             if (state["apply_index"] <= apply_index
@@ -1363,8 +1596,9 @@ class RegionColumnCache:
             return None  # batches past the image nobody carried
         writes = [w for w in img.since[pos:] if w[0] <= apply_index and w[2] <= start_ts]
         writes += [(i, *w) for i, ws, _l in batches for w in ws if w[1] <= start_ts]
-        self._check_locks(img, snap, ranges, start_ts, stats,
-                          known=self._locks_at(img, batches, apply_index))
+        known = (self._locks_at(img, batches, apply_index)
+                 if apply_index >= img.apply_index else None)
+        self._check_locks(img, snap, ranges, start_ts, stats, known=known)
         if not writes:
             return state["block_cache"], 0
         delta = _fold_writes(writes)
