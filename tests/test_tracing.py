@@ -953,6 +953,70 @@ def test_recorded_stage_and_shared_attribution():
         "tikv_trace_request_attributed_seconds_total").get(method="x") > 0
 
 
+def test_batch_stage_has_no_span_and_a_handed_back_rider_stops_sharing():
+    """A batch stage is every rider's shared time and no rider's span, also
+    where it resumes inside an attached trace; a rider handed back to its
+    own thread (unshare) gets none of the batch's later stages."""
+    from tikv_tpu.util.metrics import REGISTRY
+
+    wall = REGISTRY.histogram("tikv_trace_stage_seconds")
+    riders = [trace.start_trace("rpc.x", method="x") for _ in range(2)]
+    ctxs = [r.context for r in riders]
+    batch = []  # the batch stage's wall total before the unshare, and after
+
+    def dispatcher():
+        w0 = wall.total(stage="t.batch")
+        with trace.shared(ctxs):
+            with trace.batch_stage("t.batch"):
+                time.sleep(0.002)
+                with trace.attach(ctxs[0]):
+                    with trace.stage("t.slot"):
+                        time.sleep(0.002)
+                    time.sleep(0.002)  # the batch stage, resumed
+            batch.append(wall.total(stage="t.batch") - w0)
+            trace.unshare(ctxs[1])
+            with trace.batch_stage("t.batch"):
+                time.sleep(0.002)
+            batch.append(wall.total(stage="t.batch") - w0)
+
+    th = threading.Thread(target=dispatcher)
+    th.start()
+    th.join(5)
+    assert not th.is_alive()
+    for r in riders:
+        r.finish()
+    first, second = (trace.TRACER.get(r.rec.trace_id) for r in riders)
+    assert not _spans_named(first, "t.batch")
+    assert not _spans_named(second, "t.batch")
+    slot = _spans_named(first, "t.slot")
+    assert len(slot) == 1 and not _spans_named(second, "t.slot")
+    # the first rider waited for every batch segment (its own slot is its
+    # span); the second for the segments before it was handed back and for
+    # the other rider's slot
+    assert first["shared_ms"] == pytest.approx(batch[1] * 1e3, abs=0.002)
+    assert second["shared_ms"] == pytest.approx(
+        batch[0] * 1e3 + slot[0]["duration_ms"], abs=0.002)
+    assert batch[1] - batch[0] >= 0.002
+
+
+def test_a_suspended_stage_and_the_inner_one_meet_at_one_instant():
+    """No time falls between a stage and the one that suspends it, nor
+    between that one's end and the outer stage's next segment: a clock that
+    counts both as activities never falls back to its base between them."""
+    clock = trace.WaitClock(3, {"t.outer": 1, "t.inner": 2}.get, 0)
+    trace.bind_clock(clock)
+    try:
+        with trace.stage("t.outer"):
+            before = clock.read(time.perf_counter())
+            with trace.stage("t.inner"):
+                time.sleep(0.001)
+            after = clock.read(time.perf_counter())
+    finally:
+        trace.unbind_clock()
+    assert after[0] == before[0]
+    assert after[2] - before[2] >= 0.001
+
+
 def test_served_request_mirrors_stages_not_containers(served, mirror):
     ts = served.batched_round()
     names = set(mirror.entered)

@@ -62,6 +62,7 @@ from dataclasses import dataclass, field
 
 from ..analysis.sanitizer import make_condition, make_lock
 from ..util import trace
+from ..util.metrics import REGISTRY
 from . import observatory as _obs
 from . import overload as _overload
 from ..util.retry import DeadlineExceeded, ServerBusyError, deadline_from_context
@@ -78,6 +79,29 @@ from .plan_shape import split
 from .region_cache import _epoch_of
 
 LANES = ("high", "normal", "low")
+
+# what the dispatcher was doing while a rider waited (docs/tracing.md, "What
+# a rider waited behind"): the activities of its trace.WaitClock, in order
+BEHIND = ("linger", "repair", "device", "serve")
+_LINGER, _REPAIR, _DEVICE, _SERVE = range(4)
+_WAIT_BEHIND = tuple(
+    REGISTRY.counter(
+        "tikv_coprocessor_sched_wait_behind_seconds_total",
+        "Queue wait before dispatch, by what the dispatcher did meanwhile",
+    ).labels(behind=behind)
+    for behind in BEHIND)
+
+
+def _behind_of(stage: str) -> int | None:
+    """Which of ``BEHIND`` the dispatcher's time inside ``stage`` counts as:
+    ``repair`` in ``cache.repair`` and ``fill.*``, ``device`` in
+    ``device.*``; None for any other stage, whose time counts as what the
+    dispatcher was doing around it (``serve``)."""
+    if stage == "cache.repair" or stage.startswith("fill."):
+        return _REPAIR
+    if stage.startswith("device."):
+        return _DEVICE
+    return None
 
 
 @dataclass
@@ -155,6 +179,8 @@ class _Item:
     # names the shared device-dispatch span the item coalesced into
     trace_ctx: dict | None = None
     batch_ref: str | None = None
+    # the dispatcher's wait clock as of enqueue_t, while the plane is on
+    behind0: list | None = None
 
 
 class _Ticket:
@@ -203,6 +229,9 @@ class CoprReadScheduler:
         self._queues: dict[str, list[_Item]] = {lane: [] for lane in LANES}
         self._running = False
         self._thread: threading.Thread | None = None
+        # the dispatcher's own time by activity: what a queued rider waits
+        # behind (trace.WaitClock, bound to the dispatch thread)
+        self._clock = trace.WaitClock(len(BEHIND), _behind_of, _SERVE)
         # the store's count of reads on their way here (util/inbound.py);
         # None = nobody counts them, so a partial batch lingers to its end
         self._inbound = None
@@ -299,7 +328,7 @@ class CoprReadScheduler:
                 return
             self._running = True
             self._thread = threading.Thread(
-                target=self._dispatch_loop, daemon=True, name="copr-sched"
+                target=self._dispatch, daemon=True, name="copr-sched"
             )
             self._thread.start()
 
@@ -355,9 +384,12 @@ class CoprReadScheduler:
             # handle_batch: a mixed-version cluster keeps fusion off
             self._count_coalesce("bypass")
             return self.ep.handle_request(req)
+        enqueue_t = time.perf_counter()
         item = _Item(req=req, index=0, lane=_clamped_lane(req, self.cfg, ov),
-                     ticket=_Ticket(), sig=sig,
-                     enqueue_t=time.perf_counter(), deadline=deadline)
+                     ticket=_Ticket(), sig=sig, enqueue_t=enqueue_t,
+                     deadline=deadline,
+                     behind0=self._clock.read(enqueue_t) if trace.enabled()
+                     else None)
         # queue-lane span (docs/tracing.md): covers enqueue→batch-completion
         # on the submitting thread; the dispatcher stamps dispatcher-side
         # spans into this trace via the captured context
@@ -450,12 +482,23 @@ class CoprReadScheduler:
                 sp.link("batched_into", item.batch_ref)
             return item.ticket.resp
 
+    def _dispatch(self) -> None:
+        """The dispatch thread: the loop, with the scheduler's wait clock
+        bound to it."""
+        trace.bind_clock(self._clock)
+        try:
+            self._dispatch_loop()
+        finally:
+            trace.unbind_clock()
+
     def _dispatch_loop(self) -> None:
-        cfg = self.cfg
+        cfg, clock = self.cfg, self._clock
         while True:
             with self._mu:
                 while self._running and not any(self._queues.values()):
+                    clock.switch(_LINGER)
                     self._mu.wait(0.5)
+                    clock.switch(_SERVE)
                 if not self._running:
                     # drain whatever is queued so no caller hangs forever —
                     # but SERVE it below, outside the dispatcher lock: the
@@ -491,7 +534,9 @@ class CoprReadScheduler:
                           and self._no_lone_rider()):
                         why = "drained"
                     else:
+                        clock.switch(_LINGER)
                         self._mu.wait(min(deadline - now, 0.05))
+                        clock.switch(_SERVE)
                         continue
                     batch = []
                     for lane in LANES:  # high lane drains first
@@ -499,11 +544,9 @@ class CoprReadScheduler:
                             batch.append(self._queues[lane].pop(0))
                     self._gauge_depth()
             if batch:
-                self._count_dispatch(why, len(batch))
-                if not stopping:
-                    for it in batch:
-                        self._observe_wait(it)
-                self._serve_ticketed(batch)
+                # the batch's riders are picked up together, now
+                self._serve_ticketed(
+                    batch, why, None if stopping else time.perf_counter())
             if stopping:
                 return
 
@@ -514,18 +557,26 @@ class CoprReadScheduler:
         plans = Counter(it.sig for q in self._queues.values() for it in q)
         return min(plans.values()) >= 2
 
-    def _serve_ticketed(self, batch: list[_Item]) -> None:
+    def _serve_ticketed(self, batch: list[_Item], why: str,
+                        picked: float | None) -> None:
+        """Serve a batch taken off the lanes for ``why`` at ``picked`` (None
+        when the scheduler stops: no wait is observed)."""
         from ..util.failpoint import fail_point
 
-        # chaos/regression hook on the DISPATCHER thread: a seeded sleep
-        # here paces batch service so overload tests can saturate the
-        # bounded queue deterministically (tests/test_overload.py)
-        fail_point("sched_dispatch")
         for i, it in enumerate(batch):
             it.index = i
         # every rider waits for all of the batch's stages, whichever trace
         # they land in (docs/tracing.md, "Attributed time")
         with trace.shared([it.trace_ctx for it in batch]):
+            with trace.batch_stage("sched.pickup"):
+                self._count_dispatch(why, len(batch))
+                if picked is not None:
+                    for it in batch:
+                        self._observe_wait(it, picked)
+            # chaos/regression hook on the DISPATCHER thread: a seeded sleep
+            # here paces batch service so overload tests can saturate the
+            # bounded queue deterministically (tests/test_overload.py)
+            fail_point("sched_dispatch")
             try:
                 results, errors = self._serve(batch)
             except BaseException as exc:  # noqa: BLE001 — scheduler bug: fail all
@@ -724,8 +775,10 @@ class CoprReadScheduler:
         """Resolve a slot's region view to a FILLED block cache, running the
         region cache's build/delta pass if needed.  Returns False when the
         slot must shed to the per-request path."""
-        # the slot's stages land in its first rider's trace
-        with trace.attach(slot.items[0].trace_ctx):
+        # the slot's stages land in its first rider's trace; the code
+        # around them is the batch's own bookkeeping
+        with trace.batch_stage("sched.resolve"), \
+                trace.attach(slot.items[0].trace_ctx):
             return self._resolve_slot_attached(slot)
 
     def _resolve_slot_attached(self, slot: _Slot) -> bool:
@@ -891,14 +944,16 @@ class CoprReadScheduler:
         # own one-span trace naming every participating parent trace — and
         # each rider links back to it.  A shared dispatch can't be a child
         # of N parents; this is the honest shape for shared-slot serving.
-        riders = [it for s in live for it in s.items]
-        bsp = trace.fanin_span(
-            "sched.device_dispatch", [it.trace_ctx for it in riders],
-            kind=kind, regions=len(live), occupancy=len(riders))
-        if bsp:
-            ref = f"{bsp.rec.trace_id}:{bsp.span_id}"
-            for it in riders:
-                it.batch_ref = ref
+        # the batch's own code up to the device, which every rider waits for
+        with trace.batch_stage("sched.launch"):
+            riders = [it for s in live for it in s.items]
+            bsp = trace.fanin_span(
+                "sched.device_dispatch", [it.trace_ctx for it in riders],
+                kind=kind, regions=len(live), occupancy=len(riders))
+            if bsp:
+                ref = f"{bsp.rec.trace_id}:{bsp.span_id}"
+                for it in riders:
+                    it.batch_ref = ref
         t0 = time.perf_counter()
         try:
             # the batch's region images carry their ENCODING DESCRIPTORS on
@@ -907,7 +962,7 @@ class CoprReadScheduler:
             # when every region agrees on one signature, and decode-ship
             # (counted per-cause) when not — sharded and fused paths stay
             # eligible for compressed-resident regions either way
-            with bsp.active():
+            with bsp.active(), trace.batch_stage("sched.launch"):
                 if mesh is not None:
                     pending = jax_eval.launch_xregion_sharded(
                         ev, [s.cache for s in live], mesh, params)
@@ -934,7 +989,8 @@ class CoprReadScheduler:
         def finalize(results, errors):
             t_fin = time.perf_counter()
             try:
-                with bsp.active():
+                # and from the device's answer to each rider's response
+                with bsp.active(), trace.batch_stage("sched.answer"):
                     resps = pending.finalize()
             except Exception as exc:  # noqa: BLE001
                 self._device_failed(exc, path)
@@ -944,71 +1000,73 @@ class CoprReadScheduler:
                                path=path)
                 return
             self.ep.breaker.record_success(path)
-            pull_dt = time.perf_counter() - t_fin
-            # latency = this group's own host work (launch) + the blocking
-            # pull (residual device time).  The gap between launch and
-            # finalize is the NEXT group's prepare pass — double-buffered
-            # overlap, not this batch's cost; attributing it here would
-            # inflate the device-path percentiles with unrelated host work.
-            dt = (t_launched - t0) + pull_dt
-            with trace.attach(riders[0].trace_ctx), \
-                    trace.stage("copr.obs", batch=kind):
-                self._batch_metrics(kind, n_reqs, dt, waste, n_batch=n_batch)
-            if bsp:
-                bsp.tag(outcome="ok").finish()
-                # each rider's trace gets a span for the shared dispatch it
-                # rode, linked to the dispatch span's own trace
-                for it in riders:
-                    # batch_ref was already stamped at fanin-span creation
-                    trace.remote_span(it.trace_ctx, "sched.batched",
-                                      start=t0, end=t_fin + pull_dt,
-                                      batched_into=ref, kind=kind,
-                                      occupancy=n_batch)
-            if mesh is not None:
-                self._sharded_metrics(device_load, pull_dt)
-            # observatory profiles (docs/observatory.md): every rider the
-            # program answered records its attributed share on the batch
-            # path, with the queue wait it actually paid and the dispatch
-            # trace as its exemplar
-            obs_path = "mesh" if mesh is not None else "xregion"
-            obs_enc = getattr(pending, "obs_encoding", "plain")
-            for slot, resp in zip(live, resps):
-                rows = slot.cache.total_rows if slot.cache is not None else 0
-                for it in slot.items:
-                    if results[it.index] is not None:
-                        continue  # cold-fill: recorded by its handle_request
-                    self._record_obs(
-                        it, ev, obs_path, dt / n_reqs, rows=rows,
-                        encoding=obs_enc, occupancy=n_batch, waste=waste,
-                        dispatch_t=t0, resp=resp)
-            for slot, resp in zip(live, resps):
-                # per-region chunk payloads: every rider of this slot shares
-                # the SAME unjoined column-slab parts, so one multi-response
-                # frame gather-writes each region's slabs once
-                data = None
-                from_device = True
-                # the slot's stages land in its first rider's trace
-                with trace.attach(slot.items[0].trace_ctx):
-                    parts, enc_tp = self.ep._encode_response(resp)
-                    if slot.shadow_snap is not None:
-                        # sampled slot: CPU-oracle byte compare; a mismatch
-                        # quarantines the image and this slot serves the oracle
-                        fixed = self.ep.shadow_compare(
-                            slot.items[0].req, slot.shadow_snap,
-                            b"".join(bytes(p) for p in parts), "batch")
-                        if fixed is not None:
-                            data, parts = fixed, None
-                            from_device = False
-                from_cache = from_device and slot.outcome not in ("", "miss", "too_big")
-                for it in slot.items:
-                    if results[it.index] is not None:
-                        continue  # the cold-fill already answered this one
-                    r = CoprResponse(data, from_device=from_device,
-                                     from_cache=from_cache,
-                                     data_parts=parts, encode_type=enc_tp)
-                    self._stamp(r, it, kind=kind, occupancy=n_batch,
-                                waste=waste, total_s=dt / n_reqs)
-                    results[it.index] = r
+            with trace.batch_stage("sched.answer"):
+                pull_dt = time.perf_counter() - t_fin
+                # latency = this group's own host work (launch) + the blocking
+                # pull (residual device time).  The gap between launch and
+                # finalize is the NEXT group's prepare pass — double-buffered
+                # overlap, not this batch's cost; attributing it here would
+                # inflate the device-path percentiles with unrelated host work.
+                dt = (t_launched - t0) + pull_dt
+                with trace.attach(riders[0].trace_ctx), \
+                        trace.stage("copr.obs", batch=kind):
+                    self._batch_metrics(kind, n_reqs, dt, waste, n_batch=n_batch)
+                if bsp:
+                    bsp.tag(outcome="ok").finish()
+                    # each rider's trace gets a span for the shared dispatch it
+                    # rode, linked to the dispatch span's own trace
+                    for it in riders:
+                        # batch_ref was already stamped at fanin-span creation
+                        trace.remote_span(it.trace_ctx, "sched.batched",
+                                          start=t0, end=t_fin + pull_dt,
+                                          batched_into=ref, kind=kind,
+                                          occupancy=n_batch)
+                if mesh is not None:
+                    self._sharded_metrics(device_load, pull_dt)
+                # observatory profiles (docs/observatory.md): every rider the
+                # program answered records its attributed share on the batch
+                # path, with the queue wait it actually paid and the dispatch
+                # trace as its exemplar
+                obs_path = "mesh" if mesh is not None else "xregion"
+                obs_enc = getattr(pending, "obs_encoding", "plain")
+                for slot, resp in zip(live, resps):
+                    rows = slot.cache.total_rows if slot.cache is not None else 0
+                    for it in slot.items:
+                        if results[it.index] is not None:
+                            continue  # cold-fill: recorded by its handle_request
+                        self._record_obs(
+                            it, ev, obs_path, dt / n_reqs, rows=rows,
+                            encoding=obs_enc, occupancy=n_batch, waste=waste,
+                            dispatch_t=t0, resp=resp)
+                for slot, resp in zip(live, resps):
+                    # per-region chunk payloads: every rider of this slot shares
+                    # the SAME unjoined column-slab parts, so one multi-response
+                    # frame gather-writes each region's slabs once
+                    data = None
+                    from_device = True
+                    # the slot's stages land in its first rider's trace
+                    with trace.attach(slot.items[0].trace_ctx):
+                        parts, enc_tp = self.ep._encode_response(resp)
+                        if slot.shadow_snap is not None:
+                            # sampled slot: CPU-oracle byte compare; a mismatch
+                            # quarantines the image and this slot serves the oracle
+                            fixed = self.ep.shadow_compare(
+                                slot.items[0].req, slot.shadow_snap,
+                                b"".join(bytes(p) for p in parts), "batch")
+                            if fixed is not None:
+                                data, parts = fixed, None
+                                from_device = False
+                    from_cache = from_device and slot.outcome not in (
+                        "", "miss", "too_big")
+                    for it in slot.items:
+                        if results[it.index] is not None:
+                            continue  # the cold-fill already answered this one
+                        r = CoprResponse(data, from_device=from_device,
+                                         from_cache=from_cache,
+                                         data_parts=parts, encode_type=enc_tp)
+                        self._stamp(r, it, kind=kind, occupancy=n_batch,
+                                    waste=waste, total_s=dt / n_reqs)
+                        results[it.index] = r
 
         return finalize
 
@@ -1243,6 +1301,9 @@ class CoprReadScheduler:
             return
         if it.ticket is not None and not it.ticket.done.is_set():
             it.ticket.direct = True
+            # it serves itself from here: the batch's later stages are not
+            # its wait
+            trace.unshare(it.trace_ctx)
             it.ticket.hand_back()
             return
         try:
@@ -1410,10 +1471,9 @@ class CoprReadScheduler:
         for lane in LANES:
             g.set(len(self._queues[lane]), lane=lane)
 
-    def _observe_wait(self, it: _Item) -> None:
+    def _observe_wait(self, it: _Item, now: float) -> None:
         from ..util.metrics import REGISTRY
 
-        now = time.perf_counter()
         wait = now - it.enqueue_t
         REGISTRY.histogram(
             "tikv_coprocessor_sched_lane_wait_seconds",
@@ -1423,6 +1483,12 @@ class CoprReadScheduler:
         # connection's thread, to pick-up, on this one
         trace.remote_span(it.trace_ctx, "sched.wait", start=it.enqueue_t,
                           end=now, stage=True, lane=it.lane)
+        if it.behind0 is not None and trace.enabled():
+            # and split by what this thread did since the enqueue: the four
+            # parts tile the wait (trace.WaitClock, in BEHIND's order)
+            for series, part, then in zip(_WAIT_BEHIND, self._clock.read(now),
+                                          it.behind0):
+                series.inc(part - then)
         ov = getattr(self.ep, "overload", None)
         if ov is not None:
             # adaptive-controller evidence: sampled lane waits say whether

@@ -22,7 +22,10 @@
 #include <dirent.h>
 #include <fcntl.h>
 #include <sys/stat.h>
+#include <time.h>
 #include <unistd.h>
+
+#include <cerrno>
 
 #include <algorithm>
 #include <atomic>
@@ -2625,6 +2628,25 @@ int eng_mvcc_props(void* h, int cf, const uint8_t* start, uint64_t start_len,
   out[6] = max_ts;
   out[7] = max_row;
   return 0;
+}
+
+// The interpreter probe's sleep (util/trace.py, docs/tracing.md "The
+// interpreter probe"): sleep until `until_ns` on CLOCK_MONOTONIC, the clock
+// of Python's time.perf_counter_ns, and return the time at which the OS woke
+// the thread.  ctypes calls this without the interpreter's lock, so the time
+// returned is read before the thread asks for that lock again: the caller
+// splits its lateness into the OS's (wake - until) and the lock's (its own
+// clock on return - wake).
+int64_t eng_sleep_until(int64_t until_ns) {
+  timespec until;
+  until.tv_sec = until_ns / 1000000000;
+  until.tv_nsec = until_ns % 1000000000;
+  while (clock_nanosleep(CLOCK_MONOTONIC, TIMER_ABSTIME, &until, nullptr) ==
+         EINTR) {
+  }
+  timespec now;
+  clock_gettime(CLOCK_MONOTONIC, &now);
+  return int64_t(now.tv_sec) * 1000000000 + now.tv_nsec;
 }
 
 }  // extern "C"

@@ -168,6 +168,8 @@ def _load():
         lib.eng_merge_runs.restype = ctypes.c_int
         lib.eng_perf.argtypes = [ctypes.c_void_p, ctypes.POINTER(ctypes.c_uint64)]
         lib.eng_perf.restype = ctypes.c_int
+        lib.eng_sleep_until.argtypes = [ctypes.c_int64]
+        lib.eng_sleep_until.restype = ctypes.c_int64
         _lib = lib
         return _lib
 
@@ -195,6 +197,15 @@ def build_sst(path: str, entries) -> None:
 
 def native_available() -> bool:
     return _load() is not None
+
+
+def sleep_until_fn():
+    """``sleep_until(ns) -> ns``: sleep until ``ns`` on the clock of
+    ``time.perf_counter_ns`` without the interpreter's lock and return when
+    the OS woke the thread, on the same clock (``eng_sleep_until``); None
+    where the native engine cannot be built."""
+    lib = _load()
+    return None if lib is None else lib.eng_sleep_until
 
 
 def parse_frames(buf: bytes, n: int):

@@ -309,6 +309,8 @@ class StoreServer:
 
         self.ttl_checker = TtlChecker(self.storage)
         self._ttl_stop = threading.Event()
+        # whether start() asked for the interpreter probe (stop lets go)
+        self._probing = False
 
         def _ttl_loop(interval=float(os.environ.get("TIKV_TPU_TTL_SWEEP_SECS", "60"))):
             while not self._ttl_stop.wait(interval):
@@ -544,6 +546,11 @@ class StoreServer:
         # one interpreter serves every thread of the store: its collector's
         # pauses are timed from here on (docs/tracing.md, stage host.gc)
         trace.install_gc_hook()
+        # and its waits for the OS and for the interpreter's lock, with a
+        # slow-log line for every stall of the process (docs/tracing.md,
+        # "The interpreter probe")
+        trace.install_interpreter_probe(self.copr.slow_log)
+        self._probing = True
         # the store runs native engines in this interpreter: a crash inside
         # one leaves every thread's Python frames on stderr, whoever started
         # the process (docs/tracing.md)
@@ -597,6 +604,11 @@ class StoreServer:
             "tikv_server_stop_abandoned_thread_total",
             "Threads StoreServer.stop() gave up joining, by thread name",
         )
+        if self._probing:
+            from ..util import trace
+
+            trace.release_interpreter_probe(self.copr.slow_log)
+            self._probing = False
         if self.copr.scrubber is not None and not self.copr.scrubber.stop():
             abandoned.inc(thread="integrity-scrub")
         if not self.copr.scheduler.stop():

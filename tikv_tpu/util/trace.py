@@ -52,6 +52,21 @@ span commits, the stage time in its tree plus the stage time a shared
 batch spent on its behalf (``shared``) is its **attributed** time; the rest
 is what the instrumentation cannot see.
 
+Waits no stage can see
+-----------------------
+Two instruments measure the waits that fall between and across stages
+(docs/tracing.md, "The interpreter probe" and "What a rider waited behind").
+The **interpreter probe** is one daemon thread that sleeps a fixed period in
+native code and splits each wake's lateness into the OS's (wake lag) and the
+wait for the interpreter's lock (interpreter wait); a wake late by
+``STALL_S`` or more is a **stall**, blamed on the collector or on the stage
+open longest across it (each thread's open stage is kept in a table that
+``_Stage`` writes while the probe runs).  A **wait clock** splits one
+thread's time among activities that its owner names, with a classifier of
+stage names; the read scheduler's dispatcher keeps one, and each rider's
+queue wait is split by the difference of that clock between its enqueue and
+its pick-up.
+
 The tracer's lock is a LEAF by construction — span operations touch only
 tracer state, never another subsystem's lock — so spans are safe to open or
 finish while holding scheduler/cache/raft locks (the sanitizer's order graph
@@ -66,16 +81,19 @@ import random
 import threading
 import time
 from collections import deque
+from threading import get_ident
 
 from ..analysis.sanitizer import make_lock
 from .metrics import REGISTRY
 
 __all__ = [
-    "Span", "attach", "begin", "current", "current_context",
-    "current_trace_id", "enabled", "fanin_span", "inject",
-    "install_gc_hook", "record", "remote_span", "sample_rate", "set_mirror",
-    "set_sample_rate", "set_slow_threshold", "shared", "slow_threshold",
-    "snapshot", "span", "stage", "start_trace", "timed_stage", "timeline",
+    "Span", "WaitClock", "attach", "batch_stage", "begin", "bind_clock",
+    "current", "current_context", "current_trace_id", "enabled",
+    "fanin_span", "inject", "install_gc_hook", "install_interpreter_probe",
+    "record", "release_interpreter_probe", "remote_span", "sample_rate",
+    "set_mirror", "set_sample_rate", "set_slow_threshold", "shared",
+    "slow_threshold", "snapshot", "span", "stage", "start_trace",
+    "timed_stage", "timeline", "unbind_clock", "unshare",
     "TRACER",
 ]
 
@@ -87,6 +105,18 @@ MAX_LIVE = 2048
 RING = 64
 
 _CTX_KEYS = ("trace_id", "span_id", "sampled")
+
+#: the interpreter probe's sleep between two wakes (a constant: a few hundred
+#: wakes a second cost the serving threads next to nothing, and a wait for
+#: the interpreter of more than a millisecond meets several of them)
+PROBE_PERIOD_S = 0.005
+#: a wake this late (OS lag plus interpreter wait) is a stall of the process
+STALL_S = 0.25
+#: the probe drops the open-stage entries of threads that are gone once in
+#: this many wakes (~10 s)
+_PRUNE_EVERY = 2000
+_WAIT_BUCKETS = (1e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3, 0.01,
+                 0.025, 0.05, 0.1, 0.25, 1, 5)
 
 
 _TRACE_TOTAL = REGISTRY.counter(
@@ -110,6 +140,68 @@ _REQUEST_ATTRIBUTED = REGISTRY.counter(
 _GC_PAUSE = REGISTRY.counter(
     "tikv_process_gc_pause_seconds_total",
     "Time inside the interpreter's cyclic collector, by generation")
+_WAKE_LAG = REGISTRY.histogram(
+    "tikv_process_wake_lag_seconds",
+    "How late the OS woke the interpreter probe's sleep",
+    buckets=_WAIT_BUCKETS)
+_INTERPRETER_WAIT = REGISTRY.histogram(
+    "tikv_process_interpreter_wait_seconds",
+    "How long the woken interpreter probe waited for the interpreter's lock",
+    buckets=_WAIT_BUCKETS)
+_STALL_TOTAL = REGISTRY.counter(
+    "tikv_process_stall_total",
+    "Interpreter probe wakes late by STALL_S or more, by what they fell in")
+_STALL_SECONDS = REGISTRY.counter(
+    "tikv_process_stall_seconds_total",
+    "Length of the interpreter probe's stalls, by what they fell in")
+
+
+class WaitClock:
+    """One thread's own time since the clock was made, split among the
+    activities ``0 .. n - 1``.  Every moment falls in exactly one: inside a
+    stage that runs on the thread the clock is bound to
+    (:meth:`Tracer.bind_clock`), the activity ``classify(stage)`` names,
+    where it names one; else the one :meth:`switch` set last (``base`` at
+    first).  So the totals' difference between two moments sums to the time
+    between them.  The state is one tuple, replaced whole at each change, so
+    that any thread reads a consistent clock in one load."""
+
+    __slots__ = ("_state", "_classify", "_base")
+
+    def __init__(self, n: int, classify, base: int = 0):
+        self._classify = classify
+        self._base = base
+        # the totals, the activity in progress, and when it began
+        self._state = ((0.0,) * n, base, time.perf_counter())
+
+    def _to(self, kind: int, t: float) -> None:
+        totals, cur, t0 = self._state
+        totals = list(totals)
+        totals[cur] += t - t0
+        self._state = (tuple(totals), kind, t)
+
+    def switch(self, kind: int) -> None:
+        """Outside the classified stages the thread's time counts as
+        ``kind`` from now on."""
+        self._base = kind
+        self._to(kind, time.perf_counter())
+
+    def enter(self, stage: str, t: float) -> None:
+        kind = self._classify(stage)
+        if kind is not None:
+            self._to(kind, t)
+
+    def leave(self, stage: str, t: float) -> None:
+        if self._classify(stage) is not None:
+            self._to(self._base, t)
+
+    def read(self, t: float) -> list:
+        """The totals as of ``t``, the activity in progress counted up to
+        ``t``."""
+        totals, cur, t0 = self._state
+        totals = list(totals)
+        totals[cur] += t - t0
+        return totals
 
 
 _STAGE_SERIES: dict[str, tuple] = {}
@@ -195,9 +287,10 @@ class _Stage:
     totals and the mirror all take those two readings."""
 
     __slots__ = ("_tracer", "name", "tags", "seconds", "_sp", "_ann", "_t0",
-                 "_c0", "_outer")
+                 "_c0", "_outer", "_spans")
 
-    def __init__(self, tracer: "Tracer", name: str, tags: dict):
+    def __init__(self, tracer: "Tracer", name: str, tags: dict,
+                 spans: bool = True):
         self._tracer = tracer
         self.name = name
         self.tags = tags
@@ -205,6 +298,7 @@ class _Stage:
         self._sp = None
         self._ann = None
         self._outer = None
+        self._spans = spans  # False: a batch stage (Tracer.batch_stage)
 
     def __bool__(self):
         return True
@@ -216,39 +310,57 @@ class _Stage:
     def __enter__(self) -> "_Stage":
         st = self._tracer._state
         outer = getattr(st, "stage", None)
-        if outer is not None:
-            outer._close()  # suspended: stages never nest
         self._outer = outer
         st.stage = self
-        self._open()
+        # suspended: stages never nest, and the two meet at one instant
+        self._open(None if outer is None else outer._close())
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         if exc is not None and "error" not in self.tags:
             self.tags["error"] = repr(exc)
-        self._close()
+        t1 = self._close()
         outer = self._outer
         self._tracer._state.stage = outer
         if outer is not None:
-            outer._open()  # resumes as a new segment
+            outer._open(t1)  # resumes as a new segment, from that instant
         return False
 
-    def _open(self) -> None:
+    def _open(self, t0: float | None = None) -> None:
+        """``t0``: the instant the stage this one suspends or resumes after
+        closed at, so that no time falls between the two."""
         tracer = self._tracer
         mirror = tracer._mirror
         if mirror is not None:
             self._ann = mirror(self.name)
             self._ann.__enter__()
         self._c0 = time.thread_time()
-        self._t0 = t0 = time.perf_counter()
+        if t0 is None:
+            t0 = time.perf_counter()
+        self._t0 = t0
+        # the thread's open stage, while the probe may blame a stall on it;
+        # a clocked thread's activity
+        if tracer._probe is not None:
+            tracer._open_stages[get_ident()] = (self.name, t0, None)
+        if tracer._clocks:
+            clock = tracer._clocks.get(get_ident())
+            if clock is not None:
+                clock.enter(self.name, t0)
         cur = getattr(tracer._state, "cur", None)
-        if cur is not None:
+        if cur is not None and self._spans:
             self._sp = tracer._child(cur.rec, cur.span_id, self.name, None,
                                      start=t0, stage=True)
 
-    def _close(self) -> None:
+    def _close(self) -> float:
         t1 = time.perf_counter()
         cpu = time.thread_time() - self._c0
+        tracer = self._tracer
+        if tracer._probe is not None:
+            tracer._open_stages[get_ident()] = (self.name, self._t0, t1)
+        if tracer._clocks:
+            clock = tracer._clocks.get(get_ident())
+            if clock is not None:
+                clock.leave(self.name, t1)
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
             self._ann = None
@@ -267,6 +379,7 @@ class _Stage:
         if sp is not None:
             sp.tags.update(self.tags)
             sp.finish(end=t1)
+        return t1
 
 
 class _Clock:
@@ -482,11 +595,25 @@ class Tracer:
         # regions, so it takes no lock
         self._gc_pauses: deque = deque()
         self._gc_open = None
+        # the same pauses as (start, end), for the probe to blame stalls on
+        self._gc_spans: deque = deque(maxlen=64)
+        # thread id -> (stage, start, end or None) of the thread's current or
+        # last stage segment: one store a stage open and close (_Stage), kept
+        # while the probe runs and emptied when it ends
+        self._open_stages: dict[int, tuple] = {}
+        # thread id -> WaitClock of the threads that keep one (bind_clock)
+        self._clocks: dict[int, WaitClock] = {}
+        # the interpreter probe: one slow log (or None) for each store that
+        # asked for it (install_interpreter_probe, release_interpreter_probe)
+        self._probe_users: list = []
+        self._probe: threading.Thread | None = None
+        self._probe_mu = make_lock("util.trace.probe")
 
     # -- knobs (online-config controller + ctl.py trace set-sample-rate) ----
 
     def set_sample_rate(self, rate: float) -> None:
         self._rate = max(0.0, min(1.0, float(rate)))
+        self._ensure_probe()  # the probe runs while the rate is above 0
 
     def sample_rate(self) -> float:
         return self._rate
@@ -611,6 +738,18 @@ class Tracer:
             self._drain_gc()
         return _Stage(self, name, tags)
 
+    def batch_stage(self, name: str, **tags):
+        """A stage around a shared batch's own bookkeeping on the thread
+        that serves it (:meth:`shared`): totals, mirror and attribution as
+        any stage, but no span, also where a rider's trace is attached when
+        it resumes.  Its time is every rider's alike (``shared``), and each
+        of its segments would otherwise be one more span of some rider."""
+        if self._rate <= 0.0 and getattr(self._state, "cur", None) is None:
+            return NOOP
+        if self._gc_pauses:
+            self._drain_gc()
+        return _Stage(self, name, tags, spans=False)
+
     def timed_stage(self, name: str, **tags):
         """A stage whose site also reports its wall time elsewhere (a
         histogram that predates the stage): ``.seconds`` holds it after the
@@ -636,6 +775,14 @@ class Tracer:
                     recs.append(rec)
         return _Shared(self, recs)
 
+    def unshare(self, ctx: dict | None) -> None:
+        """The trace ``ctx`` names stops waiting for the shared batch of
+        this thread (its rider went back to its own thread): the batch's
+        later stages are no longer attributed time of it."""
+        recs = getattr(self._state, "shared", None)
+        if recs and ctx:
+            recs[:] = [r for r in recs if r.trace_id != ctx.get("trace_id")]
+
     # -- collector pauses ----------------------------------------------------
 
     def install_gc_hook(self) -> None:
@@ -658,10 +805,11 @@ class Tracer:
         elif self._gc_open is not None:
             t0, ann = self._gc_open
             self._gc_open = None
-            dt = time.perf_counter() - t0
+            t1 = time.perf_counter()
             if ann is not None:
                 ann.__exit__(None, None, None)
-            self._gc_pauses.append((info.get("generation"), dt))
+            self._gc_pauses.append((info.get("generation"), t1 - t0))
+            self._gc_spans.append((t0, t1))
 
     def _drain_gc(self) -> None:
         while True:
@@ -671,6 +819,109 @@ class Tracer:
                 return
             _GC_PAUSE.inc(dt, generation=str(generation))
             _stage_series("host.gc")[0].observe(dt)
+
+    # -- the interpreter probe ----------------------------------------------
+
+    def install_interpreter_probe(self, slow_log=None) -> None:
+        """Run the interpreter probe while a store that asked for it runs and
+        the sample rate is above 0 (module docstring, "Waits no stage can
+        see"): one daemon thread that sleeps ``PROBE_PERIOD_S`` at a time in
+        ``eng_sleep_until`` and observes each wake's lag and interpreter
+        wait.  ``slow_log`` (a ``copr.tracker.SlowLog``) takes one line a
+        stall, as does every other user's.  A store asks at start and lets
+        go at stop (:meth:`release_interpreter_probe`); at rate 0 no thread
+        runs."""
+        with self._probe_mu:
+            self._probe_users.append(slow_log)
+        self._ensure_probe()
+
+    def release_interpreter_probe(self, slow_log=None) -> None:
+        """The store that asked for the probe with ``slow_log`` no longer
+        runs: its log takes no more lines, and the probe's thread ends
+        within a period of the last user letting go."""
+        with self._probe_mu:
+            if slow_log in self._probe_users:
+                self._probe_users.remove(slow_log)
+
+    def _ensure_probe(self) -> None:
+        if not self._probe_users or self._rate <= 0.0 or self._probe is not None:
+            return
+        from ..native.engine import sleep_until_fn
+
+        sleep_until = sleep_until_fn()
+        if sleep_until is None:
+            return  # no native engine here: nothing can time the wake
+        with self._probe_mu:
+            if self._probe is None and self._rate > 0.0:
+                self._open_stages.clear()
+                self._probe = threading.Thread(
+                    target=self._probe_loop, args=(sleep_until,),
+                    name="interpreter-probe", daemon=True)
+                self._probe.start()
+
+    def _probe_loop(self, sleep_until) -> None:
+        period = int(PROBE_PERIOD_S * 1e9)
+        stall = int(STALL_S * 1e9)
+        lag, wait = _WAKE_LAG.labels(), _INTERPRETER_WAIT.labels()
+        clock = time.perf_counter_ns  # CLOCK_MONOTONIC, as eng_sleep_until's
+        wakes = 0
+        while True:
+            if self._rate <= 0.0 or not self._probe_users:
+                with self._probe_mu:
+                    if self._rate <= 0.0 or not self._probe_users:
+                        self._probe = None
+                        self._open_stages.clear()
+                        return
+            until = clock() + period
+            woke = sleep_until(until)  # without the interpreter's lock
+            back = clock()  # with it again
+            lag.observe((woke - until) / 1e9)
+            wait.observe((back - woke) / 1e9)
+            if back - until >= stall:
+                self._stall(until / 1e9, woke / 1e9, back / 1e9)
+            wakes += 1
+            if wakes % _PRUNE_EVERY == 0:
+                alive = {t.ident for t in threading.enumerate()}
+                for tid in list(self._open_stages):
+                    if tid not in alive:
+                        self._open_stages.pop(tid, None)
+
+    def _stall(self, start: float, woke: float, end: float) -> None:
+        """One stall, from the wake the probe asked for to its return (all
+        ``perf_counter`` seconds): counted by what it fell in, and one line
+        of the slow log with every thread's stage across it."""
+        stages = list(self._open_stages.items())
+        during = _stall_cause(start, end, list(self._gc_spans), stages)
+        _STALL_TOTAL.inc(during=during)
+        _STALL_SECONDS.inc(end - start, during=during)
+        logs = [log for log in self._probe_users if log is not None]
+        if not logs:
+            return
+        names = {t.ident: t.name for t in threading.enumerate()}
+        line = {
+            "start": round(start, 6),
+            "start_wall": round(time.time() - (time.perf_counter() - start), 6),
+            "ms": round((end - start) * 1e3, 3),
+            "wake_lag_ms": round((woke - start) * 1e3, 3),
+            "interpreter_wait_ms": round((end - woke) * 1e3, 3),
+            "during": during,
+            "threads": [
+                {"thread": names.get(tid, str(tid)), "stage": name,
+                 "opened_ms": round((t0 - start) * 1e3, 3),
+                 "closed_ms": None if t1 is None else round((t1 - start) * 1e3, 3)}
+                for tid, (name, t0, t1) in stages if t1 is None or t1 > start],
+        }
+        for log in logs:
+            log.record("process.stall", line)
+
+    # -- wait clocks -----------------------------------------------------------
+
+    def bind_clock(self, clock: WaitClock) -> None:
+        """Let the stages of the calling thread move ``clock``."""
+        self._clocks[get_ident()] = clock
+
+    def unbind_clock(self) -> None:
+        self._clocks.pop(get_ident(), None)
 
     # -- explicit handoff ----------------------------------------------------
 
@@ -883,6 +1134,24 @@ def timeline(trace: dict) -> str:
     return "\n".join(out)
 
 
+def _stall_cause(start: float, end: float, gc_spans: list,
+                 stages: list) -> str:
+    """What a stall from ``start`` to ``end`` fell in: ``host.gc`` where the
+    collector's pauses cover at least half of it, else the stage that some
+    thread had open across the largest part of it, at least half, else
+    ``none``.  ``stages`` is ``[(thread id, (stage, start, end or None))]``."""
+    half = (end - start) / 2
+    if sum(max(0.0, min(t1, end) - max(t0, start))
+           for t0, t1 in gc_spans) >= half:
+        return "host.gc"
+    during, most = "none", half
+    for _tid, (name, t0, t1) in stages:
+        cover = min(end if t1 is None else t1, end) - max(t0, start)
+        if cover >= most:
+            during, most = name, cover
+    return during
+
+
 class _Attach:
     __slots__ = ("_tracer", "_sp", "_ctx")
 
@@ -972,6 +1241,10 @@ def timed_stage(name: str, **tags):
     return TRACER.timed_stage(name, **tags)
 
 
+def batch_stage(name: str, **tags):
+    return TRACER.batch_stage(name, **tags)
+
+
 def set_mirror(factory) -> None:
     TRACER.set_mirror(factory)
 
@@ -980,8 +1253,28 @@ def shared(ctxs: list):
     return TRACER.shared(ctxs)
 
 
+def unshare(ctx: dict | None) -> None:
+    TRACER.unshare(ctx)
+
+
 def install_gc_hook() -> None:
     TRACER.install_gc_hook()
+
+
+def install_interpreter_probe(slow_log=None) -> None:
+    TRACER.install_interpreter_probe(slow_log)
+
+
+def release_interpreter_probe(slow_log=None) -> None:
+    TRACER.release_interpreter_probe(slow_log)
+
+
+def bind_clock(clock: WaitClock) -> None:
+    TRACER.bind_clock(clock)
+
+
+def unbind_clock() -> None:
+    TRACER.unbind_clock()
 
 
 def begin(name: str, **tags):
